@@ -4,11 +4,6 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))))
 
-if "--cpu" in sys.argv or os.environ.get("HETU_PLATFORM") == "cpu":
-    # must land before the first backend use (cookbook-wide flag)
-    import jax
-    jax.config.update("jax_platforms", "cpu")
-
 import hetu_tpu as ht
 
 datasets = ht.data.mnist()

@@ -17,24 +17,6 @@ Typical use (identical shape to reference examples)::
     executor.run('train', feed_dict={...})
 """
 
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    # jax < 0.5 compat: the codebase targets the stable ``jax.shard_map``
-    # API (``check_vma=`` keyword); older jaxlibs ship it as
-    # ``jax.experimental.shard_map.shard_map`` with the keyword spelled
-    # ``check_rep``.  Install an adapter so one spelling works everywhere.
-    from jax.experimental.shard_map import shard_map as _esm
-
-    def _shard_map_compat(f, mesh=None, in_specs=None, out_specs=None,
-                          check_vma=None, **kw):
-        if check_vma is not None and "check_rep" not in kw:
-            kw["check_rep"] = check_vma
-        return _esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    **kw)
-
-    _jax.shard_map = _shard_map_compat
-
 from . import initializers as init
 from . import optim
 from .optim import lr_scheduler as lr  # reference alias: ht.lr.StepScheduler

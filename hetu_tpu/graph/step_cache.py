@@ -25,8 +25,9 @@ cache; entries are LRU-bounded (``HETU_STEP_CACHE_MAX``, default 8)
 because of that same executor pinning.
 
 Cross-process reuse (the supervisor's post-restart resume) rides jax's
-persistent compilation cache instead: set ``HETU_COMPILE_CACHE_DIR`` (the
-launcher defaults it under ``--ckpt-dir``) and the byte-identical HLO a
+persistent compilation cache instead (``JAX_COMPILATION_CACHE_DIR``, or
+``<checkout>/.jax_cache`` when the environment names none — see
+``graph.executor.configure_compile_cache``): the byte-identical HLO a
 canonical-key rebuild produces becomes a disk cache hit.
 """
 from __future__ import annotations
@@ -279,8 +280,8 @@ def serve_signature(iex, bucket):
     rebuilt :class:`~hetu_tpu.serving.InferenceExecutor` over a
     structurally identical graph reuses the compiled executable per
     bucket instead of retracing (the serving analogue of the training
-    step cache; restart reuse across processes rides
-    ``HETU_COMPILE_CACHE_DIR`` exactly like training).
+    step cache; restart reuse across processes rides jax's persistent
+    compilation cache exactly like training).
 
     ``bucket``: the padded batch bucket (int), or a tuple for the
     autoregressive-decode plane — a (batch_bucket, len_bucket) pair for

@@ -686,7 +686,7 @@ def reset_decode_recovery_counts():
 
 # --------------------------------------------- serving rejection reasons
 # ISSUE 17: every :class:`ServeRejected` now carries a structured
-# ``reason`` from the closed taxonomy ``queue_full | over_max_len |
+# ``reason`` from the closed vocabulary ``queue_full | over_max_len |
 # deadline | shed:<class> | draining``, and every raise site counts it
 # here keyed BY that reason — bench artifacts and tests read this family
 # instead of string-matching exception text.  The legacy ``serve`` /
@@ -701,7 +701,7 @@ _serve_reject = REGISTRY.counter_family(
 
 def record_serve_rejection(reason, n=1):
     """Count ``n`` rejections with structured ``reason`` (one of the
-    ``ServeRejected.REASONS`` taxonomy, e.g. ``shed:best_effort``)."""
+    ``ServeRejected.REASONS`` vocabulary, e.g. ``shed:best_effort``)."""
     if n:
         _serve_reject.inc(str(reason), int(n))
 

@@ -123,8 +123,10 @@ def graph_flops(fetches, feeds=None, train=True):
     return (3.0 if train else 1.0) * float(spec.fwd_flops)
 
 
-#: bf16 peak FLOP/s per chip by device_kind prefix (public TPU spec
-#: sheets), most-specific prefix first.  THE one table — ``bench.py``
+#: bf16 peak FLOP/s per chip by device_kind prefix, most-specific prefix
+#: first.  Source: Google Cloud TPU documentation, the "System
+#: architecture" page of each generation (v5e: 197 TFLOP/s bf16, 16 GB HBM
+#: at 819 GB/s; v5p: 459; v4: 275; v3: 123; v2: 46; v6e/Trillium: 918).  THE one table — ``bench.py``
 #: and ``autoparallel.measure`` both resolve through
 #: :func:`device_peak_flops`, so a new device kind lands here once.
 TPU_PEAK_BY_KIND = (
@@ -135,10 +137,13 @@ TPU_PEAK_BY_KIND = (
 
 
 def device_peak_flops():
-    """(peak_flops_per_chip, device_kind).  Unknown TPU kinds get the
-    most conservative (smallest) table entry so MFU cannot be inflated
-    by a lookup miss; non-TPU backends get a nominal 50 TF placeholder
-    (their MFU is a relative gauge, never the headline number)."""
+    """(peak_flops_per_chip, device_kind).  A TPU ``device_kind`` that is
+    not in :data:`TPU_PEAK_BY_KIND` is an error — a utilisation against a
+    guessed peak is not a measurement; add the kind and its source to the
+    table.  Non-TPU backends get a nominal 50 TF placeholder: their MFU
+    is a relative CPU-side gauge, and nothing that reports a device
+    metric reaches this branch (the accelerator bench configs and
+    ``chip_smoke.py`` refuse a non-TPU backend first)."""
     import jax
     kind = jax.devices()[0].device_kind
     if jax.default_backend() != "tpu":
@@ -146,7 +151,9 @@ def device_peak_flops():
     for prefix, peak in TPU_PEAK_BY_KIND:
         if str(kind).startswith(prefix):
             return peak, kind
-    return min(p for _, p in TPU_PEAK_BY_KIND), kind
+    raise ValueError(
+        f"no peak FLOP/s known for TPU device_kind {kind!r}: add it to "
+        f"hetu_tpu.obs.TPU_PEAK_BY_KIND with its source")
 
 
 def record_mfu(label, flops_per_step, step_time_s, peak_flops):

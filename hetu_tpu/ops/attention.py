@@ -185,8 +185,52 @@ def dispatch_sdpa(q, k, v, causal=False, scale=None):
     return sdpa_reference(q, k, v, causal=causal, scale=scale)
 
 
+def _partitioned(c, fn, q, k, v, *extras):
+    """``fn(q, k, v, *extras)`` — a ``dispatch_*`` entry — under the
+    executor's mesh.  XLA's SPMD partitioner refuses a Mosaic kernel it
+    meets in a multi-device program ("cannot be automatically
+    partitioned"), so on TPU the graph-level attention ops partition
+    themselves: a ``shard_map`` with the batch dim over ``dp`` and the
+    head dim over ``tp`` (where the mesh has them and they divide), every
+    other axis replicated.  Attention is independent per (batch, head), so
+    this is the partition GSPMD would have to pick anyway.  Masks, biases,
+    lengths and positions follow their batch / head dims; broadcast (size
+    1) dims stay replicated.  Inside an enclosing ``shard_map`` (ring /
+    Ulysses steps, pipeline stages) the call is already manual and runs
+    as is."""
+    mesh = getattr(c, "mesh", None)
+    if (mesh is None or mesh.size == 1 or jax.default_backend() != "tpu"
+            or jax.sharding.get_abstract_mesh().manual_axes):
+        return fn(q, k, v, *extras)
+    from jax.sharding import PartitionSpec as P
+    b, h = q.shape[:2]
+
+    def axis(name, n):
+        ok = name in mesh.axis_names and mesh.shape[name] > 1 \
+            and n % mesh.shape[name] == 0
+        return name if ok else None
+
+    dp, tp = axis("dp", b), axis("tp", h)
+
+    def spec(x):
+        dims = [None] * x.ndim
+        if x.shape[0] == b:
+            dims[0] = dp
+        if x.ndim == 4 and x.shape[1] == h:
+            dims[1] = tp
+        return P(*dims)
+
+    args = (q, k, v) + extras
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=tuple(spec(x) for x in args),
+                         out_specs=P(dp, tp, None, None),
+                         check_vma=False)(*args)
+
+
 def _sdpa(c, q, k, v, causal=False, scale=None):
-    return dispatch_sdpa(q, k, v, causal=causal, scale=scale)
+    return _partitioned(
+        c, lambda q, k, v: dispatch_sdpa(q, k, v, causal=causal,
+                                         scale=scale), q, k, v)
 
 
 sdpa_op = def_op("ScaledDotProductAttention", _sdpa)
@@ -253,7 +297,9 @@ def dispatch_sdpa_masked(q, k, v, mask, causal=False, scale=None):
 
 
 def _sdpa_masked(c, q, k, v, mask, causal=False, scale=None):
-    return dispatch_sdpa_masked(q, k, v, mask, causal=causal, scale=scale)
+    return _partitioned(
+        c, lambda q, k, v, mask: dispatch_sdpa_masked(
+            q, k, v, mask, causal=causal, scale=scale), q, k, v, mask)
 
 
 sdpa_masked_op = def_op("ScaledDotProductAttentionMasked", _sdpa_masked)
@@ -274,7 +320,9 @@ def dispatch_sdpa_bias(q, k, v, bias, causal=False, scale=None):
 
 def _sdpa_bias(c, q, k, v, bias, causal=False, scale=None):
     """Attention with an additive logit bias (T5 relative position bias)."""
-    return dispatch_sdpa_bias(q, k, v, bias, causal=causal, scale=scale)
+    return _partitioned(
+        c, lambda q, k, v, bias: dispatch_sdpa_bias(
+            q, k, v, bias, causal=causal, scale=scale), q, k, v, bias)
 
 
 sdpa_bias_op = def_op("ScaledDotProductAttentionBias", _sdpa_bias)
@@ -299,8 +347,10 @@ def dispatch_sdpa_masked_bias(q, k, v, mask, bias, causal=False,
 
 def _sdpa_masked_bias(c, q, k, v, mask, bias, causal=False, scale=None):
     """Masked attention with an additive bias (XLNet two-stream layers)."""
-    return dispatch_sdpa_masked_bias(q, k, v, mask, bias, causal=causal,
-                                     scale=scale)
+    return _partitioned(
+        c, lambda q, k, v, mask, bias: dispatch_sdpa_masked_bias(
+            q, k, v, mask, bias, causal=causal, scale=scale),
+        q, k, v, mask, bias)
 
 
 sdpa_masked_bias_op = def_op("ScaledDotProductAttentionMaskedBias",
@@ -313,15 +363,18 @@ def _sdpa_varlen(c, q, k, v, lengths, causal=False, scale=None):
     TPU → the Pallas flash kernel's lengths path (no FLOPs spent on
     fully-masked key blocks; ragged shapes bucket); otherwise the jnp
     reference with a built column mask."""
-    if _use_flash(q, k) and _causal_bucketable(q, k, causal):
-        from .pallas.flash_attention import flash_attention
-        return flash_attention(q, k, v, causal=causal, scale=scale,
-                               lengths=lengths)
-    _note_flash_fallback(_gate_reason(q, k, causal) or "dispatch_gate")
-    s_kv = k.shape[-2]
-    cols = jnp.arange(s_kv)[None, None, None, :]
-    mask = cols < lengths.astype(jnp.int32)[:, None, None, None]
-    return sdpa_reference(q, k, v, causal=causal, scale=scale, mask=mask)
+    def local(q, k, v, lengths):
+        if _use_flash(q, k) and _causal_bucketable(q, k, causal):
+            from .pallas.flash_attention import flash_attention
+            return flash_attention(q, k, v, causal=causal, scale=scale,
+                                   lengths=lengths)
+        _note_flash_fallback(_gate_reason(q, k, causal) or "dispatch_gate")
+        s_kv = k.shape[-2]
+        cols = jnp.arange(s_kv)[None, None, None, :]
+        mask = cols < lengths.astype(jnp.int32)[:, None, None, None]
+        return sdpa_reference(q, k, v, causal=causal, scale=scale,
+                              mask=mask)
+    return _partitioned(c, local, q, k, v, lengths)
 
 
 sdpa_varlen_op = def_op("ScaledDotProductAttentionVarlen", _sdpa_varlen)
@@ -370,8 +423,10 @@ def dispatch_sdpa_decode(q, k_cache, v_cache, positions, scale=None):
 
 
 def _sdpa_decode(c, q, k_cache, v_cache, positions, scale=None):
-    return dispatch_sdpa_decode(q, k_cache, v_cache, positions,
-                                scale=scale)
+    return _partitioned(
+        c, lambda q, k, v, pos: dispatch_sdpa_decode(q, k, v, pos,
+                                                     scale=scale),
+        q, k_cache, v_cache, positions)
 
 
 sdpa_decode_op = def_op("ScaledDotProductAttentionDecode", _sdpa_decode)
@@ -464,8 +519,10 @@ def dispatch_sdpa_prefill(q, k_cache, v_cache, positions, scale=None):
 
 
 def _sdpa_prefill(c, q, k_cache, v_cache, positions, scale=None):
-    return dispatch_sdpa_prefill(q, k_cache, v_cache, positions,
-                                 scale=scale)
+    return _partitioned(
+        c, lambda q, k, v, pos: dispatch_sdpa_prefill(q, k, v, pos,
+                                                      scale=scale),
+        q, k_cache, v_cache, positions)
 
 
 sdpa_prefill_op = def_op("ScaledDotProductAttentionPrefill", _sdpa_prefill)

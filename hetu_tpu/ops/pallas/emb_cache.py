@@ -6,13 +6,13 @@ host-side — but the *math* of the hot path used to be host numpy too:
 every cached row rode host→device each step, and the grad segment-sum
 came back through a scipy-CSR host pass.  This module moves the math
 onto the chip over a device-resident ``(limit + scratch + 1, width)``
-float32 slab:
+float32 slab (``width`` rounded up to the chip's 128-lane tile,
+:func:`slab_width`):
 
 * :func:`gather_rows` — Pallas gather by slot index: per-row async DMA
   from the HBM slab into the output block (the rows of one block are
-  all in flight before the first wait — the ``moe_dispatch.row_gather``
-  discipline, re-specialized for the always-valid slot indices the
-  cache hands out).
+  all in flight before the first wait — ``moe_dispatch.row_gather``,
+  with the always-valid slot indices the cache hands out).
 * :func:`scatter_add_grads` — the training-path grad reduction:
   device-side sort by the batch's unique-inverse map + the existing
   :func:`~hetu_tpu.ops.pallas.segment_sum.sorted_segment_sum` MXU
@@ -33,14 +33,12 @@ surfaced by ``HetuProfiler.emb_pallas_fallbacks()``); never silent.
 ``HETU_REQUIRE_PALLAS_EMB=1`` escalates any fallback to a hard failure
 so a TPU run cannot quietly train off the kernel path.
 """
-import functools
 import os
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
+from .moe_dispatch import LANES, row_gather
 from .segment_sum import sorted_segment_sum
 
 #: slot indices handled per grid step — each row is one async DMA, so a
@@ -68,44 +66,24 @@ def _note_fallback(reason):
 
 
 # ----------------------------------------------------------------- gather
-def _gather_kernel(slots_ref, slab_ref, out_ref, sems, *, block):
-    b = pl.program_id(0)
-    for i in range(block):
-        row = slots_ref[b * block + i]
-        pltpu.make_async_copy(slab_ref.at[row], out_ref.at[i],
-                              sems.at[i]).start()
-    for i in range(block):
-        row = slots_ref[b * block + i]
-        pltpu.make_async_copy(slab_ref.at[row], out_ref.at[i],
-                              sems.at[i]).wait()
+def slab_width(width):
+    """Lane-aligned row width the cache allocates its device slab at.
+    :func:`gather_rows` moves whole 128-lane rows; a slab already at
+    this width is gathered in place, any other is padded (one copy of
+    the slab) on every call."""
+    return -(-width // LANES) * LANES
 
 
 def gather_rows(slab, slots, block=ROW_BLOCK, interpret=False):
-    """``out[i] = slab[slots[i]]`` — Pallas per-row async DMA gather.
+    """``out[i] = slab[slots[i]]`` — Pallas per-row async DMA gather
+    (:func:`~hetu_tpu.ops.pallas.moe_dispatch.row_gather`).
 
     ``slots`` (n,) int must all be valid slab rows (the cache's slot
     plan guarantees it: hits gather their committed slot, misses were
     filled first, overflow keys gather their scratch row)."""
-    n = slots.shape[0]
-    w = slab.shape[1]
-    if n == 0:
-        return jnp.zeros((0, w), slab.dtype)
-    n_pad = -(-n // block) * block
-    slots_p = jnp.zeros((n_pad,), jnp.int32).at[:n].set(
-        slots.astype(jnp.int32))
-    out = pl.pallas_call(
-        functools.partial(_gather_kernel, block=block),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(n_pad // block,),
-            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((block, w), lambda g, *_: (g, 0)),
-            scratch_shapes=[pltpu.SemaphoreType.DMA((block,))],
-        ),
-        out_shape=jax.ShapeDtypeStruct((n_pad, w), slab.dtype),
-        interpret=interpret,
-    )(slots_p, slab)
-    return out[:n]
+    if slots.shape[0] == 0:
+        return jnp.zeros((0, slab.shape[1]), slab.dtype)
+    return row_gather(slab, slots, block=block, interpret=interpret)
 
 
 # ------------------------------------------------------------ scatter-add
@@ -207,14 +185,16 @@ def emb_gather(slab, slots, interpret=None):
 _GATHER_JIT = {}
 
 
-def gather_for_step(slab, slots, interpret=None):
-    """The executor's per-step gather: ``emb_gather`` under a cached
+def gather_for_step(slab, slots, width, interpret=None):
+    """The executor's per-step gather: ``emb_gather`` (cut back to the
+    table's ``width`` — the slab is lane-padded) under a cached
     ``jax.jit`` so steady-state steps replay a compiled executable and
     the fallback counter keeps flash per-trace semantics."""
-    fn = _GATHER_JIT.get(interpret)
+    fn = _GATHER_JIT.get((interpret, width))
     if fn is None:
-        fn = _GATHER_JIT[interpret] = jax.jit(
-            functools.partial(emb_gather, interpret=interpret))
+        fn = _GATHER_JIT[(interpret, width)] = jax.jit(
+            lambda slab, slots: emb_gather(
+                slab, slots, interpret=interpret)[:, :width])
     return fn(slab, slots)
 
 

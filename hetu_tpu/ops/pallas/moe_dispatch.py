@@ -8,7 +8,8 @@ both layout transforms with index maps + a single Pallas primitive:
     row_gather(src, idx)[i] = src[idx[i]]   (zeros where idx < 0)
 
 implemented as per-row async DMA from HBM (the rows of one block are all
-in flight before the first wait).  Both directions of both transforms are
+in flight before the first wait) over a view of the source in which one
+row is a whole number of HBM tiles (:func:`_to_lane_rows`).  Both directions of both transforms are
 gathers given the forward (slot→token) and inverse (token→slot) maps, so
 no scatter is ever emitted:
 
@@ -32,6 +33,44 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 ROW_BLOCK = 32
+#: the chip moves HBM in whole (sublane, 128-lane) tiles of 32-bit words
+LANES = 128
+
+
+def _to_lane_rows(src):
+    """``(N, m)`` rows → ``(N, 1, W)`` 32-bit words, ``W % 128 == 0``.
+
+    The chip's compiler refuses a DMA of one row out of a 2-D HBM array:
+    a ``(N, m)`` array is tiled ``(8, 128)`` (``(16, 128)`` for 16-bit),
+    so a single row is a fraction of a tile.  With a unit second-minor
+    dimension the array is tiled ``(1, 128)`` instead and one logical
+    row is a whole number of tiles.  Narrower dtypes are bit-packed into
+    uint32 words first (a 16-bit row would still share its tile with its
+    neighbour) and the row is zero-padded to the lane width.  For a
+    32-bit source whose width is 128 the view is free; otherwise XLA
+    emits one relayout pass over ``src``."""
+    size = src.dtype.itemsize
+    if size > 4:
+        raise ValueError(
+            f"row_gather: {src.dtype} rows are wider than the 32-bit words "
+            f"the kernel moves")
+    per = 4 // size
+    pad = -src.shape[1] % (per * LANES)
+    if pad:
+        src = jnp.pad(src, ((0, 0), (0, pad)))
+    if per > 1:
+        src = jax.lax.bitcast_convert_type(
+            src.reshape(src.shape[0], -1, per), jnp.uint32)
+    return src[:, None, :]
+
+
+def _from_lane_rows(out, dtype, m):
+    """Inverse of :func:`_to_lane_rows` for the gathered rows."""
+    out = out[:, 0, :]
+    if out.dtype != dtype:
+        out = jax.lax.bitcast_convert_type(out, dtype)
+        out = out.reshape(out.shape[0], -1)
+    return out[:, :m]
 
 
 def _gather_kernel(idx_ref, src_ref, out_ref, sems, *, block):
@@ -46,7 +85,7 @@ def _gather_kernel(idx_ref, src_ref, out_ref, sems, *, block):
 
         @pl.when(row < 0)
         def _zero(i=i):
-            out_ref[i, :] = jnp.zeros((out_ref.shape[1],), out_ref.dtype)
+            out_ref[i] = jnp.zeros(out_ref.shape[1:], out_ref.dtype)
 
     for i in range(block):
         row = idx_ref[b * block + i]
@@ -62,6 +101,8 @@ def row_gather(src, idx, block=ROW_BLOCK, interpret=False):
     callers wire their own VJP from the inverse index map."""
     n = idx.shape[0]
     m = src.shape[1]
+    rows = _to_lane_rows(src)
+    w = rows.shape[2]
     n_pad = -(-n // block) * block
     idx_p = jnp.full((n_pad,), -1, jnp.int32).at[:n].set(idx.astype(jnp.int32))
     out = pl.pallas_call(
@@ -70,13 +111,13 @@ def row_gather(src, idx, block=ROW_BLOCK, interpret=False):
             num_scalar_prefetch=1,
             grid=(n_pad // block,),
             in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((block, m), lambda g, *_: (g, 0)),
+            out_specs=pl.BlockSpec((block, 1, w), lambda g, *_: (g, 0, 0)),
             scratch_shapes=[pltpu.SemaphoreType.DMA((block,))],
         ),
-        out_shape=jax.ShapeDtypeStruct((n_pad, m), src.dtype),
+        out_shape=jax.ShapeDtypeStruct((n_pad, 1, w), rows.dtype),
         interpret=interpret,
-    )(idx_p, src)
-    return out[:n]
+    )(idx_p, rows)
+    return _from_lane_rows(out[:n], src.dtype, m)
 
 
 def _f0(x):

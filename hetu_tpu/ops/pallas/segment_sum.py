@@ -31,8 +31,12 @@ def _seg_kernel(seg_ref, rows_ref, out_ref, partial, carry_row, carry_seg,
     local = seg - seg_first                            # (bt, 1)
     cols = jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
     onehot = (local == cols).astype(jnp.float32)       # (bt, W=bt)
+    # HIGHEST: at default precision the MXU rounds the f32 rows to one
+    # bf16 pass (1.5e-3 relative error per summed gradient, measured on a
+    # v5e) — the indicator is exact in bf16, the rows are not
     partial[:] = jax.lax.dot_general(
         onehot, rows_ref[:].astype(jnp.float32), (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)            # (W, d)
 
     @pl.when((b > 0) & (seg_first == carry_seg[0]))
@@ -52,45 +56,55 @@ def _seg_kernel(seg_ref, rows_ref, out_ref, partial, carry_row, carry_seg,
     cp.wait()
 
 
+#: the window DMA moves whole 128-lane tiles, and only an array exactly
+#: one lane tile wide is laid out so that a window may start at any row
+LANES = 128
+
+
 def sorted_segment_sum(rows, seg_ids, num_segments, block=128,
                        interpret=False):
     """Sum ``rows`` (n, d) over sorted, contiguous ``seg_ids`` (n,) int32.
 
     ``seg_ids`` MUST be non-decreasing starting at 0 (sort upstream).
-    Returns (num_segments, d) float32.
-    """
+    Returns (num_segments, d) float32.  ``d`` is zero-padded to the lane
+    width and reduced one 128-lane column panel per kernel call."""
     n, d = rows.shape
     n_pad = -(-n // block) * block
+    d_pad = -(-d // LANES) * LANES
     if n_pad != n:
         last = seg_ids[-1]
         seg_ids = jnp.concatenate(
             [seg_ids, jnp.full((n_pad - n,), last, jnp.int32)])
-        rows = jnp.concatenate(
-            [rows, jnp.zeros((n_pad - n, d), rows.dtype)])
+    if (n_pad, d_pad) != (n, d):
+        rows = jnp.pad(rows, ((0, n_pad - n), (0, d_pad - d)))
     num_blocks = n_pad // block
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_seg_kernel, block=block, num_blocks=num_blocks),
         grid=(num_blocks,),
         in_specs=[
             pl.BlockSpec((block, 1), lambda b: (b, 0)),
-            pl.BlockSpec((block, d), lambda b: (b, 0)),
+            pl.BlockSpec((block, LANES), lambda b: (b, 0)),
         ],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        out_shape=jax.ShapeDtypeStruct((num_segments + block, d),
+        out_shape=jax.ShapeDtypeStruct((num_segments + block, LANES),
                                        jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((block, d), jnp.float32),    # window partials
-            pltpu.VMEM((1, d), jnp.float32),        # carry row
-            pltpu.SMEM((1,), jnp.int32),            # carry segment id
+            pltpu.VMEM((block, LANES), jnp.float32),    # window partials
+            pltpu.VMEM((1, LANES), jnp.float32),        # carry row
+            pltpu.SMEM((1,), jnp.int32),                # carry segment id
             pltpu.SemaphoreType.DMA,
         ],
         interpret=interpret,
-    )(seg_ids.astype(jnp.int32)[:, None], rows)
+    )
+    seg_col = seg_ids.astype(jnp.int32)[:, None]
+    panels = [call(seg_col, rows[:, c:c + LANES])
+              for c in range(0, d_pad, LANES)]
+    out = panels[0] if len(panels) == 1 else jnp.concatenate(panels, axis=1)
     # rows past the last actual segment are uninitialised HBM (blocks only
     # DMA their own windows) — zero them so the padding contract holds
     n_actual = seg_ids[-1] + 1
     valid = jnp.arange(num_segments)[:, None] < n_actual
-    return jnp.where(valid, out[:num_segments], 0.0)
+    return jnp.where(valid, out[:num_segments, :d], 0.0)
 
 
 def dedup_rows(ids, rows, interpret=False):

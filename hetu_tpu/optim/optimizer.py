@@ -216,8 +216,15 @@ class AdamOptimizer(Optimizer):
                     new_vmax[k] = state["vmax"][k]
                 continue
             g = self._reg(p, grads[k])
-            m = self.beta1 * state["m"][k] + (1 - self.beta1) * g
-            v = self.beta2 * state["v"][k] + (1 - self.beta2) * g * g
+            # lerp form (m + (1-b1)(g - m)), not b1*m + (1-b1)*g: a sum of
+            # TWO products lets the backend contract either one into the
+            # FMA, and XLA:CPU picks differently in a loop's vector body
+            # and its scalar tail — so the same element rounded
+            # differently once ZeRO's slab layout moved it (the 1-ulp
+            # drift of ROADMAP D0).  One product per add leaves no choice.
+            m0, v0 = state["m"][k], state["v"][k]
+            m = m0 + (1 - self.beta1) * (g - m0)
+            v = v0 + (1 - self.beta2) * (g * g - v0)
             new_m[k], new_v[k] = m, v
             vhat = v / bc2
             if self.amsgrad:

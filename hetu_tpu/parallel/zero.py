@@ -32,14 +32,32 @@ Stages (``Executor(zero=...)`` / ``HETU_ZERO``):
   params happens at the top of step N+1 where it overlaps forward
   compute.  Param memory between steps drops to 1/dp as well.
 
-Bitwise discipline (load-bearing — the parity tests assert EXACT equality
-with the replicated path): the WHOLE update chain (moment updates, the
-``p - lr*upd`` axpy) must be computed under the slab sharding before
-anything is gathered.  If the final subtract is left outside the sharded
-region, the partitioner gathers ``p`` and ``lr*upd`` separately and the
-mul+sub lands in two fusions — losing the FMA contraction the replicated
-program gets, a 1-ulp drift that compounds over steps.  Hence every
-intermediate below is explicitly re-constrained to the slab spec.
+Bitwise discipline (the parity tests assert EXACT equality with the
+replicated path for sgd / adam / adamw, stages 1-3): two things hold it.
+
+* The WHOLE update chain (moment updates, the ``p - lr*upd`` axpy) is
+  computed under the slab sharding before anything is gathered.  If the
+  final subtract is left outside the sharded region, the partitioner
+  gathers ``p`` and ``lr*upd`` separately and the mul+sub lands in two
+  fusions — losing the FMA contraction the replicated program gets.
+  Hence every intermediate below is explicitly re-constrained to the slab
+  spec.
+* The update arithmetic itself leaves the backend no choice of
+  contraction.  The slab layout moves elements between a loop's vector
+  body and its scalar tail, and XLA:CPU contracts ``a*b + c*d`` into an
+  FMA around a different product in the two (the optimised HLO of the
+  sharded and the replicated Adam update is op-for-op identical; only the
+  emitted loops differ).  That was the 1-ulp drift of ROADMAP D0; Adam's
+  moments are therefore written in lerp form, one product per add
+  (``optim/optimizer.py``).  An optimizer whose update still sums two
+  products (Momentum's ``mu*v - lr*g``) is NOT covered by the bitwise
+  promise: expect last-ulp differences per step there.
+
+Bitwise equality is promised where the compiled arithmetic is the same —
+sharded vs replicated update as above, sync vs async stepping, replication
+on vs off.  Across a change of reduction order (dp=N vs one device: the
+psum of per-shard sums) the guarantee is the dp parity gate, rtol 2e-4 on
+the loss (``tests/test_parallel.py``), not equality.
 """
 from __future__ import annotations
 

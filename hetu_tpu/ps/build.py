@@ -14,43 +14,55 @@ from ..obs.lock_witness import make_lock as _make_lock
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "native", "ps_store.cc")
-_SO = os.path.join(_HERE, "native", "libhetu_ps.so")
 
 _lock = _make_lock("ps.build._lock")
 _lib = None
 
 
-def _compile():
+def _so_path():
+    """The library's path carries a hash of the source it was built from:
+    a stale ``.so`` (left by an older checkout, or carried along when the
+    tree is copied to another machine, where mtimes mean nothing) has
+    another name and is never loaded."""
+    import hashlib
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_HERE, "native", f"libhetu_ps.{digest}.so")
+
+
+def _compile(so):
     """Compile to a temp name then atomically rename, under a cross-process
     file lock, so concurrent importers never dlopen a half-written .so."""
     import fcntl
-    lock_path = _SO + ".lock"
+    lock_path = so + ".lock"
     with open(lock_path, "w") as lk:
         fcntl.flock(lk, fcntl.LOCK_EX)
         try:
-            if (os.path.exists(_SO)
-                    and os.path.getmtime(_SO) >= os.path.getmtime(_SRC)):
+            if os.path.exists(so):
                 return  # another process built it while we waited
-            tmp = f"{_SO}.tmp.{os.getpid()}"
+            tmp = f"{so}.tmp.{os.getpid()}"
             cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
                    "-pthread", _SRC, "-o", tmp]
             subprocess.run(cmd, check=True, capture_output=True)
-            os.rename(tmp, _SO)
+            os.rename(tmp, so)
         finally:
             fcntl.flock(lk, fcntl.LOCK_UN)
 
 
 def get_lib():
-    """Load (building if stale) the native library; None if unavailable."""
+    """Load (building it from the committed source if this source was
+    never built here) the native library; None — with a warning — if it
+    cannot be built.  Callers that must not run on the numpy store check
+    :func:`hetu_tpu.ps.store_kind`."""
     global _lib
     with _lock:
         if _lib is not None:
             return _lib
         try:
-            if (not os.path.exists(_SO)
-                    or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-                _compile()
-            lib = ctypes.CDLL(_SO)
+            so = _so_path()
+            if not os.path.exists(so):
+                _compile(so)
+            lib = ctypes.CDLL(so)
         except (OSError, subprocess.CalledProcessError) as e:
             import warnings
             warnings.warn(f"hetu_tpu.ps: native core unavailable ({e}); "
@@ -96,3 +108,11 @@ def get_lib():
             fn.argtypes = args
         _lib = lib
         return _lib
+
+
+def store_kind():
+    """Which store serves embedding tables in this process: ``"native"``
+    (the C++ core) or ``"numpy"`` (the slow fallback ``get_lib`` warned
+    about).  A measurement says which one it ran on."""
+    return "native" if get_lib() is not None else "numpy"
+

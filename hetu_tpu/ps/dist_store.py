@@ -2707,13 +2707,16 @@ class DistCacheTable:
     # -- device-resident mode (ISSUE 11; see class docstring) --------------
     def _ensure_dev_slab(self):
         """The device row slab: ``limit`` cache slots + ``device_scratch``
-        overflow rows + one dump row for fill padding.  Built lazily so
-        a host-mode table never touches jax."""
+        overflow rows + one dump row for fill padding, each row
+        ``slab_width(width)`` wide (the gather kernel moves whole
+        128-lane rows; readers slice back to ``width``).  Built lazily
+        so a host-mode table never touches jax."""
         if self._dev_slab is None:
             import jax.numpy as jnp
+            from ..ops.pallas.emb_cache import slab_width
             self._dev_slab = jnp.zeros(
-                (self.limit + self._dev_scratch + 1, self.width),
-                jnp.float32)
+                (self.limit + self._dev_scratch + 1,
+                 slab_width(self.width)), jnp.float32)
         return self._dev_slab
 
     def begin_lookup(self, keys):
@@ -2882,14 +2885,15 @@ class DistCacheTable:
         m = int(rows.shape[0])
         bucket = _emb.fill_bucket(m)
         # np.empty: padding rows are garbage by design — their targets
-        # all point at the dump row, which is never gathered
-        fr = np.empty((bucket, self.width), np.float32)
+        # all point at the dump row, which is never gathered (and every
+        # reader cuts the lanes past ``width`` away)
+        slab = self._ensure_dev_slab()
+        fr = np.empty((bucket, slab.shape[1]), np.float32)
         ft = np.full((bucket,), self._dev_dump, np.int32)
-        fr[:m] = rows
+        fr[:m, :self.width] = rows
         ft[:m] = targets
         self._dev_slab = _emb.fill_rows_inplace(
-            self._ensure_dev_slab(), jax.device_put(fr),
-            jax.device_put(ft))
+            slab, jax.device_put(fr), jax.device_put(ft))
 
     def abort_lookup(self, h):
         """Release a :meth:`begin_lookup` handle after a failed round
@@ -2930,7 +2934,8 @@ class DistCacheTable:
                                   jnp.asarray(h.positions[h.inv]
                                               .astype(np.int32)),
                                   interpret=self.device_interpret)
-            return np.asarray(out).reshape(keys.shape + (self.width,))
+            return np.asarray(out[:, :self.width]).reshape(
+                keys.shape + (self.width,))
         finally:
             self._lock.release()
 

@@ -430,6 +430,9 @@ class DecodeEngine:
         self.positions = np.zeros(self.bb, np.int32)
         self.caches = {name: self._alloc(self.bb, self.lb)
                        for name in self.cache_names}
+        #: host copy of the last step's (batch_bucket, vocab) logits, None
+        #: when no row read them — what a parity check compares
+        self.last_logits = None
         self._note_kv_bytes()
 
     # -- memory ------------------------------------------------------------
@@ -784,6 +787,7 @@ class DecodeEngine:
         else:
             logits = None
             record_decode("decode_logits_skipped")
+        self.last_logits = logits
         for name, new in zip(self.cache_names, outs[1:]):
             self.caches[name] = new
         record_decode("decode_steps")
@@ -853,6 +857,7 @@ class DecodeEngine:
         else:
             logits = None
             record_decode("decode_logits_skipped")
+        self.last_logits = logits
         for name, new in zip(self.cache_names, outs[1:]):
             self.caches[name] = new
         record_decode("decode_steps")

@@ -19,8 +19,8 @@ session/run loop, split out (the shared forward lowering lives in
   executor over a structurally identical graph — a supervisor-driven
   reconstruction, a bench re-run — reuses the compiled executable
   instead of retracing; restart reuse across processes rides jax's
-  persistent compilation cache (``HETU_COMPILE_CACHE_DIR``) exactly like
-  training.
+  persistent compilation cache (``graph.executor.
+  configure_compile_cache``) exactly like training.
 
 * **Read-only weights.**  Parameters load once — from a live training
   ``Executor``, a ``{name: array}`` dict, or a checkpoint directory —
@@ -116,6 +116,8 @@ class InferenceExecutor:
                  mesh=None, seed=0, validate="error", donate=True,
                  plan=None, decode=False):
         import jax
+        from ..graph.executor import configure_compile_cache
+        configure_compile_cache()
         if isinstance(fetches, Op):
             fetches = [fetches]
         self.fetches = list(fetches)

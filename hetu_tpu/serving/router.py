@@ -65,14 +65,14 @@ class ServeRejected(RuntimeError):
     upstream and retry later.
 
     Every instance carries a structured ``reason`` from the CLOSED
-    taxonomy below (plus the parameterized ``shed:<class>`` form) and an
+    vocabulary below (plus the parameterized ``shed:<class>`` form) and an
     optional admission ``klass``; construction counts the reason into
     the ``serve_rejection_reason`` metrics family, so artifacts and
     tests read ``exc.reason`` / the counter instead of string-matching
     exception text.
     """
 
-    #: the closed reason taxonomy; ``shed:<class>`` is the one
+    #: the closed reason vocabulary; ``shed:<class>`` is the one
     #: parameterized form (class-based admission shedding).
     #: ``recovery_exhausted`` (ISSUE 19) marks an in-flight decode
     #: stream the fleet could NOT resurrect after its replica died
@@ -85,7 +85,7 @@ class ServeRejected(RuntimeError):
         reason = str(reason)
         if reason not in self.REASONS and not reason.startswith("shed:"):
             raise ValueError(
-                f"unknown ServeRejected reason {reason!r} — taxonomy is "
+                f"unknown ServeRejected reason {reason!r} — vocabulary is "
                 f"{list(self.REASONS)} or 'shed:<class>'")
         self.reason = reason
         self.klass = klass

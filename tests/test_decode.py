@@ -118,7 +118,7 @@ def test_backpressure_and_too_long_rejection(decode_graph):
         router.submit([1], max_new_tokens=2)
         with pytest.raises(ServeRejected) as ei:
             router.submit([2], max_new_tokens=2)
-        assert ei.value.reason == "queue_full"      # structured taxonomy
+        assert ei.value.reason == "queue_full"      # structured vocabulary
         with pytest.raises(ServeRejected) as ei:
             router.submit(list(range(10)), max_new_tokens=_MAX_LEN)
         assert ei.value.reason == "over_max_len"

@@ -468,55 +468,6 @@ def test_controller_needs_exactly_one_liveness_source():
                           store=object())
 
 
-# ----------------------------------------- TPU-probe robustness satellite
-
-def test_probe_backoff_is_decorrelated_and_bounded():
-    """bench.py's probe retry schedule: decorrelated jitter in
-    [base, min(cap, 3*prev)], capped — never the old lockstep 15s
-    cadence (ROADMAP item 2's robustness slice)."""
-    import random
-    import bench
-    rng = random.Random(7)
-    prev, base, cap = bench.PROBE_BACKOFF_BASE_S, 5.0, 60.0
-    seen = []
-    for _ in range(50):
-        nxt = bench._next_probe_backoff(prev, rng, base=base, cap=cap)
-        assert base <= nxt <= min(cap, 3.0 * max(base, prev)) + 1e-9
-        seen.append(nxt)
-        prev = nxt
-    assert max(seen) <= cap
-    assert len({round(v, 6) for v in seen}) > 10     # jittered, not fixed
-    # same seed reproduces the schedule (unit-testable, like
-    # dist_store._next_backoff)
-    rng2 = random.Random(7)
-    prev = bench.PROBE_BACKOFF_BASE_S
-    for want in seen:
-        prev = bench._next_probe_backoff(prev, rng2, base=base, cap=cap)
-        assert prev == want
-
-
-def test_probe_log_appends_jsonl(tmp_path):
-    """Every probe attempt leaves one JSONL line (timestamp + outcome)
-    in the tpu_probe_log — the per-attempt audit trail a wedged BENCH
-    round is diagnosed from; a write failure must never fail the
-    measurement."""
-    import json
-    import bench
-    log = tmp_path / "probe.jsonl"
-    bench._append_probe_log({"ok": False, "err": "probe timed out",
-                             "source": "bench", "attempt": 0},
-                            path=str(log))
-    bench._append_probe_log({"ok": True, "err": None, "source": "bench",
-                             "attempt": 1}, path=str(log))
-    lines = [json.loads(ln) for ln in log.read_text().splitlines()]
-    assert len(lines) == 2
-    assert lines[0]["ok"] is False and "at" in lines[0]
-    assert lines[1]["ok"] is True and lines[1]["attempt"] == 1
-    # unwritable path: best-effort, no raise
-    bench._append_probe_log({"ok": False},
-                            path="/proc/definitely/not/writable.jsonl")
-
-
 # ------------------------------------------------------- slow scale proof
 
 @pytest.mark.slow

@@ -26,7 +26,7 @@ Coverage map (the ISSUE's acceptance):
 - FlapDamper (extracted from ElasticController's rejoin bookkeeping)
   gates the autoscaler: grow/shrink only after N consecutive breaching
   polls, never past the bounds (refused grows counted)
-- the ServeRejected reason taxonomy is validated at construction and
+- the ServeRejected reason vocabulary is validated at construction and
   counted in ``serve_rejection_reason``
 - the same replica contract works over DecodeRouter replicas
 """
@@ -423,9 +423,9 @@ def test_autoscaler_shrinks_after_grace_and_respects_min():
     assert hmetrics.fleet_counts()["fleet_autoscaler_polls"] >= 7
 
 
-# ------------------------------------------------- taxonomy validation
+# ------------------------------------------------- vocabulary validation
 
-def test_serve_rejected_reason_taxonomy_is_validated_and_counted():
+def test_serve_rejected_reason_vocabulary_is_validated_and_counted():
     before = dict(hmetrics.serve_rejection_counts())
     for reason in ("queue_full", "over_max_len", "deadline", "draining",
                    "shed:batch", "shed:best_effort"):
@@ -436,7 +436,7 @@ def test_serve_rejected_reason_taxonomy_is_validated_and_counted():
     for reason in ("queue_full", "over_max_len", "deadline", "draining",
                    "shed:batch", "shed:best_effort"):
         assert after.get(reason, 0) == before.get(reason, 0) + 1
-    with pytest.raises(ValueError, match="taxonomy"):
+    with pytest.raises(ValueError, match="vocabulary"):
         ServeRejected("bogus")
     with pytest.raises(ValueError):
         ServeRejected("queue full")         # old free-text form: dead

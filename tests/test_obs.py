@@ -350,7 +350,7 @@ def _run_overhead_subprocess():
     proc = subprocess.run(
         [sys.executable, os.path.join(ROOT, "tools",
                                       "host_overhead_bench.py"),
-         "--smoke", "--gate-only", "--cpu"],
+         "--smoke", "--gate-only"],
         capture_output=True, text=True, timeout=560, env=env, cwd=ROOT)
     assert proc.stdout.strip(), proc.stderr[-2000:]
     return json.loads(proc.stdout.strip().splitlines()[-1])

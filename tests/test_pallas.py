@@ -565,9 +565,11 @@ def test_flash_gate_artifact_loading(tmp_path, monkeypatch):
 @pytest.mark.parametrize("seq,with_bias", [(384, True), (421, True),
                                            (421, False)])
 def test_tpu_lowering_contains_pallas_custom_call(seq, with_bias):
-    """Cross-platform TPU lowering of biased / ragged-length attention
-    contains the Pallas (Mosaic) custom-call — the compile-time half of
-    the `flash_in_hlo: true` evidence, assertable without hardware."""
+    """Cross-platform TPU LOWERING of biased / ragged-length attention
+    contains the Pallas (Mosaic) custom-call: the dispatch reaches the
+    kernel.  ``jax.export`` serialises the kernel and never runs the
+    chip's compiler, so this says nothing about whether Mosaic accepts
+    it — tests/test_tpu_compile.py compiles for a described v5e."""
     import jax.export
 
     def f(q, k, v, bias):
@@ -602,7 +604,7 @@ def test_flash_fallback_reasons_recorded(monkeypatch):
 
     # gate forced open on a "tpu" backend: the remaining blocker (causal
     # ragged q/kv mod-128 mismatch) gets its own reason — the reason
-    # taxonomy is ordered backend → gate → shape
+    # vocabulary is ordered backend → gate → shape
     metrics.reset_flash_fallbacks()
     monkeypatch.setattr(att, "_use_flash", lambda q, k: True)
     monkeypatch.setattr(att.jax, "default_backend", lambda: "tpu")

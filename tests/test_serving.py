@@ -271,7 +271,7 @@ def test_queue_full_is_explicit_rejection_not_growth():
                 for _ in range(3)]
         with pytest.raises(ServeRejected) as ei:
             r.submit({x: np.zeros((3,), np.float32)})
-        assert ei.value.reason == "queue_full"      # structured taxonomy
+        assert ei.value.reason == "queue_full"      # structured vocabulary
         assert hmetrics.serve_counts()["serve_rejections"] == 1
         assert hmetrics.serve_rejection_counts()["queue_full"] == 1
         assert r.queue_depth == 3

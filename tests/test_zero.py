@@ -68,8 +68,10 @@ def _loss_bits(opt_name, dp, stage, steps=10):
 def test_sharded_update_bitwise_parity(dp, opt_name):
     """>=10 steps, sharded (stages 2 and 3) vs replicated on the SAME
     dp mesh and feeds: the loss trajectory must be bit-for-bit equal —
-    the whole update chain runs under the slab sharding, so no fusion /
-    FMA-contraction drift is tolerated (zero.py module docstring)."""
+    the whole update chain runs under the slab sharding AND the update
+    arithmetic has one product per add, so neither a moved fusion nor a
+    differently-contracted FMA can drift it (zero.py module docstring;
+    the two-product Adam moments drifted 1 ulp here — ROADMAP D0)."""
     base, _ = _loss_bits(opt_name, dp, stage=0)
     z2, ex2 = _loss_bits(opt_name, dp, stage=2)
     z3, ex3 = _loss_bits(opt_name, dp, stage=3)

@@ -5,9 +5,10 @@ PyTorch baseline (examples/compare/torch_baselines.py) on the SAME machine
 and prints a merged JSON table — the reference's comparison methodology
 (``examples/cnn/tf_main.py`` etc.) with committed, reproducible scripts.
 
-On this image torch is CPU-only, so for an apples-to-apples device the ours
-run is forced onto CPU too (set ``--ours-backend default`` to let ours use
-the TPU and compare cross-device throughput).
+Ours measures on the TPU (``bench.py`` refuses any other backend for these
+configs) while torch on this image is CPU-only: the table is a cross-device
+comparison and says so through each side's ``backend`` field.  This parent
+never touches jax; the children run one after another.
 """
 import argparse
 import json
@@ -24,8 +25,8 @@ def _run(cmd, env=None, timeout=900):
         proc = subprocess.run(cmd, capture_output=True, text=True,
                               timeout=timeout, env=env, cwd=ROOT)
     except subprocess.TimeoutExpired:
-        # degrade to an error row — one hung child (wedged tunnel) must
-        # not lose the other configs' results
+        # degrade to an error row — one hung child must not lose the
+        # other configs' results
         return {"error": f"timed out after {timeout}s"}
     for line in reversed(proc.stdout.strip().splitlines()):
         line = line.strip()
@@ -55,8 +56,6 @@ def main():
     p.add_argument("--batch-size", type=int, default=None)
     p.add_argument("--seq-len", type=int, default=None,
                    help="bert sequence length, pinned on BOTH sides")
-    p.add_argument("--ours-backend", default="cpu",
-                   choices=["cpu", "default"])
     args = p.parse_args()
     configs = [c.strip() for c in args.configs.split(",") if c.strip()]
     unknown = [c for c in configs if c not in CPU_BATCH]
@@ -75,20 +74,11 @@ def main():
             # embedding, so ours must be too; the HET-cache number is
             # measured separately below and reported alongside
             ours_extra += ["--wdl-embed", "dense"]
-        env = dict(os.environ, _HETU_BENCH_CHILD="1")
-        if args.ours_backend == "cpu":
-            env["_HETU_BENCH_FORCE_CPU"] = "1"
-        def _normalize_cpu_note(res):
-            # a requested CPU run is not a failure — keep the note but
-            # don't present it as an error (genuine errors stay)
-            if res.get("error", "").startswith("TPU backend unavailable") \
-                    and args.ours_backend == "cpu":
-                res.setdefault("extra", {})["note"] = res.pop("error")
-            return res
-
-        ours = _normalize_cpu_note(
-            _run([sys.executable, os.path.join(ROOT, "bench.py"),
-                  "--config", config] + ours_extra, env=env))
+        # ours measures on the chip in its own process (bench.py refuses
+        # any other backend); this parent never touches jax, and the
+        # children run one after another — one process per chip
+        ours = _run([sys.executable, os.path.join(ROOT, "bench.py"),
+                     "--config", config] + ours_extra)
         theirs = _run([sys.executable,
                        os.path.join(ROOT, "examples", "compare",
                                     "torch_baselines.py"),
@@ -102,10 +92,9 @@ def main():
                 row["ours_het_cache"] = {
                     "error": f"skipped: dense run failed ({ours['error'][:120]})"}
             else:
-                row["ours_het_cache"] = _normalize_cpu_note(
-                    _run([sys.executable, os.path.join(ROOT, "bench.py"),
-                          "--config", "wdl"] + extra
-                         + ["--wdl-embed", "lru"], env=env))
+                row["ours_het_cache"] = _run(
+                    [sys.executable, os.path.join(ROOT, "bench.py"),
+                     "--config", "wdl"] + extra + ["--wdl-embed", "lru"])
         ov, tv = ours.get("value"), theirs.get("value")
         if ov and tv:
             higher_better = ours.get("unit", "") != "ms/step"

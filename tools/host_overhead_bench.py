@@ -37,11 +37,6 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-import jax  # noqa: E402
-
-if os.environ.get("_HETU_AUDIT_FORCE_CPU") or "--cpu" in sys.argv:
-    jax.config.update("jax_platforms", "cpu")
-
 
 def main():
     from bench import bench_overhead

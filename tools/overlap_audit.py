@@ -379,14 +379,6 @@ def main():
                    help="skip the measured-run Perfetto twin")
     args = p.parse_args()
 
-    if os.environ.get("_HETU_AUDIT_FORCE_CPU"):
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                flags + " --xla_force_host_platform_device_count=8").strip()
-        import jax
-        jax.config.update("jax_platforms", "cpu")
-
     res = run_overlap_audit(dp=args.dp, batch_size=args.batch_size,
                             seq_len=args.seq_len,
                             trace=not args.no_trace)

@@ -14,12 +14,11 @@ table, re-rank candidates by measured step time, and persist
 verdict (the naive dp plan is always a candidate, so the reranked best is
 measured-no-worse by construction; the artifact records the margin).
 
-No arguments (legacy, what ``tools/tpu_watch.py`` runs as a
-post-calibration job): re-run the flagship-shaped layerwise search with
+No arguments (the post-calibration job): re-run the flagship-shaped layerwise search with
 the MEASURED on-chip constants (``artifacts/tpu_calibration.json``)
 against the estimated-constants plan and persist
 ``artifacts/plan_calibration_diff.json``; exits non-zero while the
-calibration artifact is absent so the watcher retries.
+calibration artifact is absent.
 
 The search itself is pure host work — the backend is pinned to CPU so
 this never occupies the chip during a measurement window.
