@@ -103,6 +103,16 @@ COMPILE_CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))), ".jax_cache")
 
+#: programs that compile faster than this are not written to the cache.
+#: The cache the chip tool hands over is capped (``JAX_COMPILATION_CACHE_
+#: MAX_SIZE``, 192 MiB) and evicts least-recently-used entries; with every
+#: program cached, one ``chip_smoke.py`` pass wrote more than the cap and
+#: the entry worth most — the BERT-base step, 53 MiB for 75 s of compile —
+#: was always the one evicted, so nothing ever hit.  The 22 decode programs
+#: (about 5 MiB and 2-3 s each) are what overflowed it: they are cheaper to
+#: recompile than to keep.
+COMPILE_CACHE_MIN_COMPILE_SECS = 5.0
+
 _compile_cache_configured = False
 
 
@@ -112,8 +122,9 @@ def configure_compile_cache():
     with ``JAX_COMPILATION_CACHE_DIR`` set, jax already has its directory
     and none is set in code; otherwise the cache is
     :data:`COMPILE_CACHE_DIR`.  A CPU process keeps jax's default (no
-    persistent cache).  Either way every program is cached (both
-    ``jax_persistent_cache_min_*`` thresholds zero): jitting with
+    persistent cache).  Either way the two ``jax_persistent_cache_min_*``
+    thresholds are set: any size, but only programs that took
+    :data:`COMPILE_CACHE_MIN_COMPILE_SECS` to compile.  Jitting with
     canonical input keys makes a rebuilt executor's HLO byte-identical,
     so a restarted process reads its step back instead of compiling."""
     global _compile_cache_configured
@@ -128,7 +139,8 @@ def configure_compile_cache():
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      COMPILE_CACHE_MIN_COMPILE_SECS)
 
 
 def _filter_spec(mesh, spec):
