@@ -38,6 +38,9 @@ from .gradients import GradientOp
 from ..ndarray import NDArray, wrap_device
 from .. import metrics as _metrics
 from ..obs.trace import TRACER as _TRACE
+from ..obs.trace import annotate as _annotate
+from ..obs.trace import annotate_end as _annotate_end
+from ..obs.trace import span as _span
 
 
 def _dev_roundtrip(h):
@@ -802,10 +805,14 @@ class SubExecutor:
         timed = _TRACE.on or _metrics.step_timing
         t0 = _time.perf_counter_ns() if timed else 0
         # captured BEFORE the step increments it: the span's step arg
-        # must equal the StepTraceAnnotation step_num of the same run
-        # (HetuProfiler.trace correlation), and eval subgraphs — which
-        # never increment — use the same convention
+        # equals the StepTraceAnnotation step_num of the same run
+        # (HetuProfiler.trace), and eval subgraphs — which never
+        # increment — use the same convention
         step0 = ex._step_counter if timed else 0
+        # the step span in the profiler's trace too, while one is being
+        # captured: the device's ops and idle gaps then lie under it
+        ann = _annotate("step", sub=self.name, step=step0) \
+            if timed and _TRACE.on else None
         ex._in_step = True
         try:
             out = self._run_impl(feed_dict, convert_to_numpy_ret_vals,
@@ -832,6 +839,7 @@ class SubExecutor:
                 # the hot path — the exporter rebuilds it
                 b.items[i % b.cap] = ("S", self.name, t0, t1, step0)
                 b.i = i + 1
+            _annotate_end(ann)
         return out
 
     def _derive_lr_state(self):
@@ -964,6 +972,7 @@ class SubExecutor:
             # the lookup window starts at run()'s own stamp when it has
             # one (sub-us skew, one clock read saved on the hot path)
             t_pl = t_run0 or _time.perf_counter_ns()
+            ann = _annotate("run_plan.lookup")
         plan = cache.lookup(feed_dict)
         if not convert_to_numpy_ret_vals and plan._fast_eligible:
             fast = plan._fast
@@ -974,17 +983,21 @@ class SubExecutor:
             # hand the lookup window to the fast lane: it batches ALL
             # three phase spans into one ring write (a separate emit
             # here would double the hot path's buffer walks)
+            _annotate_end(ann)
             return fast(feed_dict, sync, t_pl, _time.perf_counter_ns())
         if tr is not None:
             # general path (PS / ZeRO-3 / convert): not the dispatch-gap
             # hot path — the method-call emit is fine here
             tr.complete("run_plan.lookup", t_pl, _time.perf_counter_ns(),
                         cat="executor")
+            _annotate_end(ann)
             t_fd = _time.perf_counter_ns()
+            ann = _annotate("feeds.place")
         feeds = plan.place_feeds(feed_dict)
         if tr is not None:
             tr.complete("feeds.place", t_fd, _time.perf_counter_ns(),
                         cat="executor")
+            _annotate_end(ann)
 
         if self._ps_items:
             if tr is not None:
@@ -1016,12 +1029,14 @@ class SubExecutor:
         # back to host after construction/restore).
         if tr is not None:
             t_jit = _time.perf_counter_ns()
+            ann = _annotate("jit.dispatch")
         outs, new_tparams, updates, new_opt_states, new_step = self._jit(
             tparams, sparams, opt_states, feeds, ex.master_key,
             ex._step_input(), lrs)
         if tr is not None:
             tr.complete("jit.dispatch", t_jit, _time.perf_counter_ns(),
                         cat="executor")
+            _annotate_end(ann)
 
         # step N+1's host→device feed copies start NOW, overlapping the
         # in-flight device work (the double-buffered feed pipeline)
@@ -2461,10 +2476,22 @@ class Executor:
         if len(self._async_pending) > self._async_window:
             from ..metrics import record_run_plan
             record_run_plan("async_sync_points")
-            fid = self._async_fids.popleft() if self._async_fids else None
-            if fid is not None and _TRACE.on:
+            self._sync_oldest()
+
+    def _sync_oldest(self):
+        """Materialise the oldest in-flight ``run(sync=False)`` step.
+        Traced, the wait is an ``executor.sync`` span (ring and
+        profiler's trace) that the dispatch's ``async_step`` flow arrow
+        ends in."""
+        fid = self._async_fids.popleft() if self._async_fids else None
+        oldest = [self._async_pending.popleft()]
+        if not _TRACE.on:
+            _sync_outs(oldest)
+            return
+        with _span("executor.sync", cat="async"):
+            if fid is not None:
                 _TRACE.flow_end("async_step", fid)
-            _sync_outs([self._async_pending.popleft()])
+            _sync_outs(oldest)
 
     def _drain_async(self):
         """Force every in-flight async step to completion (counted as one
@@ -2479,10 +2506,7 @@ class Executor:
         from ..metrics import record_run_plan
         record_run_plan("async_sync_points")
         while self._async_pending:
-            fid = self._async_fids.popleft() if self._async_fids else None
-            if fid is not None and _TRACE.on:
-                _TRACE.flow_end("async_step", fid)
-            _sync_outs([self._async_pending.popleft()])
+            self._sync_oldest()
 
     def logOut(self, path, clear=True):
         """Write recorded step timings (reference Executor.logOut:548)."""

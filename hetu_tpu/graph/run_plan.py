@@ -51,6 +51,8 @@ import numpy as np
 from ..metrics import record_run_plan
 from ..ndarray import NDArray, wrap_device
 from ..obs.trace import TRACER as _TR
+from ..obs.trace import annotate as _annotate
+from ..obs.trace import annotate_end as _annotate_end
 
 
 #: marks "this feed node is dataloader-fed (absent from feed_dict)" in
@@ -249,8 +251,12 @@ class RunPlan:
             # the caller's run-plan-lookup window; the step span lives
             # in SubExecutor.run.
             tr = tracer if tracer.on else None
-            if tr is not None and not t0:
-                t0 = _time.perf_counter_ns()
+            if tr is not None:
+                if not t0:
+                    t0 = _time.perf_counter_ns()
+                # the same boundaries in the profiler's trace, while one
+                # is being captured (obs/trace.py ``annotate``)
+                ann = _annotate("feeds.place")
             feeds = {}
             for key, fetch in steps:
                 feeds[key] = fetch(feed_dict)
@@ -266,11 +272,14 @@ class RunPlan:
             step = ex._step_counter
             if tr is not None:
                 t1 = _time.perf_counter_ns()
+                _annotate_end(ann)
+                ann = _annotate("jit.dispatch")
             outs, new_tparams, updates, new_opt_states, new_step = jit(
                 tparams, sparams, opt_states, feeds, ex.master_key,
                 step_input(),
                 lrs_const if lrs_const is not None else host_lrs(step))
             if tr is not None:
+                _annotate_end(ann)
                 # ONE packed record for the whole phase set ("P" —
                 # expanded to three spans by the exporter): one
                 # allocation, one ring store, no per-step dicts; GC

@@ -565,7 +565,27 @@ def reset_serve_counts():
 # step avoided vs the token-by-token path: the widest row's chunk minus
 # one, per chunked step), and ``decode_logits_skipped`` (steps that
 # skipped the (batch, vocab) logits D2H because no row was past its
-# prompt).  Surfaced by ``HetuProfiler.decode_counters()`` and
+# prompt).  The step accounts for its own time (ISSUE 25), integer
+# microseconds summed over steps, the phases touching and not overlapping
+# from ``DecodeEngine.step``'s entry to its return:
+#   ``decode_step_plan_us``      chunk pick, bucket growth, plan lookup
+#   ``decode_step_feed_us``      building the host feeds
+#   ``decode_step_dispatch_us``  the jitted call until it returns
+#   ``decode_step_wait_us``      until the logits are ready on the device
+#   ``decode_step_readback_us``  their (batch, vocab) D2H copy
+#   ``decode_step_host_us``      argmax, emission, callbacks, bookkeeping
+# (no wait/readback on a step that skips its logits; ``feed`` … ``host``
+# is what the ``step`` latency histogram observes), and
+#   ``decode_between_steps_us``  the router loop from one step's return to
+#                                the next one's entry while rows are seated
+#   ``decode_join_wait_us``      submit -> seated, summed over
+#                                ``decode_joins`` (the ``join_wait``
+#                                histogram's observations)
+#   ``decode_padded_row_tokens`` batch bucket x chunk bucket per step: the
+#                                row-tokens computed, padding included
+#   ``decode_chunk_width``       chunk bucket summed over
+#                                ``decode_prefill_steps``
+# Surfaced by ``HetuProfiler.decode_counters()`` and
 # ``bench.py --config decode``; a process that never decodes reports an
 # empty dict.
 

@@ -23,10 +23,20 @@ makes them visible from one place:
 
 * **Export** (:mod:`~hetu_tpu.obs.export`):
   ``obs.export_chrome_trace(path)`` writes Chrome trace JSON — load it
-  at https://ui.perfetto.dev.  For host-span <-> device-trace
-  correlation, ``HetuProfiler.trace()`` wraps each captured step in
-  ``jax.profiler.StepTraceAnnotation`` so XProf aligns device slices
-  with the host step index.
+  at https://ui.perfetto.dev.  That file is the host's clock alone.
+
+* **The program's spans and the device's ops in ONE trace**: capture
+  with ``jax.profiler`` (``jax.profiler.trace(dir)``, or
+  ``HetuProfiler.trace()``, which also switches span tracing on for the
+  capture).  While a session captures, every ``obs.span`` and the
+  executor's ``step`` / ``run_plan.lookup`` / ``feeds.place`` /
+  ``jit.dispatch`` / ``executor.sync`` boundaries (with ``HETU_TRACE=1``)
+  open a ``jax.profiler.TraceAnnotation`` too, and the decode plane's
+  ``decode.step`` with its phases and ``decode.between``
+  (:class:`~hetu_tpu.obs.trace.Phases`) do so ALWAYS — so the
+  ``.xplane.pb`` holds the program's phases on the device's clock and an
+  idle gap of the chip has an owner.  ``benchmarks/trace_reduce.py``
+  reduces such a file; TensorBoard/XProf and Perfetto show it.
 
 * **Metrics registry** (:mod:`~hetu_tpu.obs.registry`): every counter
   family, latency histogram and gauge registers against

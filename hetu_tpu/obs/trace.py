@@ -36,6 +36,15 @@ events per thread instead of growing without bound, and
 
 Timestamps are ``time.perf_counter_ns()`` everywhere — one monotonic
 base shared by every thread, so cross-track ordering is meaningful.
+
+The rings live on the host's clock alone.  To stand beside the device's
+operations a span also has to be in the trace ``jax.profiler`` writes,
+which a ``jax.profiler.TraceAnnotation`` (a ``TraceMe``) opened at the
+same boundary does: :func:`span` and the executor's traced branches open
+one while a profiler session captures (:func:`annotate`), and
+:class:`Phases` — the serving loop's per-step accounting — always
+does, so that any ``jax.profiler`` session shows the program's phases
+over the device's idle gaps with no switch to flip first.
 """
 from __future__ import annotations
 
@@ -43,6 +52,27 @@ import itertools
 import os
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
+
+
+def annotate(name, **meta):
+    """An ENTERED ``TraceAnnotation``, or None unless a ``jax.profiler``
+    session is capturing (one C++ flag read, ~60 ns) — for :func:`span`
+    and the executor's traced branches, which stamp their boundaries
+    inline: with ``HETU_TRACE=1`` alone they pay the flag read, not an
+    object per phase.  Close it with :func:`annotate_end`."""
+    if not TraceAnnotation.is_enabled():
+        return None
+    ann = TraceAnnotation(name, **meta)
+    ann.__enter__()
+    return ann
+
+
+def annotate_end(ann):
+    """Close what :func:`annotate` returned (None: nothing was open)."""
+    if ann is not None:
+        ann.__exit__(None, None, None)
 
 
 def _env_on():
@@ -203,9 +233,11 @@ TRACER = Tracer()
 
 
 class _SpanCtx:
-    """Context-manager span for non-hot call sites (``obs.span(...)``)."""
+    """Context-manager span for non-hot call sites (``obs.span(...)``):
+    one ring record, and the same interval in the profiler's trace while
+    one is being captured."""
 
-    __slots__ = ("name", "cat", "args", "t0")
+    __slots__ = ("name", "cat", "args", "t0", "ann")
 
     def __init__(self, name, cat, args):
         self.name = name
@@ -213,12 +245,14 @@ class _SpanCtx:
         self.args = args or None
 
     def __enter__(self):
+        self.ann = annotate(self.name)
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         TRACER.complete(self.name, self.t0, time.perf_counter_ns(),
                         self.cat, self.args)
+        annotate_end(self.ann)
         return False
 
 
@@ -251,4 +285,93 @@ def event(name, cat="hetu", **args):
         TRACER.instant(name, cat, args or None)
 
 
-__all__ = ["Tracer", "TRACER", "span", "event"]
+class Phases:
+    """One interval of a loop — a decode step, the router's time between
+    two steps — cut into phases that touch and do not overlap.  Each
+    boundary is stamped ONCE (``perf_counter_ns``) and the stamp feeds
+    three records of the same interval:
+
+    1. an always-on counter, integer microseconds: ``record(kind, us)``
+       per phase (``kinds``: ``{phase: (annotation name, counter
+       kind)}``, built once by the caller), and the whole interval under
+       ``total`` if given.  The microseconds are
+       differences of the truncated stamps, so the phases of one
+       interval add up to its length exactly;
+    2. a ``jax.profiler.TraceAnnotation`` per phase, nested in one named
+       ``name`` (``**meta`` becomes its arguments), ALWAYS opened: with
+       no profiler session a ``TraceMe`` is a flag check in C++, and
+       with one — the benchmark's, an operator's — the program's phases
+       are on the device trace's clock with nothing to switch on;
+    3. the :data:`TRACER` ring when ``TRACER.on``: the phases as ``X``
+       records inside the ``name`` span (``self.args`` its arguments).
+
+    ``with Phases(...) as ph: ph.mark("plan"); ...; ph.mark("feed")``:
+    :meth:`mark` ends the open phase and starts the next, returning the
+    stamp; leaving the block (an exception too) ends the last phase and
+    the interval, after which ``ph.t1`` is the closing stamp.  A phase
+    marked twice in one interval counts twice."""
+
+    __slots__ = ("name", "cat", "args", "t0", "t1", "_record", "_kinds",
+                 "_total", "_outer", "_inner", "_phase", "_t")
+
+    def __init__(self, name, record, kinds=None, total=None, cat="hetu",
+                 **meta):
+        self.name = name
+        self.cat = cat
+        self.args = None
+        self._record = record
+        self._kinds = kinds
+        self._total = total
+        self._inner = self._phase = self.t1 = None
+        self.t0 = self._t = time.perf_counter_ns()
+        self._outer = TraceAnnotation(name, **meta)
+        self._outer.__enter__()
+
+    def meta(self, **meta):
+        """More arguments for the interval's annotation, known only once
+        it has begun (a decode step's chunk size)."""
+        self._outer.set_metadata(**meta)
+
+    def _end_phase(self, now):
+        self._inner.__exit__(None, None, None)
+        span, kind = self._kinds[self._phase]
+        self._record(kind, now // 1000 - self._t // 1000)
+        if TRACER.on:
+            TRACER.complete(span, self._t, now, self.cat)
+        self._inner = None
+
+    def mark(self, phase):
+        """End the open phase, begin ``phase``; returns the stamp (ns)."""
+        now = time.perf_counter_ns()
+        if self._inner is not None:
+            self._end_phase(now)
+        self._phase = phase
+        self._inner = TraceAnnotation(self._kinds[phase][0])
+        self._inner.__enter__()
+        self._t = now
+        return now
+
+    def close(self):
+        """End the open phase and the interval (once; later calls do
+        nothing)."""
+        if self.t1 is not None:
+            return
+        now = self.t1 = time.perf_counter_ns()
+        if self._inner is not None:
+            self._end_phase(now)
+        self._outer.__exit__(None, None, None)
+        if self._total is not None:
+            self._record(self._total, now // 1000 - self.t0 // 1000)
+        if TRACER.on:
+            TRACER.complete(self.name, self.t0, now, self.cat, self.args)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.close()
+        return False
+
+
+__all__ = ["Tracer", "TRACER", "span", "event", "annotate", "annotate_end",
+           "Phases"]

@@ -252,7 +252,11 @@ def _fwd_kernel(*refs, scale, causal, flags, block_q, block_k, num_kv,
 
 def _flash_fwd(q, k, v, lengths, kmask, kbias, fmask, bias, scale, causal,
                gmode_mask, gmode_bias, gmode_kbias, heads, block_q, block_k,
-               interpret):
+               interpret, name="flash_fwd"):
+    # ``name=`` on each pallas_call: the device trace calls the kernel's
+    # instruction after its place in jax's name stack, so without one the
+    # forward reads ``jvp__`` or ``infer`` after whatever traced it and
+    # the two backward kernels share ``transpose_jvp___`` (ISSUE 25)
     bh, s_q, d = q.shape
     s_kv = k.shape[1]
     num_q = s_q // block_q
@@ -270,6 +274,7 @@ def _flash_fwd(q, k, v, lengths, kmask, kbias, fmask, bias, scale, causal,
         kv_off=s_kv - s_q)
     out, lse = pl.pallas_call(
         kernel,
+        name=name,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -463,6 +468,7 @@ def _flash_bwd(q, k, v, lengths, kmask, kbias, fmask, bias, out, lse, do,
                           flags=flags, emit_dbias=emit_dbias,
                           block_q=block_q, block_k=block_k, num_kv=num_kv,
                           kv_off=s_kv - s_q),
+        name="flash_bwd_dq",
         grid=(bh, num_q, num_kv),
         in_specs=[qspec, kspec, kspec]
         + _extra_specs(lambda b, i, j: (b, i, j), heads, gmode_mask,
@@ -504,6 +510,7 @@ def _flash_bwd(q, k, v, lengths, kmask, kbias, fmask, bias, out, lse, do,
                           flags=flags, emit_dkbias=emit_dkbias,
                           block_q=block_q, block_k=block_k,
                           num_q=num_q, kv_off=s_kv - s_q),
+        name="flash_bwd_dkv",
         grid=(bh, num_kv, num_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
@@ -548,30 +555,30 @@ def _group_reduce(d, gmode, b, heads, shape, dtype):
     return d.reshape(shape).astype(dtype)
 
 
-_STATIC = (8, 9, 10, 11, 12, 13, 14, 15, 16)
+_STATIC = (8, 9, 10, 11, 12, 13, 14, 15, 16, 17)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=_STATIC)
 def _flash(q3, k3, v3, lengths, kmask, kbias, fmask, bias, scale, causal,
            gmode_mask, gmode_bias, gmode_kbias, heads, block_q, block_k,
-           interpret):
+           interpret, fwd_name):
     out, _ = _flash_fwd(q3, k3, v3, lengths, kmask, kbias, fmask, bias,
                         scale, causal, gmode_mask, gmode_bias, gmode_kbias,
-                        heads, block_q, block_k, interpret)
+                        heads, block_q, block_k, interpret, fwd_name)
     return out
 
 
 def _flash_vjp_fwd(q3, k3, v3, lengths, kmask, kbias, fmask, bias, scale,
                    causal, gmode_mask, gmode_bias, gmode_kbias, heads,
-                   block_q, block_k, interpret):
+                   block_q, block_k, interpret, fwd_name):
     out, lse = _flash_fwd(q3, k3, v3, lengths, kmask, kbias, fmask, bias,
                           scale, causal, gmode_mask, gmode_bias, gmode_kbias,
-                          heads, block_q, block_k, interpret)
+                          heads, block_q, block_k, interpret, fwd_name)
     return out, (q3, k3, v3, lengths, kmask, kbias, fmask, bias, out, lse)
 
 
 def _flash_vjp_bwd(scale, causal, gmode_mask, gmode_bias, gmode_kbias, heads,
-                   block_q, block_k, interpret, res, do):
+                   block_q, block_k, interpret, fwd_name, res, do):
     q3, k3, v3, lengths, kmask, kbias, fmask, bias, out, lse = res
     dq, dk, dv, dbias, dkbias = _flash_bwd(
         q3, k3, v3, lengths, kmask, kbias, fmask, bias, out, lse, do, scale,
@@ -737,9 +744,12 @@ def flash_attention(q, k, v, causal=False, scale=None, lengths=None,
             kbias3 = ba.reshape(-1, 1, s_kv)
         else:
             bias3, gmode_bias = _broadcast_group(ba, b, h, s_q, s_kv, "bias")
+    # the one-token decode call (one query row, padded to a bucket of
+    # 128) under a name of its own in the device trace
     out = _flash(q3, k3, v3, len3, kmask2, kbias3, fmask3, bias3, scale,
                  causal, gmode_mask, gmode_bias, gmode_kbias, h, block_q,
-                 block_k, interpret)
+                 block_k, interpret,
+                 "flash_fwd_q1" if s_q_orig == 1 else "flash_fwd")
     out = out.reshape(b, h, s_q, d)
     if s_q != s_q_orig:
         out = out[:, :, :s_q_orig]    # unpad: bucketing is caller-invisible

@@ -107,6 +107,7 @@ def row_gather(src, idx, block=ROW_BLOCK, interpret=False):
     idx_p = jnp.full((n_pad,), -1, jnp.int32).at[:n].set(idx.astype(jnp.int32))
     out = pl.pallas_call(
         functools.partial(_gather_kernel, block=block),
+        name="row_gather",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n_pad // block,),

@@ -80,6 +80,7 @@ def sorted_segment_sum(rows, seg_ids, num_segments, block=128,
     num_blocks = n_pad // block
     call = pl.pallas_call(
         functools.partial(_seg_kernel, block=block, num_blocks=num_blocks),
+        name="sorted_segment_sum",
         grid=(num_blocks,),
         in_specs=[
             pl.BlockSpec((block, 1), lambda b: (b, 0)),

@@ -431,7 +431,19 @@ class HetuProfiler:
         q_len=C entry (``decode_prefill_steps``), dispatches saved vs
         token-by-token ingestion (``decode_prefill_steps_saved``), and
         logits D2H copies skipped on pure-prefill steps
-        (``decode_logits_skipped``).  Per-token latency rides
+        (``decode_logits_skipped``).  Where a step's time goes, in
+        microseconds summed over steps (ISSUE 25):
+        ``decode_step_{plan,feed,dispatch,wait,readback,host}_us`` —
+        the phases of ``DecodeEngine.step`` from entry to return (chunk
+        pick and plan lookup; host feeds; the jitted call; until the
+        logits are ready on the device; their D2H; argmax, emission and
+        bookkeeping) — and ``decode_between_steps_us``, the router
+        loop's time between two steps; ``decode_join_wait_us`` sums
+        submit -> seated over ``decode_joins``;
+        ``decode_padded_row_tokens`` (batch bucket x chunk bucket per
+        step) is the denominator of the share of computed row-tokens
+        that were not padding, ``decode_chunk_width`` the chunk bucket
+        summed over ``decode_prefill_steps``.  Per-token latency rides
         ``metrics.decode_latency_stats()``.  A process that never
         decodes reports an empty dict."""
         from .metrics import decode_counts
@@ -559,24 +571,37 @@ class HetuProfiler:
         """Capture a hardware trace of real steps into ``log_dir``
         (TensorBoard/XProf format via ``jax.profiler`` — the TPU-native
         replacement for the reference's per-op CUDA-event timeline;
-        SURVEY.md §5.1).  Each step is wrapped in
-        ``jax.profiler.StepTraceAnnotation`` so XProf groups its device
-        slices under the host step index — with ``HETU_TRACE=1`` the
-        host-side ``obs`` spans carry the same step numbers, giving
-        host-span <-> device-trace correlation (match ``step_num``
-        against the ``step`` span's ``step`` arg).  Returns the
-        directory for convenience."""
+        SURVEY.md §5.1), with the program's own spans in the SAME trace,
+        on the device's clock: span tracing (``obs.enable``) is on for
+        the capture and put back as it was after, and while a profiler
+        session captures the executor opens a
+        ``jax.profiler.TraceAnnotation`` at every boundary it stamps —
+        ``step`` (arguments ``sub``, ``step``), ``run_plan.lookup``,
+        ``feeds.place``, ``jit.dispatch``, ``executor.sync`` — as does
+        every ``obs.span``.  So the ``.xplane.pb`` says what the host
+        was doing over each idle gap of the device
+        (``benchmarks/trace_reduce.py`` reduces one to busy/idle time,
+        time by operation and the owner of each gap).  Each step is also
+        wrapped in ``jax.profiler.StepTraceAnnotation`` so XProf groups
+        its device slices under the step index.  Returns the directory
+        for convenience."""
         import jax
+        from .obs import TRACER
         if steps < 1:
             raise ValueError("trace needs steps >= 1")
         self._sync(self.sub.run(feed_dict))  # compile+warm OUTSIDE the trace
         first = int(self.ex.step_counter)
-        with jax.profiler.trace(str(log_dir)):
-            for i in range(steps):
-                with jax.profiler.StepTraceAnnotation(
-                        "hetu_step", step_num=first + i):
-                    out = self.sub.run(feed_dict)
-            self._sync(out)
+        was_on = TRACER.on
+        TRACER.enable(True)
+        try:
+            with jax.profiler.trace(str(log_dir)):
+                for i in range(steps):
+                    with jax.profiler.StepTraceAnnotation(
+                            "hetu_step", step_num=first + i):
+                        out = self.sub.run(feed_dict)
+                self._sync(out)
+        finally:
+            TRACER.enable(was_on)
         return str(log_dir)
 
 

@@ -109,9 +109,15 @@ from ..graph import step_cache
 from ..metrics import (record_decode, record_decode_latency,
                        record_decode_recovery)
 from ..obs.lock_witness import make_condition, make_lock
+from ..obs.trace import Phases as _Phases
 from ..obs.trace import TRACER as _TR
 from .executor import InferenceExecutor, default_buckets
 from .router import ServeRejected
+
+#: the phases of one ``DecodeEngine.step`` (docstring there): span names
+#: ``decode.step.<phase>``, counters ``decode_step_<phase>_us``
+_STEP_PHASES = {p: (f"decode.step.{p}", f"decode_step_{p}_us") for p in (
+    "plan", "feed", "dispatch", "wait", "readback", "host")}
 
 
 class DecodeStream:
@@ -566,8 +572,9 @@ class DecodeEngine:
             record_decode_latency(
                 "recovery", (time.monotonic() - req.detached_ts) * 1e6)
         else:
-            record_decode_latency(
-                "join_wait", (time.monotonic() - req.t_arrival) * 1e6)
+            wait_us = (time.monotonic() - req.t_arrival) * 1e6
+            record_decode_latency("join_wait", wait_us)
+            record_decode("decode_join_wait_us", int(wait_us))
         if _TR.on:
             if req.fid is not None:
                 _TR.flow_end("decode.recovery" if req.detached_ts is not None
@@ -748,152 +755,141 @@ class DecodeEngine:
         """Decode ONE batch step: every active slot consumes its pending
         token(s), caches append in place, rows past their prompt emit.
         With a chunked entry, steps where some row still owes multiple
-        prompt tokens run the q_len=C chunked path (generating rows ride
-        along); otherwise the PR 16 one-token path runs unchanged.
-        Returns the number of tokens emitted."""
+        prompt tokens run the q_len=C chunked path (each active row
+        consumes up to ``chunk`` pending tokens — its prompt remainder,
+        or its one generated token at column 0 — and the caches take a
+        masked multi-row append); otherwise the PR 16 one-token path
+        runs.  Only rows that finished their prompt read logits — a
+        pure-prefill step skips the D2H entirely.  Returns the number of
+        tokens emitted.
+
+        The step accounts for its own time (ISSUE 25): from entry to
+        return it is cut into the phases ``plan`` (chunk pick, bucket
+        growth, plan lookup), ``feed`` (host feeds), ``dispatch`` (the
+        jitted call until it returns), ``wait`` (until the logits are
+        ready on the device), ``readback`` (their D2H) and ``host``
+        (argmax, emission, stream callbacks, bookkeeping) — see
+        :class:`~hetu_tpu.obs.trace.Phases` for the three records each
+        boundary feeds.  The ``step`` latency histogram keeps its
+        boundaries: ``feed`` … ``host``."""
         active = [i for i, s in enumerate(self.slots) if s is not None]
         if not active:
             return 0
-        chunk = self._pick_chunk(active)
-        if chunk > 1:
-            return self._step_chunked(active, chunk)
-        self._grow_len_if_needed()
-        fn = self._step_fn()
-        t0 = time.perf_counter_ns()
-        # fed as COPIES: jax's CPU client may alias an aligned numpy
-        # feed zero-copy, and the engine mutates tokens/positions right
-        # after dispatch — without the logits D2H sync (skipped on
-        # pure-prefill steps) an aliased feed would race the device read
-        feeds = {
-            self._fk["input_ids"]: self.tokens.reshape(self.bb, 1).copy(),
-            self._fk["positions"]: self.positions.copy(),
-        }
-        for name in self.cache_names:
-            feeds[self._fk[name]] = self.caches[name]
-        # the caches are DONATED device arrays fed straight back from the
-        # previous step's fetches — no host round-trip (_place_feed's
-        # np.asarray would force one, so the engine bypasses infer_rows)
-        with warnings.catch_warnings():
-            # ids/positions are int32 inputs with no matching output
-            # buffer; only the caches can (and do) donate
-            warnings.filterwarnings(
-                "ignore", message="Some donated buffers were not usable")
-            outs = fn(self.iex.params, feeds)
-        # the logits D2H is paid only when some row will read it — a
-        # pure-prefill step never looks at outs[0] (ISSUE 18 satellite)
-        if any(self.slots[i].ptr >= len(self.slots[i].req.prompt) - 1
-               for i in active):
-            logits = np.asarray(outs[0])
-        else:
-            logits = None
-            record_decode("decode_logits_skipped")
-        self.last_logits = logits
-        for name, new in zip(self.cache_names, outs[1:]):
-            self.caches[name] = new
-        record_decode("decode_steps")
-        emitted = 0
-        now = time.monotonic()
-        for i in active:
-            seq = self.slots[i]
-            self.positions[i] += 1
-            if seq.ptr < len(seq.req.prompt) - 1:
-                # mid-prompt: next prompt token, nothing to emit yet
-                seq.ptr += 1
-                self.tokens[i] = seq.req.prompt[seq.ptr]
-                record_decode("decode_prefill_rows")
-                continue
-            # this row's logits are live: greedy argmax (deterministic
-            # first-max tie-break keeps decode bitwise stable)
-            tok = int(np.argmax(logits[i]))
-            seq.ptr = len(seq.req.prompt)
-            emitted += self._emit_token(i, seq, tok, now)
-        t1 = time.perf_counter_ns()
-        record_decode_latency("step", (t1 - t0) / 1e3)
-        if _TR.on:
-            _TR.complete("decode.step", t0, t1, cat="decode",
-                         args={"batch": self.bb, "len": self.lb,
-                               "rows": len(active), "emitted": emitted})
-        return emitted
-
-    def _step_chunked(self, active, chunk):
-        """One chunked-prefill step: each active row consumes up to
-        ``chunk`` pending tokens (its prompt remainder, or its one
-        generated token at column 0), the caches take a masked multi-row
-        append, and only rows that finished their prompt read logits —
-        a pure-prefill chunk skips the D2H entirely."""
-        self._grow_len_if_needed(span=chunk)
-        fn = self._chunk_step_fn(chunk)
-        t0 = time.perf_counter_ns()
-        ids = np.zeros((self.bb, chunk), np.int32)
-        valid = np.zeros(self.bb, np.int32)
-        consume = {}
-        emit_rows = []
-        for i in active:
-            seq = self.slots[i]
-            rem = len(seq.req.prompt) - seq.ptr
-            if rem > 0:
-                n = min(rem, chunk)
-                ids[i, :n] = seq.req.prompt[seq.ptr:seq.ptr + n]
+        with _Phases("decode.step", record_decode, _STEP_PHASES,
+                     cat="decode", rows=len(active)) as ph:
+            ph.mark("plan")
+            chunk = self._pick_chunk(active)
+            ph.meta(chunk=chunk)
+            self._grow_len_if_needed(span=chunk)
+            if chunk > 1:
+                fn, ex, fk = self._chunk_step_fn(chunk), self.ciex, self._cfk
             else:
-                n = 1
-                ids[i, 0] = self.tokens[i]
-            valid[i] = n
-            consume[i] = n
-            if seq.ptr + n >= len(seq.req.prompt):
-                emit_rows.append(i)
-        feeds = {
-            self._cfk["input_ids"]: ids,
-            self._cfk["positions"]: self.positions.copy(),
-            self._cfk["valid"]: valid,
-        }
-        for name in self.cache_names:
-            feeds[self._cfk[name]] = self.caches[name]
-        with warnings.catch_warnings():
-            warnings.filterwarnings(
-                "ignore", message="Some donated buffers were not usable")
-            outs = fn(self.ciex.params, feeds)
-        if emit_rows:
-            logits = np.asarray(outs[0])
-        else:
-            logits = None
-            record_decode("decode_logits_skipped")
-        self.last_logits = logits
-        for name, new in zip(self.cache_names, outs[1:]):
-            self.caches[name] = new
-        record_decode("decode_steps")
-        record_decode("decode_prefill_steps")
-        # dispatches saved vs token-by-token: the widest row would have
-        # needed max(consume) one-token steps; this step is one
-        record_decode("decode_prefill_steps_saved",
-                      max(consume.values()) - 1)
-        emitted = 0
-        now = time.monotonic()
-        for i in active:
-            seq = self.slots[i]
-            n = consume[i]
-            self.positions[i] += n
-            plen = len(seq.req.prompt)
-            if seq.ptr + n < plen:
-                # still mid-prompt after this chunk
-                seq.ptr += n
-                self.tokens[i] = seq.req.prompt[seq.ptr]
-                record_decode("decode_prefill_rows", n)
-                continue
-            # prompt finished this step (n-1 of the consumed tokens were
-            # prefill rows, the last is the generate row) or the row was
-            # already generating (n == 1, zero prefill rows)
-            prefill_rows = (plen - seq.ptr - 1) if seq.ptr < plen else 0
-            record_decode("decode_prefill_rows", prefill_rows)
-            seq.ptr = plen
-            tok = int(np.argmax(logits[i]))
-            emitted += self._emit_token(i, seq, tok, now)
-        t1 = time.perf_counter_ns()
-        record_decode_latency("step", (t1 - t0) / 1e3)
-        if _TR.on:
-            _TR.complete("decode.step", t0, t1, cat="decode",
-                         args={"batch": self.bb, "len": self.lb,
-                               "chunk": chunk, "rows": len(active),
-                               "emitted": emitted})
+                fn, ex, fk = self._step_fn(), self.iex, self._fk
+            t0 = ph.mark("feed")
+            # fed as COPIES: jax's CPU client may alias an aligned numpy
+            # feed zero-copy, and the engine mutates tokens/positions
+            # right after dispatch — without the logits D2H sync (skipped
+            # on pure-prefill steps) an aliased feed would race the
+            # device read
+            if chunk > 1:
+                ids = np.zeros((self.bb, chunk), np.int32)
+                consume = np.zeros(self.bb, np.int32)
+                for i in active:
+                    seq = self.slots[i]
+                    rem = len(seq.req.prompt) - seq.ptr
+                    if rem > 0:
+                        n = min(rem, chunk)
+                        ids[i, :n] = seq.req.prompt[seq.ptr:seq.ptr + n]
+                    else:
+                        n = 1
+                        ids[i, 0] = self.tokens[i]
+                    consume[i] = n
+                feeds = {fk["input_ids"]: ids,
+                         fk["positions"]: self.positions.copy(),
+                         fk["valid"]: consume}
+            else:
+                consume = [1] * self.bb
+                feeds = {
+                    fk["input_ids"]: self.tokens.reshape(self.bb, 1).copy(),
+                    fk["positions"]: self.positions.copy()}
+            # the caches are DONATED device arrays fed straight back from
+            # the previous step's fetches — no host round-trip
+            # (_place_feed's np.asarray would force one, so the engine
+            # bypasses infer_rows)
+            for name in self.cache_names:
+                feeds[fk[name]] = self.caches[name]
+            ph.mark("dispatch")
+            with warnings.catch_warnings():
+                # ids/positions are int32 inputs with no matching output
+                # buffer; only the caches can (and do) donate
+                warnings.filterwarnings(
+                    "ignore", message="Some donated buffers were not usable")
+                outs = fn(ex.params, feeds)
+            # the logits D2H is paid only when some row will read it — a
+            # pure-prefill step never looks at outs[0] (ISSUE 18
+            # satellite)
+            if any(self.slots[i].ptr + int(consume[i])
+                   >= len(self.slots[i].req.prompt) for i in active):
+                # the D2H is queued behind the step NOW, as np.asarray
+                # alone would queue it: waiting for the logits first and
+                # asking for the copy after costs a host wake-up and a
+                # transfer dispatch per step with the chip idle
+                outs[0].copy_to_host_async()
+                ph.mark("wait")
+                outs[0].block_until_ready()
+                ph.mark("readback")
+                logits = np.asarray(outs[0])
+                ph.mark("host")
+            else:
+                ph.mark("host")
+                logits = None
+                record_decode("decode_logits_skipped")
+            self.last_logits = logits
+            for name, new in zip(self.cache_names, outs[1:]):
+                self.caches[name] = new
+            record_decode("decode_steps")
+            # every row of the batch bucket computes ``chunk`` tokens,
+            # whatever it holds: the denominator of the padding share
+            record_decode("decode_padded_row_tokens", self.bb * chunk)
+            if chunk > 1:
+                record_decode("decode_prefill_steps")
+                record_decode("decode_chunk_width", chunk)
+                # dispatches saved vs token-by-token: the widest row
+                # would have needed max(consume) one-token steps; this
+                # step is one
+                record_decode("decode_prefill_steps_saved",
+                              int(consume.max()) - 1)
+            emitted = 0
+            now = time.monotonic()
+            for i in active:
+                seq = self.slots[i]
+                n = int(consume[i])
+                self.positions[i] += n
+                plen = len(seq.req.prompt)
+                if seq.ptr + n < plen:
+                    # still mid-prompt: next prompt token, nothing to
+                    # emit yet
+                    seq.ptr += n
+                    self.tokens[i] = seq.req.prompt[seq.ptr]
+                    record_decode("decode_prefill_rows", n)
+                    continue
+                # prompt finished this step (n-1 of the consumed tokens
+                # were prefill rows, the last is the generate row) or the
+                # row was already generating (n == 1, zero prefill rows)
+                record_decode("decode_prefill_rows",
+                              (plen - seq.ptr - 1) if seq.ptr < plen else 0)
+                seq.ptr = plen
+                # this row's logits are live: greedy argmax
+                # (deterministic first-max tie-break keeps decode bitwise
+                # stable)
+                tok = int(np.argmax(logits[i]))
+                emitted += self._emit_token(i, seq, tok, now)
+            # dropped here, not at return: freeing the device's logits
+            # and the donated slabs' handles is the step's work too
+            del feeds, outs
+            ph.args = {"batch": self.bb, "len": self.lb, "chunk": chunk,
+                       "rows": len(active), "emitted": emitted}
+        record_decode_latency("step", (ph.t1 - t0) / 1e3)
         return emitted
 
 
@@ -1211,6 +1207,11 @@ class DecodeRouter:
                 self._cv.wait(0.05)
 
     def _loop(self):
+        # open from one step's return to the next one's entry while rows
+        # stay seated (only this thread seats or evicts, so a step
+        # follows, or the loop ends): the loop's own share of the gap
+        # between tokens
+        between = None
         while True:
             joins = self._take_joins()
             if joins is None:
@@ -1242,10 +1243,15 @@ class DecodeRouter:
             if not self.engine.idle:
                 try:
                     self.engine.evict_expired()
+                    if between is not None:
+                        between.close()
                     emitted = self.engine.step()
                 except Exception as e:    # noqa: BLE001 — every in-flight
                     self.engine.abort(e)  # stream must learn its fate; the
                                           # router keeps serving new work
+                between = None if self.engine.idle else _Phases(
+                    "decode.between", record_decode,
+                    total="decode_between_steps_us", cat="decode")
             with self._cv:
                 seated = [s.req for s in self.engine.slots
                           if s is not None]

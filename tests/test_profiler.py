@@ -96,3 +96,40 @@ def test_trace_writes_profile(tmp_path):
                          tmp_path / "trace")
     found = [f for _, _, fs in os.walk(out_dir) for f in fs]
     assert found, "trace produced no files"
+
+
+def test_trace_holds_the_executors_spans_on_the_profilers_clock(tmp_path):
+    """ISSUE 25: ``HetuProfiler.trace()`` switches span tracing on for its
+    capture (and puts it back), so the ``.xplane.pb`` it writes holds the
+    executor's step phases beside whatever the device did — read back with
+    the benchmark's own loader."""
+    import os
+    import sys
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from benchmarks import trace_reduce
+    from hetu_tpu import obs
+    ex, feeds = _mlp_executor()
+    prof = ht.HetuProfiler(ex, "train")
+    assert not obs.enabled()
+    obs.clear_trace()
+    out_dir = prof.trace(feeds, tmp_path / "trace", steps=3)
+    assert not obs.enabled()            # put back as it was
+    planes = trace_reduce.load(trace_reduce.find_xplane(out_dir))
+    host = [e for lines in planes.values() for evs in lines.values()
+            for e in evs]
+    steps = sorted((e for e in host if e[0] == "step"), key=lambda e: e[1])
+    assert len(steps) == 3
+    for name in ("run_plan.lookup", "feeds.place", "jit.dispatch"):
+        kids = [e for e in host if e[0] == name]
+        assert len(kids) == 3, name
+        assert all(any(s[1] <= k[1] and k[1] + k[2] <= s[1] + s[2]
+                       for s in steps) for k in kids), name
+    assert sum(e[0] == "hetu_step" for e in host) == 3
+    # the ring recorded the same steps; outside a capture with tracing on,
+    # no annotation object is built (obs.trace.annotate is the one gate)
+    ring = [e for e in obs.trace_events() if e.get("name") == "step"]
+    obs.clear_trace()
+    assert len(ring) == 3
+    from hetu_tpu.obs.trace import annotate
+    assert annotate("x") is None

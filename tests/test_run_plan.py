@@ -477,3 +477,35 @@ def test_overhead_bench_smoke():
                 "numpy_feed_us", "pipelined_feed_us",
                 "dispatch_overhead_us", "overhead_multiple_vs_raw_jit"):
         assert fld in e and e[fld] >= 0
+
+
+def test_async_sync_point_is_a_span_the_flow_arrow_ends_in():
+    """ISSUE 25: traced, materialising a ``run(sync=False)`` step is an
+    ``executor.sync`` span, and each dispatch's ``async_step`` arrow ends
+    inside one; untraced, the ring stays empty."""
+    from hetu_tpu import obs
+    x, loss, train = _dense_graph()
+    ex = ht.Executor({"train": [loss, train]}, seed=0)
+    xv = _feed()
+    obs.enable(False)
+    obs.clear_trace()
+    for _ in range(3):
+        ex.run("train", feed_dict={x: xv}, sync=False)
+    ex._drain_async()
+    assert obs.TRACER.records() == []
+    obs.enable(True)
+    try:
+        for _ in range(3):
+            ex.run("train", feed_dict={x: xv}, sync=False)
+        ex._drain_async()
+    finally:
+        obs.enable(False)
+    evs = obs.trace_events()
+    obs.clear_trace()
+    syncs = [e for e in evs if e.get("ph") == "X"
+             and e["name"] == "executor.sync"]
+    ends = [e for e in evs if e.get("ph") == "f"
+            and e["name"] == "async_step"]
+    assert len(syncs) == len(ends) == 3
+    assert all(any(s["ts"] <= e["ts"] <= s["ts"] + s["dur"] for s in syncs)
+               for e in ends)
