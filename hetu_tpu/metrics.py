@@ -559,7 +559,11 @@ def reset_serve_counts():
 # claim), queue-full rejections (``decode_rejections``), and the
 # device-resident KV-cache footprint high-water mark
 # (``decode_kv_bytes_hw`` — gauge semantics: the recorded value is the MAX
-# ever seen).  Chunked prefill (ISSUE 18) adds the prompt-ingestion
+# ever seen) beside the slab format those bytes are stored in
+# (``decode_kv_slab_format_hw``, a gauge too: key rows per slab row — 1
+# for plain (B, H, L, D) rows, 2 for GPT-2's 64-wide heads, two to a
+# 128-lane row; ``ops.attention.kv_slab_shape``, chosen by ``head_dim``
+# alone).  Chunked prefill (ISSUE 18) adds the prompt-ingestion
 # accounting: ``decode_prefill_steps`` (steps that ran the q_len=C
 # chunked entry), ``decode_prefill_steps_saved`` (dispatches a chunked
 # step avoided vs the token-by-token path: the widest row's chunk minus

@@ -123,9 +123,10 @@ class _DecodeBlockLayer:
 def _block_decode(cfg, x, k_cache, v_cache, positions, name):
     """One-token decode of :func:`_block`: identical weights BY NAME
     (``.ln1``/``.attn.{q,k,v,o}``/``.ln2``/``.mlp_fc``/``.mlp_proj``),
-    attention against the bucketed KV cache through the flash kernel's
-    q_len=1 entry instead of the full sequence.  No dropout: decode is a
-    serving graph.  Returns (x, new_k_cache, new_v_cache, layer)."""
+    attention against the bucketed KV slabs through the one-token
+    kernel (``sdpa_decode_op``) instead of the full sequence.  No
+    dropout: decode is a serving graph.  Returns (x, new_k_cache,
+    new_v_cache, layer)."""
     dk = cfg.n_embd // cfg.n_head
     h = LayerNorm(cfg.n_embd, cfg.layer_norm_epsilon, name + ".ln1")(x)
 
@@ -173,9 +174,14 @@ def gpt2_decode_graph(cfg, max_len=None, name="gpt2"):
       during generation)
     * ``positions`` (B,) int32 — the cache row that token writes; keys
       beyond it stay invisible to the q_len=1 attention
-    * ``k_cache_i`` / ``v_cache_i`` (B, n_head, L, head_dim) per layer —
-      the device-resident caches, fed back from the previous step's
-      fetches (donated: XLA updates them in place)
+    * ``k_cache_i`` / ``v_cache_i`` per layer — the device-resident KV
+      slabs, fed back from the previous step's fetches (donated: XLA
+      updates them in place).  Their stored shape follows from
+      ``head_dim`` alone (:func:`~hetu_tpu.ops.attention.kv_slab_shape`):
+      (B, n_head, L/r, r*head_dim) with ``r = 128 // head_dim``
+      consecutive key rows per 128-lane row when ``head_dim`` divides 128
+      (GPT-2's 64: two), plain (B, n_head, L, head_dim) otherwise — the
+      layout the append and the attention both read without a relayout
 
     Returns ``(feeds, logits, cache_fetches, layers)``: ``feeds`` maps
     the names above to placeholder nodes, ``logits`` is (B, vocab) for
@@ -198,12 +204,10 @@ def gpt2_decode_graph(cfg, max_len=None, name="gpt2"):
     feeds = {"input_ids": ids, "positions": positions}
     cache_fetches, layers = [], []
     for i in range(cfg.n_layer):
-        kc = placeholder_op(
-            f"k_cache_{i}", dtype=np.float32,
-            shape=(cfg.batch_size, cfg.n_head, max_len, dk))
-        vc = placeholder_op(
-            f"v_cache_{i}", dtype=np.float32,
-            shape=(cfg.batch_size, cfg.n_head, max_len, dk))
+        kc = ops.kv_slab_placeholder(
+            f"k_cache_{i}", cfg.batch_size, cfg.n_head, max_len, dk)
+        vc = ops.kv_slab_placeholder(
+            f"v_cache_{i}", cfg.batch_size, cfg.n_head, max_len, dk)
         feeds[f"k_cache_{i}"] = kc
         feeds[f"v_cache_{i}"] = vc
         x, kc2, vc2, layer = _block_decode(cfg, x, kc, vc, positions,
@@ -274,8 +278,9 @@ def gpt2_decode_chunked_graph(cfg, max_len=None, chunk=4, name="gpt2"):
     * ``valid`` (B,) int32 — how many chunk columns each sequence
       actually consumes (0 for idle slots); rows ``>= valid`` neither
       write the cache nor reach the logits
-    * ``k_cache_i`` / ``v_cache_i`` (B, n_head, L, head_dim) per layer —
-      donated, fed back from the previous step's fetches
+    * ``k_cache_i`` / ``v_cache_i`` per layer — the same KV slabs as the
+      one-token graph's (the two executors hand the same device arrays
+      back and forth), donated, fed back from the previous step's fetches
 
     Returns ``(feeds, logits, cache_fetches, layers)`` like the
     one-token graph; ``logits`` is (B, vocab) for each sequence's LAST
@@ -304,12 +309,10 @@ def gpt2_decode_chunked_graph(cfg, max_len=None, chunk=4, name="gpt2"):
     feeds = {"input_ids": ids, "positions": positions, "valid": valid}
     cache_fetches, layers = [], []
     for i in range(cfg.n_layer):
-        kc = placeholder_op(
-            f"k_cache_{i}", dtype=np.float32,
-            shape=(cfg.batch_size, cfg.n_head, max_len, dk))
-        vc = placeholder_op(
-            f"v_cache_{i}", dtype=np.float32,
-            shape=(cfg.batch_size, cfg.n_head, max_len, dk))
+        kc = ops.kv_slab_placeholder(
+            f"k_cache_{i}", cfg.batch_size, cfg.n_head, max_len, dk)
+        vc = ops.kv_slab_placeholder(
+            f"v_cache_{i}", cfg.batch_size, cfg.n_head, max_len, dk)
         feeds[f"k_cache_{i}"] = kc
         feeds[f"v_cache_{i}"] = vc
         x, kc2, vc2, layer = _block_decode_chunked(

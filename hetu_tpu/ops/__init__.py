@@ -38,6 +38,7 @@ from .moe import (topk_gate_op, ktop1_gate_op, sam_gate_op,
 from .attention import (sdpa_op, sdpa_masked_op, sdpa_bias_op,
                         sdpa_masked_bias_op, sdpa_varlen_op,
                         sdpa_decode_op, kv_cache_append_op,
+                        kv_slab_placeholder, kv_slab_shape,
                         sdpa_prefill_op, chunk_positions_op,
                         split_heads_chunk_op, merge_heads_chunk_op,
                         chunk_emit_gather_op,

@@ -12,6 +12,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .base import def_op
 
@@ -380,8 +381,115 @@ def _sdpa_varlen(c, q, k, v, lengths, causal=False, scale=None):
 sdpa_varlen_op = def_op("ScaledDotProductAttentionVarlen", _sdpa_varlen)
 
 
-def _decode_gate_reason(k_cache):
-    """Why a decode step leaves the flash path (None = flash-able).  The
+# ------------------------------------------------------------ KV slabs
+# One format for the decode plane's KV caches, chosen by ``head_dim``
+# alone.  The device stores an array whose minor dimension is under 128
+# lanes with that dimension moved inward (a (B, H, L, 64) f32 array is
+# stored length-minor), and every kernel or dot that wants (L, D) rows
+# then pays a whole-slab transposing copy, per layer, per step.  So a
+# head narrower than a lane row shares it: ``r = 128 // D`` consecutive
+# key rows side by side, slab shape ``(B, H, ceil(L / r), r * D)``.  The
+# append, the one-token kernel and the chunked steps' jnp attention all
+# read and write that shape as it is stored; ``(H, m, D)`` rows exist
+# only at the edges (prefix snapshots: ``kv_slab_from_rows`` /
+# ``kv_slab_to_rows``).  The ops below derive ``r`` from the shapes they
+# are handed (slab lanes over the query's / new rows' ``D``), so a plain
+# ``(B, H, L, D)`` cache is simply the ``r = 1`` case.
+_LANES = 128
+
+
+def kv_slab_pack(head_dim):
+    """Key rows per slab row: ``128 // head_dim`` when ``head_dim`` is a
+    proper divisor of the 128 lanes, else 1 (a head that fills whole lane
+    rows, or cannot share one evenly, keeps plain (B, H, L, D) rows)."""
+    d = int(head_dim)
+    return _LANES // d if d < _LANES and _LANES % d == 0 else 1
+
+
+def kv_slab_shape(batch, heads, length, head_dim):
+    """Stored shape of a KV slab holding ``length`` rows of ``head_dim``:
+    ``(batch, heads, ceil(length / r), r * head_dim)``, key row ``p`` in
+    slab row ``p // r``, lanes ``[(p % r) * D, (p % r + 1) * D)``."""
+    r = kv_slab_pack(head_dim)
+    return (batch, heads, -(-int(length) // r), r * int(head_dim))
+
+
+def kv_slab_placeholder(name, batch, heads, length, head_dim,
+                        dtype=np.float32):
+    """The feed of one KV slab: a placeholder of :func:`kv_slab_shape`
+    that also says which ``head_dim`` it was packed for (``attrs``), the
+    one thing the shape alone cannot tell the decode engine."""
+    from ..graph.node import placeholder_op
+    node = placeholder_op(name, dtype=dtype,
+                          shape=kv_slab_shape(batch, heads, length,
+                                              head_dim))
+    node.attrs["head_dim"] = int(head_dim)
+    return node
+
+
+def kv_slab_from_rows(rows, lanes):
+    """(..., m, D) key rows -> (..., ceil(m / r), lanes) slab rows, the
+    last one zero-filled past row ``m``."""
+    m, d = rows.shape[-2:]
+    r = lanes // d
+    pad = -m % r
+    if pad:
+        rows = jnp.pad(rows, [(0, 0)] * (rows.ndim - 2) + [(0, pad), (0, 0)])
+    return rows.reshape(*rows.shape[:-2], (m + pad) // r, lanes)
+
+
+def kv_slab_to_rows(slab, head_dim):
+    """(..., n, r * D) slab rows -> the (..., n * r, D) key rows they
+    hold.  A relayout on the device: for snapshots, tests and the one
+    step that amortises it (a prefill chunk that tiles the flash
+    kernel), never for a whole slab in a one-token step."""
+    n, lanes = slab.shape[-2:]
+    return slab.reshape(*slab.shape[:-2], n * (lanes // head_dim), head_dim)
+
+
+def kv_slab_queries(q, pack):
+    """(..., D) query rows -> (..., r, r * D): copy ``j`` of a query sits
+    in lanes ``[j * D, (j + 1) * D)`` of row ``j``, zeros elsewhere.  Its
+    product with a slab scores ``r`` keys per slab row — row ``j``,
+    column ``m`` is key ``m * r + j`` (the same products plus exact
+    zeros) — with no slab-shaped slice or transpose."""
+    eye = jnp.eye(pack, dtype=q.dtype)
+    rows = eye[:, :, None] * q[..., None, None, :]       # (..., r, r, D)
+    return rows.reshape(*q.shape[:-1], pack, pack * q.shape[-1])
+
+
+def sdpa_slab_reference(q, k_slab, v_slab, lengths, scale=None):
+    """Plain-jnp attention of (B, H, C, D) queries over KV slabs, read as
+    stored.  ``lengths``: (B, C) int — query ``c`` of sequence ``b`` sees
+    keys ``< lengths[b, c]`` (at least one).  The softmax runs over the
+    ``r`` score rows of a query together; the output is the sum over
+    ``j`` of lanes ``[j * D, (j + 1) * D)`` of row ``j`` of ``P @ V``."""
+    b, h, chunk, d = q.shape
+    slab_rows, lanes = k_slab.shape[2:]
+    pack = lanes // d
+    scale = scale if scale is not None else 1.0 / (d ** 0.5)
+    qr = kv_slab_queries(q, pack)                  # (B, H, C, r, lanes)
+    s = jnp.einsum("bhcjl,bhml->bhcjm", qr, k_slab,
+                   preferred_element_type=jnp.float32) * scale
+    key = (jnp.arange(slab_rows, dtype=jnp.int32)[None, :] * pack
+           + jnp.arange(pack, dtype=jnp.int32)[:, None])     # (r, L/r)
+    seen = key[None, None, None] < lengths.astype(
+        jnp.int32)[:, None, :, None, None]         # (B, 1, C, r, L/r)
+    s = jnp.where(seen, s, -1e30)
+    probs = jax.nn.softmax(
+        s.reshape(b, h, chunk, pack * slab_rows), axis=-1).reshape(s.shape)
+    out = jnp.einsum("bhcjm,bhml->bhcjl", probs.astype(q.dtype), v_slab)
+    return jnp.einsum("bhcjjd->bhcd",
+                      out.reshape(b, h, chunk, pack, pack, d))
+
+
+def _slab_len(q, k_cache):
+    """Key rows a slab has room for: slab rows times rows per slab row."""
+    return k_cache.shape[-2] * (k_cache.shape[-1] // q.shape[-1])
+
+
+def _decode_gate_reason(q, k_cache):
+    """Why a decode step leaves the kernel path (None = kernel-able).  The
     decode gate keys on the KV-CACHE length — the axis the kernel tiles
     and the axis that grows as generation proceeds — not the base gate's
     q_len (always 1 in decode, where the base gate would refuse every
@@ -389,7 +497,7 @@ def _decode_gate_reason(k_cache):
     be = jax.default_backend()
     if be != "tpu":
         return f"backend:{be}"
-    s_kv = k_cache.shape[-2]
+    s_kv = _slab_len(q, k_cache)
     if s_kv < _FLASH_MIN_LEN:
         return f"decode_below_gate:kv{s_kv}<{_FLASH_MIN_LEN}"
     if s_kv % 128:
@@ -398,28 +506,27 @@ def _decode_gate_reason(k_cache):
 
 
 def dispatch_sdpa_decode(q, k_cache, v_cache, positions, scale=None):
-    """One autoregressive decode step against a bucketed KV cache — the
-    degenerate q_len=1 entry of the flash kernel's lengths path.
+    """One autoregressive decode step against a bucketed KV cache.
 
     ``q``: the current token's query, (B, H, 1, D).  ``k_cache`` /
-    ``v_cache``: (B, H, L, D) with the new token already appended at
-    ``positions`` (see ``kv_cache_append_op``).  ``positions``: (B,)
-    int — the row each sequence just wrote; keys beyond it are invisible
-    (so ``causal`` is implied: the query IS the last valid key).  On TPU
-    a cache at a mod-128 bucket >= the flash gate rides the kernel's
-    lengths path (fully-masked key blocks cost no FLOPs — exactly where
-    a long cache pays); anything else is the counted jnp reference."""
+    ``v_cache``: KV slabs (:func:`kv_slab_shape`: (B, H, L/r, r*D), or
+    plain (B, H, L, D) — ``r`` is read off the shapes) with the new token
+    already appended at ``positions`` (see ``kv_cache_append_op``).
+    ``positions``: (B,) int — the row each sequence just wrote; keys
+    beyond it are invisible (so ``causal`` is implied: the query IS the
+    last valid key).  On TPU a cache at a mod-128 bucket >= the flash
+    gate goes to the one-token kernel (:mod:`~hetu_tpu.ops.pallas.
+    decode_attention`: the slab read as stored, key blocks past a
+    sequence's length neither fetched nor computed); anything else is
+    the counted jnp reference over the same slabs."""
     lengths = positions.astype(jnp.int32) + 1
-    reason = _decode_gate_reason(k_cache)
+    reason = _decode_gate_reason(q, k_cache)
     if reason is None:
-        from .pallas.flash_attention import flash_attention
-        return flash_attention(q, k_cache, v_cache, causal=False,
-                               scale=scale, lengths=lengths)
+        from .pallas.decode_attention import decode_attention
+        return decode_attention(q, k_cache, v_cache, lengths, scale=scale)
     _note_flash_fallback(reason)
-    s_kv = k_cache.shape[-2]
-    cols = jnp.arange(s_kv)[None, None, None, :]
-    mask = cols < lengths[:, None, None, None]
-    return sdpa_reference(q, k_cache, v_cache, scale=scale, mask=mask)
+    return sdpa_slab_reference(q, k_cache, v_cache, lengths[:, None],
+                               scale=scale)
 
 
 def _sdpa_decode(c, q, k_cache, v_cache, positions, scale=None):
@@ -433,36 +540,60 @@ sdpa_decode_op = def_op("ScaledDotProductAttentionDecode", _sdpa_decode)
 
 
 def _kv_cache_append(c, cache, new, positions, valid=None):
-    """Append (B, H, C, D) token rows into the (B, H, L, D) cache at
-    ``positions[b] .. positions[b]+C`` — a batched dynamic_update_slice,
-    the incremental write that makes a generation O(S) total attention
-    work instead of re-prefill's O(S^2).  C=1 is the classic decode
-    write; C>1 is a chunked-prefill write (ISSUE 18).
+    """Append (B, H, C, D) token rows into a KV slab at key rows
+    ``positions[b] .. positions[b]+C`` — the incremental write that makes
+    a generation O(S) total attention work instead of re-prefill's
+    O(S^2).  C=1 is the classic decode write; C>1 is a chunked-prefill
+    write (ISSUE 18).
+
+    ``cache`` is (B, H, L/r, r*D) (:func:`kv_slab_shape`; ``r`` is read
+    off the shapes, 1 for a plain (B, H, L, D) cache): key row ``p``
+    lives in slab row ``p // r`` at lanes ``[(p % r)*D, (p % r + 1)*D)``.
+    The write is a read-modify-write of the few slab rows the chunk
+    touches — per sequence a dynamic_slice, a select and a
+    dynamic_update_slice on the donated buffer, which XLA performs in
+    place — so every lane it does not own keeps its bytes.
 
     ``valid`` (optional 4th graph input, (B,) int): rows ``>= valid[b]``
-    of the chunk are NOT written — the old cache bytes are preserved via
-    a select, not a shorter slice, so a ragged chunk (a row consuming
-    fewer than C prompt tokens, or an idle slot with valid=0) leaves the
-    cache bitwise-identical to the token-by-token path.  That byte-level
+    of the chunk are NOT written — the old cache bytes are preserved by
+    the same select, so a ragged chunk (a row consuming fewer than C
+    prompt tokens, or an idle slot with valid=0) leaves the cache
+    bitwise-identical to the token-by-token path.  That byte-level
     path-independence is what makes shared-prefix KV snapshots safe to
     reuse across ingestion modes.  The engine guarantees positions+C
-    never exceeds the bucketed L (out-of-range starts clamp under XLA
-    dynamic_update_slice semantics and would shift the write window)."""
-    positions = positions.astype(jnp.int32)
-    if valid is None:
-        def upd(c_hld, n_hcd, p):
-            return jax.lax.dynamic_update_slice(c_hld, n_hcd, (0, p, 0))
-        return jax.vmap(upd)(cache, new, positions)
-    chunk = new.shape[-2]
-    keep = (jnp.arange(chunk)[None, :, None]
-            < valid.astype(jnp.int32)[:, None, None])  # (B, C, 1)
+    never exceeds the rows the slab holds (past it the window clamps
+    under XLA dynamic-slice semantics and the write would shift)."""
+    positions = jnp.asarray(positions, jnp.int32)
+    heads, chunk, d = new.shape[1:]
+    slab_rows, lanes = cache.shape[2:]
+    r = lanes // d
+    # slab rows a chunk can touch, wherever in a slab row it starts
+    win = min((chunk + r - 2) // r + 1, slab_rows)
+    count = (jnp.full(positions.shape, chunk, jnp.int32) if valid is None
+             else jnp.minimum(jnp.asarray(valid, jnp.int32), chunk))
+    # key row (counted from the window's first) of every window element
+    at = (jnp.arange(win, dtype=jnp.int32)[:, None] * r
+          + jnp.arange(lanes, dtype=jnp.int32)[None, :] // d)
 
-    def updv(c_hld, n_hcd, p, k_c1):
-        old = jax.lax.dynamic_slice(
-            c_hld, (0, p, 0), (c_hld.shape[0], chunk, c_hld.shape[2]))
+    def write(b, slab):
+        # one sequence: its window out, the chunk's rows selected in,
+        # the window back — a loop over the batch, not a vmap, because a
+        # batched dynamic_slice is a gather, for whose operand the
+        # compiler relays out the whole slab
+        p = positions[b]
+        row0 = jnp.minimum(p // r, slab_rows - win)
+        off = p - row0 * r
+        old = jax.lax.dynamic_slice(slab, (b, 0, row0, 0),
+                                    (1, heads, win, lanes))
+        rows = jax.lax.dynamic_slice(new, (b, 0, 0, 0),
+                                     (1, heads, chunk, d))
+        fresh = jax.lax.dynamic_update_slice(
+            jnp.zeros((1, heads, win * r, d), slab.dtype),
+            rows.astype(slab.dtype), (0, 0, off, 0)).reshape(old.shape)
+        keep = jnp.logical_and(at >= off, at < off + count[b])
         return jax.lax.dynamic_update_slice(
-            c_hld, jnp.where(k_c1, n_hcd, old), (0, p, 0))
-    return jax.vmap(updv)(cache, new, positions, keep)
+            slab, jnp.where(keep, fresh, old), (b, 0, row0, 0))
+    return jax.lax.fori_loop(0, cache.shape[0], write, cache)
 
 
 kv_cache_append_op = def_op("KVCacheAppend", _kv_cache_append)
@@ -478,7 +609,7 @@ def _prefill_gate_reason(q, k_cache):
     be = jax.default_backend()
     if be != "tpu":
         return f"backend:{be}"
-    s_kv = k_cache.shape[-2]
+    s_kv = _slab_len(q, k_cache)
     if s_kv < _FLASH_MIN_LEN:
         return f"prefill_below_gate:kv{s_kv}<{_FLASH_MIN_LEN}"
     if s_kv % 128:
@@ -493,29 +624,33 @@ def dispatch_sdpa_prefill(q, k_cache, v_cache, positions, scale=None):
     generalization of ``dispatch_sdpa_decode`` (ISSUE 18).
 
     ``q``: this chunk's queries, (B, H, C, D).  ``k_cache`` /
-    ``v_cache``: (B, H, L, D) with the chunk's rows already appended at
-    ``positions..positions+C`` (see ``kv_cache_append_op``).
-    ``positions``: (B,) int — the cache row of each sequence's FIRST
-    chunk token; chunk-local query j may see keys ``< positions+j+1``
-    (causal-within-chunk, everything before the chunk visible).  The
-    per-batch offsets put TPU dispatch on the kernel's full-mask path
-    (kernel-causal can't shift its diagonal per batch row); elsewhere
-    the counted jnp reference.  Rows past a sequence's real prompt are
-    masked by the CALLER's cache-write ``valid`` and sliced away by the
-    emit gather — their outputs are don't-cares here."""
-    chunk = q.shape[-2]
+    ``v_cache``: KV slabs (:func:`kv_slab_shape`) with the chunk's rows
+    already appended at ``positions..positions+C`` (see
+    ``kv_cache_append_op``).  ``positions``: (B,) int — the cache row of
+    each sequence's FIRST chunk token; chunk-local query j may see keys
+    ``< positions+j+1`` (causal-within-chunk, everything before the
+    chunk visible).  Chunks below 128 rows — every chunk of the engine's
+    default ladder — take the counted jnp reference, which reads the
+    slabs as stored.  A chunk that tiles goes to the flash kernel's
+    full-mask path (kernel-causal can't shift its diagonal per batch
+    row), which wants (L, D) rows: a packed slab is unpacked for it, a
+    whole-slab relayout that a 128-row chunk amortises.  Rows past a
+    sequence's real prompt are masked by the CALLER's cache-write
+    ``valid`` and sliced away by the emit gather — their outputs are
+    don't-cares here."""
+    chunk, d = q.shape[-2:]
     lengths = (positions.astype(jnp.int32)[:, None]
                + 1 + jnp.arange(chunk, dtype=jnp.int32)[None, :])  # (B, C)
-    s_kv = k_cache.shape[-2]
-    cols = jnp.arange(s_kv, dtype=jnp.int32)
-    mask = cols[None, None, None, :] < lengths[:, None, :, None]
     reason = _prefill_gate_reason(q, k_cache)
     if reason is None:
         from .pallas.flash_attention import flash_attention
-        return flash_attention(q, k_cache, v_cache, causal=False,
+        cols = jnp.arange(_slab_len(q, k_cache), dtype=jnp.int32)
+        mask = cols[None, None, None, :] < lengths[:, None, :, None]
+        return flash_attention(q, kv_slab_to_rows(k_cache, d),
+                               kv_slab_to_rows(v_cache, d), causal=False,
                                scale=scale, mask=mask)
     _note_flash_fallback(reason)
-    return sdpa_reference(q, k_cache, v_cache, scale=scale, mask=mask)
+    return sdpa_slab_reference(q, k_cache, v_cache, lengths, scale=scale)
 
 
 def _sdpa_prefill(c, q, k_cache, v_cache, positions, scale=None):

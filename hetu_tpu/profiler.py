@@ -426,7 +426,9 @@ class HetuProfiler:
         ladder growths (``decode_batch_grows`` / ``decode_len_grows`` —
         each at most one fresh compile), queue-full rejections, the
         device-resident KV-cache footprint high-water mark
-        (``decode_kv_bytes_hw`` — a max gauge, not a sum), and the
+        (``decode_kv_bytes_hw`` — a max gauge, not a sum) with the slab
+        format it is stored in (``decode_kv_slab_format_hw``: key rows
+        per 128-lane slab row, chosen by ``head_dim``), and the
         chunked-prefill accounting (ISSUE 18): steps through the
         q_len=C entry (``decode_prefill_steps``), dispatches saved vs
         token-by-token ingestion (``decode_prefill_steps_saved``), and

@@ -7,13 +7,20 @@ This module is the serving plane for that workload:
 
 * **Incremental KV cache.**  Each decode step feeds exactly one token per
   sequence through the q_len=1 attention entry
-  (:func:`~hetu_tpu.ops.sdpa_decode_op`) against per-layer caches of
-  shape ``(batch_bucket, heads, len_bucket, head_dim)`` — the bucketed,
-  slot-major realization of the paper's per-sequence
-  ``(layers, 2, max_len, heads, head_dim)`` cache.  Caches live on
-  device for the whole generation: the engine feeds the previous step's
-  fetched cache arrays straight back into the next jitted call (donated,
-  so XLA appends in place) and never round-trips them through the host.
+  (:func:`~hetu_tpu.ops.sdpa_decode_op`) against per-layer KV slabs —
+  the bucketed, slot-major realization of the paper's per-sequence
+  ``(layers, 2, max_len, heads, head_dim)`` cache.  A slab is stored in
+  the layout the attention reads, and ``head_dim`` alone picks it
+  (:func:`~hetu_tpu.ops.attention.kv_slab_shape`): ``(batch_bucket,
+  heads, len_bucket / r, r * head_dim)`` with ``r = 128 // head_dim``
+  consecutive key rows sharing one 128-lane row when ``head_dim`` is a
+  divisor of 128, plain ``(batch_bucket, heads, len_bucket, head_dim)``
+  otherwise — a minor dimension under 128 lanes would be stored
+  length-minor by the device and transposed, whole, for every layer of
+  every step.  Caches live on device for the whole generation: the
+  engine feeds the previous step's fetched cache arrays straight back
+  into the next jitted call (donated, so XLA appends in place) and never
+  round-trips them through the host.
 
 * **Bucketed growth, compile-once steady state.**  Both the batch dim and
   the cache length walk the same flash-legal ladder serving uses
@@ -355,7 +362,13 @@ class DecodeEngine:
     (any graph with the same feed contract works): ``feeds`` maps
     ``input_ids`` (B, 1) / ``positions`` (B,) / per-layer cache
     placeholders to nodes, ``logits`` is the (B, vocab) fetch,
-    ``cache_fetches`` the appended caches in feed order.
+    ``cache_fetches`` the appended caches in feed order.  The cache
+    placeholders are KV slabs (:func:`~hetu_tpu.ops.kv_slab_placeholder`:
+    (B, heads, L/r, r*head_dim), ``r`` key rows per 128-lane row, chosen
+    by ``head_dim`` alone); the engine allocates, grows, seats and
+    snapshots them in that stored shape, and a plain (B, heads, L,
+    head_dim) placeholder is the ``r = 1`` case.  Prefix snapshots keep
+    external ``(heads, m, head_dim)`` rows, whatever the slab format.
 
     ``max_slots`` caps the in-flight batch (the top of the batch-bucket
     ladder); ``max_len`` caps the cache length (prompt + generated).
@@ -394,7 +407,11 @@ class DecodeEngine:
         # placeholder node -> executor feed key, by feed NAME
         self._fk = {name: self.iex._k(node) for name, node in feeds.items()}
         ck0 = feeds[self.cache_names[0]]
-        self._heads, self._head_dim = ck0.shape[1], ck0.shape[3]
+        # the slab's lanes hold ``_pack`` key rows of ``_head_dim`` each
+        # (1 for a plain (B, H, L, D) placeholder, which says no more)
+        self._heads, self._lanes = ck0.shape[1], ck0.shape[3]
+        self._head_dim = int(ck0.attrs.get("head_dim", self._lanes))
+        self._pack = self._lanes // self._head_dim
         self._cache_dtype = np.dtype(getattr(ck0, "dtype", np.float32))
         self.ciex = None
         self.chunk_ladder = (1,)
@@ -443,15 +460,22 @@ class DecodeEngine:
 
     # -- memory ------------------------------------------------------------
 
+    def _slab_rows(self, n):
+        """Slab rows that hold ``n`` key rows."""
+        return -(-int(n) // self._pack)
+
     def _alloc(self, bb, lb):
         import jax.numpy as jnp
-        z = jnp.zeros((bb, self._heads, lb, self._head_dim),
+        z = jnp.zeros((bb, self._heads, self._slab_rows(lb), self._lanes),
                       self._cache_dtype)
         return self.iex._place(z)
 
     def _note_kv_bytes(self):
         record_decode("decode_kv_bytes_hw",
                       sum(int(c.nbytes) for c in self.caches.values()))
+        # which slab format this process's engines serve from: key rows
+        # per slab row (1 = plain (B, H, L, D) rows)
+        record_decode("decode_kv_slab_format_hw", self._pack)
 
     @property
     def kv_bytes(self):
@@ -517,7 +541,7 @@ class DecodeEngine:
                 raise RuntimeError(
                     f"cache position {need} exceeds max_len {self.max_len}")
             record_decode("decode_len_grows")
-        pad = lb - self.lb
+        pad = self._slab_rows(lb) - self._slab_rows(self.lb)
         self.caches = {
             name: self.iex._place(
                 jnp.pad(c, ((0, 0), (0, 0), (0, pad), (0, 0))))
@@ -551,9 +575,13 @@ class DecodeEngine:
             # the snapshot rows land at 0..m-1: grow the length bucket
             # first (the fresh padding is all-zero, like a cold slot)
             self._grow_len_if_needed()
+            from ..ops.attention import kv_slab_from_rows
             for name in self.cache_names:
+                # whole slab rows: the last one zero past row m, rows no
+                # key of this sequence has reached yet
                 self.caches[name] = self.iex._place(
-                    self.caches[name].at[slot, :, :m, :].set(rows[name]))
+                    self.caches[name].at[slot, :, :self._slab_rows(m), :]
+                    .set(kv_slab_from_rows(rows[name], self._lanes)))
         if self._used[slot]:
             record_decode("decode_slot_recycles")
         self._used[slot] = True
@@ -632,6 +660,27 @@ class DecodeEngine:
 
     # -- the decode step ---------------------------------------------------
 
+    def _program(self, ex, fk):
+        """``fn(params, (feeds, slabs))`` over executor ``ex``: its
+        serving step with the KV slabs handed over as a TUPLE in
+        ``cache_names`` order — the order of the step's cache fetches.
+        A donated input is paired with the first output of its shape, in
+        argument order, and every slab has the same shape: fed inside
+        the feed dict the slabs arrive sorted by feed key (``s1016`` <
+        ``s16``), each paired with ANOTHER layer's output, and the
+        compiler has to copy nearly every slab, whole, in every step to
+        honour the pairing.  In a tuple, slab ``i`` is donated to
+        updated slab ``i`` and the append is in place.  Captures the
+        keys, never the engine (the serve cache keeps it alive)."""
+        infer = ex._infer_fn()
+        keys = [fk[name] for name in self.cache_names]
+
+        def step(params, fed):
+            feeds, slabs = fed
+            return infer(params, {**feeds, **dict(zip(keys, slabs))})
+
+        return step
+
     def _step_fn(self):
         """The jitted step for the CURRENT (batch_bucket, len_bucket):
         dispatched through the keyed plan cache (hit = zero planning),
@@ -641,7 +690,7 @@ class DecodeEngine:
 
         def build():
             return step_cache.lookup_or_build_serve(
-                self.iex, key, self.iex._infer_fn())
+                self.iex, key, self._program(self.iex, self._fk))
 
         return self._plans.lookup(key, build)
 
@@ -655,7 +704,7 @@ class DecodeEngine:
 
         def build():
             return step_cache.lookup_or_build_serve(
-                self.ciex, key, self.ciex._infer_fn())
+                self.ciex, key, self._program(self.ciex, self._cfk))
 
         return self._plans.lookup(key, build)
 
@@ -747,8 +796,10 @@ class DecodeEngine:
         p = len(seq.req.prompt)
         if p < self.prefix.min_tokens:
             return
-        rows = {name: self.caches[name][i, :, :p, :]
-                for name in self.cache_names}
+        from ..ops.attention import kv_slab_to_rows
+        rows = {name: kv_slab_to_rows(
+            self.caches[name][i, :, :self._slab_rows(p), :],
+            self._head_dim)[:, :p, :] for name in self.cache_names}
         self.prefix.insert(seq.req.prompt, rows)
 
     def step(self):
@@ -815,16 +866,16 @@ class DecodeEngine:
             # the caches are DONATED device arrays fed straight back from
             # the previous step's fetches — no host round-trip
             # (_place_feed's np.asarray would force one, so the engine
-            # bypasses infer_rows)
-            for name in self.cache_names:
-                feeds[fk[name]] = self.caches[name]
+            # bypasses infer_rows) — in the fetches' own order
+            # (``_program``)
+            slabs = tuple(self.caches[name] for name in self.cache_names)
             ph.mark("dispatch")
             with warnings.catch_warnings():
                 # ids/positions are int32 inputs with no matching output
                 # buffer; only the caches can (and do) donate
                 warnings.filterwarnings(
                     "ignore", message="Some donated buffers were not usable")
-                outs = fn(ex.params, feeds)
+                outs = fn(ex.params, (feeds, slabs))
             # the logits D2H is paid only when some row will read it — a
             # pure-prefill step never looks at outs[0] (ISSUE 18
             # satellite)
@@ -886,7 +937,7 @@ class DecodeEngine:
                 emitted += self._emit_token(i, seq, tok, now)
             # dropped here, not at return: freeing the device's logits
             # and the donated slabs' handles is the step's work too
-            del feeds, outs
+            del feeds, slabs, outs
             ph.args = {"batch": self.bb, "len": self.lb, "chunk": chunk,
                        "rows": len(active), "emitted": emitted}
         record_decode_latency("step", (ph.t1 - t0) / 1e3)

@@ -86,6 +86,101 @@ def test_decode_bitwise_stable_across_batch_mates(decode_graph):
     assert len(solo) == 6
 
 
+# ------------------------------------------------ KV slab format (ISSUE 26)
+# head_dim 64 packs two key rows into a 128-lane slab row; head_dim 128
+# keeps plain (B, H, L, D) rows.  Both must append and attend exactly as
+# a (B, H, L, D) cache would.
+
+def _slab_case(head_dim, length=32, batch=4, heads=2, seed=0):
+    from hetu_tpu.ops.attention import kv_slab_from_rows, kv_slab_shape
+    rng = np.random.RandomState(seed)
+    rows = rng.standard_normal(
+        (batch, heads, length, head_dim)).astype(np.float32)
+    shape = kv_slab_shape(batch, heads, length, head_dim)
+    slab = np.asarray(kv_slab_from_rows(rows, shape[-1]))
+    assert slab.shape == shape and shape[-1] % 128 == 0
+    return rng, rows, slab
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["all", "valid"])
+@pytest.mark.parametrize("chunk", [1, 4])
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_kv_append_is_bitwise_the_row_append(head_dim, chunk, masked):
+    """The appended slab, read back as rows, is bit for bit what a
+    (B, H, L, D) append leaves: rows at and past ``valid`` keep their
+    bytes, an idle slot (valid 0) is untouched, and so is every lane the
+    chunk does not own — at position 0, at an odd position (the second
+    half of a slab row) and with the chunk ending on the last row."""
+    from hetu_tpu.ops.attention import _kv_cache_append, kv_slab_to_rows
+    rng, rows, slab = _slab_case(head_dim)
+    new = rng.standard_normal(
+        (4, 2, chunk, head_dim)).astype(np.float32)
+    positions = np.array([0, 5, 32 - chunk, 17], np.int32)
+    valid = np.array([chunk, 0, chunk, max(1, chunk - 1)], np.int32)
+    want = rows.copy()
+    for b in range(4):
+        n = int(valid[b]) if masked else chunk
+        want[b, :, positions[b]:positions[b] + n] = new[b, :, :n]
+    got = _kv_cache_append(None, slab, new, positions,
+                           valid if masked else None)
+    assert got.shape == slab.shape
+    assert np.array_equal(np.asarray(kv_slab_to_rows(got, head_dim)), want)
+
+
+@pytest.mark.parametrize("path", ["kernel", "kernel_blocks", "jnp",
+                                  "jnp_chunk"])
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_attention_over_slabs_matches_row_reference(head_dim, path,
+                                                    monkeypatch):
+    """The one-token attention over the stored slabs — the Pallas body in
+    interpret mode (whole cache in one key block, and cut into blocks so
+    the length-clamped block index is exercised) and the jnp path the
+    CPU serves — agrees with ``sdpa_reference`` over the unpacked rows
+    for ragged positions, position 0 and an odd position included; so
+    does the chunked steps' attention."""
+    from hetu_tpu.ops import attention as att
+    from hetu_tpu.ops.pallas import decode_attention as da
+    rng, k_rows, k_slab = _slab_case(head_dim, seed=1)
+    _, v_rows, v_slab = _slab_case(head_dim, seed=2)
+    chunk = 4 if path == "jnp_chunk" else 1
+    q = rng.standard_normal((4, 2, chunk, head_dim)).astype(np.float32)
+    positions = np.array([0, 5, 32 - chunk, 17], np.int32)
+    seen = positions[:, None] + 1 + np.arange(chunk)[None, :]   # (B, C)
+    mask = np.arange(32)[None, None, None, :] < seen[:, None, :, None]
+    want = np.asarray(att.sdpa_reference(q, k_rows, v_rows, mask=mask))
+    if path == "jnp":
+        got = att.dispatch_sdpa_decode(q, k_slab, v_slab, positions)
+    elif path == "jnp_chunk":
+        got = att.dispatch_sdpa_prefill(q, k_slab, v_slab, positions)
+    else:
+        if path == "kernel_blocks":
+            monkeypatch.setattr(da, "MAX_BLOCK_ROWS", 8)
+        got = da.decode_attention(q, k_slab, v_slab, positions + 1,
+                                  interpret=True)
+    assert got.shape == q.shape
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-6)
+
+
+def test_slab_format_follows_head_dim_alone(decode_graph):
+    """The rule: a head that is a proper divisor of the 128 lanes shares
+    a lane row, any other keeps plain rows; the engine reads the format
+    off the graph's placeholders and reports it."""
+    from hetu_tpu.ops.attention import kv_slab_pack, kv_slab_shape
+    assert [kv_slab_pack(d) for d in (8, 32, 64, 96, 128, 256)] == \
+        [16, 4, 2, 1, 1, 1]
+    assert kv_slab_shape(16, 16, 768, 64) == (16, 16, 384, 128)
+    assert kv_slab_shape(16, 16, 768, 128) == (16, 16, 768, 128)
+    assert kv_slab_shape(2, 2, 5, 64) == (2, 2, 3, 128)
+    metrics.reset_decode_counts()
+    eng = _engine(decode_graph)
+    assert (eng._heads, eng._head_dim, eng._pack) == (2, 64, 2)
+    assert all(c.shape[-1] == 128 for c in eng.caches.values())
+    assert metrics.decode_counts()["decode_kv_slab_format_hw"] == 2
+    # the slabs hold what (B, H, L, D) slabs held: not a byte more
+    assert eng.kv_bytes == (len(eng.caches) * eng.bb * 2 * 64 * 4
+                            * -(-eng.lb // 2) * 2)
+
+
 # ---------------------------------------------- continuous batching plane
 
 def test_continuous_join_leave_slot_recycle(decode_graph):

@@ -180,6 +180,50 @@ def test_prefix_cache_hit_bitwise_equal_and_counted(graphs):
     assert metrics.prefix_cache_counts()["prefix_cache_hits"] == 2
 
 
+#: what the parent commit (f1935ec: (B, H, L, D) slabs) served for the
+#: scenario below, every combination alike — recorded from its checkout
+_PARENT_STREAMS = [[321, 321, 92, 321, 92, 210], [321, 321, 92, 321, 92, 210],
+                   [67, 67, 67, 67, 67], [321, 356, 461, 147],
+                   [321, 321, 321, 321, 321, 321]]
+
+
+@pytest.mark.parametrize("store", [False, True], ids=["cold", "prefix_hit"])
+@pytest.mark.parametrize("chunked", [False, True],
+                         ids=["one_token", "chunked"])
+def test_router_streams_are_the_parents_token_for_token(graphs, chunked,
+                                                        store):
+    """ISSUE 26: the slab format is invisible in what is served.  One
+    lone request, then four together (the first prompt again, a
+    one-token prompt, a prompt sharing the first one's five leading
+    tokens, a short one) through ``DecodeRouter``: the streams are the
+    parent's on the same seed, one-token and chunked ingestion, with
+    and without ``PrefixKVStore`` hits (whose snapshots stay external
+    (H, m, D) rows)."""
+    metrics.reset_prefix_cache_counts()
+    rng = np.random.RandomState(26)
+    prompts = [rng.randint(1, _CFG.vocab_size, n).tolist()
+               for n in (9, 1, 6, 3)]
+    kw = {"prefix_store": PrefixKVStore(capacity_bytes=1 << 20)} \
+        if store else {}
+    if chunked:
+        kw["max_chunk"] = 4
+    eng = _engine(graphs, chunked=chunked, **kw)
+    with DecodeRouter(eng) as router:
+        first = router.submit(prompts[0], max_new_tokens=6).result(
+            timeout=120)
+        streams = [router.submit(p, max_new_tokens=n) for p, n in (
+            (prompts[0], 6), (prompts[1], 5),
+            (prompts[0][:5] + prompts[2], 4), (prompts[3], 6))]
+        got = [first] + [s.result(timeout=120) for s in streams]
+    assert got == _PARENT_STREAMS
+    if store:
+        pc = metrics.prefix_cache_counts()
+        assert pc["prefix_cache_hits"] >= 2
+        assert pc["prefix_cache_hit_rows"] >= 8 + 5
+        rows = next(iter(kw["prefix_store"]._entries.values())).rows
+        assert all(r.shape[0::2] == (2, 64) for r in rows.values())
+
+
 def test_prefix_cache_lru_eviction_bound(graphs):
     """Capacity is a hard byte bound: inserts past it evict the
     least-recently-used entry (counted, bytes freed), and an evicted

@@ -15,6 +15,7 @@ so nothing here touches it at import, in ``skipif`` or in ``parametrize``),
 and all of these tests live in this one file so one worker runs them all.
 """
 import os
+import re
 
 import pytest
 
@@ -97,6 +98,93 @@ def test_flash_decode_q1_compiles(chip):
         lambda q, k, v, n: flash_attention(q, k, v, lengths=n),
         chip((8, 12, 1, 64), jnp.float32), chip((8, 12, 256, 64), jnp.float32),
         chip((8, 12, 256, 64), jnp.float32), chip((8,), jnp.int32))
+
+
+# ------------------------------------------------------- decode over KV slabs
+@pytest.mark.parametrize("head_dim,heads", [(64, 16), (128, 8), (32, 8)])
+def test_decode_attention_over_slabs_compiles(chip, head_dim, heads):
+    """The one-token kernel over the stored slabs: GPT-2's 64-wide heads
+    two to a lane row at the chat cell's 16 x 768, a lane-wide head on
+    plain rows, a 32-wide head four to a row — and no slab-shaped copy
+    in front of it (the slab is consumed as stored)."""
+    from hetu_tpu.ops.attention import kv_slab_shape
+    from hetu_tpu.ops.pallas.decode_attention import decode_attention
+    slab = kv_slab_shape(16, heads, 768, head_dim)
+    text = _compiles_with_kernel(
+        decode_attention, chip((16, heads, 1, head_dim), jnp.float32),
+        chip(slab, jnp.float32), chip(slab, jnp.float32),
+        chip((16,), jnp.int32))
+    assert not _slab_copies(text, slab)
+
+
+def _slab_copies(text, slab):
+    """``copy`` instructions of the optimized HLO whose result has a
+    slab's element count (a prefetch is a ``copy-start``, not a copy)."""
+    import math
+    import re
+    hits = []
+    for m in re.finditer(r"= \w+\[([\d,]+)\]\S* copy\(", text):
+        if math.prod(int(d) for d in m.group(1).split(",")) \
+                == math.prod(slab):
+            hits.append(m.group(0))
+    return hits
+
+
+@pytest.fixture(scope="module")
+def chat_engine():
+    """GPT-2 medium's widths (1024 wide, 16 heads of 64), four layers
+    deep (feed keys of two and three digits: sorted by key, the slabs
+    are NOT in layer order), a small vocabulary: the decode engine the
+    chat cell builds, with its one-token and chunked executors."""
+    from hetu_tpu.models import (GPT2Config, gpt2_decode_chunked_graph,
+                                 gpt2_decode_graph)
+    from hetu_tpu.serving import DecodeEngine
+    cfg = GPT2Config(vocab_size=512, n_positions=1024, n_embd=1024,
+                     n_layer=4, n_head=16, batch_size=1, seq_len=1024)
+    feeds, logits, caches, _ = gpt2_decode_graph(cfg, max_len=1024)
+    cf, cl, cc, _ = gpt2_decode_chunked_graph(cfg, max_len=1024)
+    return DecodeEngine(feeds, logits, caches, max_slots=16, max_len=1024,
+                        seed=0, chunked=(cf, cl, cc), max_chunk=32)
+
+
+@pytest.mark.parametrize("chunk", [1, 2, 32])
+def test_decode_steps_hold_no_slab_copy(chip, chat_engine, monkeypatch,
+                                        chunk):
+    """ISSUE 26: the engine's one-token step and its chunked steps at
+    the chat cell's shape (batch 16, cache 768), compiled for the
+    described chip as the engine jits them, hold NO ``copy`` of a slab's
+    size.  Two mechanisms put one there.  Stored as (16, 16, 768, 64)
+    the slabs were kept length-minor and transposed, padded, in front
+    of every attention call; as (16, 16, 384, 128) they are stored in
+    the layout the append, the kernel and the chunked steps' dots read.
+    And fed inside the feed dict, sorted by key, each donated slab was
+    paired with another layer's output and copied whole; handed over in
+    the fetches' order, slab i is updated in place."""
+    from hetu_tpu.ops.attention import kv_slab_shape
+    eng = chat_engine
+    iex, keys = (eng.iex, eng._fk) if chunk == 1 else (eng.ciex, eng._cfk)
+    slab = kv_slab_shape(16, eng._heads, 768, eng._head_dim)
+    assert slab == (16, 16, 384, 128)
+    assert sorted(keys[n] for n in eng.cache_names) \
+        != [keys[n] for n in eng.cache_names]
+    feeds = {"input_ids": ((16, chunk), jnp.int32),
+             "positions": ((16,), jnp.int32)}
+    if chunk > 1:
+        feeds["valid"] = ((16,), jnp.int32)
+    params = {k: chip(v.shape, v.dtype) for k, v in iex.params.items()}
+    fed = ({keys[name]: chip(dims, dtype)
+            for name, (dims, dtype) in feeds.items()},
+           tuple(chip(slab, jnp.float32) for _ in eng.cache_names))
+    # the attention dispatch asks jax.default_backend() and must hear tpu
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = jax.jit(eng._program(iex, keys), donate_argnums=(1,)).lower(
+        params, fed).compile().as_text()
+    assert ("tpu_custom_call" in text) == (chunk == 1)
+    assert not _slab_copies(text, slab)
+    # and the slabs are fed and returned row-major, unpadded
+    layout = re.search(r"entry_computation_layout=\{(.*)\}", text).group(1)
+    assert "f32[16,16,384,128]{3,2,1,0:T(8,128)}" in layout
+    assert "f32[16,16,384,128]{2," not in layout
 
 
 # ------------------------------------------------------------ moe dispatch
