@@ -219,9 +219,13 @@ def lower_forward(topo, ctx, resolve_leaf, mesh=None, skip=(),
                 continue
             if isinstance(node, PlaceholderOp):
                 env[node] = resolve_leaf(node)
-            else:
+            elif getattr(node, "scope", None) is None:
                 env[node] = constrain(
                     node, node.lower(ctx, *[env[i] for i in node.inputs]))
+            else:
+                with jax.named_scope(node.scope):
+                    env[node] = constrain(
+                        node, node.lower(ctx, *[env[i] for i in node.inputs]))
         return env
 
     # segmented path: topo_sort guarantees inputs precede consumers, and

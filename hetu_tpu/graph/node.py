@@ -65,6 +65,32 @@ def _next_id() -> int:
     return _NODE_COUNTER
 
 
+#: the scope nodes are being created under (``name_scope``), or None
+_SCOPE = None
+
+
+class name_scope:
+    """``with name_scope("mix.ssm"):`` — every node created inside
+    carries the name, and the executors lower it inside
+    ``jax.named_scope(name)``, so the operations of one part of a model
+    keep that part's name in the compiled program's metadata and in a
+    device trace (``benchmarks/trace_scopes.py`` sums device time by it).
+    The innermost scope wins; a node created outside any has none and
+    lowers exactly as before."""
+
+    def __init__(self, name):
+        self.name = str(name)
+
+    def __enter__(self):
+        global _SCOPE
+        self._outer, _SCOPE = _SCOPE, self.name
+        return self
+
+    def __exit__(self, *exc):
+        global _SCOPE
+        _SCOPE = self._outer
+
+
 class LowerCtx:
     """Per-build lowering context threaded through ``Op.lower``.
 
@@ -125,6 +151,7 @@ class Op:
         self.name = name or f"{self.op_type}_{self.id}"
         # Provenance: the user line that created this node (diagnostics)
         self.creation_site = _creation_site()
+        self.scope = _SCOPE   # name_scope the node was created under
         # Placement metadata (DeviceGroup / sharding spec); consumed by the
         # distribution layer, ignored in single-device runs.
         from ..context import current_context

@@ -25,3 +25,5 @@ from .bigbird import (BigBirdConfig, bigbird_model, bigbird_mlm_graph,
                       bigbird_attention_mask)
 from .xlnet import (XLNetConfig, xlnet_model, xlnet_plm_graph,
                     perm_masks_from_order, synthetic_plm_batch)
+from .phi4flash import (Phi4FlashConfig, phi4flash_decode_graph,
+                        phi4flash_decode_chunked_graph, phi4flash_lm_graph)

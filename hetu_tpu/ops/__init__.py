@@ -39,11 +39,16 @@ from .attention import (sdpa_op, sdpa_masked_op, sdpa_bias_op,
                         sdpa_masked_bias_op, sdpa_varlen_op,
                         sdpa_decode_op, kv_cache_append_op,
                         kv_slab_placeholder, kv_slab_shape,
+                        state_placeholder,
                         sdpa_prefill_op, chunk_positions_op,
                         split_heads_chunk_op, merge_heads_chunk_op,
                         chunk_emit_gather_op,
                         ring_attention_op, ulysses_attention_op)
 from .matmul import einsum_op
+from .ssm import (swiglu_op, silu_gate_op, greedy_token_op,
+                  conv_state_shift_op, ssm_step_op, ssm_chunk_scan_op,
+                  ring_append_op, diff_attention_kv_op,
+                  diff_attention_ring_op, pair_rows_op, zeros_op)
 from .rnn import rnn_op, lstm_op, gru_op
 from .transform import clone_op, cumsum_op, group_topk_idx_op
 

@@ -427,6 +427,40 @@ def kv_slab_placeholder(name, batch, heads, length, head_dim,
     return node
 
 
+#: what a decode graph's state placeholder can be (``state_placeholder``)
+STATE_KINDS = ("kv", "ring", "recurrent")
+
+
+def state_placeholder(name, kind, shape=None, dtype=np.float32, **slab):
+    """The feed of one piece of per-sequence decode state, which tells
+    the decode engine its KIND (``attrs["state_kind"]``) — how to
+    allocate, grow, seat and account it:
+
+    * ``"kv"`` — a growable KV slab: give ``batch``, ``heads``, ``length``
+      and ``head_dim`` in place of a shape
+      (:func:`kv_slab_placeholder`); its rows axis walks the
+      length ladder, and a row is read only below its sequence's
+      position, so a re-seated slot needs no clearing.
+    * ``"ring"`` — a fixed ``(B, heads, window, width)`` buffer written
+      at ``position mod window`` and read by position, never grown along
+      the window and never cleared.
+    * ``"recurrent"`` — a fixed ``(B, ...)`` state that every step folds
+      its token into: the engine ZEROES a slot's rows when it seats a
+      sequence there.
+
+    A ``kv_slab_placeholder`` without a kind is a ``kv`` state."""
+    if kind not in STATE_KINDS:
+        raise ValueError(f"state kind {kind!r}: expected one of "
+                         f"{STATE_KINDS}")
+    if kind == "kv":
+        node = kv_slab_placeholder(name, dtype=dtype, **slab)
+    else:
+        from ..graph.node import placeholder_op
+        node = placeholder_op(name, dtype=dtype, shape=tuple(shape))
+    node.attrs["state_kind"] = kind
+    return node
+
+
 def kv_slab_from_rows(rows, lanes):
     """(..., m, D) key rows -> (..., ceil(m / r), lanes) slab rows, the
     last one zero-filled past row ``m``."""

@@ -11,7 +11,15 @@ import jax.numpy as jnp
 
 from .base import def_op
 
+
+
+def _lookup(c, table, idx, dtype=None):
+    """``dtype``: the rows' type where it is not the table's (a table
+    stored in bfloat16 under a float32 residual stream)."""
+    rows = jnp.take(table, idx.astype(jnp.int32), axis=0)
+    return rows if dtype is None else rows.astype(dtype)
+
+
 embedding_lookup_op = def_op(
-    "EmbeddingLookup",
-    lambda c, table, idx: jnp.take(table, idx.astype(jnp.int32), axis=0),
-    lambda table, idx: tuple(idx) + (table[1],))
+    "EmbeddingLookup", _lookup,
+    lambda table, idx, dtype=None: tuple(idx) + (table[1],))

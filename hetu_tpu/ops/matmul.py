@@ -13,21 +13,28 @@ JAX's dot vjp then promotes the bf16 operand, running all dgrad/wgrad dots
 as f32×f32 at half MXU throughput (found by tools/hlo_audit.py: 196 of 294
 flagship-step dots were f32).  Softmax-feeding contractions that genuinely
 need an f32 RESULT (attention scores) opt in locally in ops/attention.py.
+Serving graphs whose weights are STORED narrow under a float32 residual
+stream opt in per node with ``out_dtype=``: the left operand is cast to the
+right one's type and the result comes out in ``out_dtype`` (bfloat16
+weights: one MXU pass, f32 accumulation; no backward to pay for).
 """
 import jax.numpy as jnp
 
 from .base import def_op
 
 
-def _mm(c, a, b, trans_A=False, trans_B=False):
+def _mm(c, a, b, trans_A=False, trans_B=False, out_dtype=None):
     if trans_A:
         a = a.T
     if trans_B:
         b = b.T
+    if out_dtype is not None:
+        return jnp.matmul(a.astype(b.dtype), b,
+                          preferred_element_type=out_dtype)
     return jnp.matmul(a, b)
 
 
-def _mm_shape(a, b, trans_A=False, trans_B=False):
+def _mm_shape(a, b, trans_A=False, trans_B=False, out_dtype=None):
     # mirrors the lowering exactly: `.T` REVERSES all axes (not a swap of
     # the trailing two), and jnp.matmul broadcasts leading batch dims /
     # promotes 1-D operands — the old 2-D-only rule was caught wrong on

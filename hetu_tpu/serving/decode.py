@@ -381,11 +381,31 @@ class DecodeEngine:
     :func:`~hetu_tpu.models.gpt2_decode_chunked_graph` (same weight
     names, extra ``valid`` feed): its executor is loaded FROM the
     primary executor's params — never independently initialized, so
-    both entries serve the same weight bytes — and prompt ingestion
+    both entries serve the same weight bytes, held ONCE: the second
+    executor keeps the first one's device arrays — and prompt ingestion
     runs ``ceil(P/C)`` chunked steps instead of P.  ``max_chunk`` caps
     the chunk ladder (default ``min(32, max_len)``).  ``prefix_store=``
     accepts a :class:`~hetu_tpu.serving.PrefixKVStore` for shared-
     prefix KV reuse (may be shared across engines).
+
+    **State kinds (ISSUE 27).**  A state placeholder declares what it
+    is (:func:`~hetu_tpu.ops.state_placeholder`, ``attrs["state_kind"]``;
+    a KV slab without one is ``kv``) and the engine allocates, grows,
+    seats, clears and accounts each by kind: ``kv`` slabs walk the
+    length ladder; a ``ring`` is a fixed ``window``-row buffer its graph
+    writes at ``position mod window`` and reads by position; a
+    ``recurrent`` state is fixed-shape and its slot's rows are ZEROED at
+    :meth:`join` (``decode_state_clears``) — a slab or a ring is read
+    only where the seated sequence wrote, a recurrence folds in whatever
+    it finds.  ``decode_state_bytes_<kind>_hw`` gauge each kind,
+    ``decode_kv_bytes_hw`` their sum.  ``prefix_store=`` and ``plan=``
+    with ``ring`` or ``recurrent`` state raise at construction.
+    ``tokens=`` names a (B,) int32 fetch of each row's greedy token
+    (``chunked=`` then takes a fourth element, the chunked graph's): the
+    step brings back those ids and leaves the (B, vocab) logits on the
+    device (:attr:`last_logits` fetches them on request).
+    :meth:`reserve` puts the engine at given buckets before the first
+    request.
 
     NOT thread-safe by design: the owning :class:`DecodeRouter` loop
     thread (or a single test thread) makes every call after
@@ -394,25 +414,54 @@ class DecodeEngine:
     def __init__(self, feeds, logits, cache_fetches, weights=None, *,
                  max_slots=8, max_len=128, plan=None, mesh=None,
                  seed=0, donate=True, validate="error",
-                 chunked=None, max_chunk=None, prefix_store=None):
+                 chunked=None, max_chunk=None, prefix_store=None,
+                 tokens=None):
+        self.cache_names = [n for n in feeds
+                            if n not in ("input_ids", "positions")]
+        #: per state: its kind, its shape past the batch and its type, as
+        #: the placeholder declares them (``ops.state_placeholder``)
+        self._kinds = {n: feeds[n].attrs.get("state_kind", "kv")
+                       for n in self.cache_names}
+        self._tails = {n: (tuple(feeds[n].shape[1:]), np.dtype(
+            getattr(feeds[n], "dtype", None) or np.float32))
+            for n in self.cache_names}
+        self._recurrent = [n for n in self.cache_names
+                           if self._kinds[n] == "recurrent"]
+        other = sorted({k for k in self._kinds.values() if k != "kv"})
+        if other and (prefix_store is not None or plan is not None):
+            raise ValueError(
+                f"this graph keeps {' and '.join(other)} state beside its "
+                f"KV slabs: " + (
+                    "a prefix store snapshots and seats KV rows only, and "
+                    "a sequence seated past its prefix would find its "
+                    "other state empty" if prefix_store is not None else
+                    "a tp plan shards KV slabs by head and says nothing "
+                    "of the other kinds") +
+                " — build the engine without "
+                + ("prefix_store=" if prefix_store is not None else "plan="))
+        #: fetches in front of the states: the greedy token ids when the
+        #: graph computes them (``tokens=``), then the logits
+        head = [logits] if tokens is None else [tokens, logits]
+        self._head = len(head)
         self.iex = InferenceExecutor(
-            [logits] + list(cache_fetches), weights=weights,
+            head + list(cache_fetches), weights=weights,
             buckets=default_buckets(max_slots), mesh=mesh, seed=seed,
             donate=donate, validate=validate, plan=plan, decode=True)
         self.max_len = int(max_len)
         self.batch_ladder = self.iex.buckets
         self.len_ladder = tuple(b for b in default_buckets(self.max_len))
-        self.cache_names = [n for n in feeds
-                            if n not in ("input_ids", "positions")]
         # placeholder node -> executor feed key, by feed NAME
         self._fk = {name: self.iex._k(node) for name, node in feeds.items()}
-        ck0 = feeds[self.cache_names[0]]
-        # the slab's lanes hold ``_pack`` key rows of ``_head_dim`` each
+        kv = [n for n in self.cache_names if self._kinds[n] == "kv"]
+        ck0 = feeds[kv[0]] if kv else None
+        # a KV slab's lanes hold ``_pack`` key rows of ``_head_dim`` each
         # (1 for a plain (B, H, L, D) placeholder, which says no more)
-        self._heads, self._lanes = ck0.shape[1], ck0.shape[3]
-        self._head_dim = int(ck0.attrs.get("head_dim", self._lanes))
-        self._pack = self._lanes // self._head_dim
-        self._cache_dtype = np.dtype(getattr(ck0, "dtype", np.float32))
+        if ck0 is not None:
+            self._heads, self._lanes = ck0.shape[1], ck0.shape[3]
+            self._head_dim = int(ck0.attrs.get("head_dim", self._lanes))
+            self._pack = self._lanes // self._head_dim
+        else:
+            self._heads = self._lanes = self._head_dim = self._pack = 0
         self.ciex = None
         self.chunk_ladder = (1,)
         self.chunk_top = 1
@@ -422,16 +471,20 @@ class DecodeEngine:
                 raise ValueError(
                     "chunked prefill under a tp plan is not supported: "
                     "bind the plan to the one-token entry only")
-            cfeeds, clogits, ccaches = chunked
+            cfeeds, clogits, ccaches, *ctokens = chunked
+            if bool(ctokens) != (tokens is not None):
+                raise ValueError("the one-token and the chunked entry must "
+                                 "both fetch token ids, or neither")
             # the chunked executor MUST serve the primary's exact weight
             # bytes: independent construction would re-init every
             # variable from fold_in(seed, topo_index) over a DIFFERENT
-            # topo order, silently diverging the two entries
-            w = {self.iex.var_names[n]:
-                 np.asarray(self.iex.params[self.iex._k(n)])
+            # topo order, silently diverging the two entries.  It is
+            # handed the primary's DEVICE arrays, which it keeps as they
+            # are: one set of weight buffers under both entries
+            w = {self.iex.var_names[n]: self.iex.params[self.iex._k(n)]
                  for n in self.iex.var_nodes}
             self.ciex = InferenceExecutor(
-                [clogits] + list(ccaches), weights=w,
+                ctokens + [clogits] + list(ccaches), weights=w,
                 buckets=default_buckets(max_slots), mesh=mesh, seed=seed,
                 donate=donate, validate=validate, decode=True)
             top = int(max_chunk) if max_chunk else min(32, self.max_len)
@@ -451,12 +504,22 @@ class DecodeEngine:
         self._used = [False] * self.bb       # slot served a sequence before
         self.tokens = np.zeros(self.bb, np.int32)
         self.positions = np.zeros(self.bb, np.int32)
-        self.caches = {name: self._alloc(self.bb, self.lb)
+        self.caches = {name: self._alloc(name, self.bb, self.lb)
                        for name in self.cache_names}
-        #: host copy of the last step's (batch_bucket, vocab) logits, None
-        #: when no row read them — what a parity check compares
-        self.last_logits = None
+        self._clear = None        # jitted zeroing of a slot's recurrent rows
+        self._logits = None
         self._note_kv_bytes()
+
+    @property
+    def last_logits(self):
+        """Host copy of the last step's (batch_bucket, vocab) logits, None
+        when no row read them — what a parity check compares.  With
+        ``tokens=`` the step brings back token ids only and the logits
+        stay on the device until this is asked for."""
+        if self._logits is not None \
+                and not isinstance(self._logits, np.ndarray):
+            self._logits = np.asarray(self._logits)
+        return self._logits
 
     # -- memory ------------------------------------------------------------
 
@@ -464,22 +527,74 @@ class DecodeEngine:
         """Slab rows that hold ``n`` key rows."""
         return -(-int(n) // self._pack)
 
-    def _alloc(self, bb, lb):
+    def _alloc(self, name, bb, lb):
+        """Zeros for state ``name`` at batch bucket ``bb``: a ``kv`` slab
+        with room for ``lb`` key rows, any other kind at its declared
+        shape."""
         import jax.numpy as jnp
-        z = jnp.zeros((bb, self._heads, self._slab_rows(lb), self._lanes),
-                      self._cache_dtype)
-        return self.iex._place(z)
+        tail, dtype = self._tails[name]
+        if self._kinds[name] == "kv":
+            tail = (tail[0], self._slab_rows(lb), tail[2])
+        return self.iex._place(jnp.zeros((bb,) + tail, dtype))
+
+    def _resize(self, bb, lb):
+        """Every state zero-padded to batch bucket ``bb``, the ``kv``
+        slabs also to ``lb`` key rows (``ring`` and ``recurrent`` state
+        has no length)."""
+        import jax.numpy as jnp
+        rows = self._slab_rows(lb) - self._slab_rows(self.lb) \
+            if self._pack else 0
+        for name, c in self.caches.items():
+            pad = [(0, bb - self.bb)] + [(0, 0)] * (c.ndim - 1)
+            if self._kinds[name] == "kv":
+                pad[2] = (0, rows)
+            if any(p != (0, 0) for p in pad):
+                self.caches[name] = self.iex._place(jnp.pad(c, pad))
+        grow = bb - self.bb
+        self.slots += [None] * grow
+        self._used += [False] * grow
+        self.tokens = np.concatenate([self.tokens, np.zeros(grow, np.int32)])
+        self.positions = np.concatenate([self.positions,
+                                         np.zeros(grow, np.int32)])
+        self.bb, self.lb = bb, lb
+        self._note_kv_bytes()
+
+    def reserve(self, batch=None, length=None):
+        """Put the engine at the buckets that hold ``batch`` sequences of
+        ``length`` tokens NOW, in one step, instead of walking the ladders
+        as traffic arrives: a server of known size compiles one one-token
+        program and one per chunk width, and none for the buckets on the
+        way there.  Buckets never shrink; what is seated stays seated."""
+        bb = self.bb if batch is None else self.iex.bucket_for(int(batch))
+        lb = self.lb if length is None else next(
+            (b for b in self.len_ladder if b >= int(length)), None)
+        if bb is None or lb is None:
+            raise ValueError(
+                f"reserve({batch}, {length}) exceeds the engine's "
+                f"max_slots {self.batch_ladder[-1]} / max_len {self.max_len}")
+        self._resize(max(bb, self.bb), max(lb, self.lb))
+        return self.bb, self.lb
+
+    def state_bytes(self):
+        """``{kind: bytes}`` of the device-resident state."""
+        out = {}
+        for name, c in self.caches.items():
+            kind = self._kinds[name]
+            out[kind] = out.get(kind, 0) + int(c.nbytes)
+        return out
 
     def _note_kv_bytes(self):
-        record_decode("decode_kv_bytes_hw",
-                      sum(int(c.nbytes) for c in self.caches.values()))
+        by_kind = self.state_bytes()
+        record_decode("decode_kv_bytes_hw", sum(by_kind.values()))
+        for kind, n in by_kind.items():
+            record_decode(f"decode_state_bytes_{kind}_hw", n)
         # which slab format this process's engines serve from: key rows
         # per slab row (1 = plain (B, H, L, D) rows)
         record_decode("decode_kv_slab_format_hw", self._pack)
 
     @property
     def kv_bytes(self):
-        return sum(int(c.nbytes) for c in self.caches.values())
+        return sum(self.state_bytes().values())
 
     # -- capacity ----------------------------------------------------------
 
@@ -504,24 +619,11 @@ class DecodeEngine:
         return None
 
     def _grow_batch(self):
-        import jax.numpy as jnp
         nb = self._next_bucket(self.batch_ladder, self.bb)
         if nb is None:
             raise RuntimeError(f"no free slot at max batch bucket {self.bb}")
-        pad = nb - self.bb
-        self.caches = {
-            name: self.iex._place(
-                jnp.pad(c, ((0, pad), (0, 0), (0, 0), (0, 0))))
-            for name, c in self.caches.items()}
-        self.slots += [None] * pad
-        self._used += [False] * pad
-        self.tokens = np.concatenate([self.tokens,
-                                      np.zeros(pad, np.int32)])
-        self.positions = np.concatenate([self.positions,
-                                         np.zeros(pad, np.int32)])
-        self.bb = nb
+        self._resize(nb, self.lb)
         record_decode("decode_batch_grows")
-        self._note_kv_bytes()
 
     def _grow_len_if_needed(self, span=1):
         """Ensure the cache length bucket covers every active position
@@ -529,7 +631,6 @@ class DecodeEngine:
         step's write window — dynamic_update_slice CLAMPS out-of-range
         starts, which would shift the window onto wrong rows, so the
         bucket must cover it up front)."""
-        import jax.numpy as jnp
         need = max((int(self.positions[i]) for i, s in enumerate(self.slots)
                     if s is not None), default=-1) + int(span) - 1
         if need < self.lb:
@@ -541,13 +642,31 @@ class DecodeEngine:
                 raise RuntimeError(
                     f"cache position {need} exceeds max_len {self.max_len}")
             record_decode("decode_len_grows")
-        pad = self._slab_rows(lb) - self._slab_rows(self.lb)
-        self.caches = {
-            name: self.iex._place(
-                jnp.pad(c, ((0, 0), (0, 0), (0, pad), (0, 0))))
-            for name, c in self.caches.items()}
-        self.lb = lb
-        self._note_kv_bytes()
+        self._resize(self.bb, lb)
+
+    def _clear_recurrent(self, slot):
+        """Zero slot ``slot``'s rows of every ``recurrent`` state: the
+        sequence seated there starts from nothing.  (A ``kv`` slab is
+        read below the sequence's position only and a ``ring`` by
+        position, so neither needs it.)  One jitted, donated call for all
+        of them, the slot a traced scalar: in place, compiled once per
+        batch bucket."""
+        names = self._recurrent
+        if not names:
+            return
+        if self._clear is None:
+            import jax
+
+            def clear(states, slot):
+                return tuple(jax.lax.dynamic_update_slice_in_dim(
+                    s, jax.numpy.zeros((1,) + s.shape[1:], s.dtype), slot, 0)
+                    for s in states)
+
+            self._clear = jax.jit(clear, donate_argnums=(0,))
+        new = self._clear(tuple(self.caches[n] for n in names),
+                          np.int32(slot))
+        self.caches.update(zip(names, new))
+        record_decode("decode_state_clears")
 
     # -- join / leave ------------------------------------------------------
 
@@ -568,6 +687,7 @@ class DecodeEngine:
         if self.prefix is not None:
             m, rows = self.prefix.lookup(req.prompt)
         self.slots[slot] = seq
+        self._clear_recurrent(slot)
         seq.ptr = m
         self.tokens[slot] = req.prompt[m]
         self.positions[slot] = m
@@ -830,7 +950,11 @@ class DecodeEngine:
                      cat="decode", rows=len(active)) as ph:
             ph.mark("plan")
             chunk = self._pick_chunk(active)
-            ph.meta(chunk=chunk)
+            # rows still taking in their prompt (more than its last token
+            # is owed), beside the rows that generate
+            prefill = sum(len(self.slots[i].req.prompt) - self.slots[i].ptr
+                          > 1 for i in active)
+            ph.meta(chunk=chunk, prefill=prefill)
             self._grow_len_if_needed(span=chunk)
             if chunk > 1:
                 fn, ex, fk = self._chunk_step_fn(chunk), self.ciex, self._cfk
@@ -876,27 +1000,30 @@ class DecodeEngine:
                 warnings.filterwarnings(
                     "ignore", message="Some donated buffers were not usable")
                 outs = fn(ex.params, (feeds, slabs))
-            # the logits D2H is paid only when some row will read it — a
+            # the D2H is paid only when some row will read it — a
             # pure-prefill step never looks at outs[0] (ISSUE 18
-            # satellite)
+            # satellite).  What comes back is the (batch, vocab) logits,
+            # or with ``tokens=`` the (batch,) greedy token ids the
+            # program computed from them: the logits then stay where
+            # they are (``last_logits`` fetches them on request)
             if any(self.slots[i].ptr + int(consume[i])
                    >= len(self.slots[i].req.prompt) for i in active):
                 # the D2H is queued behind the step NOW, as np.asarray
-                # alone would queue it: waiting for the logits first and
+                # alone would queue it: waiting for the result first and
                 # asking for the copy after costs a host wake-up and a
                 # transfer dispatch per step with the chip idle
                 outs[0].copy_to_host_async()
                 ph.mark("wait")
                 outs[0].block_until_ready()
                 ph.mark("readback")
-                logits = np.asarray(outs[0])
+                read = np.asarray(outs[0])
                 ph.mark("host")
+                self._logits = read if self._head == 1 else outs[1]
             else:
                 ph.mark("host")
-                logits = None
+                read = self._logits = None
                 record_decode("decode_logits_skipped")
-            self.last_logits = logits
-            for name, new in zip(self.cache_names, outs[1:]):
+            for name, new in zip(self.cache_names, outs[self._head:]):
                 self.caches[name] = new
             record_decode("decode_steps")
             # every row of the batch bucket computes ``chunk`` tokens,
@@ -933,13 +1060,14 @@ class DecodeEngine:
                 # this row's logits are live: greedy argmax
                 # (deterministic first-max tie-break keeps decode bitwise
                 # stable)
-                tok = int(np.argmax(logits[i]))
+                tok = int(np.argmax(read[i]) if self._head == 1 else read[i])
                 emitted += self._emit_token(i, seq, tok, now)
             # dropped here, not at return: freeing the device's logits
             # and the donated slabs' handles is the step's work too
             del feeds, slabs, outs
             ph.args = {"batch": self.bb, "len": self.lb, "chunk": chunk,
-                       "rows": len(active), "emitted": emitted}
+                       "rows": len(active), "prefill": prefill,
+                       "emitted": emitted}
         record_decode_latency("step", (ph.t1 - t0) / 1e3)
         return emitted
 

@@ -269,6 +269,15 @@ class InferenceExecutor:
             self.var_names[node] = node.name if count == 0 \
                 else f"{node.name}~{count}"
         named = self._weights_dict(weights) if weights is not None else {}
+
+        def stored(node, v):
+            """``v`` in the float type the variable declares, if any."""
+            want = None if node.dtype is None else np.dtype(node.dtype)
+            if want is not None and v.dtype != want \
+                    and jax.numpy.issubdtype(want, jax.numpy.floating):
+                v = v.astype(want)
+            return v
+
         vals, missing = {}, []
         # initializers run ONLY for variables the weights source does not
         # cover (a large-model cold start must not pay a full random init
@@ -277,14 +286,20 @@ class InferenceExecutor:
         for i, node in enumerate(self.var_nodes):
             v = named.get(self.var_names[node])
             if v is not None:
-                vals[node] = np.asarray(v)
+                # a device array stays where it is (no trip through the
+                # host, and a second executor handed the first one's
+                # arrays shares their buffers); anything is stored in the
+                # type the variable declares, if it declares a float type
+                if not isinstance(v, jax.Array):
+                    v = np.asarray(v)
+                vals[node] = stored(node, v)
                 continue
             if weights is not None:
                 missing.append(self.var_names[node])
             val = node.get_init_value(jax.random.fold_in(init_key, i))
             if val is None:
                 raise ValueError(f"variable {node} has no value/initializer")
-            val = np.asarray(val)
+            val = stored(node, np.asarray(val))
             vals[node] = val.astype(np.float32) \
                 if val.dtype == np.float64 else val
         if missing:

@@ -523,3 +523,62 @@ def test_decode_bench_smoke():
     assert rec["zero_survivor"]["recovery_exhausted"] >= 1
     assert extra["total_tokens"] > 0
     assert res["vs_baseline"] > 0, res
+
+
+# ------------------------------------------------ state kinds (ISSUE 27)
+def test_an_all_kv_graph_is_allocated_grown_and_donated_as_before():
+    """GPT-2's graph is the all-``kv`` case of the engine's state kinds:
+    the same slabs, the same growth along both ladders, one program per
+    bucket pair used, every slab donated to its own update — and both
+    entries over one set of weight buffers."""
+    import jax
+    from hetu_tpu import metrics as ht_metrics
+    from hetu_tpu.graph import step_cache
+    from hetu_tpu.models import gpt2_decode_chunked_graph
+    from hetu_tpu.serving.decode import _DecodeRequest
+    step_cache.clear()
+    ht_metrics.reset_all()
+    cfg = GPT2Config.tiny(n_positions=64, batch_size=1, seq_len=32)
+    feeds, logits, caches, _ = gpt2_decode_graph(cfg, max_len=32)
+    cf, cl, cc, _ = gpt2_decode_chunked_graph(cfg, max_len=32)
+    eng = DecodeEngine(feeds, logits, caches, max_slots=4, max_len=32,
+                       seed=0, chunked=(cf, cl, cc), max_chunk=8)
+    assert set(eng._kinds.values()) == {"kv"} and eng._head == 1
+    heads, lanes = eng._heads, eng._lanes
+    n = len(eng.cache_names)
+
+    def slab_bytes(bb, lb):
+        return n * bb * heads * eng._slab_rows(lb) * lanes * 4
+    assert eng.state_bytes() == {"kv": slab_bytes(1, 1)} \
+        and eng.kv_bytes == slab_bytes(1, 1)
+    reqs = [_DecodeRequest(np.full(p, 3, np.int32), 6, None, None)
+            for p in (9, 2, 5)]
+    for r in reqs:
+        eng.join(r)
+    assert (eng.bb, eng.lb) == (4, 1)           # 1 -> 2 -> 4 seats
+    while not eng.idle:
+        eng.step()
+    c = ht_metrics.decode_counts()
+    assert c["decode_batch_grows"] == 2 and eng.lb == 16
+    assert c["decode_len_grows"] == 4           # 1 -> 8 at once, then 16
+    assert c["decode_kv_bytes_hw"] == c["decode_state_bytes_kv_hw"] \
+        == eng.kv_bytes == slab_bytes(4, 16)
+    assert "decode_state_clears" not in c
+    assert "decode_state_bytes_ring_hw" not in c
+    # one chunked program and one one-token program: a bucket pair is
+    # compiled when a step first needs it and never again
+    assert ht_metrics.serve_counts()["serve_bucket_compiles"] == 2
+    # every slab is donated to its own update: the step's aliases pair
+    # input i of the slab tuple with output 1 + i
+    fed = ({eng._fk["input_ids"]: np.zeros((4, 1), np.int32),
+            eng._fk["positions"]: np.zeros(4, np.int32)},
+           tuple(eng.caches[k] for k in eng.cache_names))
+    text = jax.jit(eng._program(eng.iex, eng._fk),
+                   donate_argnums=(1,)).lower(eng.iex.params, fed).as_text()
+    assert text.count("tf.aliasing_output") >= n
+    # one set of weight buffers under both entries
+    one = {eng.iex.var_names[v]: eng.iex.params[eng.iex._k(v)]
+           for v in eng.iex.var_nodes}
+    two = {eng.ciex.var_names[v]: eng.ciex.params[eng.ciex._k(v)]
+           for v in eng.ciex.var_nodes}
+    assert one.keys() == two.keys() and all(one[k] is two[k] for k in one)

@@ -187,6 +187,70 @@ def test_decode_steps_hold_no_slab_copy(chip, chat_engine, monkeypatch,
     assert "f32[16,16,384,128]{2," not in layout
 
 
+@pytest.fixture(scope="module")
+def hybrid_engine():
+    """The SambaY decode graphs at the published head width (64: a pair's
+    keys fill the 128 lanes), window 512 and scan state 16, the model
+    itself narrow and eight layers deep (every kind of layer), bfloat16
+    weights and KV / ring state: the three kinds of state side by side."""
+    from hetu_tpu.models import (Phi4FlashConfig,
+                                 phi4flash_decode_chunked_graph,
+                                 phi4flash_decode_graph)
+    from hetu_tpu.serving import DecodeEngine
+    cfg = Phi4FlashConfig(vocab_size=512, hidden_size=512,
+                          intermediate_size=1024, num_hidden_layers=8,
+                          num_attention_heads=8, num_key_value_heads=4,
+                          param_dtype=jnp.bfloat16, cache_dtype=jnp.bfloat16)
+    feeds, logits, states, tokens = phi4flash_decode_graph(cfg, 1024)
+    return DecodeEngine(feeds, logits, states, tokens=tokens, max_slots=16,
+                        max_len=1024, seed=0, max_chunk=32,
+                        chunked=phi4flash_decode_chunked_graph(cfg, 1024))
+
+
+@pytest.mark.parametrize("chunk", [1, 2, 32])
+def test_hybrid_decode_steps_update_every_kind_of_state_in_place(
+        chip, hybrid_engine, monkeypatch, chunk):
+    """ISSUE 27: ``kv`` slabs, ``ring`` buffers and ``recurrent`` scan
+    state in one step, each donated to its own update: the step compiled
+    for the described chip holds no copy of a slab, a ring or a scan
+    state (a ring written through a gather was laid out group-major and
+    copied, whole, twice a layer — the one-row write is a select), and
+    every one of them is fed and returned row-major."""
+    eng = hybrid_engine
+    iex, keys = (eng.iex, eng._fk) if chunk == 1 else (eng.ciex, eng._cfk)
+    b, length = 16, 1024
+
+    def dims(name):
+        tail, dtype = eng._tails[name]
+        if eng._kinds[name] == "kv":
+            tail = (tail[0], length, tail[2])
+        return (b,) + tuple(tail), dtype
+    shapes = {eng._kinds[n]: dims(n) for n in eng.cache_names
+              if not n.startswith("conv")}
+    assert shapes == {"kv": ((16, 2, 1024, 128), jnp.bfloat16),
+                      "ring": ((16, 2, 512, 128), jnp.bfloat16),
+                      "recurrent": ((16, 16, 1024), jnp.float32)}
+    feeds = {"input_ids": ((b, chunk), jnp.int32),
+             "positions": ((b,), jnp.int32)}
+    if chunk > 1:
+        feeds["valid"] = ((b,), jnp.int32)
+    params = {k: chip(v.shape, v.dtype) for k, v in iex.params.items()}
+    fed = ({keys[name]: chip(d, t) for name, (d, t) in feeds.items()},
+           tuple(chip(*dims(n)) for n in eng.cache_names))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = jax.jit(eng._program(iex, keys), donate_argnums=(1,)).lower(
+        params, fed).compile().as_text()
+    for kind, (shape, dtype) in shapes.items():
+        tag = ("bf16" if dtype == jnp.bfloat16 else "f32") \
+            + "[" + ",".join(map(str, shape)) + "]"
+        assert not re.findall(r"= " + re.escape(tag) + r"\S* copy\(",
+                              text), kind
+        layout = re.search(r"entry_computation_layout=\{(.*)\}",
+                           text).group(1)
+        minor = ",".join(str(i) for i in reversed(range(len(shape))))
+        assert tag + "{" + minor in layout, kind
+
+
 # ------------------------------------------------------------ moe dispatch
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
