@@ -467,6 +467,13 @@ def _flash_cases(interpret, small_shape, model_shapes):
             {"causal": True, "lengths": lengths, "key_mask": km},
             {"causal": True,
              "mask": jnp.logical_and(lmask, km[:, None, None, :])}),
+        # the rule gives every non-causal case above the whole key range
+        # in one block (straight softmax, one-pass backward); these keep
+        # the online-softmax forward and the dq + dkv backward on the chip
+        "key_mask_128x128": ({"key_mask": km, "block_q": 128,
+                              "block_k": 128}, {"mask": km[:, None, None, :]}),
+        "bias_128x128": ({"bias": bias, "block_q": 128, "block_k": 128},
+                         {"bias": bias}),
     }
     for name, (fkw, rkw) in small.items():
         run(name, (B, H, S, D), jnp.float32, fkw, rkw, 2e-2)

@@ -86,6 +86,29 @@ def reset_flash_fallbacks():
     _flash.reset()
 
 
+# Which geometry the flash kernels compiled with: the block shapes come
+# from a rule over the call's shapes (``flash_attention._pick_blocks``),
+# and this says what it chose.  Per TRACE like the fallbacks, and like
+# them silent under an abstract shape trace.
+_flash_calls = REGISTRY.counter_family(
+    "flash_calls",
+    "flash-attention calls by block shape and backward kind, "
+    "\"<block_q>x<block_k>:<one_pass|two_pass>\" (per jax trace)")
+
+
+def record_flash_call(block_q, block_k, one_pass):
+    """Count one traced flash-attention call by the geometry it runs."""
+    if counters_suppressed():
+        return
+    _flash_calls.inc(f"{block_q}x{block_k}:"
+                     f"{'one_pass' if one_pass else 'two_pass'}")
+
+
+def flash_call_counts():
+    """{"<block_q>x<block_k>:<one_pass|two_pass>": count} snapshot."""
+    return _flash_calls.counts()
+
+
 # ---------------------------------------------- embedding Pallas fallbacks
 # The device-resident embedding-cache dispatchers
 # (``ops/pallas/emb_cache.py``) record WHY a gather / grad scatter-add
@@ -994,6 +1017,7 @@ def run_gauges():
 #: profiler's ``all_counters`` read this instead of seven accessors
 _FAMILIES = {
     "flash_fallbacks": _flash,
+    "flash_calls": _flash_calls,
     "emb_pallas_fallbacks": _emb_pallas,
     "faults": _faults,
     "elastic": _elastic,

@@ -18,14 +18,15 @@ from .base import def_op
 
 
 def _load_flash_gate(default=256):
-    """Empirical flash-vs-XLA dispatch threshold + measured block shapes.
+    """Empirical flash-vs-XLA dispatch threshold.
 
-    ``tools/flash_ab.py`` measures both paths on the real chip and commits
-    the winner table to ``artifacts/flash_ab.json``; the gate and the
-    per-seq (block_q, block_k) come from data when that artifact exists
-    (round-2 verdict: a guessed gate meant the kernel was never in the
-    measured hot path)."""
-    blocks = {}
+    ``tools/flash_ab.py`` measures both paths on the real chip and writes
+    the winner table to ``artifacts/flash_ab.json`` (or wherever
+    ``HETU_FLASH_AB_PATH`` points); the gate comes from data when that
+    artifact exists (round-2 verdict: a guessed gate meant the kernel was
+    never in the measured hot path).  Block shapes are NOT read here: the
+    kernel module chooses them from each call's shapes
+    (``flash_attention._pick_blocks``)."""
     path = os.environ.get("HETU_FLASH_AB_PATH") or os.path.join(
         os.path.dirname(__file__), os.pardir, os.pardir,
         "artifacts", "flash_ab.json")
@@ -33,27 +34,20 @@ def _load_flash_gate(default=256):
     try:
         with open(path) as f:
             data = json.load(f)
-        if data.get("backend") == "tpu":
-            # a PARTIAL artifact (sweep killed mid-way) still serves its
-            # measured block shapes, but its gate covers only a prefix of
-            # the lengths — keep the default gate until the sweep completes
-            if not data.get("partial"):
-                gate = int(data["flash_min_len"])
-            for seq, row in data.get("rows", {}).items():
-                for tag in ("dense", "causal", "kmask"):
-                    bl = row.get(f"blocks_{tag}")
-                    if bl:
-                        blocks[(int(seq), tag)] = tuple(bl)
+        # a PARTIAL artifact (sweep killed mid-way) covers only a prefix
+        # of the lengths — keep the default gate until the sweep completes
+        if data.get("backend") == "tpu" and not data.get("partial"):
+            gate = int(data["flash_min_len"])
     except (OSError, ValueError, KeyError, TypeError):
         pass
     env = os.environ.get("HETU_FLASH_MIN_LEN")
     if env:
         gate = int(env)
-    return (default if gate is None else gate), blocks
+    return default if gate is None else gate
 
 
-#: below the gate, XLA's fusion is fine; blocks are measured per seq
-_FLASH_MIN_LEN, _FLASH_BLOCKS = _load_flash_gate()
+#: below the gate, XLA's fusion is fine
+_FLASH_MIN_LEN = _load_flash_gate()
 
 
 @jax.custom_vjp
@@ -160,18 +154,6 @@ def _use_flash(q, k):
     return jax.default_backend() == "tpu" and s_q >= _FLASH_MIN_LEN
 
 
-def _clipped_blocks(tag, q, k):
-    """Measured (block_q, block_k) for this (seq, tag), dropped when they
-    exceed or fail to divide the actual dims (the artifact measures square
-    (s, s) shapes; cross-attention must not inherit a bad block)."""
-    bq, bk = _FLASH_BLOCKS.get((q.shape[-2], tag), (None, None))
-    if bq is not None and (bq > q.shape[-2] or q.shape[-2] % bq):
-        bq = None
-    if bk is not None and (bk > k.shape[-2] or k.shape[-2] % bk):
-        bk = None
-    return bq, bk
-
-
 def dispatch_sdpa(q, k, v, causal=False, scale=None):
     """Backend-dispatched dense attention: the Pallas flash kernel when the
     empirical gate says it wins, XLA-composed otherwise.  The functional
@@ -179,9 +161,7 @@ def dispatch_sdpa(q, k, v, causal=False, scale=None):
     full-sequence local step, pipeline stages)."""
     if _use_flash(q, k) and _causal_bucketable(q, k, causal):
         from .pallas.flash_attention import flash_attention
-        bq, bk = _clipped_blocks("causal" if causal else "dense", q, k)
-        return flash_attention(q, k, v, causal=causal, scale=scale,
-                               block_q=bq, block_k=bk)
+        return flash_attention(q, k, v, causal=causal, scale=scale)
     _note_flash_fallback(_gate_reason(q, k, causal) or "dispatch_gate")
     return sdpa_reference(q, k, v, causal=causal, scale=scale)
 
@@ -286,12 +266,8 @@ def dispatch_sdpa_masked(q, k, v, mask, causal=False, scale=None):
     if _flash_maskable(q, k, mask) and _causal_bucketable(q, k, causal):
         from .pallas.flash_attention import flash_attention
         km, fm = _split_mask_kinds(mask, q)
-        # the key-mask strip path (flagship) uses ITS OWN measured blocks
-        bq, bk = (None, None)
-        if km is not None and not causal:
-            bq, bk = _clipped_blocks("kmask", q, k)
         return flash_attention(q, k, v, causal=causal, scale=scale,
-                               key_mask=km, mask=fm, block_q=bq, block_k=bk)
+                               key_mask=km, mask=fm)
     _note_flash_fallback(_masked_reason(q, k, causal, mask)
                          or "dispatch_gate")
     return sdpa_reference(q, k, v, causal=causal, scale=scale, mask=mask)

@@ -13,6 +13,28 @@ sequential TPU grid (bh, q_block, kv_block) — accumulators live in VMEM
 scratch and persist across the minor-most kv grid steps; outputs are written
 once on the final kv step (standard TPU revisiting-grid pattern).
 
+Block shapes come from ONE rule, :func:`_pick_blocks`, that reads only what
+the call can observe (both lengths, the head size, the operand item size,
+``causal`` and how many dense (S_q, S_kv) extras ride along) against a
+stated VMEM budget; explicit ``block_q=`` / ``block_k=`` still win.  No
+table, no environment variable, no model name chooses a tile (a grid step
+costs ≈0.3 µs on a v5e, as much as a 128 × 128 tile's work: PERF.md §6,
+PR 28).  When one block holds the whole key range (``num_kv == 1``: BERT's
+512 keys) the kernels specialise, statically like the mask menu below:
+
+* the forward is a straight softmax — no running max / sum scratch, no
+  ``alpha`` rescale of the accumulator, ``lse`` written directly;
+* the backward is ONE kernel (``flash_bwd``): K and V of a (b, h) stay
+  resident over the query blocks, scores / probabilities / dP / dS are
+  computed once and feed dq, dk and dv — 5 products where the two-pass
+  kernels (``flash_bwd_dq`` + ``flash_bwd_dkv``, kept for key ranges that
+  do not fit one block) spend 7; ``delta`` is computed in the kernel.
+
+Row statistics (``lse``, ``delta``) cross HBM lane-oriented, (B·H, 1, S): a
+(B·H, S, 1) f32 array is stored padded to 128 lanes (100 MB a layer where
+0.8 MB is data at BERT's shape: compile rehearsal, PR 28); the kernels turn
+a column of statistics into a row and back in VMEM (:func:`_col_to_row`).
+
 Masking/bias menu (every combination is a STATIC trace-time specialization,
 so the dense hot path compiles the original straight-line code):
 
@@ -46,8 +68,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-DEFAULT_BLOCK_Q = 128
-DEFAULT_BLOCK_K = 128
 #: flash-legal sequence lengths are multiples of this (the Mosaic lane
 #: width); ragged lengths are padded UP to the next bucket (128/256/384/…)
 FLASH_BUCKET = 128
@@ -64,6 +84,71 @@ def _pad_seq(x, axis, pad, value=0.0):
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
     return jnp.pad(x, widths, constant_values=value)
+
+
+# ------------------------------------------------------------ block shapes
+#: what one program's blocks and score-shaped temporaries may take of
+#: VMEM by :func:`_block_bytes`' count — half of Mosaic's default scoped
+#: limit of 16 MiB.  The count is an upper bound: a 512 × 512 one-pass
+#: backward, 7.3 MiB by it, compiles under a 4 MiB limit (compile
+#: rehearsal, PR 28)
+_VMEM_BUDGET = 8 * 2 ** 20
+#: f32 (block_q, block_k) values a backward program holds at once: s, p,
+#: dp, t, ds and the bf16 copies of p and ds that feed the MXU
+_SCORE_TEMPS = 6
+def _block_bytes(block_q, block_k, d, itemsize, dense_tiles):
+    """VMEM one program takes at these blocks, counted from shapes: the
+    score-shaped f32 temporaries, each dense extra (bias, full mask, the
+    ``dbias`` output: 4-byte (block_q, block_k) tiles, double-buffered),
+    the row and key blocks (q, o, do, dq over block_q; k, v, dk, dv over
+    block_k; double-buffered) and the f32 accumulators."""
+    tiles = block_q * block_k * 4 * (_SCORE_TEMPS + 2 * dense_tiles)
+    rows = 2 * itemsize * d * 4 * (block_q + block_k)
+    return tiles + rows + 4 * d * (block_q + 2 * block_k)
+
+
+def _pick_blocks(s_q, s_kv, d, itemsize, causal=False, dense_tiles=0):
+    """(block_q, block_k) from what the call can observe — the ONE place
+    a tile is chosen (callers' explicit blocks are honoured before this).
+
+    Candidates are the multiples of 128 that divide each (bucketed)
+    length.  A block that holds the WHOLE key range is taken when it fits
+    ``_VMEM_BUDGET`` with at least 128 query rows, with the most query
+    rows that still fit: the kernels then drop the online-softmax state
+    and the backward runs in one pass — under ``causal`` too, where it
+    computes the masked half it could have pruned and still wins (at
+    s = 1024 one pass over whole rows takes 3.4 ms where pruned 512 × 512
+    tiles take 4.5: PERF.md §6, PR 28).  Otherwise the largest tile that
+    fits, the squarest among equals (a two-pass backward re-fetches K/V
+    once per query block and q/dO once per key block); under ``causal``
+    such a tile spans at most half of each length, so blocks above the
+    diagonal exist to be pruned.  Dense extras shrink the tile: each
+    costs ``block_q × block_k × 4 B``, double-buffered."""
+    fits = [(bq, bk)
+            for bq in range(128, s_q + 1, 128) if s_q % bq == 0
+            for bk in range(128, s_kv + 1, 128) if s_kv % bk == 0
+            and _block_bytes(bq, bk, d, itemsize, dense_tiles)
+            <= _VMEM_BUDGET]
+    whole = [c for c in fits if c[1] == s_kv]
+    if whole:
+        return max(whole)
+    if causal:
+        fits = [(bq, bk) for bq, bk in fits
+                if bq <= max(128, s_q // 2) and bk <= max(128, s_kv // 2)]
+    return max(fits, key=lambda c: (c[0] * c[1], -abs(c[0] - c[1]), c[1]),
+               default=(128, 128))
+
+
+def _col_to_row(x):
+    """(n, 1) f32 column of row statistics → (1, n) lane-oriented row: a
+    lane broadcast and one aligned (n, 128) transpose in VMEM."""
+    return jnp.broadcast_to(x, (x.shape[0], 128)).T[:1]
+
+
+def _row_to_col(x):
+    """(1, n) lane-oriented row → (n, 1) column (sublane broadcast, one
+    aligned transpose)."""
+    return jnp.broadcast_to(x, (128, x.shape[1])).T[:, :1]
 
 
 # ------------------------------------------------------------- index maps
@@ -123,8 +208,8 @@ def _extra_specs(order, heads, gmode_mask, gmode_bias, gmode_kbias, block_q,
 
 
 # ---------------------------------------------------------------- masking
-def _block_logits(qi, ki, q, k, len_ref, kmask_ref, kbias_ref, fmask_ref,
-                  bias_ref, *, scale, causal, block_q, block_k, kv_off):
+def _block_logits(qi, ki, q, k, *, len_ref, kmask_ref, kbias_ref, fmask_ref,
+                  bias_ref, scale, causal, block_q, block_k, kv_off):
     """Masked+biased logits for one (qi, ki) block → (s, valid).
 
     ``valid`` is None on the pure-dense path (no masking of any kind) so
@@ -180,32 +265,48 @@ def _live(qi, ki, len_ref, *, causal, block_q, block_k, kv_off):
 def _unpack(refs, *, has_lengths, has_kmask, has_kbias, has_fmask, has_bias):
     """Split the flat pallas ref list into (fixed-ins, extras, outs+scratch).
     Optional inputs are present only when their static flag is set, keeping
-    the kernel arity minimal per specialization."""
-    q_ref, k_ref, v_ref = refs[:3]
-    i = 3
-    len_ref = kmask_ref = kbias_ref = fmask_ref = bias_ref = None
-    if has_lengths:
-        len_ref = refs[i]; i += 1                       # noqa: E702
-    if has_kmask:
-        kmask_ref = refs[i]; i += 1                     # noqa: E702
-    if has_kbias:
-        kbias_ref = refs[i]; i += 1                     # noqa: E702
-    if has_fmask:
-        fmask_ref = refs[i]; i += 1                     # noqa: E702
-    if has_bias:
-        bias_ref = refs[i]; i += 1                      # noqa: E702
-    return (q_ref, k_ref, v_ref), \
-        (len_ref, kmask_ref, kbias_ref, fmask_ref, bias_ref), refs[i:]
+    the kernel arity minimal per specialization; ``extras`` names each one
+    (``None`` when absent) as :func:`_block_logits` takes them."""
+    rest = list(refs[3:])
+    extras = {
+        name: rest.pop(0) if present else None
+        for name, present in (
+            ("len_ref", has_lengths), ("kmask_ref", has_kmask),
+            ("kbias_ref", has_kbias), ("fmask_ref", has_fmask),
+            ("bias_ref", has_bias))}
+    return refs[:3], extras, rest
 
 
 # ---------------------------------------------------------------- forward
-def _fwd_kernel(*refs, scale, causal, flags, block_q, block_k, num_kv,
-                kv_off):
+def _fwd_kernel(*refs, scale, flags, geom, num_kv):
     (q_ref, k_ref, v_ref), extras, rest = _unpack(refs, **flags)
-    len_ref, kmask_ref, kbias_ref, fmask_ref, bias_ref = extras
-    o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
     qi = pl.program_id(1)
     ki = pl.program_id(2)
+    logits = functools.partial(_block_logits, qi, ki, scale=scale, **extras,
+                               **geom)
+
+    if num_kv == 1:
+        # the block IS the key range: a straight softmax.  Same numbers
+        # as one step of the loop below (alpha = exp(NEG_INF - m) = 0 on
+        # a zero accumulator); a row with no valid key still reads zero
+        # (p * valid), so a dead block needs no pruning predicate
+        o_ref, lse_ref = rest
+        v = v_ref[0]
+        s, valid = logits(q_ref[0], k_ref[0])
+        m = jnp.max(s, axis=-1, keepdims=True)
+        p = jnp.exp(s - m)
+        if valid is not None:
+            p = p * valid
+        l = jnp.sum(p, axis=-1, keepdims=True)
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        acc = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
+        lse_ref[0] = _col_to_row(m + jnp.log(l_safe))
+        return
+
+    o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
 
     @pl.when(ki == 0)
     def _init():
@@ -213,18 +314,12 @@ def _fwd_kernel(*refs, scale, causal, flags, block_q, block_k, num_kv,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    live = _live(qi, ki, len_ref, causal=causal, block_q=block_q,
-                 block_k=block_k, kv_off=kv_off)
+    live = _live(qi, ki, extras["len_ref"], **geom)
 
     @pl.when(live)
     def _block():
-        q = q_ref[0]                                   # (bq, d)
-        k = k_ref[0]                                   # (bk, d)
         v = v_ref[0]                                   # (bk, d)
-        s, valid = _block_logits(
-            qi, ki, q, k, len_ref, kmask_ref, kbias_ref, fmask_ref,
-            bias_ref, scale=scale, causal=causal, block_q=block_q,
-            block_k=block_k, kv_off=kv_off)
+        s, valid = logits(q_ref[0], k_ref[0])          # (bq, bk)
         m_prev = m_scr[:, :1]                          # (bq, 1)
         l_prev = l_scr[:, :1]
         m_cur = jnp.max(s, axis=-1, keepdims=True)
@@ -245,35 +340,39 @@ def _fwd_kernel(*refs, scale, causal, flags, block_q, block_k, num_kv,
         l = l_scr[:, :1]
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
-        # lse is (bh, s_q, 1): sublane-oriented column write — no
-        # in-kernel transpose, no 128x lane broadcast in HBM
-        lse_ref[0] = m_scr[:, :1] + jnp.log(l_safe)
+        lse_ref[0] = _col_to_row(m_scr[:, :1] + jnp.log(l_safe))
+
+
+def _flags(lengths, kmask, kbias, fmask, bias):
+    return dict(has_lengths=lengths is not None, has_kmask=kmask is not None,
+                has_kbias=kbias is not None, has_fmask=fmask is not None,
+                has_bias=bias is not None)
 
 
 def _flash_fwd(q, k, v, lengths, kmask, kbias, fmask, bias, scale, causal,
                gmode_mask, gmode_bias, gmode_kbias, heads, block_q, block_k,
                interpret, name="flash_fwd"):
+    """→ ``(out (bh, s_q, d), lse (bh, s_q, 1) f32)``.  The kernel writes
+    ``lse`` lane-oriented, (bh, 1, s_q); the reshape to the column form
+    callers combine with (``parallel/ring_flash.py``) moves no data."""
     # ``name=`` on each pallas_call: the device trace calls the kernel's
     # instruction after its place in jax's name stack, so without one the
     # forward reads ``jvp__`` or ``infer`` after whatever traced it and
-    # the two backward kernels share ``transpose_jvp___`` (ISSUE 25)
+    # the backward kernels share ``transpose_jvp___`` (ISSUE 25)
     bh, s_q, d = q.shape
     s_kv = k.shape[1]
     num_q = s_q // block_q
     num_kv = s_kv // block_k
     grid = (bh, num_q, num_kv)
-    flags = dict(has_lengths=lengths is not None, has_kmask=kmask is not None,
-                 has_kbias=kbias is not None, has_fmask=fmask is not None,
-                 has_bias=bias is not None)
+    flags = _flags(lengths, kmask, kbias, fmask, bias)
     inputs = [q, k, v] + [x for x in (lengths, kmask, kbias, fmask, bias)
                           if x is not None]
 
-    kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, flags=flags,
-        block_q=block_q, block_k=block_k, num_kv=num_kv,
-        kv_off=s_kv - s_q)
     out, lse = pl.pallas_call(
-        kernel,
+        functools.partial(
+            _fwd_kernel, scale=scale, flags=flags, num_kv=num_kv,
+            geom=dict(causal=causal, block_q=block_q, block_k=block_k,
+                      kv_off=s_kv - s_q)),
         name=name,
         grid=grid,
         in_specs=[
@@ -284,27 +383,95 @@ def _flash_fwd(q, k, v, lengths, kmask, kbias, fmask, bias, scale, causal,
                          gmode_bias, gmode_kbias, block_q, block_k, **flags),
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s_q, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, s_q, 1), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, s_q), jnp.float32),
         ],
-        scratch_shapes=[
+        scratch_shapes=[] if num_kv == 1 else [
             pltpu.VMEM((block_q, 128), jnp.float32),   # running max
             pltpu.VMEM((block_q, 128), jnp.float32),   # running sum
             pltpu.VMEM((block_q, d), jnp.float32),     # output accumulator
         ],
         interpret=interpret,
     )(*inputs)
-    return out, lse
+    return out, lse.reshape(bh, s_q, 1)
 
 
 # ---------------------------------------------------------------- backward
-def _dq_kernel(*refs, scale, causal, flags, emit_dbias, block_q, block_k,
-               num_kv, kv_off):
+def _grad_logits(logits, q, k, v, do, lse, delta):
+    """→ ``(p, t)`` of one block: the probabilities recomputed from the
+    saved ``lse`` and ``t = p ⊙ (dO·Vᵀ − delta)``, the gradient of the
+    logits before ``scale`` (what ``dbias`` is)."""
+    s, valid = logits(q, k)
+    p = jnp.exp(s - lse)                                # (bq, bk)
+    if valid is not None:
+        p = p * valid
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)             # (bq, bk)
+    return p, p * (dp - delta)
+
+
+def _bwd_kernel(*refs, scale, flags, geom, emit_dbias, emit_dkbias, num_q):
+    """One pass over a (b, h) whose whole key range is one block: grid
+    (bh, num_q, 1), K/V (and the dk/dv output blocks) keep their index
+    over ``qi`` — fetched once, written once — and every score-shaped
+    value is computed once for dq, dk and dv."""
     (q_ref, k_ref, v_ref), extras, rest = _unpack(refs, **flags)
-    len_ref, kmask_ref, kbias_ref, fmask_ref, bias_ref = extras
+    o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref = rest[:6]
+    rest = rest[6:]
+    dbias_ref = rest.pop(0) if emit_dbias else None
+    dkb_ref = rest.pop(0) if emit_dkbias else None
+    qi = pl.program_id(1)
+    q = q_ref[0]                                        # (bq, d)
+    k = k_ref[0]                                        # (bk, d)
+    do = do_ref[0]
+    # delta_i = rowsum(dO ⊙ O), in the kernel: it never crosses HBM
+    delta = jnp.sum(do.astype(jnp.float32) * o_ref[0].astype(jnp.float32),
+                    axis=-1, keepdims=True)             # (bq, 1)
+    p, t = _grad_logits(
+        functools.partial(_block_logits, qi, 0, scale=scale, **extras,
+                          **geom),
+        q, k, v_ref[0], do, _row_to_col(lse_ref[0]), delta)
+    if emit_dbias:
+        dbias_ref[0] = t.astype(dbias_ref.dtype)
+    ds = (t * scale).astype(k.dtype)                    # (bq, bk)
+    dq_ref[0] = jax.lax.dot_general(
+        ds, k, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(dq_ref.dtype)
+    sums = [
+        # dV = Pᵀ·dO, dK = dSᵀ·Q
+        (dv_ref, jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)),
+        (dk_ref, jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)),
+    ]
+    if emit_dkbias:
+        # d(key-bias)[k] = column sums of t
+        sums.append((dkb_ref, jnp.sum(t, axis=0, keepdims=True)))
+    if num_q == 1:
+        for ref, val in sums:
+            ref[0] = val.astype(ref.dtype)
+        return
+    # several query blocks: sum over them in f32 scratch, write at the last
+    for (ref, val), scr in zip(sums, rest):
+        @pl.when(qi == 0)
+        def _init(scr=scr):
+            scr[:] = jnp.zeros_like(scr)
+
+        scr[:] += val
+
+        @pl.when(qi == num_q - 1)
+        def _finish(ref=ref, scr=scr):
+            ref[0] = scr[:].astype(ref.dtype)
+
+
+def _dq_kernel(*refs, scale, flags, geom, emit_dbias, num_kv):
+    (q_ref, k_ref, v_ref), extras, rest = _unpack(refs, **flags)
     do_ref, lse_ref, delta_ref = rest[:3]
     rest = rest[3:]
     if emit_dbias:
@@ -319,28 +486,16 @@ def _dq_kernel(*refs, scale, causal, flags, emit_dbias, block_q, block_k,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    live = _live(qi, ki, len_ref, causal=causal, block_q=block_q,
-                 block_k=block_k, kv_off=kv_off)
+    live = _live(qi, ki, extras["len_ref"], **geom)
     live_static = live is True
 
     def _body(write_dbias):
-        q = q_ref[0]
         k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]                                  # (bq, d)
-        lse = lse_ref[0]                                # (bq, 1)
-        delta = delta_ref[0]                            # (bq, 1)
-        s, valid = _block_logits(
-            qi, ki, q, k, len_ref, kmask_ref, kbias_ref, fmask_ref,
-            bias_ref, scale=scale, causal=causal, block_q=block_q,
-            block_k=block_k, kv_off=kv_off)
-        p = jnp.exp(s - lse)                            # (bq, bk)
-        if valid is not None:
-            p = p * valid
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)         # (bq, bk)
-        t = p * (dp - delta)       # = dL/d(logits) block (pre-scale)
+        _, t = _grad_logits(
+            functools.partial(_block_logits, qi, ki, scale=scale, **extras,
+                              **geom),
+            q_ref[0], k, v_ref[0], do_ref[0], _row_to_col(lse_ref[0]),
+            _row_to_col(delta_ref[0]))
         if write_dbias:
             dbias_ref[0] = t.astype(dbias_ref.dtype)
         ds = t * scale
@@ -365,10 +520,8 @@ def _dq_kernel(*refs, scale, causal, flags, emit_dbias, block_q, block_k,
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(*refs, scale, causal, flags, emit_dkbias, block_q, block_k,
-                num_q, kv_off):
+def _dkv_kernel(*refs, scale, flags, geom, emit_dkbias, num_q):
     (q_ref, k_ref, v_ref), extras, rest = _unpack(refs, **flags)
-    len_ref, kmask_ref, kbias_ref, fmask_ref, bias_ref = extras
     if emit_dkbias:
         (do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dkb_ref,
          dk_scr, dv_scr, dkb_scr) = rest
@@ -385,32 +538,21 @@ def _dkv_kernel(*refs, scale, causal, flags, emit_dkbias, block_q, block_k,
         if emit_dkbias:
             dkb_scr[:] = jnp.zeros_like(dkb_scr)
 
-    live = _live(qi, ki, len_ref, causal=causal, block_q=block_q,
-                 block_k=block_k, kv_off=kv_off)
+    live = _live(qi, ki, extras["len_ref"], **geom)
 
     @pl.when(live)
     def _block():
         q = q_ref[0]                                    # (bq, d)
-        k = k_ref[0]                                    # (bk, d)
-        v = v_ref[0]
         do = do_ref[0]
-        lse = lse_ref[0]                                 # (bq, 1)
-        delta = delta_ref[0]                             # (bq, 1)
-        s, valid = _block_logits(
-            qi, ki, q, k, len_ref, kmask_ref, kbias_ref, fmask_ref,
-            bias_ref, scale=scale, causal=causal, block_q=block_q,
-            block_k=block_k, kv_off=kv_off)
-        p = jnp.exp(s - lse)                             # (bq, bk)
-        if valid is not None:
-            p = p * valid
+        p, t = _grad_logits(
+            functools.partial(_block_logits, qi, ki, scale=scale, **extras,
+                              **geom),
+            q, k_ref[0], v_ref[0], do, _row_to_col(lse_ref[0]),
+            _row_to_col(delta_ref[0]))
         # dV += P^T @ dO
         dv_scr[:] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)          # (bq, bk)
-        t = p * (dp - delta)            # dL/d(logits) block (pre-scale)
         if emit_dkbias:
             # d(key-bias)[k] = sum over query rows of t — accumulated
             # across this ki column's q blocks (broadcast over the scratch
@@ -434,48 +576,84 @@ def _dkv_kernel(*refs, scale, causal, flags, emit_dkbias, block_q, block_k,
 def _flash_bwd(q, k, v, lengths, kmask, kbias, fmask, bias, out, lse, do,
                scale, causal, gmode_mask, gmode_bias, gmode_kbias, heads,
                block_q, block_k, interpret):
+    """→ ``(dq, dk, dv, dbias, dkbias)`` from the forward's ``out`` and an
+    ``lse`` (bh, s_q, 1) that need not be this call's own (the ring hands
+    in its global one).  One pass when ``block_k`` is the whole key range,
+    else the dq and dkv kernels."""
     bh, s_q, d = q.shape
     s_kv = k.shape[1]
     num_q = s_q // block_q
     num_kv = s_kv // block_k
-    flags = dict(has_lengths=lengths is not None, has_kmask=kmask is not None,
-                 has_kbias=kbias is not None, has_fmask=fmask is not None,
-                 has_bias=bias is not None)
+    flags = _flags(lengths, kmask, kbias, fmask, bias)
+    geom = dict(causal=causal, block_q=block_q, block_k=block_k,
+                kv_off=s_kv - s_q)
     emit_dbias = bias is not None
     emit_dkbias = kbias is not None
     extras = [x for x in (lengths, kmask, kbias, fmask, bias)
               if x is not None]
-    # delta_i = rowsum(dO ⊙ O): tiny elementwise+reduce — XLA fuses it.
-    # Shaped (bh, s_q, 1) like lse: the unit lane dim keeps the row
-    # blocks legal under Mosaic tiling AND reads back in sublane
-    # orientation (no in-kernel transpose).
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)[..., None]                   # (bh, s_q, 1)
+    lse = lse.reshape(bh, 1, s_q)             # lane-oriented, no data moved
 
     qspec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
     kspec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
-    rowspec = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
-    dq_outs = [qspec]
-    dq_shapes = [jax.ShapeDtypeStruct((bh, s_q, d), q.dtype)]
-    if emit_dbias:
-        # dbias is dense — O(B·H·S²) like the score matrix; unavoidable,
-        # the bias gradient has that shape before broadcast-reduction
-        dq_outs.append(pl.BlockSpec((1, block_q, block_k),
-                                    lambda b, i, j: (b, i, j)))
-        dq_shapes.append(jax.ShapeDtypeStruct((bh, s_q, s_kv), jnp.float32))
+    rowspec = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i))
+    extra_specs = _extra_specs(
+        lambda b, i, j: (b, i, j), heads, gmode_mask, gmode_bias,
+        gmode_kbias, block_q, block_k, **flags)
+    # dbias is dense — O(B·H·S²) like the score matrix; unavoidable, the
+    # bias gradient has that shape before broadcast-reduction
+    dbias_spec = pl.BlockSpec((1, block_q, block_k),
+                              lambda b, i, j: (b, i, j))
+    dbias_shape = jax.ShapeDtypeStruct((bh, s_q, s_kv), jnp.float32)
+    dq_shape = jax.ShapeDtypeStruct((bh, s_q, d), q.dtype)
+    dkv_shapes = [jax.ShapeDtypeStruct((bh, s_kv, d), k.dtype),
+                  jax.ShapeDtypeStruct((bh, s_kv, d), v.dtype)]
+    # d(key-bias): O(S) per bh, a column strip reduced over the broadcast
+    # group by the VJP wrapper
+    dkb_shape = jax.ShapeDtypeStruct((bh, 1, s_kv), jnp.float32)
+
+    if num_kv == 1:
+        outs = [(qspec, dq_shape), (kspec, dkv_shapes[0]),
+                (kspec, dkv_shapes[1])]
+        scratch = [pltpu.VMEM((s_kv, d), jnp.float32),
+                   pltpu.VMEM((s_kv, d), jnp.float32)]
+        if emit_dbias:
+            outs.append((dbias_spec, dbias_shape))
+        if emit_dkbias:
+            outs.append((pl.BlockSpec((1, 1, s_kv),
+                                      lambda b, i, j: (b, 0, 0)), dkb_shape))
+            scratch.append(pltpu.VMEM((1, s_kv), jnp.float32))
+        res = pl.pallas_call(
+            functools.partial(_bwd_kernel, scale=scale, flags=flags,
+                              geom=geom, emit_dbias=emit_dbias,
+                              emit_dkbias=emit_dkbias, num_q=num_q),
+            name="flash_bwd",
+            grid=(bh, num_q, 1),
+            in_specs=[qspec, kspec, kspec] + extra_specs
+            + [qspec, qspec, rowspec],
+            out_specs=[spec for spec, _ in outs],
+            out_shape=[shape for _, shape in outs],
+            scratch_shapes=scratch if num_q > 1 else [],
+            interpret=interpret,
+        )(q, k, v, *extras, out, do, lse)
+        dq, dk, dv = res[:3]
+        rest = list(res[3:])
+        dbias = rest.pop(0) if emit_dbias else None
+        dkbias = rest.pop(0) if emit_dkbias else None
+        return dq, dk, dv, dbias, dkbias
+
+    # delta_i = rowsum(dO ⊙ O): tiny elementwise+reduce — XLA fuses it.
+    # Lane-oriented (bh, 1, s_q) like lse.
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1)[:, None, :]
     res = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          flags=flags, emit_dbias=emit_dbias,
-                          block_q=block_q, block_k=block_k, num_kv=num_kv,
-                          kv_off=s_kv - s_q),
+        functools.partial(_dq_kernel, scale=scale, flags=flags, geom=geom,
+                          emit_dbias=emit_dbias, num_kv=num_kv),
         name="flash_bwd_dq",
         grid=(bh, num_q, num_kv),
-        in_specs=[qspec, kspec, kspec]
-        + _extra_specs(lambda b, i, j: (b, i, j), heads, gmode_mask,
-                       gmode_bias, gmode_kbias, block_q, block_k, **flags)
+        in_specs=[qspec, kspec, kspec] + extra_specs
         + [qspec, rowspec, rowspec],
-        out_specs=dq_outs if emit_dbias else dq_outs[0],
-        out_shape=dq_shapes if emit_dbias else dq_shapes[0],
+        out_specs=[qspec, dbias_spec] if emit_dbias else qspec,
+        out_shape=[dq_shape, dbias_shape] if emit_dbias else dq_shape,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
     )(q, k, v, *extras, do, lse, delta)
@@ -490,26 +668,18 @@ def _flash_bwd(q, k, v, lengths, kmask, kbias, fmask, bias, out, lse, do,
         pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
         pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
     ]
-    dkv_shapes = [
-        jax.ShapeDtypeStruct((bh, s_kv, d), k.dtype),
-        jax.ShapeDtypeStruct((bh, s_kv, d), v.dtype),
-    ]
     dkv_scratch = [
         pltpu.VMEM((block_k, d), jnp.float32),
         pltpu.VMEM((block_k, d), jnp.float32),
     ]
     if emit_dkbias:
-        # O(S) per bh: column-strip gradient, reduced over the broadcast
-        # group by the VJP wrapper
         dkv_outs.append(pl.BlockSpec((1, 1, block_k),
                                      lambda b, j, i: (b, 0, j)))
-        dkv_shapes.append(jax.ShapeDtypeStruct((bh, 1, s_kv), jnp.float32))
+        dkv_shapes.append(dkb_shape)
         dkv_scratch.append(pltpu.VMEM((8, block_k), jnp.float32))
     res2 = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          flags=flags, emit_dkbias=emit_dkbias,
-                          block_q=block_q, block_k=block_k,
-                          num_q=num_q, kv_off=s_kv - s_q),
+        functools.partial(_dkv_kernel, scale=scale, flags=flags, geom=geom,
+                          emit_dkbias=emit_dkbias, num_q=num_q),
         name="flash_bwd_dkv",
         grid=(bh, num_kv, num_q),
         in_specs=[
@@ -520,8 +690,8 @@ def _flash_bwd(q, k, v, lengths, kmask, kbias, fmask, bias, out, lse, do,
                          block_q, block_k, **flags)
         + [
             pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i)),
+            pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i)),
         ],
         out_specs=dkv_outs,
         out_shape=dkv_shapes,
@@ -647,9 +817,13 @@ def flash_attention(q, k, v, causal=False, scale=None, lengths=None,
     ``bias``: optional additive logit bias, same broadcast menu,
     differentiable (T5 relative position bias).
     With none of these the kernels compile the original dense
-    straight-line code with zero masking overhead.  Ragged (non-128-
-    multiple) sequence lengths are BUCKETED: padded up to the next
-    flash-legal bucket (128/256/384/…), the pad keys masked through the
+    straight-line code with zero masking overhead.
+    ``block_q`` / ``block_k``: left ``None``, :func:`_pick_blocks` chooses
+    them from this call's shapes (the whole key range in one block where
+    it fits VMEM); given, they are used as they are.  Which geometry a
+    trace compiled is counted in ``metrics.flash_call_counts()``.
+    Ragged (non-128-multiple) sequence lengths are BUCKETED: padded up to
+    the next flash-legal bucket (128/256/384/…), the pad keys masked by the
     kernel's existing lengths/key-mask strip path, and the output sliced
     back to the caller's length — ``seq=384+r`` stays on the fast path.
     The one unbucketable case is causal CROSS-attention whose lengths
@@ -705,12 +879,6 @@ def flash_attention(q, k, v, causal=False, scale=None, lengths=None,
                 bias = _pad_seq(jnp.asarray(bias, jnp.float32), 2, pad_q)
         s_q += pad_q
         s_kv += pad_k
-    block_q = block_q or min(DEFAULT_BLOCK_Q, s_q)
-    block_k = block_k or min(DEFAULT_BLOCK_K, s_kv)
-    if s_q % block_q or s_kv % block_k:
-        raise ValueError(
-            f"flash_attention needs seq divisible by block "
-            f"({s_q}, {s_kv}) vs ({block_q}, {block_k})")
     scale = float(scale) if scale is not None else 1.0 / (d ** 0.5)
     q3 = q.reshape(b * h, s_q, d)
     k3 = k.reshape(b * h, s_kv, d)
@@ -744,6 +912,19 @@ def flash_attention(q, k, v, causal=False, scale=None, lengths=None,
             kbias3 = ba.reshape(-1, 1, s_kv)
         else:
             bias3, gmode_bias = _broadcast_group(ba, b, h, s_q, s_kv, "bias")
+    # blocks by the rule, from this call's shapes; a dense bias counts
+    # twice (its tile and the backward's dbias tile).  Explicit blocks win.
+    rule_q, rule_k = _pick_blocks(
+        s_q, s_kv, d, q.dtype.itemsize, causal,
+        (fmask3 is not None) + 2 * (bias3 is not None))
+    block_q = block_q or rule_q
+    block_k = block_k or rule_k
+    if s_q % block_q or s_kv % block_k:
+        raise ValueError(
+            f"flash_attention needs seq divisible by block "
+            f"({s_q}, {s_kv}) vs ({block_q}, {block_k})")
+    from ...metrics import record_flash_call
+    record_flash_call(block_q, block_k, one_pass=block_k == s_kv)
     # the one-token decode call (one query row, padded to a bucket of
     # 128) under a name of its own in the device trace
     out = _flash(q3, k3, v3, len3, kmask2, kbias3, fmask3, bias3, scale,

@@ -228,8 +228,8 @@ class HetuProfiler:
     def all_counters():
         """{family: {kind: count}} over EVERY counter family on the
         observability registry in one call (``hetu_tpu.metrics``
-        ``all_counts``): flash_fallbacks, emb_pallas_fallbacks, faults,
-        elastic, autoparallel, cache, zero, step_cache, run_plan, serve,
+        ``all_counts``): flash_fallbacks, flash_calls,
+        emb_pallas_fallbacks, faults, elastic, autoparallel, cache, zero, step_cache, run_plan, serve,
         decode, prefix_cache, decode_recovery, serve_rejection_reason,
         fleet, protocol, ps_rpc_bytes.  The per-family
         accessors below are thin slices of this — same registry, same
@@ -269,6 +269,15 @@ class HetuProfiler:
         hard failures instead of counters."""
         from .metrics import flash_fallback_counts
         return flash_fallback_counts()
+
+    @staticmethod
+    def flash_calls():
+        """{"<block_q>x<block_k>:<one_pass|two_pass>": count} of traced
+        flash-attention calls by the block shapes the kernel module's
+        rule chose for them and the backward they get (one kernel while
+        the whole key range is one block, else dq + dkv).  Per trace."""
+        from .metrics import flash_call_counts
+        return flash_call_counts()
 
     @staticmethod
     def emb_pallas_fallbacks():

@@ -584,6 +584,37 @@ def test_ring_flash_bias_matches_single_device_cp2():
                                    rtol=3e-4, atol=3e-5, err_msg=n)
 
 
+@pytest.mark.parametrize("S,blocks", [
+    (256, {}),                                  # chunk 128: one block
+    (512, {"block_q": 128, "block_k": 256}),    # two query blocks a chunk
+], ids=["chunk128", "chunk256-q128"])
+def test_ring_flash_global_lse_through_one_pass_backward(S, blocks):
+    """Each ring step's block is its whole resident chunk, so the ring's
+    backward is the ONE-PASS kernel, handed the ring's GLOBAL lse (not the
+    chunk's own) and the global output: q, k, v gradients against the
+    unsharded reference, under a key mask."""
+    import jax
+    rng = np.random.RandomState(38)
+    q, k, v = _qkv(rng, B=1, H=1, S=S, D=8)
+    km = rng.rand(1, S) > 0.3
+    km[:, 0] = True
+    mesh = ht.make_mesh({"cp": 2}, jax.devices()[:2])
+
+    def f(q, k, v):
+        return (_ring_flash_call(q, k, v, mesh, key_mask=km,
+                                 **blocks) ** 2).sum()
+
+    def fr(q, k, v):
+        return (sdpa_reference(q, k, v,
+                               mask=km[:, None, None, :]) ** 2).sum()
+
+    g = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(fr, argnums=(0, 1, 2))(q, k, v)
+    for a, b, n in zip(g, gr, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=3e-4, atol=3e-5, err_msg=n)
+
+
 def test_ring_flash_key_strip_bias_causal_cp2():
     """A row-broadcast (B, 1, 1, S) bias rides the kernel's O(S)
     key-strip path per ring step, composed with causal chunk skipping."""

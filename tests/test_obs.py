@@ -184,6 +184,7 @@ def test_metrics_dump_roundtrips_every_counter_family():
     accessors on the same run — one registry, two views."""
     metrics.reset_all()
     metrics.record_flash_fallback("test_reason")
+    metrics.record_flash_call(512, 512, one_pass=True)
     metrics.record_fault("test_fault", 2)
     metrics.record_elastic("elastic_shrink")
     metrics.record_concurrency("concurrency_preemptions")
@@ -210,6 +211,7 @@ def test_metrics_dump_roundtrips_every_counter_family():
     dump = obs.metrics_dump()
     legacy = {
         "flash_fallbacks": metrics.flash_fallback_counts(),
+        "flash_calls": metrics.flash_call_counts(),
         "emb_pallas_fallbacks": metrics.emb_pallas_fallback_counts(),
         "faults": metrics.fault_counts(),
         "elastic": metrics.elastic_counts(),
@@ -230,6 +232,7 @@ def test_metrics_dump_roundtrips_every_counter_family():
     }
     for fam, want in legacy.items():
         assert dump["counters"][fam] == want, fam
+    assert legacy["flash_calls"] == {"512x512:one_pass": 1}
     assert legacy["faults"] == {"test_fault": 2}
     assert legacy["serve"]["serve_queue_depth_hw"] == 9
     assert legacy["decode"] == {"decode_tokens": 7,

@@ -10,6 +10,15 @@ from hetu_tpu.ops.attention import sdpa_reference
 from hetu_tpu.ops.pallas.flash_attention import flash_attention
 
 
+#: block shapes the parity tests run under: the module's rule (s <= 512
+#: non-causal: the WHOLE key range in one block — straight softmax, the
+#: one-pass backward) and 128 x 128 (two or three key blocks at s = 256 /
+#: 384: the online-softmax forward, the dq + dkv backward)
+BLOCKS = pytest.mark.parametrize(
+    "blocks", [{}, {"block_q": 128, "block_k": 128}],
+    ids=["rule", "128x128"])
+
+
 def _rand_qkv(b, h, s, d, seed=0, dtype=jnp.float32):
     rng = np.random.RandomState(seed)
     mk = lambda: jnp.asarray(rng.randn(b, h, s, d).astype(np.float32) * 0.3,
@@ -17,23 +26,25 @@ def _rand_qkv(b, h, s, d, seed=0, dtype=jnp.float32):
     return mk(), mk(), mk()
 
 
+@BLOCKS
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("s", [256, 384])
-def test_flash_forward_parity(causal, s):
+def test_flash_forward_parity(causal, s, blocks):
     q, k, v = _rand_qkv(2, 3, s, 64)
-    out = flash_attention(q, k, v, causal=causal, interpret=True)
+    out = flash_attention(q, k, v, causal=causal, interpret=True, **blocks)
     ref = sdpa_reference(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
 
+@BLOCKS
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_backward_parity(causal):
+def test_flash_backward_parity(causal, blocks):
     q, k, v = _rand_qkv(1, 2, 256, 64, seed=1)
 
     def f_flash(q, k, v):
         return jnp.sum(flash_attention(q, k, v, causal=causal,
-                                       interpret=True) ** 2)
+                                       interpret=True, **blocks) ** 2)
 
     def f_ref(q, k, v):
         return jnp.sum(sdpa_reference(q, k, v, causal=causal) ** 2)
@@ -161,21 +172,23 @@ def _grad_parity(f_flash, f_ref, args, names, rtol=2e-4, atol=2e-4):
                                    rtol=rtol, atol=atol, err_msg=n)
 
 
+@BLOCKS
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_key_mask(causal):
+def test_flash_key_mask(causal, blocks):
     # non-prefix key masks (the general padded-batch form: BERT attention
     # masks that are NOT sorted-by-length prefixes)
     q, k, v = _rand_qkv(2, 3, 256, 64, seed=5)
     rng = np.random.RandomState(5)
     km = jnp.asarray(rng.rand(2, 256) > 0.3)
     out = flash_attention(q, k, v, causal=causal, key_mask=km,
-                          interpret=True)
+                          interpret=True, **blocks)
     ref = sdpa_reference(q, k, v, causal=causal, mask=km[:, None, None, :])
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
     _grad_parity(
         lambda q, k, v: jnp.sum(flash_attention(
-            q, k, v, causal=causal, key_mask=km, interpret=True) ** 2),
+            q, k, v, causal=causal, key_mask=km, interpret=True,
+            **blocks) ** 2),
         lambda q, k, v: jnp.sum(sdpa_reference(
             q, k, v, causal=causal, mask=km[:, None, None, :]) ** 2),
         (q, k, v), "qkv")
@@ -202,38 +215,48 @@ def test_flash_full_mask_broadcast_groups(gshape):
         (q, k, v), "qkv")
 
 
+@BLOCKS
 @pytest.mark.parametrize("gshape", [(1, 3), (2, 3), (1, 1)])
-def test_flash_bias_grad(gshape):
+def test_flash_bias_grad(gshape, blocks):
     # differentiable additive bias (T5 relative position bias): dbias is
     # emitted per-block and broadcast-reduced to the stored bias shape
     q, k, v = _rand_qkv(2, 3, 256, 64, seed=7)
     rng = np.random.RandomState(7)
     bias = jnp.asarray(rng.randn(*gshape, 256, 256).astype(np.float32) * .5)
-    out = flash_attention(q, k, v, bias=bias, interpret=True)
+    out = flash_attention(q, k, v, bias=bias, interpret=True, **blocks)
     ref = sdpa_reference(q, k, v, bias=bias)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
     _grad_parity(
         lambda q, k, v, b: jnp.sum(flash_attention(
-            q, k, v, bias=b, interpret=True) ** 2),
+            q, k, v, bias=b, interpret=True, **blocks) ** 2),
         lambda q, k, v, b: jnp.sum(sdpa_reference(q, k, v, bias=b) ** 2),
         (q, k, v, bias), ["q", "k", "v", "bias"])
 
 
-def test_flash_mask_bias_causal_combo():
-    # XLNet-style: permutation mask + positional bias + causal, with grads
+@pytest.mark.parametrize(
+    "blocks", [{}, {"block_q": 128, "block_k": 128},
+               {"block_q": 128, "block_k": 256}],
+    ids=["rule", "128x128", "128x256"])
+def test_flash_mask_bias_causal_combo(blocks):
+    # XLNet-style: permutation mask + positional bias + causal, with grads.
+    # The rule takes the 256 keys whole in one 256 x 256 program; 128 x 128
+    # prunes the block above the diagonal; 128 x 256 puts the diagonal,
+    # the full mask and the dbias tiles through the whole-range kernels
+    # with dk/dv summed in scratch over two query blocks
     q, k, v = _rand_qkv(2, 2, 256, 64, seed=8)
     rng = np.random.RandomState(8)
     fm = jnp.asarray(rng.rand(2, 2, 256, 256) > 0.2)
     bias = jnp.asarray(rng.randn(1, 2, 256, 256).astype(np.float32) * .5)
     out = flash_attention(q, k, v, causal=True, mask=fm, bias=bias,
-                          interpret=True)
+                          interpret=True, **blocks)
     ref = sdpa_reference(q, k, v, causal=True, mask=fm, bias=bias)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
     _grad_parity(
         lambda q, k, v, b: jnp.sum(flash_attention(
-            q, k, v, causal=True, mask=fm, bias=b, interpret=True) ** 2),
+            q, k, v, causal=True, mask=fm, bias=b, interpret=True,
+            **blocks) ** 2),
         lambda q, k, v, b: jnp.sum(sdpa_reference(
             q, k, v, causal=True, mask=fm, bias=b) ** 2),
         (q, k, v, bias), ["q", "k", "v", "bias"])
@@ -475,8 +498,9 @@ def test_sparse_moe_layer_trains():
     assert losses[-1] < losses[0]
 
 
+@BLOCKS
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_varlen_padding_mask(causal):
+def test_flash_varlen_padding_mask(causal, blocks):
     """lengths argument == reference column mask, fwd and grads."""
     b, h, s, d = 3, 2, 256, 32
     rng = np.random.RandomState(11)
@@ -488,7 +512,7 @@ def test_flash_varlen_padding_mask(causal):
     mask = (cols < np.asarray(lengths)[:, None, None, None])
 
     out = flash_attention(q, k, v, causal=causal, lengths=lengths,
-                          interpret=True)
+                          interpret=True, **blocks)
     ref = sdpa_reference(q, k, v, causal=causal,
                          mask=jnp.asarray(mask, jnp.float32))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -497,7 +521,7 @@ def test_flash_varlen_padding_mask(causal):
     def f_flash(q, k, v):
         return jnp.sum(flash_attention(q, k, v, causal=causal,
                                        lengths=lengths,
-                                       interpret=True) ** 2)
+                                       interpret=True, **blocks) ** 2)
 
     def f_ref(q, k, v):
         return jnp.sum(sdpa_reference(
@@ -533,33 +557,144 @@ def test_sdpa_varlen_op_graph():
 
 
 def test_flash_gate_artifact_loading(tmp_path, monkeypatch):
-    # the dispatcher's gate + block shapes come from the committed on-chip
-    # A/B artifact (tools/flash_ab.py)
+    # the dispatcher's gate comes from the on-chip A/B artifact
+    # (tools/flash_ab.py); block shapes do NOT (the kernel module's rule)
     import json
-    import os
     from hetu_tpu.ops import attention as att
 
     art = {"backend": "tpu", "flash_min_len": 128, "rows": {
         "128": {"blocks_dense": [128, 128], "winner_dense": "flash"},
-        "512": {"blocks_dense": [128, 256], "blocks_causal": [256, 128],
-                "blocks_kmask": [256, 256], "winner_dense": "flash"}}}
+        "512": {"blocks_kmask": [256, 256], "winner_dense": "flash"}}}
     d = tmp_path / "artifacts"
     d.mkdir()
     (d / "flash_ab.json").write_text(json.dumps(art))
     monkeypatch.setenv("HETU_FLASH_AB_PATH", str(d / "flash_ab.json"))
-    gate, blocks = att._load_flash_gate()
-    assert gate == 128
-    assert blocks[(512, "dense")] == (128, 256)
-    assert blocks[(512, "causal")] == (256, 128)
-    assert blocks[(512, "kmask")] == (256, 256)
-    assert blocks[(128, "dense")] == (128, 128)
+    assert att._load_flash_gate() == 128
 
-    # a PARTIAL artifact serves blocks but never its prefix-only gate
+    # a PARTIAL artifact never serves its prefix-only gate
     art["partial"] = True
     (d / "flash_ab.json").write_text(json.dumps(art))
-    gate, blocks = att._load_flash_gate(default=256)
-    assert gate == 256                       # default kept
-    assert blocks[(512, "kmask")] == (256, 256)
+    assert att._load_flash_gate(default=256) == 256      # default kept
+    monkeypatch.setenv("HETU_FLASH_MIN_LEN", "384")
+    assert att._load_flash_gate(default=256) == 384
+    # a table of blocks left in an old artifact overrides nothing
+    assert not hasattr(att, "_FLASH_BLOCKS")
+    assert not hasattr(att, "_clipped_blocks")
+
+
+@pytest.mark.parametrize("s_q,s_kv,d,itemsize,causal,dense", [
+    (512, 512, 64, 2, False, 0),      # bert-base, key mask: strips only
+    (512, 512, 64, 2, False, 2),      # T5: bias tile + dbias tile
+    (512, 512, 64, 2, False, 3),      # XLNet: full mask + bias + dbias
+    (1024, 1024, 64, 2, True, 0),     # gpt2 causal
+    (512, 512, 64, 2, True, 0),
+    (4096, 4096, 64, 2, True, 0),     # causal, past one block
+    (8192, 8192, 128, 2, True, 3),
+    (1024, 1024, 64, 2, False, 0),
+    (2048, 2048, 128, 2, False, 0),   # llama-width head, long
+    (4096, 4096, 64, 2, False, 0),
+    (256, 512, 64, 4, False, 0),      # cross-attention, f32
+    (128, 768, 64, 4, False, 1),      # chunked prefill: full mask
+    (384, 384, 64, 4, True, 0),       # 384 has no 256 divisor
+    (128, 128, 64, 4, True, 0),
+])
+def test_flash_block_rule(s_q, s_kv, d, itemsize, causal, dense):
+    """``_pick_blocks``: multiples of 128 that divide both lengths, within
+    the stated VMEM budget, and nothing else decides."""
+    import importlib
+    fa = importlib.import_module("hetu_tpu.ops.pallas.flash_attention")
+    bq, bk = fa._pick_blocks(s_q, s_kv, d, itemsize, causal, dense)
+    assert bq % 128 == 0 and bk % 128 == 0
+    assert s_q % bq == 0 and s_kv % bk == 0
+    assert fa._block_bytes(bq, bk, d, itemsize, dense) <= fa._VMEM_BUDGET
+    if causal and bk < s_kv:
+        # several key blocks: pruning-friendly, at least two blocks a side
+        assert bq <= max(128, s_q // 2) and bk <= max(128, s_kv // 2)
+    # the same call is answered the same way: no state, no environment
+    assert (bq, bk) == fa._pick_blocks(s_q, s_kv, d, itemsize, causal,
+                                       dense)
+
+
+def test_flash_block_rule_choices(monkeypatch):
+    """What the rule gives the shapes the models run, and what moves it."""
+    import importlib
+    fa = importlib.import_module("hetu_tpu.ops.pallas.flash_attention")
+    pick = fa._pick_blocks
+    # BERT's 512 dense keys: one program per (b, h) takes the whole
+    # sequence (6,144 programs a kernel at 128 x 128 -> 384)
+    assert pick(512, 512, 64, 2) == (512, 512)
+    # a dense bias and its dbias tile cost block_q x block_k x 4 B each,
+    # double-buffered: the query block shrinks, the key range stays whole
+    assert pick(512, 512, 64, 2, False, 2) == (256, 512)
+    assert pick(512, 512, 64, 2, False, 3) == (256, 512)
+    small = pick(512, 512, 64, 2, False, 2)
+    assert small[0] * small[1] < 512 * 512
+    # causal: a key range that fits is still taken whole (one pass over
+    # the masked half beat pruned tiles on the chip, PERF.md PR 28) ...
+    assert pick(512, 512, 64, 2, True) == (512, 512)
+    assert pick(1024, 1024, 64, 2, True) == (256, 1024)
+    # ... and past that, blocks above the diagonal must exist to be pruned
+    bq, bk = pick(4096, 4096, 64, 2, True)
+    assert bq <= 2048 and bk <= 2048
+    assert pick(256, 256, 64, 2, True, 100) == (128, 128)  # nothing fits
+    # long rows fall back to what fits: square tiles, several key blocks
+    assert pick(4096, 4096, 64, 2) == (512, 512)
+    assert pick(2048, 2048, 64, 2)[1] < 2048
+    # cross-attention takes each side's own length
+    assert pick(256, 512, 64, 4) == (256, 512)
+    # no environment variable and no artifact reaches the rule
+    monkeypatch.setenv("HETU_FLASH_AB_PATH", "/nonexistent")
+    monkeypatch.setenv("HETU_FLASH_MIN_LEN", "4096")
+    assert pick(512, 512, 64, 2) == (512, 512)
+
+
+def test_flash_explicit_blocks_win_and_geometry_is_counted():
+    """Callers' ``block_q=`` / ``block_k=`` are used as given (tests and
+    the ring choose their own), and every traced call records the
+    geometry it compiled with — except under an abstract shape trace."""
+    from hetu_tpu import metrics
+    from hetu_tpu.profiler import HetuProfiler
+    q, k, v = _rand_qkv(1, 2, 256, 64, seed=31)
+    metrics.reset_all()
+    flash_attention(q, k, v, interpret=True)
+    flash_attention(q, k, v, causal=True, interpret=True)
+    flash_attention(q, k, v, block_q=128, block_k=128, interpret=True)
+    jax.grad(lambda q: jnp.sum(flash_attention(
+        q, k, v, block_q=128, block_k=256, interpret=True)))(q)
+    assert HetuProfiler.flash_calls() == {
+        "256x256:one_pass": 2,          # the rule: whole key range
+        "128x128:two_pass": 1,          # explicit
+        "128x256:one_pass": 1}
+    assert HetuProfiler.all_counters()["flash_calls"] \
+        == metrics.flash_call_counts()
+    with metrics.suppress_perf_counters():
+        jax.eval_shape(lambda q: flash_attention(q, k, v, interpret=True),
+                       q)
+    assert sum(metrics.flash_call_counts().values()) == 4
+    with pytest.raises(ValueError, match="divisible by block"):
+        flash_attention(q, k, v, block_q=96, block_k=128, interpret=True)
+    metrics.reset_all()
+
+
+def test_flash_whole_range_backward_sums_over_query_blocks():
+    """block_k = S_kv with several query blocks: K/V stay resident, dk /
+    dv / the key-bias gradient are summed in scratch across the query
+    blocks and written at the last — against the reference, with a key
+    mask, a per-key bias and cross-attention lengths."""
+    rng = np.random.RandomState(41)
+    q = jnp.asarray(rng.randn(2, 2, 384, 32).astype(np.float32) * 0.3)
+    k = jnp.asarray(rng.randn(2, 2, 256, 32).astype(np.float32) * 0.3)
+    v = jnp.asarray(rng.randn(2, 2, 256, 32).astype(np.float32) * 0.3)
+    km = jnp.asarray(rng.rand(2, 256) > 0.3)
+    kb = jnp.asarray(rng.randn(2, 1, 1, 256).astype(np.float32))
+    mask = km[:, None, None, :]
+    _grad_parity(
+        lambda q, k, v, b: jnp.sum(flash_attention(
+            q, k, v, key_mask=km, bias=b, block_q=128, block_k=256,
+            interpret=True) ** 2),
+        lambda q, k, v, b: jnp.sum(sdpa_reference(
+            q, k, v, mask=mask, bias=b) ** 2),
+        (q, k, v, kb), ["q", "k", "v", "key_bias"])
 
 
 @pytest.mark.parametrize("seq,with_bias", [(384, True), (421, True),

@@ -67,28 +67,72 @@ def _sum32(x):
 
 # ------------------------------------------------------------------ flash
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
-@pytest.mark.parametrize("case", ["bert_key_mask", "gpt2_causal"])
+@pytest.mark.parametrize("case", ["bert_key_mask", "gpt2_causal",
+                                  "bert_cell_b32", "t5_dense_bias",
+                                  "long_s4096"])
 def test_flash_attention_compiles_at_model_shapes(chip, case, grad):
     """bert-base b64 x s512 with a key-padding mask; gpt2-medium heads,
-    causal, s1024 — bf16, forward and backward."""
+    causal, s1024; the benchmark cell's own (32, 12, 512, 64) with its
+    key mask; T5-base width with a dense (1, H, S, S) bias (the block
+    shrinks for the bias and dbias tiles); s4096, where the rule falls
+    back to several key blocks — bf16, forward and backward, every one
+    with the blocks the module's rule picks (none passed by hand), and
+    with the geometry the rule is expected to give."""
+    from hetu_tpu import metrics
     from hetu_tpu.ops.pallas.flash_attention import flash_attention
-    if case == "bert_key_mask":
-        qkv = chip((64, 12, 512, 64), jnp.bfloat16)
-        extra = (chip((64, 512), jnp.bool_),)
+    extra = ()
+    if case in ("bert_key_mask", "bert_cell_b32"):
+        b = 64 if case == "bert_key_mask" else 32
+        qkv = chip((b, 12, 512, 64), jnp.bfloat16)
+        extra = (chip((b, 512), jnp.bool_),)
+        want = "512x512:one_pass"
 
         def f(q, k, v, km):
             return flash_attention(q, k, v, key_mask=km)
-    else:
+    elif case == "gpt2_causal":
         qkv = chip((8, 16, 1024, 64), jnp.bfloat16)
-        extra = ()
+        want = "256x1024:one_pass"
 
         def f(q, k, v):
             return flash_attention(q, k, v, causal=True)
+    elif case == "t5_dense_bias":
+        qkv = chip((8, 12, 512, 64), jnp.bfloat16)
+        extra = (chip((1, 12, 512, 512), jnp.float32),)
+        want = "256x512:one_pass"
+
+        def f(q, k, v, bias):
+            return flash_attention(q, k, v, bias=bias)
+    else:
+        qkv = chip((2, 8, 4096, 64), jnp.bfloat16)
+        want = "512x512:two_pass"
+
+        def f(q, k, v):
+            return flash_attention(q, k, v)
     if grad:
-        fn = jax.grad(lambda *a: _sum32(f(*a)), argnums=(0, 1, 2))
+        diff = (0, 1, 2, 3) if case == "t5_dense_bias" else (0, 1, 2)
+        fn = jax.grad(lambda *a: _sum32(f(*a)), argnums=diff)
     else:
         fn = f
+    before = metrics.flash_call_counts().get(want, 0)
     _compiles_with_kernel(fn, qkv, qkv, qkv, *extra)
+    assert metrics.flash_call_counts().get(want, 0) == before + 1
+
+
+def test_flash_statistics_cross_hbm_unpadded(chip):
+    """``lse`` leaves the forward lane-oriented: the kernel's second
+    result is f32[bh, 1, s], not f32[bh, s, 1] padded to 128 lanes
+    (100 MB a layer at the bert cell's shape where 0.8 MB is data)."""
+    import importlib
+    fa = importlib.import_module("hetu_tpu.ops.pallas.flash_attention")
+    q = chip((384, 512, 64), jnp.bfloat16)
+    km = chip((32, 1, 512), jnp.int32)
+    text = _compiles_with_kernel(
+        lambda q, k, v, km: fa._flash_fwd(
+            q, k, v, None, km, None, None, None, 0.125, False, "one", "one",
+            "one", 12, 512, 512, False), q, q, q, km)
+    call = next(line for line in text.splitlines()
+                if "tpu_custom_call" in line)
+    assert "f32[384,1,512]" in call and "f32[384,512,1]" not in call
 
 
 def test_flash_decode_q1_compiles(chip):

@@ -7,6 +7,14 @@ fwd+bwd of both paths at BERT-base head geometry across sequence lengths
 and persists the winner table to ``artifacts/flash_ab.json``;
 ``hetu_tpu/ops/attention.py`` reads that artifact to set the gate
 empirically.  A chip tool: run it on the machine with the chip.
+
+The flash side is timed as the dispatcher runs it: with the blocks the
+kernel module's rule picks from the call's shapes (``_pick_blocks``).
+Beside it each row keeps a SWEEP for the reader of PERF.md, not for the
+program (nothing reads block shapes from the artifact): forward-only and
+forward + backward milliseconds at yesterday's 128 × 128, at square tiles
+and at whole-key-range blocks, so the rule's choice can be seen against
+its neighbours.  ``--seqs 512 --tags kmask`` narrows a run.
 """
 import functools
 import json
@@ -23,8 +31,9 @@ SEQS = (128, 256, 512, 1024)
 REPS, INNER = 3, 10
 
 
-def _timed_grad_step(fn, q, k, v):
-    """Best-of-REPS time for INNER fwd+bwd steps of ``fn``."""
+def _timed_grad_step(fn, q, k, v, grad=True):
+    """Best-of-REPS time for INNER fwd+bwd steps of ``fn`` (forward only
+    with ``grad=False``)."""
     import jax
     import jax.numpy as jnp
 
@@ -34,6 +43,8 @@ def _timed_grad_step(fn, q, k, v):
 
     @jax.jit
     def step(q, k, v):
+        if not grad:
+            return loss(q, k, v)
         l, grads = jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
         return l + sum(jnp.sum(g.astype(jnp.float32)) for g in grads)
 
@@ -49,20 +60,42 @@ def _timed_grad_step(fn, q, k, v):
     return best * 1e3           # ms
 
 
-def main():
+def _sweep_blocks(seq):
+    """Block shapes worth seeing beside the rule's: 128 × 128 (every call's
+    blocks until PR 28), square tiles, and whole-key-range blocks (under
+    ``causal`` those prune nothing but run the one-pass backward)."""
+    sizes = [b for b in (128, 256, 512, 1024) if b <= seq and seq % b == 0]
+    cands = {(b, b) for b in sizes} | {(b, seq) for b in sizes}
+    # a 1024 × 1024 f32 score tile is 4 MiB a temporary: past VMEM
+    return sorted(c for c in cands if c[0] * c[1] <= 512 * 1024)
+
+
+def main(argv=None):
+    import argparse
+    import importlib
+
     import jax
     import jax.numpy as jnp
 
     from hetu_tpu.ops.attention import sdpa_reference
-    from hetu_tpu.ops.pallas.flash_attention import flash_attention
+    # the package re-exports the function under the module's name
+    fa = importlib.import_module("hetu_tpu.ops.pallas.flash_attention")
+    flash_attention = fa.flash_attention
+
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--seqs", type=int, nargs="*", default=list(SEQS))
+    ap.add_argument("--tags", nargs="*",
+                    default=["dense", "causal", "kmask"])
+    args = ap.parse_args(argv)
+    narrowed = tuple(args.seqs) != SEQS or len(args.tags) != 3
 
     backend = jax.default_backend()
     if backend != "tpu":
         print(f"refusing flash A/B on the {backend} backend",
               file=sys.stderr)
         return 1
-    rows = _load_previous_rows(backend)
-    for seq in SEQS:
+    rows = {} if narrowed else _load_previous_rows(backend)
+    for seq in args.seqs:
         if str(seq) in rows:
             print(f"seq {seq}: already measured (resumed)", flush=True)
             continue
@@ -74,10 +107,6 @@ def main():
         k = jax.random.normal(kk, shape, jnp.bfloat16)
         v = jax.random.normal(kv, shape, jnp.bfloat16)
         row = {"batch": b}
-        # block-shape sweep: the best (block_q, block_k) is measured, not
-        # guessed — recorded per seq for the dispatcher
-        block_cands = [(bq, bk) for bq in (128, 256) for bk in (128, 256)
-                       if bq <= seq and bk <= seq]
         # padded-pretraining key mask (the FLAGSHIP bench path since round
         # 4): same length distribution as synthetic_mlm_batch
         import numpy as np
@@ -89,28 +118,48 @@ def main():
         cases = [("dense", {}), ("causal", {"causal": True}),
                  ("kmask", {"key_mask": km})]
         for tag, kw in cases:
-            best = (float("inf"), None)
-            for bq, bk in block_cands:
-                t = _timed_grad_step(
-                    functools.partial(flash_attention, block_q=bq,
-                                      block_k=bk,
-                                      **kw), q, k, v)
-                if t < best[0]:
-                    best = (t, (bq, bk))
-            fl, blocks = best
-            ref_kw = dict(causal=kw.get("causal", False))
+            if tag not in args.tags:
+                continue
+            causal = kw.get("causal", False)
+            # what the dispatcher runs: the rule's blocks
+            fl = _timed_grad_step(
+                functools.partial(flash_attention, **kw), q, k, v)
+            row[f"flash_ms_{tag}"] = round(fl, 3)
+            row[f"flash_fwd_ms_{tag}"] = round(_timed_grad_step(
+                functools.partial(flash_attention, **kw), q, k, v,
+                grad=False), 3)
+            row[f"rule_{tag}"] = "%dx%d" % fa._pick_blocks(
+                seq, seq, HEAD_DIM, q.dtype.itemsize, causal)
+            sweep = {}
+            for bq, bk in _sweep_blocks(seq):
+                fn = functools.partial(flash_attention, block_q=bq,
+                                       block_k=bk, **kw)
+                sweep[f"{bq}x{bk}"] = [
+                    round(_timed_grad_step(fn, q, k, v, grad=False), 3),
+                    round(_timed_grad_step(fn, q, k, v), 3)]
+            row[f"sweep_{tag}"] = sweep         # [fwd ms, fwd+bwd ms]
+            ref_kw = dict(causal=causal)
             if "key_mask" in kw:
                 ref_kw["mask"] = km[:, None, None, :]
-            xl = _timed_grad_step(
-                functools.partial(sdpa_reference, **ref_kw), q, k, v)
-            row[f"flash_ms_{tag}"] = round(fl, 3)
-            row[f"blocks_{tag}"] = list(blocks)
+            ref = functools.partial(sdpa_reference, **ref_kw)
+            xl = _timed_grad_step(ref, q, k, v)
             row[f"xla_ms_{tag}"] = round(xl, 3)
+            row[f"xla_fwd_ms_{tag}"] = round(
+                _timed_grad_step(ref, q, k, v, grad=False), 3)
             row[f"winner_{tag}"] = "flash" if fl < xl else "xla"
         rows[str(seq)] = row
-        print(f"seq {seq}: {row}", flush=True)
-        _persist(backend, rows, partial=True)  # completion marked below
+        print(f"seq {seq}: {json.dumps(row)}", flush=True)
+        if not narrowed:
+            _persist(backend, rows, partial=True)  # completion marked below
 
+    if narrowed:
+        # a narrowed run is a reading for PERF.md, not a gate: the gate's
+        # rule needs every length and both flagship cases
+        os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+        with open(os.path.join(ROOT, "chiprun_out", "flash_ab_rows.json"),
+                  "w") as f:
+            json.dump(rows, f, indent=1, sort_keys=True)
+        return 0
     out = _persist(backend, rows, partial=False)
     print(json.dumps({"flash_min_len": out["flash_min_len"]}))
     return 0
