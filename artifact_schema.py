@@ -1,123 +1,16 @@
-"""Provenance stamping shared by bench.py and the tools/ artifact writers.
+"""Provenance stamping shared by the ``tools/`` artifact writers.
 
 Side-effect-free on import (no jax, no env-gated config mutation) — tools
 that must control backend initialisation order (tools/calibrate_tpu.py)
 can import this before touching jax.
 
-Schema (see the note at the top of bench.py): every committed artifact
-carries ``git_sha`` (HEAD when the number was MEASURED), ``workload`` (the
-knobs that define the metric — canonical; no loose duplicates elsewhere in
-the artifact) and ``workload_hash`` (sha256[:12] of the canonical workload
-JSON).  Artifacts whose own schema already exposes the knobs top-level for
-programmatic consumers (flash_ab's resume check) embed only the hash.
-
-``artifacts/host_overhead.json`` (``bench.py --config overhead`` /
-``tools/host_overhead_bench.py``) records the executor dispatch-path
-evidence: ``raw_jit_us`` (bare trivial-jit dispatch — the floor),
-``step_jit_us`` (the executor's OWN jitted step dispatched bare: the
-program's compute/thunk floor a zero-overhead executor would still
-pay), ``device_feed_us``/``numpy_feed_us``/``pipelined_feed_us``
-(``ex.run`` / ``ex.run_steps(sync=False)`` wall per step),
-``dispatch_overhead_us`` (the executor's per-step host Python, measured
-directly as loop wall minus in-jit time under synchronous dispatch),
-``plan_cache`` (run-plan hit/miss counters over the steady-schema loop)
-and ``async_bitwise_equal`` (sync=False vs sync loss/state parity).
-``overhead_multiple_vs_raw_jit`` = (overhead_pair_raw_us +
-dispatch_overhead_us) / overhead_pair_raw_us, each quantity the MINIMUM
-over many short interleaved rounds (shared-host contention only ever
-inflates a round, so min is the least-noise estimate of each; the raw
-per-round pairs ride in ``overhead_pairs``; a minimum-RATIO pick would
-be floor-seeking) — the ISSUE 9 ≤ 2.0 gate; pre-ISSUE-9 artifacts
-computed
-``device_feed_us / raw_jit_us`` (kept as ``wall_multiple_vs_raw_jit``),
-which folded ``step_jit_us`` into "overhead".
-
-ISSUE 10 added the telemetry fields: ``host_overhead.json`` records the
-span-tracing tax (``traced_dispatch_overhead_us``, ``trace_overhead_us``
-= traced minus untraced per-step host Python over interleaved toggled
-rounds, ``trace_overhead_pct`` against the untraced dispatch path,
-gated <= ``trace_gate_pct`` 25%); step-timed configs carry
-``step_time_hist_ms`` ({sub: count/mean/p50/p99}) from the obs
-registry's log-bucketed ``step_time_us`` histogram — percentiles, not
-just means; ``--config serve`` adds ``latency_hist_ms`` /
-``chaos_latency_hist_ms`` ({queue_wait, batch} per run) from
-``serve_latency_us``; ``--config trace`` commits
-``artifacts/trace_step.json``, a Chrome/Perfetto trace (the
-``traceEvents`` schema, NOT the provenance schema) of a 5-step wdl-PS
-run with a mid-run primary kill — step spans, per-opcode RPC spans,
-fault point events, serving + feed-pipeline tracks.
-
-``artifacts/decode_bench.json`` (``bench.py --config decode``, ISSUE 16
-schema v2 per ISSUE 18) compares continuous (chunked-prefill),
-token-by-token and request-level decoding of one seeded zipf stream in
-interleaved best-of rounds: per-leg ``tokens_per_s``/``p50_ms``/
-``p99_ms`` + decode counters, ``streams_bitwise_equal`` across all
-three, ``compile_once`` (``bucket_keys`` now counts ``(batch, len)``
-pairs PLUS chunked ``(batch, chunk, len)`` triples against
-``bucket_key_bound``), ``prefill`` (chunked steps, steps saved vs
-token-by-token, skipped logits fetches), ``ttft_vs_token_by_token``
-(per-prompt-length chunked vs token-by-token time-to-first-token,
-measured directly on engines, min over reps; ``ttft_wins_every_length``
-gates it), ``ttft_histogram`` (the ``ttft`` label of
-``decode_latency_us`` — one observation per stream,
-``ttft_counted_per_stream``), ``prefix_cache`` (pool-stream hit/miss/
-eviction counts, ``hit_rate``, ``prefill_rows_cold`` vs ``_warm``, and
-the warm run's bitwise parity with its cold reference), the ISSUE 16
-``kv_cache_vs_reprefill`` per-length leg, and the ISSUE 19
-``recovery`` leg (schema v3): a 2-replica decode FrontDoor under a
-``kill:replica@0:tok<n>`` chaos fault on the engine's token clock —
-``kill_spec``, ``failed_streams`` (must be 0), ``restarts`` (must be
-0), ``streams_bitwise_equal_to_unkilled``, the ``decode_recovery_*``
-``counters`` + fleet counters, ``reseat_latency_us`` (the ``recovery``
-label of ``decode_latency_us`` — one observation per reseated
-stream), and ``zero_survivor`` (killing a 1-replica door's only
-replica: every in-flight stream fails loudly with
-``recovery_exhausted`` and ``partials_attached``).
-
-``artifacts/fleet_bench.json`` (``bench.py --config fleet``, ISSUE 17)
-is the fleet-tier acceptance: ``slo`` (interactive p99 vs target, both
-runs), ``scaling`` (the autoscaler's resize timeline on the admission
-clock — ``{admitted, kind, from_replicas, to_replicas, p99_ms,
-load_factor}`` — plus ``replicas_hw``), ``rejections`` /
-``per_class_rejections`` (structured ``serve_rejection_reason`` counts;
-the family counts at ServeRejected CONSTRUCTION, so internal dispatch
-retries against a freshly killed replica can appear as ``draining``
-entries that were absorbed, never user-visible — the per-class dict is
-the door-visible truth), ``bounded_queues`` (max per-replica pending vs
-``queue_limit``; a chaos-run survivor may briefly hold up to 2x while
-ADOPTING a dead replica's rescued queue), ``spin_up`` (scale-out's
-``step_cache_serve_hit`` vs ``serve_bucket_compiles`` deltas) and
-``chaos`` (the ``kill:replica`` run: restarts=0, failed futures,
-bitwise response parity on requests admitted in both runs).
-
-Chaos/robustness artifacts (``chaos``, ``failover``, ``serve``,
-``partition``, ``fleet``) additionally follow a shared convention in
-``extra``:
-``restarts``/``resumes`` (must be 0 for the transparent-recovery
-configs), ``fault_counters`` (the chaos run's evidence),
-``clean_run_counters`` (must be ``{}``), and loss/response parity flags
-against the clean run.
-
-``artifacts/protocol_verify.json`` (``tools/verify_protocols.py
---deep --out ...``, ISSUE 20) is the protocol model checker's verdict:
-per-model ``models.<name>`` blocks (``states``/``transitions``/
-``depth`` of the exhaustive BFS, ``complete`` — False means the budget
-truncated exploration and the verdict is NOT exhaustive — and
-``violations`` with rendered shortest counterexample traces, empty at
-HEAD), ``mutations.<name>`` (each seeded historical bug class with the
-``expected`` vs ``violated`` invariant name and counterexample length —
-all must be CAUGHT), and ``conformance_selftest`` (the trace monitors
-accept a canned well-formed run and flag each canned bad trace by
-rule).  The chaos artifacts above additionally carry
-``protocol_conformance`` blocks in ``extra``: the recorded kill-run
-event trace replayed against the same models' transition relations
-(``ok`` gates the leg; a non-empty ``divergences`` list names the
-violated rule per event).  ``--config partition``
-(``artifacts/partition_smoke.json``) adds the fencing-epoch evidence:
-``fsck_serving_ranks``/``fsck_epochs`` (exactly one serving epoch per
-shard post-heal), ``noheal_lineage_violations`` (the unhealed split
-brain fsck detects), and ``two_cell`` (per-cell admitted/answered/
-rejections through the cross-cell cut plus post-heal fsck convergence).
+Every artifact a tool writes carries ``git_sha`` (HEAD when it was made),
+``workload`` (the knobs that define it — canonical; no loose duplicates
+elsewhere in the artifact) and ``workload_hash`` (sha256[:12] of the
+canonical workload JSON).  Artifacts whose own schema already exposes the
+knobs top-level for programmatic consumers (flash_ab's resume check) embed
+only the hash.  ``artifacts/README.md`` lists the committed artifacts,
+each with its writer and its reader.
 """
 import hashlib
 import json

@@ -25,10 +25,9 @@ def resnet(x, y_, num_layers=18, num_class=10, data_format="NCHW"):
     ``data_format``: the feed stays NCHW (reference/torch convention);
     "NHWC" transposes ONCE at the stem and keeps activations channels-last
     through the network — the layout the TPU wants (C on the 128-lane
-    axis).  MEASURED per backend (artifacts/resnet_cpu_root_cause.json):
-    on XLA-CPU channels-last is 1.5x SLOWER in composition (its NCHW
-    pipeline already relayouts internally where profitable), so NCHW
-    stays the default; bench.py picks the layout per backend.
+    axis).  On XLA-CPU channels-last composed slower (its NCHW pipeline
+    already relayouts internally where profitable), so NCHW stays the
+    default; ``tools/audit_graphs.py`` builds NHWC, the program for the TPU.
     """
     df = data_format
     if df == "NHWC":

@@ -21,13 +21,11 @@ def _embed(ids_node, vocab, dim, mode, lr, name, batch_ids=None):
     Modes: ``dense`` (in-graph variable), ``ps`` (direct host store, no
     cache), ``lru``/``lfu``/``lfuopt`` (native C++ HET cache),
     ``vlru``/``vlfu`` (the vectorized numpy HET cache —
-    :class:`hetu_tpu.ps.DistCacheTable` — the batched sparse-RPC path
-    ``bench.py --config wdl --emb-policy`` exercises), and
+    :class:`hetu_tpu.ps.DistCacheTable` — the batched sparse-RPC path), and
     ``vlru_dev``/``vlfu_dev`` (the same cache with the DEVICE-RESIDENT
     slab: hit rows gathered on-device by slot index, only miss rows
     crossing the host boundary, grads segment-summed by the Pallas
-    scatter-add kernel — ``bench.py --config wdl --emb-device
-    device``)."""
+    scatter-add kernel)."""
     if mode == "dense":
         table = ht.Variable(
             name, initializer=ht.init.GenNormal(0.0, 0.01), shape=(vocab, dim),

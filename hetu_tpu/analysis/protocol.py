@@ -39,9 +39,9 @@ the real transition sites (``ps/dist_store.py``, ``serving/decode.py``,
 ``serving/fleet.py``, ``parallel/elastic.py`` — flag-guarded, ISSUE 10
 tracer discipline: one attribute load when off), and
 :func:`check_conformance` replays a recorded run against the models'
-transition relations.  ``bench.py`` gates the failover / partition /
-decode-recovery chaos legs on it, so every committed fault-injection
-artifact is also a machine-checked trace of the verified model.
+transition relations.  The failover / partition / decode-recovery fault
+scenarios (``tests/scenarios.py``) gate on it, so every such run is also
+a machine-checked trace of the verified model.
 
 Stdlib-only BY DESIGN (the `analysis.concurrency` convention):
 ``tools/hetu_lint.py`` and ``tools/verify_protocols.py`` load this

@@ -20,7 +20,8 @@ from .cost_model import (HardwareSpec, LayerSpec, MemoryCostModel, Strategy,
 from .search import DPAlg, candidate_strategies, search, search_graph
 from .plan import ParallelPlan
 from .measure import (PlanMeasurement, measure_plan, measure_plans,
-                      plan_diff, format_plan_diff)
+                      plan_diff, format_plan_diff, graph_flops,
+                      device_peak_flops)
 
 
 def calibrate_hardware(mesh=None, mem_bytes=None,

@@ -561,7 +561,7 @@ def graph_layer_spec(fetches, feeds=None, name="graph", dtype_bytes=4,
                      count=1):
     """One fused :class:`LayerSpec` for a REAL fetch subgraph — the
     single-bucket view of :func:`graph_layer_specs` (same walk, same
-    numbers; ``obs.graph_flops`` and the remat planner read this)."""
+    numbers; ``measure.graph_flops`` and the remat planner read this)."""
     specs = graph_layer_specs(fetches, feeds=feeds,
                               split=lambda _n: None, name=name,
                               dtype_bytes=dtype_bytes)

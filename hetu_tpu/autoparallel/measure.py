@@ -8,9 +8,10 @@ plans for a few steps each and feeds the measurements back:
   candidate, reused thereafter — re-measuring a plan hits the cache,
   counted as ``autoparallel_candidate_cache_hits``), per-step wall times
   forced honest by a scalar host read (the only reliable sync — the
-  calibration probes' discipline), published into the PR 10 registry as
-  per-plan ``step_time_us`` histogram observations and per-plan MFU
-  gauges;
+  calibration probes' discipline), published into the registry as
+  per-plan ``step_time_us`` histogram observations; on a TPU each
+  measurement also carries its MFU (:func:`graph_flops` over the step
+  time and :func:`device_peak_flops`);
 * :func:`plan_diff` — per-layer predicted-vs-measured cost table for one
   measured plan (the cost model's end-to-end error, attributed per layer);
 * :meth:`ParallelPlan.rerank <hetu_tpu.autoparallel.ParallelPlan.rerank>`
@@ -46,7 +47,7 @@ class PlanMeasurement:
     #: the search's predicted step time, microseconds (None when the plan
     #: was constructed by hand without an estimate)
     predicted_us: float = None
-    #: model-FLOPs utilization gauge published for this plan (None when
+    #: model-FLOPs utilization of this plan (None off the TPU, or when
     #: graph FLOPs could not be inferred)
     mfu: float = None
     #: True when this candidate's executable was built fresh (a step-cache
@@ -58,14 +59,47 @@ class PlanMeasurement:
         return self.step_time_us / 1e6
 
 
-def _peak_flops():
-    """Per-device peak FLOP/s for the MFU gauge — the shared
-    ``obs.device_peak_flops`` table ``bench.py`` resolves through (one
-    table, so a new device kind lands once).  Non-TPU backends get its
-    nominal placeholder: MFU becomes a relative gauge there, still
-    monotone in step time for one workload."""
-    from ..obs import device_peak_flops
-    return device_peak_flops()[0]
+def graph_flops(fetches, feeds=None, train=True):
+    """Per-step FLOPs of a fetch subgraph from the inferred-shape cost
+    model (:func:`~.cost_model.graph_layer_spec`: every matmul-family
+    and attention contraction priced off the abstract-interpreter
+    shapes — no hand-derived approximation).  ``train=True`` applies
+    the standard 3x forward multiplier (forward + ~2x backward matmul
+    work); pass ``train=False`` for inference-only graphs."""
+    from .cost_model import graph_layer_spec
+    spec = graph_layer_spec(fetches, feeds=feeds)
+    return (3.0 if train else 1.0) * float(spec.fwd_flops)
+
+
+#: bf16 peak FLOP/s per chip by device_kind prefix, most-specific prefix
+#: first.  Source: Google Cloud TPU documentation, the "System
+#: architecture" page of each generation (v5e: 197 TFLOP/s bf16, 16 GB HBM
+#: at 819 GB/s; v5p: 459; v4: 275; v3: 123; v2: 46; v6e/Trillium: 918).
+#: This table prices a searched plan's MFU; the benchmark's own peaks are
+#: ``benchmarks/peaks.json``.
+TPU_PEAK_BY_KIND = (
+    ("TPU v6 lite", 918e12), ("TPU v6", 918e12),     # Trillium
+    ("TPU v5 lite", 197e12), ("TPU v5p", 459e12), ("TPU v5", 459e12),
+    ("TPU v4", 275e12), ("TPU v3", 123e12), ("TPU v2", 46e12),
+)
+
+
+def device_peak_flops():
+    """(peak_flops_per_chip, device_kind).  A TPU ``device_kind`` that is
+    not in :data:`TPU_PEAK_BY_KIND` is an error — a utilisation against a
+    guessed peak is not a measurement; add the kind and its source to the
+    table.  Off the TPU there is no peak (``None``): a plan measured there
+    has a step time and no MFU."""
+    import jax
+    kind = jax.devices()[0].device_kind
+    if jax.default_backend() != "tpu":
+        return None, kind
+    for prefix, peak in TPU_PEAK_BY_KIND:
+        if str(kind).startswith(prefix):
+            return peak, kind
+    raise ValueError(
+        f"no peak FLOP/s known for TPU device_kind {kind!r}: add it to "
+        f"hetu_tpu.autoparallel.measure.TPU_PEAK_BY_KIND with its source")
 
 
 class _CandidateRun:
@@ -110,30 +144,32 @@ class _CandidateRun:
             record_step_time(dt * 1e6, label=self.tag)
         return dt
 
-    def finalize(self, peak_flops=None):
-        from ..metrics import record_autoparallel
+    def finalize(self):
+        from ..metrics import record_autoparallel, record_run_gauges
         record_autoparallel("autoparallel_plans_measured")
         # min over THIS run's walls — the registry histogram under the
         # same tag is process-wide (it may hold an earlier measurement's
         # steps), so the per-candidate verdict never reads back through it
         step_us = min(self.walls)
+        record_run_gauges(self.tag, step_us / 1e3)
         mfu = None
-        try:
-            from ..obs import graph_flops, record_mfu
-            # the FORWARD fetch only (the loss, out[0] by the build
-            # contract): the optimizer fetch carries the backward
-            # matmuls, which graph_flops' train=True 3x multiplier
-            # already prices — including it would double-count
-            flops = graph_flops([self.ex.eval_node_dict[self.name][0]],
-                                feeds=self.fd)
-            # the step spans every device in the executor's mesh; peak
-            # is per-device (bench.py's mfu divides by peak * n_dev too)
-            mesh = getattr(self.ex, "mesh", None)
-            n_dev = mesh.size if mesh is not None else 1
-            mfu = record_mfu(self.tag, flops, step_us / 1e6,
-                             (peak_flops or _peak_flops()) * n_dev)
-        except Exception:
-            pass  # MFU is best-effort evidence; the step time is the verdict
+        peak = device_peak_flops()[0]
+        if peak:
+            try:
+                # the FORWARD fetch only (the loss, out[0] by the build
+                # contract): the optimizer fetch carries the backward
+                # matmuls, which graph_flops' train=True 3x multiplier
+                # already prices — including it would double-count
+                flops = graph_flops([self.ex.eval_node_dict[self.name][0]],
+                                    feeds=self.fd)
+            except Exception:
+                flops = None  # MFU is best-effort; the step time is the verdict
+            if flops:
+                # the step spans every device in the executor's mesh; the
+                # peak is per device
+                mesh = getattr(self.ex, "mesh", None)
+                n_dev = mesh.size if mesh is not None else 1
+                mfu = flops / (step_us / 1e6) / (peak * n_dev)
         est = getattr(self.plan, "est_time", None)
         self.plan.measured_time = step_us / 1e6
         return PlanMeasurement(
@@ -143,8 +179,7 @@ class _CandidateRun:
             compiled=self.compiled)
 
 
-def measure_plan(plan, build, steps=4, warmup=1, label="autoparallel",
-                 peak_flops=None):
+def measure_plan(plan, build, steps=4, warmup=1, label="autoparallel"):
     """Run one candidate for ``steps`` measured steps; returns a
     :class:`PlanMeasurement`.
 
@@ -160,11 +195,11 @@ def measure_plan(plan, build, steps=4, warmup=1, label="autoparallel",
     run.walls.clear()
     for _ in range(max(1, steps)):
         run.step(record=True)
-    return run.finalize(peak_flops)
+    return run.finalize()
 
 
 def measure_plans(candidates, build, steps=4, warmup=1,
-                  label="autoparallel", peak_flops=None):
+                  label="autoparallel"):
     """Measure every candidate (``plan.candidates`` order); returns the
     :class:`PlanMeasurement` list ``ParallelPlan.rerank`` consumes.
 
@@ -183,7 +218,7 @@ def measure_plans(candidates, build, steps=4, warmup=1,
     for _ in range(max(1, steps)):
         for r in runs:
             r.step(record=True)
-    return [r.finalize(peak_flops) for r in runs]
+    return [r.finalize() for r in runs]
 
 
 def plan_diff(plan, measured=None, hw=None, microbatches=None):

@@ -121,7 +121,7 @@ _compile_cache_configured = False
 
 def configure_compile_cache():
     """The ONE compile-cache rule (``Executor``, ``InferenceExecutor`` —
-    hence ``DecodeEngine`` — ``bench.py`` and ``chip_smoke.py`` call it):
+    hence ``DecodeEngine`` — and ``chip_smoke.py`` call it):
     with ``JAX_COMPILATION_CACHE_DIR`` set, jax already has its directory
     and none is set in code; otherwise the cache is
     :data:`COMPILE_CACHE_DIR`.  A CPU process keeps jax's default (no
@@ -169,7 +169,7 @@ def _chaos_active():
 
 def _sync_outs(outs):
     """Wait until every step output is computed — THE sync helper
-    (``HetuProfiler._sync``, ``bench.py``, the autoparallel probes and
+    (``HetuProfiler._sync``, the autoparallel probes and
     the async in-flight window all come here).  Training steps chain
     through the params, so waiting on the last outputs waits on every
     dispatched step.  Free on an already-complete array."""
@@ -804,8 +804,7 @@ class SubExecutor:
             self._check_lr_objs()
         # telemetry: the step span (HETU_TRACE=1) and the opt-in wall-
         # time histogram share one timed wrapper; both disabled costs
-        # two module/attribute reads — the dispatch-gap gate
-        # (tools/host_overhead_bench.py) holds that claim
+        # two module/attribute reads
         timed = _TRACE.on or _metrics.step_timing
         t0 = _time.perf_counter_ns() if timed else 0
         # captured BEFORE the step increments it: the span's step arg
@@ -3366,9 +3365,9 @@ class Executor:
 
     def memory_accounting(self, feed_dict=None, name=None):
         """Per-device byte accounting of the persistent training state —
-        the numbers the ZeRO memory claim is judged on (``bench.py``
-        artifact schema; works on CPU where ``memory_stats`` reports
-        nothing).
+        the numbers the ZeRO memory claim is judged on
+        (``tests/test_zero.py``; works on CPU where ``memory_stats``
+        reports nothing).
 
         * ``param_bytes_per_device`` — full per-param master arrays
           (replicated: each device pays all of it).  Stage-3 ZeRO params

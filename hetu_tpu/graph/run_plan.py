@@ -1,9 +1,8 @@
 """Cached run plans: the per-step Python of ``SubExecutor._run_impl``
 resolved ONCE per (subgraph, feed schema).
 
-The round-5 host-overhead artifact (``artifacts/host_overhead.json``)
-measured the executor's dispatch path at 5.2x a raw ``jax.jit`` call —
-at real TPU step rates the per-step Python (feed-key resolution,
+A round-5 CPU measurement put the executor's dispatch path at 5.2x a raw
+``jax.jit`` call — at real TPU step rates the per-step Python (feed-key resolution,
 ``_place_feed`` placement/cast introspection, ``_check_feeds``
 validation, the ``host_lr`` calls and the little dicts rebuilt every
 step) IS the step time floor, no matter what XLA does.  Everything in

@@ -60,8 +60,8 @@ def counters_suppressed():
 # TRACE, not per step — dispatch happens when jax traces the program, so
 # a counter that keeps climbing across steps means the jit cache is
 # thrashing, and a single nonzero entry means that workload compiled onto
-# the slow path.  Surfaced by ``HetuProfiler.flash_fallbacks()`` and the
-# bench.py attention microbench; ``HETU_REQUIRE_FLASH=1`` turns any
+# the slow path.  Surfaced by ``HetuProfiler.flash_fallbacks()``
+# and ``chip_smoke.py``; ``HETU_REQUIRE_FLASH=1`` turns any
 # recording into a hard failure (ops/attention.py).
 
 _flash = REGISTRY.counter_family(
@@ -117,8 +117,8 @@ def flash_call_counts():
 # means that workload compiled onto the fallback (``jnp.take`` /
 # ``jax.ops.segment_sum``) path, and a count climbing across steps means
 # the jit cache is thrashing.  Surfaced by
-# ``HetuProfiler.emb_pallas_fallbacks()`` and ``bench.py --config wdl
-# --emb-device device``; ``HETU_REQUIRE_PALLAS_EMB=1`` turns any
+# ``HetuProfiler.emb_pallas_fallbacks()``;
+# ``HETU_REQUIRE_PALLAS_EMB=1`` turns any
 # recording into a hard failure (emb_cache._note_fallback).
 
 _emb_pallas = REGISTRY.counter_family(
@@ -170,8 +170,8 @@ def reset_emb_pallas_fallbacks():
 # EXCEPT the ``auto_save`` bookkeeping records a detected fault or a
 # recovery action, so a clean run — replicated or not — reports none of
 # those, and a clean run without auto-checkpointing records nothing at
-# all.  Surfaced by ``HetuProfiler.fault_counters()`` and ``bench.py
-# --config chaos`` / ``--config failover``.
+# all.  Surfaced by ``HetuProfiler.fault_counters()``; the fault scenarios
+# (``tests/scenarios.py``) assert on them.
 
 _faults = REGISTRY.counter_family(
     "faults",
@@ -213,8 +213,7 @@ def reset_faults():
 # timeline).  Whether a resize recompiled or reused an executable is
 # the step-cache family's story (``step_cache_hit`` on a grow-back).
 # Invariant (asserted by the elastic tests): a fixed-world run records
-# nothing here.  Surfaced by ``HetuProfiler.elastic_counters()`` and
-# ``bench.py --config elastic``.
+# nothing here.  Surfaced by ``HetuProfiler.elastic_counters()``.
 
 _elastic = REGISTRY.counter_family(
     "elastic",
@@ -253,7 +252,7 @@ def reset_elastic_counts():
 # ``HETU_REQUIRE_OFFLOAD=1`` hard-fails instead).  Counts are per plan
 # BUILD, not per step (flash-counter semantics: a count climbing across
 # steps means executors are being rebuilt).  Surfaced by
-# ``HetuProfiler.remat_counters()`` and ``bench.py --config remat``; a
+# ``HetuProfiler.remat_counters()``; a
 # run without ``Executor(remat=...)`` records nothing.
 
 _remat = REGISTRY.counter_family(
@@ -378,8 +377,7 @@ def reset_autoparallel_counts():
 # the CSR fast path; device-resident tables skip the host pass
 # entirely).  Invariant (asserted by the tests):
 # only sparse-PS traffic records here, so a clean dense run reports an
-# empty dict.  Surfaced by ``HetuProfiler.cache_counters()`` and
-# ``bench.py --config emb``.
+# empty dict.  Surfaced by ``HetuProfiler.cache_counters()``.
 
 _cache = REGISTRY.counter_family(
     "cache",
@@ -411,8 +409,8 @@ def reset_cache_counts():
 # shard evenly (``zero_pad_bytes``).  Counts are per TRACE, not per step
 # (the slabs are built when jax traces the program — flash-counter
 # semantics): a count that keeps climbing across steps means the jit cache
-# is thrashing.  Surfaced by ``HetuProfiler.zero_counters()`` and
-# ``bench.py --config zero``; a run without ``zero=`` records nothing.
+# is thrashing.  Surfaced by ``HetuProfiler.zero_counters()``;
+# a run without ``zero=`` records nothing.
 
 _zero = REGISTRY.counter_family(
     "zero",
@@ -483,8 +481,7 @@ def reset_step_cache_counts():
 # counts the places where non-blocking stepping (``run(..., sync=False)``)
 # was FORCED to materialize — a numpy conversion, a PS push boundary, a
 # checkpoint save, or the bounded in-flight window filling up.  Surfaced
-# by ``HetuProfiler.run_plan_counters()`` and ``bench.py --config
-# overhead``.
+# by ``HetuProfiler.run_plan_counters()``.
 
 _run_plan = REGISTRY.counter_family(
     "run_plan",
@@ -536,7 +533,7 @@ def reset_run_plan_counts():
 # refreshes (``serve_emb_refresh_rows``), and the queue-depth high-water
 # mark (``serve_queue_depth_hw`` — gauge semantics: the recorded value is
 # the MAX ever seen, not a sum).  Surfaced by
-# ``HetuProfiler.serve_counters()`` and ``bench.py --config serve``; a
+# ``HetuProfiler.serve_counters()``; a
 # process that never serves reports an empty dict.
 
 _serve = REGISTRY.counter_family(
@@ -612,9 +609,8 @@ def reset_serve_counts():
 #                                row-tokens computed, padding included
 #   ``decode_chunk_width``       chunk bucket summed over
 #                                ``decode_prefill_steps``
-# Surfaced by ``HetuProfiler.decode_counters()`` and
-# ``bench.py --config decode``; a process that never decodes reports an
-# empty dict.
+# Surfaced by ``HetuProfiler.decode_counters()``; a process that never
+# decodes reports an empty dict.
 
 _decode = REGISTRY.counter_family(
     "decode",
@@ -777,9 +773,8 @@ def reset_serve_rejection_counts():
 # (``fleet_autoscaler_polls``) and resizes refused at the min/max bound
 # (``fleet_scale_refused``), and the live-replica high-water mark
 # (``fleet_replicas_hw`` — gauge semantics: the recorded value is the
-# MAX ever seen).  Surfaced by ``HetuProfiler.fleet_counters()`` and
-# ``bench.py --config fleet``; a process with no fleet reports an empty
-# dict.
+# MAX ever seen).  Surfaced by ``HetuProfiler.fleet_counters()``; a
+# process with no fleet reports an empty dict.
 
 _fleet = REGISTRY.counter_family(
     "fleet",
@@ -985,30 +980,22 @@ def reset_step_times():
 
 
 # ------------------------------------------------------------- run gauges
-# Per-run step-time/MFU gauges: ``obs.record_mfu`` computes MFU from the
-# PR 5 inferred-shape FLOP model (``obs.graph_flops``) over measured
-# step time and publishes both here, labeled by run/config name — the
-# measured half of the BENCH trajectory (ROADMAP item 2).
+# One measured step time per run, labeled by run/plan name:
+# ``autoparallel.measure`` publishes each candidate plan's verdict here.
 
-_mfu_gauge = REGISTRY.gauge(
-    "mfu",
-    "model FLOP/s utilization per run: inferred-shape FLOPs / step "
-    "time / hardware peak")
 _step_gauge = REGISTRY.gauge(
     "step_time_ms",
     "measured step wall time per run, milliseconds")
 
 
-def record_run_gauges(label, step_time_ms, mfu):
-    """Publish one run's measured step time + MFU gauges."""
+def record_run_gauges(label, step_time_ms):
+    """Publish one run's measured step time."""
     _step_gauge.set(step_time_ms, label=label)
-    _mfu_gauge.set(mfu, label=label)
 
 
 def run_gauges():
-    """{"mfu": {label: v}, "step_time_ms": {label: v}}."""
-    return {"mfu": _mfu_gauge.values(),
-            "step_time_ms": _step_gauge.values()}
+    """{"step_time_ms": {label: v}}."""
+    return {"step_time_ms": _step_gauge.values()}
 
 
 # ------------------------------------------------------------ one-registry view

@@ -1,5 +1,5 @@
 """hetu_tpu.obs — unified telemetry: step-span tracing, a metrics
-registry with latency histograms and MFU gauges, Chrome/Perfetto export
+registry with latency histograms and gauges, Chrome/Perfetto export
 (ISSUE 10).
 
 The framework's behaviours worth reproducing — overlapped
@@ -44,9 +44,8 @@ makes them visible from one place:
   JSON-able dict and ``tools/metricsd.py`` exposes the same registry as
   Prometheus text (file export or a tiny HTTP endpoint).  The
   histograms are log-bucketed (8 buckets/octave) with p50/p90/p99
-  accessors; the ``mfu``/``step_time_ms`` gauges are computed per run
-  from the PR 5 inferred-shape FLOP model over measured step time
-  (:func:`graph_flops` / :func:`record_mfu`).
+  accessors; the ``step_time_ms`` gauge holds each measured plan's
+  step time (``autoparallel.measure``).
 
 Diagnostic-style conventions follow PR 5/PR 8: every exported name
 says WHERE the number comes from and what a surprising value means.
@@ -119,69 +118,7 @@ def reset_all_metrics():
     registry.reset_all()
 
 
-# -- MFU / step-time gauges --------------------------------------------------
-
-def graph_flops(fetches, feeds=None, train=True):
-    """Per-step FLOPs of a fetch subgraph from the PR 5 inferred-shape
-    cost model (``autoparallel.graph_layer_spec``: every matmul-family
-    and attention contraction priced off the abstract-interpreter
-    shapes — no hand-derived approximation).  ``train=True`` applies
-    the standard 3x forward multiplier (forward + ~2x backward matmul
-    work); pass ``train=False`` for inference-only graphs."""
-    from ..autoparallel.cost_model import graph_layer_spec
-    spec = graph_layer_spec(fetches, feeds=feeds)
-    return (3.0 if train else 1.0) * float(spec.fwd_flops)
-
-
-#: bf16 peak FLOP/s per chip by device_kind prefix, most-specific prefix
-#: first.  Source: Google Cloud TPU documentation, the "System
-#: architecture" page of each generation (v5e: 197 TFLOP/s bf16, 16 GB HBM
-#: at 819 GB/s; v5p: 459; v4: 275; v3: 123; v2: 46; v6e/Trillium: 918).  THE one table — ``bench.py``
-#: and ``autoparallel.measure`` both resolve through
-#: :func:`device_peak_flops`, so a new device kind lands here once.
-TPU_PEAK_BY_KIND = (
-    ("TPU v6 lite", 918e12), ("TPU v6", 918e12),     # Trillium
-    ("TPU v5 lite", 197e12), ("TPU v5p", 459e12), ("TPU v5", 459e12),
-    ("TPU v4", 275e12), ("TPU v3", 123e12), ("TPU v2", 46e12),
-)
-
-
-def device_peak_flops():
-    """(peak_flops_per_chip, device_kind).  A TPU ``device_kind`` that is
-    not in :data:`TPU_PEAK_BY_KIND` is an error — a utilisation against a
-    guessed peak is not a measurement; add the kind and its source to the
-    table.  Non-TPU backends get a nominal 50 TF placeholder: their MFU
-    is a relative CPU-side gauge, and nothing that reports a device
-    metric reaches this branch (the accelerator bench configs and
-    ``chip_smoke.py`` refuse a non-TPU backend first)."""
-    import jax
-    kind = jax.devices()[0].device_kind
-    if jax.default_backend() != "tpu":
-        return 50e12, kind
-    for prefix, peak in TPU_PEAK_BY_KIND:
-        if str(kind).startswith(prefix):
-            return peak, kind
-    raise ValueError(
-        f"no peak FLOP/s known for TPU device_kind {kind!r}: add it to "
-        f"hetu_tpu.obs.TPU_PEAK_BY_KIND with its source")
-
-
-def record_mfu(label, flops_per_step, step_time_s, peak_flops):
-    """Compute and publish the per-run ``mfu`` + ``step_time_ms``
-    gauges: ``flops_per_step`` (see :func:`graph_flops`) over measured
-    ``step_time_s``, against the hardware peak (``bench.py``'s
-    per-device-kind table).  Returns the MFU value; ``metrics_dump()``
-    exposes both gauges under ``label``."""
-    from .. import metrics
-    mfu = float(flops_per_step) / max(float(step_time_s), 1e-12) \
-        / max(float(peak_flops), 1e-12)
-    metrics.record_run_gauges(label, step_time_s * 1e3, mfu)
-    return mfu
-
-
 __all__ = ["TRACER", "span", "event", "enabled", "enable",
            "set_track_name", "clear_trace", "flow_begin", "flow_end",
            "trace_events", "export_chrome_trace", "registry",
-           "metrics_dump", "prometheus_text", "reset_all_metrics",
-           "graph_flops", "record_mfu", "device_peak_flops",
-           "TPU_PEAK_BY_KIND"]
+           "metrics_dump", "prometheus_text", "reset_all_metrics"]

@@ -22,8 +22,7 @@ there are the recording API; this module is the storage + readout.
   mean.  Optionally labeled (one sub-histogram per label, e.g. per
   opcode).
 * :class:`Gauge` — last-written values per label (the per-run
-  step-time/MFU gauges: ``flops_per_step`` from PR 5's inferred-shape
-  cost model over measured step time).
+  ``step_time_ms`` gauge).
 """
 from __future__ import annotations
 
@@ -176,7 +175,7 @@ class Histogram:
 
 
 class Gauge:
-    """Last-written values per label (``mfu``, ``step_time_ms``, ...)."""
+    """Last-written values per label (``step_time_ms``)."""
 
     kind = "gauge"
     __slots__ = ("name", "doc", "_v", "_lock")
@@ -246,7 +245,7 @@ class Registry:
         ``{"counters": {family: {kind: n}}, "histograms": {name: {label:
         {count/sum/min/max/mean/p50/p90/p99}}}, "gauges": {name: {label:
         value}}}`` — the single source of truth ``metrics_dump()``,
-        ``bench.py`` artifacts and ``tools/metricsd.py`` all read."""
+        ``HetuProfiler`` and ``tools/metricsd.py`` all read."""
         out = {"counters": {}, "histograms": {}, "gauges": {}}
         for name, inst in sorted(self.instruments().items()):
             out[inst.kind + "s"][name] = inst.snapshot()

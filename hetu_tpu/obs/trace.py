@@ -6,9 +6,7 @@ PER-THREAD ring buffers: the hot path touches only thread-local state
 ``perf_counter_ns`` reads plus one ring store (~0.3us) — cheap enough
 to leave compiled into the executor's dispatch path behind a single
 ``TRACER.on`` flag read (the ``HETU_TRACE=0`` default pays one
-attribute load per guarded site, nothing else; the host-overhead gate
-in ``tools/host_overhead_bench.py`` holds that claim to <= 2.0x a raw
-jit dispatch, and the traced path to <= 25% over the untraced one).
+attribute load per guarded site, nothing else).
 
 Record shapes (plain tuples — a ring slot assignment, never a dict):
 
@@ -178,7 +176,7 @@ class Tracer:
 
     def enable(self, on=True):
         """Turn tracing on/off at runtime (the env knob sets the initial
-        state; tests and ``bench.py --config trace`` flip it live)."""
+        state; tests flip it live)."""
         self.on = bool(on)
 
     def set_capacity(self, cap):

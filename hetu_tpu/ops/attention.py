@@ -7,7 +7,6 @@ flash-attention kernel (:mod:`hetu_tpu.ops.pallas.flash_attention`) on TPU,
 with a reference jnp lowering for CPU tests; ring/blockwise variants live in
 :mod:`hetu_tpu.parallel.ring_attention`.
 """
-import json
 import os
 
 import jax
@@ -17,37 +16,10 @@ import numpy as np
 from .base import def_op
 
 
-def _load_flash_gate(default=256):
-    """Empirical flash-vs-XLA dispatch threshold.
-
-    ``tools/flash_ab.py`` measures both paths on the real chip and writes
-    the winner table to ``artifacts/flash_ab.json`` (or wherever
-    ``HETU_FLASH_AB_PATH`` points); the gate comes from data when that
-    artifact exists (round-2 verdict: a guessed gate meant the kernel was
-    never in the measured hot path).  Block shapes are NOT read here: the
-    kernel module chooses them from each call's shapes
-    (``flash_attention._pick_blocks``)."""
-    path = os.environ.get("HETU_FLASH_AB_PATH") or os.path.join(
-        os.path.dirname(__file__), os.pardir, os.pardir,
-        "artifacts", "flash_ab.json")
-    gate = None
-    try:
-        with open(path) as f:
-            data = json.load(f)
-        # a PARTIAL artifact (sweep killed mid-way) covers only a prefix
-        # of the lengths — keep the default gate until the sweep completes
-        if data.get("backend") == "tpu" and not data.get("partial"):
-            gate = int(data["flash_min_len"])
-    except (OSError, ValueError, KeyError, TypeError):
-        pass
-    env = os.environ.get("HETU_FLASH_MIN_LEN")
-    if env:
-        gate = int(env)
-    return default if gate is None else gate
-
-
-#: below the gate, XLA's fusion is fine
-_FLASH_MIN_LEN = _load_flash_gate()
+#: sequences shorter than this take XLA's fused attention: below it the
+#: flash kernel's per-program overhead is not paid back (every run the
+#: ledger holds used 256; ``tools/flash_ab.py`` times both sides on the chip)
+_FLASH_MIN_LEN = 256
 
 
 @jax.custom_vjp
@@ -112,7 +84,7 @@ def sdpa_reference(q, k, v, causal=False, scale=None, mask=None, bias=None):
 def _note_flash_fallback(reason):
     """Record a dispatch that left the flash fast path — NEVER silent:
     the reason lands in the ``hetu_tpu.metrics`` counter registry
-    (surfaced by ``HetuProfiler.flash_fallbacks()`` and bench.py), and
+    (surfaced by ``HetuProfiler.flash_fallbacks()``), and
     ``HETU_REQUIRE_FLASH=1`` escalates it to a hard failure so a TPU run
     that silently compiled onto the einsum path cannot masquerade as a
     flash measurement."""

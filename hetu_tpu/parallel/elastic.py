@@ -80,9 +80,9 @@ class LogicalRank:
     elastic harness kills and rejoins.
 
     On real clusters a "rank" is a process (killed by the launcher /
-    preemption); the in-process simulation the tier-1 tests and
-    ``bench.py --config elastic`` run makes it an object with the same
-    two behaviours that matter to elasticity: it can **die**
+    preemption); the in-process simulation the tests run
+    (``tests/scenarios.py::elastic_scenario``) makes it an object with the
+    same two behaviours that matter to elasticity: it can **die**
     (``stop()`` — also the ``kill:proc@rank<r>:step<n>`` chaos target,
     via :func:`hetu_tpu.chaos.ChaosInjector.register_proc`) and it can
     **heartbeat** (``attach_heartbeat(store)`` pings the dist store's

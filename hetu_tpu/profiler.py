@@ -248,8 +248,8 @@ class HetuProfiler:
         detach->reseat stream ``recovery`` on the decode plane),
         ``step_time_us``
         per subexecutor (opt-in — ``metrics.enable_step_timing`` or
-        ``HETU_STEP_TIMING=1``), and the per-run ``mfu`` /
-        ``step_time_ms`` gauges."""
+        ``HETU_STEP_TIMING=1``), and the per-run
+        ``step_time_ms`` gauge."""
         from .metrics import (decode_latency_stats, rpc_stats,
                               run_gauges, serve_latency_stats,
                               step_time_stats)

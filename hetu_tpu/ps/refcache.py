@@ -13,8 +13,9 @@ overflow, flush alike).  Two jobs:
 1. **Parity oracle** — the tests replay identical traces through both
    implementations over identically-seeded stores and require bitwise
    equality (outputs, final server table, versions, stats).
-2. **Bench baseline** — ``bench.py --config emb`` measures the vectorized
-   cache's rows/s against this model on the same zipf trace; the pre-PR
+2. **Cost-shape baseline** — ``tests/test_emb_cache.py`` counts the
+   vectorized cache's RPC frames and pulled rows against this model's on
+   the same zipf trace; the pre-PR
    ``DistCacheTable`` had this cost shape (per-key dict ops + per-key
    RPCs), so the ratio is the honest speedup claim.
 """

@@ -55,10 +55,10 @@ is the serving half the training executor never had:
   ``ServeRejected('recovery_exhausted')`` carrying
   ``DecodeStream.partial()``.
 
-Proven end-to-end by ``bench.py --config serve`` (zipf request stream,
-p50/p99/QPS, chaos primary-kill mid-load with bitwise response parity)
-and ``bench.py --config partition`` (cross-cell partition + heal with
-zero local rejections and post-heal fsck convergence).
+Proven end-to-end by ``tests/scenarios.py``: ``serve_scenario`` (zipf
+request stream, chaos primary-kill mid-load with bitwise response parity)
+and ``partition_scenario`` (cross-cell partition + heal with zero local
+rejections and post-heal fsck convergence).
 """
 from .cells import CellHead, CellMap
 from .decode import DecodeEngine, DecodeRouter, DecodeStream

@@ -20,7 +20,7 @@ the write plane when the link heals, riding three existing layers:
 * **chaos** — :meth:`CellMap.partition_spec` emits the
   ``partition:rankA+...|rankB+...@step<n>[:heal<m>]`` chaos-DSL form for
   a cross-cell cut, so the whole scenario replays deterministically from
-  one seed (``bench.py --config partition``).
+  one seed (``tests/scenarios.py::partition_scenario``).
 
 The classes here are thin, deliberately: cells are *names over ranks*
 plus the serving plumbing each cell repeats — the stores, graphs and

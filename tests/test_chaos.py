@@ -19,6 +19,7 @@ import pytest
 
 import hetu_tpu as ht
 from hetu_tpu import chaos
+from scenarios import free_ports as _free_ports
 from hetu_tpu.graph.executor import Executor
 from hetu_tpu.metrics import fault_counts, reset_faults
 from hetu_tpu.parallel.preduce import DistPartialReduce
@@ -36,16 +37,6 @@ def _clean_chaos_and_counters():
     reset_faults()
 
 
-def _free_ports(n):
-    socks, ports = [], []
-    for _ in range(n):
-        s = socket.socket()
-        s.bind(("127.0.0.1", 0))
-        socks.append(s)
-        ports.append(s.getsockname()[1])
-    for s in socks:
-        s.close()
-    return ports
 
 
 # ------------------------------------------------------- schedule parsing
@@ -91,7 +82,7 @@ def test_chaos_role_kills_resolve_serving_and_holding_servers():
     """kill:primary targets whoever SERVES the shard at fire time;
     kill:backup targets the non-serving holder — after a failover the
     same spec form therefore tracks the promoted server (the double-kill
-    schedules in bench --config failover rely on exactly this)."""
+    schedule of ``scenarios.failover_scenario`` relies on exactly this)."""
     from hetu_tpu.ps.dist_store import DistributedStore
     ports = _free_ports(2)
     endpoints = [("127.0.0.1", p) for p in ports]

@@ -2,7 +2,6 @@
 chip: the phase functions run at tiny widths on the CPU, nothing measures
 on a backend that is not the TPU, importing the package initialises no
 backend, and the compile cache lives where the one rule says."""
-import json
 import os
 import subprocess
 import sys
@@ -51,24 +50,14 @@ def test_chip_smoke_refuses_a_cpu_backend():
     assert "no TPU" in proc.stderr
 
 
-@pytest.mark.parametrize("config", ["bert", "attn"])
-def test_bench_accelerator_config_refuses_a_cpu_backend(config):
-    """``bench.py --config <accelerator config>`` off the chip exits
-    non-zero and prints no metric — never a CPU number under its name."""
-    proc = _run(["bench.py", "--config", config])
-    assert proc.returncode != 0
-    assert "metric" not in proc.stdout
-    assert "refusing" in proc.stderr
-
-
 def test_imports_initialise_no_backend():
     """A parent that has touched jax holds the chip: importing the package,
-    the launcher, the serving plane, the models and the two entry scripts
+    the launcher, the serving plane, the models and the entry script
     must initialise no backend (one process per chip)."""
     code = (
         "import sys; sys.path.insert(0, %r)\n"
         "import hetu_tpu, hetu_tpu.launcher, hetu_tpu.serving\n"
-        "import hetu_tpu.models, bench, chip_smoke\n"
+        "import hetu_tpu.models, chip_smoke\n"
         "from jax._src import xla_bridge\n"
         "assert not xla_bridge._backends, xla_bridge._backends\n"
         "print('clean')\n" % ROOT)
@@ -117,16 +106,19 @@ def test_compile_cache_rule(monkeypatch):
 def test_unknown_tpu_kind_is_an_error(monkeypatch):
     """A utilisation against a guessed peak is not a measurement."""
     import jax
-    from hetu_tpu import obs
+    from hetu_tpu.autoparallel import device_peak_flops
 
     class _Dev:
         device_kind = "TPU v99 hyper"
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(jax, "devices", lambda *a: [_Dev()])
     with pytest.raises(ValueError, match="TPU v99 hyper"):
-        obs.device_peak_flops()
+        device_peak_flops()
     _Dev.device_kind = "TPU v5 lite"
-    assert obs.device_peak_flops() == (197e12, "TPU v5 lite")
+    assert device_peak_flops() == (197e12, "TPU v5 lite")
+    # and off the TPU there is no peak at all, not a placeholder
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert device_peak_flops() == (None, "TPU v5 lite")
 
 
 def test_native_store_is_keyed_by_source_hash(tmp_path, monkeypatch):

@@ -24,7 +24,7 @@ import numpy as np
 import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))    # repo root: bench.py import
+    os.path.abspath(__file__))))    # repo root: tools import
 
 import hetu_tpu as ht
 from hetu_tpu import chaos, obs
@@ -471,18 +471,17 @@ def test_controller_needs_exactly_one_liveness_source():
 # ------------------------------------------------------- slow scale proof
 
 @pytest.mark.slow
-def test_elastic_bench_smoke_artifact():
-    """The dp=4 end-to-end scale proof: ``bench.py --config elastic
-    --smoke`` in-process — chaos-driven kill + rejoin, loss parity vs
-    the dp-matched reference, restarts=0, both resizes in the exported
-    trace, artifact schema intact."""
-    import bench
-    res = bench.bench_elastic(smoke=True)
-    assert "error" not in res, res.get("error")
-    ex = res["extra"]
+def test_elastic_scenario():
+    """The dp=4 end-to-end scale proof: chaos-driven kill + rejoin, loss
+    parity vs the dp-matched reference, restarts=0, both resizes in the
+    trace, the grow-back a step-cache hit."""
+    import scenarios
+    ex = scenarios.elastic_scenario()
     assert ex["restarts"] == 0 and ex["resumes"] == 0
     assert ex["loss_bitwise_equal_vs_reference"] is True
-    kinds = [e["kind"] for e in ex["resize_timeline"]]
-    assert kinds == ["shrink", "grow"]
+    assert ex["world_trajectory"] == ex["expected_trajectory"]
+    assert ex["resize_kinds"] == ["shrink", "grow"]
     assert ex["trace"]["resize_spans"] == 2
+    assert ex["step_cache"]["step_cache_miss"] == 2
     assert ex["step_cache"]["step_cache_hit"] >= 1
+    assert ex["ok"] is True, ex

@@ -32,7 +32,7 @@ import numpy as np
 import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))          # repo root: bench.py import
+    os.path.abspath(__file__))))          # repo root
 
 import jax
 import jax.numpy as jnp
@@ -494,21 +494,27 @@ def test_executor_device_rejects_asp_and_ssp():
             ex.run("train", feed_dict={ids: B[0][0] % 64, y_: B[0][1]})
 
 
-def test_bench_wdl_device_smoke():
-    """Satellite: ``--emb-device device`` artifact fields — cache mode,
-    hit rate, fallback counters, same-trace host comparison, H2D row
-    evidence."""
-    import bench
-    res = bench.bench_wdl(batch_size=64, steps=2, warmup=1,
-                          policy="vlru", emb_device="device")
-    extra = res["extra"]
-    assert extra["cache_mode"] == "device"
-    assert extra["cache"] == "vlru_dev"
-    assert "emb_pallas_fallback_reason" in extra
-    assert extra["vs_host_cache"] > 0
-    assert extra["h2d_rows_per_step"]["device_miss_rows_per_step"] \
-        <= extra["h2d_rows_per_step"]["host_all_rows_per_step"]
-    assert extra["cache_hit_rate"] is not None
+def test_device_cache_moves_only_miss_rows_across_the_host_boundary():
+    """What the device slab buys, as rows: host mode materialises and
+    transfers every looked-up occurrence every step, device mode only the
+    rows it PULLED (misses and refreshes) — fewer once the cache is warm,
+    and never more.  The kernels' fallback reasons are counted."""
+    hmetrics.reset_emb_pallas_fallbacks()
+    ex, ids, y_, cache, _store, _t = _build_exec(True)
+    B = _batches(6)
+    host_rows = 0
+    for iv, yv in B:
+        ex.run("train", feed_dict={ids: iv, y_: yv})
+        host_rows += iv.size
+    cache.flush()
+    perf = cache.perf()
+    assert perf["lookups"] == host_rows
+    assert 0 < perf["fetches"] < perf["lookups"]
+    assert 0.0 < perf["hit_rate"] < 1.0
+    # off the TPU the Pallas kernels are not the path; a dispatch that is
+    # traced here counts that as its reason, and no other reason appears
+    assert set(hmetrics.emb_pallas_fallback_counts()) <= {
+        "gather:backend_cpu", "scatter_add:backend_cpu"}
 
 
 @pytest.mark.slow

@@ -344,7 +344,7 @@ def test_remat_training_parity():
 @pytest.mark.slow     # 12s at HEAD (ISSUE 12 tier-1 budget);
 # bf16 training stays via the test_bf16_parity sweep
 def test_mixed_precision_bf16_trains_with_f32_masters():
-    """The flagship's compute_dtype path (bench.py bert on TPU): bf16
+    """The flagship's compute_dtype path (the bert cell on TPU): bf16
     inside the step, fp32 master weights outside, int feeds exempt from
     the cast.  No other test exercised this end-to-end."""
     import numpy as np

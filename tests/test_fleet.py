@@ -478,21 +478,23 @@ def test_decode_fleet_kill_rescues_queued_streams():
         door.close()
 
 
-# ------------------------------------------------------------ bench smoke
+# ---------------------------------------------------------- the scenario
 
 @pytest.mark.slow
-def test_fleet_bench_smoke():
-    """The committed ``artifacts/fleet_bench.json`` is this run: flash
-    crowd absorbed by a recorded scale-out, per-class counted sheds,
-    zero interactive rejections, a mid-spike replica kill with bitwise
-    response parity and zero restarts."""
-    import bench
-    res = bench.bench_fleet(smoke=True, write_artifact=False)
-    extra = res["extra"]
-    assert extra["slo"]["held"] is True
+def test_fleet_scenario():
+    """Flash crowd absorbed by a recorded scale-out (each spin-up a
+    serve-cache hit, one compile in all), per-class counted sheds lowest
+    class first, zero interactive rejections, bounded queues, a mid-spike
+    replica kill with bitwise response parity and no failed future."""
+    import scenarios
+    extra = scenarios.fleet_scenario()
     assert extra["scaling"]["events"], "no scale-out recorded"
     assert extra["rejections"].get("shed:best_effort", 0) > 0
     assert extra["rejections"].get("shed:interactive", 0) == 0
-    assert extra["chaos"]["restarts"] == 0
+    assert extra["interactive_rejections"] == {"clean": 0, "chaos": 0}
+    assert extra["bounded_queues"]["bounded"] is True
+    assert extra["spin_up"]["cheap"] is True
+    assert extra["chaos"]["kill_absorbed"] is True
+    assert extra["chaos"]["failed_futures"] == 0
     assert extra["chaos"]["responses_bitwise_equal"] is True
-    assert res["vs_baseline"] > 0, res
+    assert extra["ok"] is True, extra

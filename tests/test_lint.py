@@ -355,3 +355,59 @@ def test_protocol_alphabet_requires_reasons():
         {"synthetic.py": src}, alphabet={"OP_A": "modeled"},
         allowlist={})
     assert clean == [], clean
+
+
+# ------------------------------------------------------------------ layering
+
+def _imported_modules(path):
+    """(line, absolute dotted name) of every import in ``path``, at module
+    level or inside a function; ``from a import b`` yields ``a`` and
+    ``a.b`` (``b`` may be a module)."""
+    import ast
+    pkg = os.path.relpath(os.path.dirname(path), ROOT).split(os.sep)
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            for a in node.names:
+                yield node.lineno, a.name
+        elif isinstance(node, ast.ImportFrom):
+            base = pkg[:len(pkg) - (node.level - 1)] if node.level else []
+            mod = ".".join(base + ([node.module] if node.module else []))
+            yield node.lineno, mod
+            for a in node.names:
+                yield node.lineno, f"{mod}.{a.name}"
+
+
+#: what lives above the step: the plan search, the serving plane, and
+#: everything outside the package (the root's scripts, tools, benchmark,
+#: tests, examples)
+_UPWARD = ("hetu_tpu.autoparallel", "hetu_tpu.serving")
+_OUTSIDE = {os.path.splitext(n)[0] for n in os.listdir(ROOT)
+            if n.endswith(".py") or n in ("tools", "benchmarks", "tests",
+                                          "examples")}
+
+
+@pytest.mark.parametrize("scope,also", [
+    ("hetu_tpu/obs", ("hetu_tpu.parallel",)),
+    ("hetu_tpu/metrics.py", ("hetu_tpu.parallel",)),
+    ("hetu_tpu/ops", ()),
+    ("hetu_tpu/graph", ()),
+])
+def test_lower_layers_import_nothing_above_them(scope, also):
+    """Observability, the counters, the ops and the graph executor are
+    what the planes are built ON: none of them imports the plan search,
+    the serving plane or a script from outside the package — and the
+    observability layer nothing of ``parallel`` either (the executor's
+    use of ``parallel.zero`` / ``parallel.remat`` and the ops' of
+    ``parallel.ring_attention`` is the design)."""
+    top = os.path.join(ROOT, scope)
+    files = [top] if scope.endswith(".py") else [
+        os.path.join(d, n) for d, _, names in os.walk(top)
+        for n in names if n.endswith(".py")]
+    assert files
+    bad = [f"{os.path.relpath(f, ROOT)}:{line}: {mod}"
+           for f in files for line, mod in _imported_modules(f)
+           if mod.startswith(_UPWARD + also)
+           or mod.split(".")[0] in _OUTSIDE]
+    assert not bad, "\n".join(bad)

@@ -556,27 +556,19 @@ def test_sdpa_varlen_op_graph():
     np.testing.assert_allclose(got, np.asarray(ref), rtol=2e-5, atol=2e-5)
 
 
-def test_flash_gate_artifact_loading(tmp_path, monkeypatch):
-    # the dispatcher's gate comes from the on-chip A/B artifact
-    # (tools/flash_ab.py); block shapes do NOT (the kernel module's rule)
-    import json
+def test_flash_gate_is_the_module_constant(monkeypatch):
+    # the dispatcher's gate is one constant: the reason a call stays on
+    # XLA's path names it, and moving the constant moves the dispatch
+    import jax
     from hetu_tpu.ops import attention as att
 
-    art = {"backend": "tpu", "flash_min_len": 128, "rows": {
-        "128": {"blocks_dense": [128, 128], "winner_dense": "flash"},
-        "512": {"blocks_kmask": [256, 256], "winner_dense": "flash"}}}
-    d = tmp_path / "artifacts"
-    d.mkdir()
-    (d / "flash_ab.json").write_text(json.dumps(art))
-    monkeypatch.setenv("HETU_FLASH_AB_PATH", str(d / "flash_ab.json"))
-    assert att._load_flash_gate() == 128
-
-    # a PARTIAL artifact never serves its prefix-only gate
-    art["partial"] = True
-    (d / "flash_ab.json").write_text(json.dumps(art))
-    assert att._load_flash_gate(default=256) == 256      # default kept
-    monkeypatch.setenv("HETU_FLASH_MIN_LEN", "384")
-    assert att._load_flash_gate(default=256) == 384
+    assert att._FLASH_MIN_LEN == 256
+    q = jax.ShapeDtypeStruct((1, 2, 255, 64), jnp.float32)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert att._gate_reason(q, q) == "below_gate:seq255<256"
+    assert not att._use_flash(q, q)
+    monkeypatch.setattr(att, "_FLASH_MIN_LEN", 128)
+    assert att._gate_reason(q, q) is None and att._use_flash(q, q)
     # a table of blocks left in an old artifact overrides nothing
     assert not hasattr(att, "_FLASH_BLOCKS")
     assert not hasattr(att, "_clipped_blocks")
@@ -642,9 +634,9 @@ def test_flash_block_rule_choices(monkeypatch):
     assert pick(2048, 2048, 64, 2)[1] < 2048
     # cross-attention takes each side's own length
     assert pick(256, 512, 64, 4) == (256, 512)
-    # no environment variable and no artifact reaches the rule
-    monkeypatch.setenv("HETU_FLASH_AB_PATH", "/nonexistent")
-    monkeypatch.setenv("HETU_FLASH_MIN_LEN", "4096")
+    # the dispatch gate does not reach the rule
+    from hetu_tpu.ops import attention as att
+    monkeypatch.setattr(att, "_FLASH_MIN_LEN", 4096)
     assert pick(512, 512, 64, 2) == (512, 512)
 
 

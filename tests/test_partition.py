@@ -5,7 +5,7 @@ ex-primary demotes itself into re-replication instead of acking clients
 partitioned from dead, ``ps_fsck --retries`` keeps live-cluster verify
 usable, fsck's lineage check makes an unconverged split brain visible,
 and the 2-cell serving scenario + the whole acceptance rides
-``bench.py --config partition`` (smoke-tested here).
+``scenarios.partition_scenario``.
 
 Everything is in-process multi-rank like test_ps_replication.py so the
 file stays tier-1 cheap."""
@@ -17,9 +17,9 @@ import numpy as np
 import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))          # repo root: bench/tools import
+    os.path.abspath(__file__))))          # repo root: tools import
 
-from bench import _free_ports
+from scenarios import free_ports as _free_ports
 from hetu_tpu import chaos
 from hetu_tpu.metrics import fault_counts, reset_faults
 from hetu_tpu.ps.dist_store import (DistributedStore, OP_PUSH,
@@ -323,21 +323,16 @@ def test_cellmap_validation_is_loud():
 # ------------------------------------------- CI smoke of the acceptance
 
 @pytest.mark.timeout(420)
-def test_partition_bench_smoke():
-    """The committed ``artifacts/partition_smoke.json`` is this run's
-    output shape: partition shard 1's primary from its clients at step
+def test_partition_scenario():
+    """Partition shard 1's primary from its clients at step
     3, heal at step 7 — zero restarts, zero lost acked writes (bitwise
     loss parity in BOTH chaos variants), the healed stale ex-primary
     epoch-refused + demoted, post-heal fsck(retries=2) zero stable
     divergence + one serving epoch per shard, the unhealed run's split
     brain visible, and the 2-cell scenario serving local reads through
     the cut (rejections=0) and converging after heal."""
-    import bench
-    res = bench.bench_partition(steps=10)
-    assert res["metric"] == "partition_recovery_ms"
-    extra = res["extra"]
-    assert res["vs_baseline"] == 1.0, res
-    assert extra["restarts"] == 0 and extra["resumes"] == 0
+    import scenarios
+    extra = scenarios.partition_scenario(steps=10)
     assert extra["loss_parity_heal"] is True
     assert extra["loss_parity_noheal"] is True
     assert extra["probe_acked"] is True
@@ -357,3 +352,7 @@ def test_partition_bench_smoke():
     assert two["served_through_cut"] is True
     assert all(s["rejections"] == 0 for s in two["cell_stats"].values())
     assert two["fsck_ok"] is True
+    for conf in ("protocol_conformance", "noheal_protocol_conformance",
+                 "clean_protocol_conformance"):
+        assert extra[conf]["ok"] is True, extra[conf]
+    assert extra["ok"] is True

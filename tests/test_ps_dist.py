@@ -7,6 +7,8 @@ import traceback
 import numpy as np
 import pytest
 
+from scenarios import free_ports as _free_ports
+
 
 def _child(rank, ports, barrier, errq):
     try:
@@ -95,19 +97,6 @@ def _child(rank, ports, barrier, errq):
             barrier.abort()
         except Exception:
             pass
-
-
-def _free_ports(n):
-    import socket
-    socks, ports = [], []
-    for _ in range(n):
-        s = socket.socket()
-        s.bind(("127.0.0.1", 0))
-        socks.append(s)
-        ports.append(s.getsockname()[1])
-    for s in socks:
-        s.close()
-    return ports
 
 
 @pytest.mark.timeout(180)

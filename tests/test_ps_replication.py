@@ -9,8 +9,8 @@ failure is survivable, heartbeat liveness survives rank-0 death, and
 Everything here is in-process multi-rank (2–3 server threads in one
 pytest process) so the whole file stays tier-1 cheap; the real
 two-process failover lives in test_ps_dist.py and the end-to-end
-training acceptance in ``bench.py --config failover`` (smoke-tested
-here too)."""
+training acceptance in ``scenarios.failover_scenario`` (run here
+too)."""
 import os
 import socket
 import sys
@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))          # repo root: bench/tools import
+    os.path.abspath(__file__))))          # repo root: tools import
 
 from hetu_tpu import chaos
 from hetu_tpu.metrics import fault_counts, reset_faults
@@ -568,20 +568,17 @@ def test_backoff_is_decorrelated_jittered_and_env_tunable(monkeypatch):
 # ------------------------------------------- CI smoke of the acceptance
 
 @pytest.mark.timeout(300)
-def test_failover_bench_smoke():
-    """The committed ``artifacts/failover_smoke.json`` is this run's
-    output shape: double-kill a replicated primary under chaos, finish
-    with zero restarts and bitwise loss parity, fsck-verified
-    re-replication, and an empty clean-run counter set."""
-    import bench
-    res = bench.bench_failover(steps=10)
-    assert res["metric"] == "failover_recovery_ms"
-    extra = res["extra"]
-    assert res["vs_baseline"] == 1.0, res
+def test_failover_scenario():
+    """Double-kill a replicated primary under chaos with NO try/except
+    and no resume around a step: bitwise loss parity, both kills absorbed
+    by a promotion inside the step, fsck-verified re-replication, a
+    conforming protocol trace and an empty clean-run counter set."""
+    import scenarios
+    extra = scenarios.failover_scenario(steps=10)
     assert extra["loss_parity"] is True
-    assert extra["restarts"] == 0 and extra["resumes"] == 0
     assert len(extra["failover_steps"]) == 2
     assert extra["redundancy_restored"] is True
-    assert res["value"] < extra["recovery_bound_ms"]
+    assert extra["protocol_conformance"]["ok"] is True
     assert extra["clean_run_counters"] == {}
+    assert extra["ok"] is True
     assert extra["fault_counters"]["chaos_kill_primary"] == 2

@@ -4,9 +4,11 @@ Round-2 verdict: the flash dispatch gate (``_FLASH_MIN_LEN``) was a guess,
 so there was no evidence the kernel beats XLA at any length — and the
 flagship BERT bench (seq=128) never reached it.  This microbench times
 fwd+bwd of both paths at BERT-base head geometry across sequence lengths
-and persists the winner table to ``artifacts/flash_ab.json``;
-``hetu_tpu/ops/attention.py`` reads that artifact to set the gate
-empirically.  A chip tool: run it on the machine with the chip.
+and writes the winner table to ``artifacts/flash_ab.json`` for the reader:
+the program reads nothing from it (the gate is the constant
+``ops/attention.py:_FLASH_MIN_LEN``; a table that disagrees with it is the
+evidence for changing the constant).  A chip tool: run it on the machine
+with the chip.
 
 The flash side is timed as the dispatcher runs it: with the blocks the
 kernel module's rule picks from the call's shapes (``_pick_blocks``).
@@ -197,8 +199,7 @@ def _persist(backend, rows, partial):
     # gate rule: the smallest seq from which flash wins BOTH the dense AND
     # the key-mask case at every measured length >= it (kmask is the
     # flagship padded-pretraining path; dense the generic one).  Partial
-    # artifacts carry a prefix-only gate — consumers must ignore it until
-    # partial=false (ops/attention.py does).
+    # artifacts carry a prefix-only gate: read it only once partial=false.
     def _wins(s):
         row = rows[str(s)]
         # an absent kmask measurement is NOT a win — the flagship path

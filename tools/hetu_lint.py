@@ -359,8 +359,7 @@ def check_metrics(metrics_src, profiler_src, usage_srcs=None):
 
     # names defined/called across the package (outside metrics.py), plus
     # the ad-hoc recorder sweep: record_* defs in obs/ are part of the
-    # telemetry surface (obs.record_mfu wraps registry gauges); anywhere
-    # else they bypass the registry
+    # telemetry surface; anywhere else they bypass the registry
     usage_names = set()
     allowed_recorders = set(recorders) | {
         n.name for n in mtree.body if isinstance(n, ast.FunctionDef)

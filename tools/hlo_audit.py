@@ -1,9 +1,9 @@
-"""HLO audit of the bench-config training steps (round-4 verdict item 2,
-extended to every tracked config in round 5).
+"""HLO audit of the training steps of ``tools/audit_graphs.py`` (round-4
+verdict item 2, extended to every tracked config in round 5).
 
 Audits the programs off hardware (run it with ``JAX_PLATFORMS=cpu`` and,
 for the ``zero`` config's dp=4 mesh, eight virtual devices through
-``XLA_FLAGS``): AOT-compiles the exact ``bench.py`` graphs
+``XLA_FLAGS``): AOT-compiles those graphs
 (flagship BERT seq-512 padded MLM, resnet18 NHWC, WDL dense, MoE top-2)
 and audits each compiled HLO for the properties that set the TPU
 performance ceiling:
@@ -19,16 +19,16 @@ performance ceiling:
                        the step's matmuls.  WDL is exempt: CTR trains
                        f32 end-to-end by design (embedding-lookup bound,
                        bf16 would round 100k-row ids' gradients for no
-                       MXU win — bench.py:621 passes no compute_dtype).
+                       MXU win — ``build_wdl_graph`` passes no
+                       compute_dtype).
   donation             params + optimizer state buffers are donated
                        (input_output_alias in the compiled module) so
                        weights update in place — no 2x HBM residency
   no_host_transfers    no infeed/outfeed/send/recv custom-calls inside
                        the step
   flops reconciliation (flagship only) XLA cost_analysis FLOPs vs
-                       bench.py's analytic 6N+attention formula — the
-                       ratio validates the MFU denominator a reviewer
-                       reconciles against bench.py
+                       the analytic 6N+attention formula — the ratio
+                       says how far a 6N-based MFU denominator is off
 
 Writes ``artifacts/hlo_audit_{backend}.json``; exits non-zero if a MUST
 property fails.  Runs on any backend (the audit is structural); flash-
@@ -44,36 +44,30 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
-# The audit compiles bench.py's OWN graph builders — the audited program
-# and the measured program cannot drift apart (they are the same code).
-# compute_dtype is forced to bfloat16 where the bench would pick it per
-# backend (_compute_dtype is bf16 on TPU): the audit predicts the TPU
-# program even when compiled on CPU.  resnet18 likewise pins NHWC (the
-# bench's TPU-side layout pick).
+# The audit compiles the graphs of tools/audit_graphs.py with
+# compute_dtype bfloat16 and resnet18 in NHWC: it predicts the TPU
+# program even when compiled on CPU.
+
+from tools import audit_graphs  # noqa: E402
+
 
 def _build_bert(**kw):
-    from bench import build_bert_graph
-    return build_bert_graph(compute_dtype="bfloat16", **kw)
+    return audit_graphs.build_bert_graph(compute_dtype="bfloat16", **kw)
 
 
 def _build_resnet18(**kw):
-    from bench import build_resnet18_graph
-    return build_resnet18_graph(data_format="NHWC",
-                                compute_dtype="bfloat16", **kw)
+    return audit_graphs.build_resnet18_graph(compute_dtype="bfloat16", **kw)
 
 
 def _build_wdl(**kw):
     """The jitted step with the plain (dense) embedding; the HET-cache
     row traffic happens OUTSIDE the step and does not change the
     compiled program."""
-    from bench import build_wdl_graph
-    cfg, ex, fd, _nodes = build_wdl_graph(policy="dense", **kw)
-    return cfg, ex, fd
+    return audit_graphs.build_wdl_graph(policy="dense", **kw)
 
 
 def _build_moe(**kw):
-    from bench import build_moe_graph
-    return build_moe_graph(compute_dtype="bfloat16", **kw)
+    return audit_graphs.build_moe_graph(compute_dtype="bfloat16", **kw)
 
 
 #: name → (builder, expect_bf16_contractions)
@@ -146,18 +140,17 @@ def _audit_config(name, backend, args):
     from hetu_tpu.profiler import HetuProfiler
 
     import inspect
-    import bench
 
     builder, expect_bf16 = BUILDERS[name]
     # --batch-size/--seq-len apply to bert only; the other configs audit
-    # the bench builders' OWN defaults (read from their signatures, not
-    # re-hardcoded here — retuning a bench default retunes the audit)
+    # the builders' OWN defaults (read from their signatures, not
+    # re-hardcoded here)
     if name == "bert":
         kw = {"batch_size": args.batch_size or 64,
               "seq_len": args.seq_len or 512}
     else:
         kw = {}
-    bench_fn = getattr(bench, f"build_{name}_graph")
+    bench_fn = getattr(audit_graphs, f"build_{name}_graph")
     # effective workload dims recorded in the artifact so bert's
     # bench_formula_flops can always be tied to the dimensions it was
     # computed with
@@ -200,7 +193,7 @@ def _audit_config(name, backend, args):
     # an order of magnitude for a TPU layout); the real roofline comes
     # from tools/calibrate_tpu.py's measured constants at a healthy
     # window.  bytes_accessed stays in the detail as a CPU diagnostic.
-    V5E_PEAK_FLOPS = 197e12   # bf16, public spec (obs.TPU_PEAK_BY_KIND)
+    V5E_PEAK_FLOPS = 197e12   # bf16, public spec (benchmarks/peaks.json)
     xla_flops = float(cost.get("flops", 0.0))
     compute_s = xla_flops / V5E_PEAK_FLOPS
     projection = {
@@ -229,8 +222,8 @@ def _audit_config(name, backend, args):
     }
 
     if name == "bert":
-        # reconcile XLA-counted FLOPs with bench.py's analytic formula
-        # (the MFU denominator): cost_analysis counts the optimized
+        # reconcile XLA-counted FLOPs with the analytic 6N + attention
+        # formula: cost_analysis counts the optimized
         # module's real flops — fwd+bwd matmuls, attention, remat replays
         import numpy as np
         bs, sl = kw["batch_size"], kw["seq_len"]
@@ -283,9 +276,8 @@ def _audit_zero(backend, args, dp=4):
         return {"checks": {}, "ok": True,
                 "detail": {"skipped": f"needs >= {dp} devices, have "
                                       f"{len(jax.devices())}"}}
-    from bench import build_bert_graph
-    cfg, ex, fd = build_bert_graph(batch_size=4, seq_len=128, size="tiny",
-                                   compute_dtype=None, dp=dp, zero=3)
+    cfg, ex, fd = audit_graphs.build_bert_graph(
+        batch_size=4, seq_len=128, size="tiny", dp=dp, zero=3)
     ex.run("train", feed_dict=fd)    # build + prove the live path once
     prof = HetuProfiler(ex, name="train")
     lowered = prof.lowered_text(fd)

@@ -144,7 +144,7 @@ def _seed_demo():
     metrics.record_rpc("OP_PULL", 210.0, 4096)
     metrics.record_rpc("OP_PUSH", 480.0, 8192)
     metrics.record_serve_latency("queue_wait", 120.0)
-    metrics.record_run_gauges("demo", 3.2, 0.41)
+    metrics.record_run_gauges("demo", 3.2)
 
 
 def main(argv=None):

@@ -46,8 +46,7 @@ device side.
 ``main`` prints the verdict JSON and exits non-zero on failure;
 ``tools/hlo_audit.py --config zero`` embeds the same checks in
 ``artifacts/hlo_audit_{backend}.json`` (the regenerated-artifact half
-of the acceptance), and ``bench.py --config remat`` gates on it — an
-audit failure is a bench ``error``, never a silent pass.
+of the acceptance).
 """
 from __future__ import annotations
 
@@ -231,20 +230,19 @@ def audit_hlo(hlo_text):
 # --------------------------------------------------------------- the config
 
 def build_zero_config(dp=4, batch_size=4, seq_len=128):
-    """The audited program: bench.py's OWN dp=4 zero=3 bert-tiny builder
-    (the audited and measured programs cannot drift), with 1 MB ZeRO
+    """The audited program: the dp=4 zero=3 bert-tiny of
+    ``tools/audit_graphs.py``, with 1 MB ZeRO
     buckets so several param gathers exist to overlap.  The bucket env
     is scoped to the build — an explicit caller setting wins, and
     nothing leaks into later builds in the same process."""
-    from bench import build_bert_graph
+    from tools.audit_graphs import build_bert_graph
     prev = os.environ.get("HETU_ZERO_BUCKET_MB")
     if prev is None:
         os.environ["HETU_ZERO_BUCKET_MB"] = AUDIT_BUCKET_MB
     try:
         cfg, ex, fd = build_bert_graph(batch_size=batch_size,
                                        seq_len=seq_len,
-                                       size="tiny", compute_dtype=None,
-                                       dp=dp, zero=3)
+                                       size="tiny", dp=dp, zero=3)
         # build the jitted step INSIDE the env scope: the step-cache
         # signature reads HETU_ZERO_BUCKET_MB at build time and must see
         # the same value the bucket plan was constructed under (else a
