@@ -109,6 +109,29 @@ def flash_call_counts():
     return _flash_calls.counts()
 
 
+# Which geometry the one-token attention over a KV slab compiled with
+# (``decode_attention.geometry``: heads per program and slab rows per key
+# block, from the call's shape and dtype).  Per TRACE, silent under an
+# abstract shape trace; a decode program that shows none here reads its
+# slabs whole through the jnp path.
+_decode_attn_calls = REGISTRY.counter_family(
+    "decode_attn_calls",
+    "one-token KV-slab attention calls by geometry, "
+    "\"<heads per program>x<block rows>\" (per jax trace)")
+
+
+def record_decode_attn_call(heads, block_rows):
+    """Count one traced one-token attention call by its geometry."""
+    if counters_suppressed():
+        return
+    _decode_attn_calls.inc(f"{heads}x{block_rows}")
+
+
+def decode_attn_call_counts():
+    """{"<heads per program>x<block rows>": count} snapshot."""
+    return _decode_attn_calls.counts()
+
+
 # ---------------------------------------------- embedding Pallas fallbacks
 # The device-resident embedding-cache dispatchers
 # (``ops/pallas/emb_cache.py``) record WHY a gather / grad scatter-add
@@ -609,6 +632,15 @@ def reset_serve_counts():
 #                                row-tokens computed, padding included
 #   ``decode_chunk_width``       chunk bucket summed over
 #                                ``decode_prefill_steps``
+#   ``decode_kv_rows_read``      key rows a step's attention fetches of a
+#                                KV slab, summed over the batch bucket's
+#                                slots: each slot's live key blocks where
+#                                the one-token kernel serves
+#                                (``ops.attention.kv_rows_read``), the
+#                                whole slab on a chunked step and on the
+#                                jnp path
+#   ``decode_kv_rows_held``      key rows the slab holds for those slots:
+#                                the denominator of the share read
 # Surfaced by ``HetuProfiler.decode_counters()``; a process that never
 # decodes reports an empty dict.
 
@@ -1005,6 +1037,7 @@ def run_gauges():
 _FAMILIES = {
     "flash_fallbacks": _flash,
     "flash_calls": _flash_calls,
+    "decode_attn_calls": _decode_attn_calls,
     "emb_pallas_fallbacks": _emb_pallas,
     "faults": _faults,
     "elastic": _elastic,

@@ -422,9 +422,11 @@ def kv_slab_from_rows(rows, lanes):
 
 def kv_slab_to_rows(slab, head_dim):
     """(..., n, r * D) slab rows -> the (..., n * r, D) key rows they
-    hold.  A relayout on the device: for snapshots, tests and the one
-    step that amortises it (a prefill chunk that tiles the flash
-    kernel), never for a whole slab in a one-token step."""
+    hold.  A relayout on the device where ``r > 1``: for snapshots, tests
+    and the one step that amortises it (a prefill chunk that tiles the
+    flash kernel), never for a whole slab in a one-token step — both
+    one-token callers (``dispatch_sdpa_decode`` and ``ops/ssm.py``'s
+    ``_diff_attention_kv``) hand their slabs to the kernel as stored."""
     n, lanes = slab.shape[-2:]
     return slab.reshape(*slab.shape[:-2], n * (lanes // head_dim), head_dim)
 
@@ -470,21 +472,36 @@ def _slab_len(q, k_cache):
     return k_cache.shape[-2] * (k_cache.shape[-1] // q.shape[-1])
 
 
-def _decode_gate_reason(q, k_cache):
-    """Why a decode step leaves the kernel path (None = kernel-able).  The
-    decode gate keys on the KV-CACHE length — the axis the kernel tiles
-    and the axis that grows as generation proceeds — not the base gate's
-    q_len (always 1 in decode, where the base gate would refuse every
-    step)."""
+def _decode_gate_reason(s_kv):
+    """Why a one-token step over a slab with room for ``s_kv`` key rows
+    leaves the kernel path (None = kernel-able).  The decode gate keys on
+    the KV-CACHE length — the axis the kernel tiles and the axis that
+    grows as generation proceeds — not the base gate's q_len (always 1 in
+    decode, where the base gate would refuse every step)."""
     be = jax.default_backend()
     if be != "tpu":
         return f"backend:{be}"
-    s_kv = _slab_len(q, k_cache)
     if s_kv < _FLASH_MIN_LEN:
         return f"decode_below_gate:kv{s_kv}<{_FLASH_MIN_LEN}"
     if s_kv % 128:
         return f"decode_kv_ragged:kv{s_kv}"
     return None
+
+
+def kv_rows_read(lengths, slab_shape, pack, itemsize):
+    """Key rows a ONE-TOKEN step's attention fetches of ``(B, H, L/r,
+    lanes)`` slabs (``r = pack``) for sequences of ``lengths`` keys: the
+    live key blocks of the kernel's geometry, whole, where the decode
+    gate lets the kernel in; every row the slabs hold on the jnp path.
+    Host arithmetic over shapes — what ``DecodeEngine`` counts a step
+    by (``decode_kv_rows_read``)."""
+    b, heads, slab_rows, lanes = slab_shape
+    if _decode_gate_reason(slab_rows * pack) is not None:
+        return b * slab_rows * pack
+    from .pallas.decode_attention import geometry
+    keys = geometry(heads, slab_rows, lanes, itemsize)[1] * pack
+    live = -(-np.clip(lengths, 1, slab_rows * pack) // keys)
+    return int(live.sum()) * keys
 
 
 def dispatch_sdpa_decode(q, k_cache, v_cache, positions, scale=None):
@@ -498,14 +515,19 @@ def dispatch_sdpa_decode(q, k_cache, v_cache, positions, scale=None):
     beyond it are invisible (so ``causal`` is implied: the query IS the
     last valid key).  On TPU a cache at a mod-128 bucket >= the flash
     gate goes to the one-token kernel (:mod:`~hetu_tpu.ops.pallas.
-    decode_attention`: the slab read as stored, key blocks past a
-    sequence's length neither fetched nor computed); anything else is
-    the counted jnp reference over the same slabs."""
+    decode_attention`: the slab read as stored, only the key blocks below
+    a sequence's length fetched, computed or stepped over); anything else
+    is the counted jnp reference over the same slabs."""
     lengths = positions.astype(jnp.int32) + 1
-    reason = _decode_gate_reason(q, k_cache)
+    reason = _decode_gate_reason(_slab_len(q, k_cache))
     if reason is None:
         from .pallas.decode_attention import decode_attention
-        return decode_attention(q, k_cache, v_cache, lengths, scale=scale)
+        d = q.shape[-1]
+        pack = k_cache.shape[-1] // d
+        scale = scale if scale is not None else 1.0 / (d ** 0.5)
+        rows = kv_slab_queries(q[:, :, 0, :] * scale, pack)
+        return decode_attention(rows.astype(k_cache.dtype), k_cache, v_cache,
+                                lengths, pack=pack).astype(q.dtype)
     _note_flash_fallback(reason)
     return sdpa_slab_reference(q, k_cache, v_cache, lengths[:, None],
                                scale=scale)

@@ -21,8 +21,9 @@ optional trailing ``valid`` input ``(B,)`` says how many of a row's ``C``
 columns are real: state advances by exactly that many tokens (a row that
 consumes 3 of 8 columns moves its scan state 3 steps, writes 3 ring rows)
 and the outputs of the columns past it are don't-cares.  All lowerings
-are plain ``jax.numpy``; sums, softmax, scan and norms run in float32
-whatever the storage type.
+are plain ``jax.numpy`` but one: the one-token read of a ``kv`` slab on
+the chip is the Pallas kernel of ``ops/pallas/decode_attention.py``.
+Sums, softmax, scan and norms run in float32 whatever the storage type.
 """
 import jax
 import jax.numpy as jnp
@@ -151,21 +152,26 @@ def ssm_step_op(*inputs, name=None):
 
 # ------------------------------------------------- differential attention
 
-def _diff_scores(q, keys):
-    """Scores of query pairs against paired keys.  ``q``: (B, C, P, 2, D),
-    ``P`` query pairs of two heads each; ``keys``: (B, G, M, 2D), ``G``
-    key pairs, each row ``[k1; k2]``; query pair ``p`` reads key pair ``p
-    // (P // G)``.  Head 1 of a pair scores against ``k1`` and head 2
-    against ``k2`` in ONE product over the 2D lanes: each query is laid
-    in its own half with zeros in the other, the same products plus
-    exact zeros, and the keys are read as stored.  Returns (B, G, R, 2,
-    C, M) float32, scaled by ``1 / sqrt(D)``."""
+def _diff_rows(q, g):
+    """The score rows of query pairs: ``q`` (B, C, P, 2, D), ``P`` query
+    pairs of two heads each, read by ``G`` key pairs (query pair ``p``
+    reads key pair ``p // (P // G)``) -> (B, C, G, R, 2, 2D) float32,
+    scaled by ``1 / sqrt(D)``.  Head 1 of a pair scores against ``k1``
+    and head 2 against ``k2`` in ONE product over the 2D lanes of a
+    paired key row ``[k1; k2]``: each query is laid in its own half with
+    zeros in the other, the same products plus exact zeros, and the keys
+    are read as stored."""
     b, chunk, pairs, _, d = q.shape
-    g = keys.shape[1]
     q = _f32(q).reshape(b, chunk, g, pairs // g, 2, d) * (d ** -0.5)
     zero = jnp.zeros_like(q[..., 0, :])
-    rows = jnp.stack([jnp.concatenate([q[..., 0, :], zero], -1),
+    return jnp.stack([jnp.concatenate([q[..., 0, :], zero], -1),
                       jnp.concatenate([zero, q[..., 1, :]], -1)], axis=-2)
+
+
+def _diff_scores(q, keys):
+    """Scores of query pairs (:func:`_diff_rows`) against paired keys
+    (B, G, M, 2D).  Returns (B, G, R, 2, C, M) float32."""
+    rows = _diff_rows(q, keys.shape[1])
     return jnp.einsum("bcgrwl,bgml->bgrwcm", rows.astype(keys.dtype), keys,
                       preferred_element_type=jnp.float32)
 
@@ -192,6 +198,12 @@ def _diff_combine(scores, seen, values, lam, norm_w, lam_init, eps):
             "bgrcm,bgml->bcgrl", p[..., at:at + m].astype(v.dtype), v,
             preferred_element_type=jnp.float32)
         at += m
+    return _diff_norm(out, norm_w, lam_init, eps)
+
+
+def _diff_norm(out, norm_w, lam_init, eps):
+    """(B, C, G, R, 2D) differences ``(A1 − λ A2) [v1; v2]`` RMS-normed
+    over the 2D lanes and scaled by ``1 − λ_init`` -> (B*C, P * 2D)."""
     out = out * jax.lax.rsqrt(
         jnp.mean(jnp.square(out), axis=-1, keepdims=True) + eps)
     out = out * _f32(norm_w) * (1.0 - lam_init)
@@ -214,20 +226,42 @@ def _diff_attention_kv(c, q, k_slab, v_slab, positions, ids, lq1, lk1, lq2,
     ``2p + 1`` forming pair ``p``; slabs (B, G, L/r, r * 2D) of paired
     rows ``[k1; k2]`` / ``[v1; v2]`` (``r = 1`` when ``2D`` fills the 128
     lanes).  The cross-attention layers of a shared-KV decoder call this
-    on ANOTHER layer's slabs."""
-    from .attention import kv_slab_to_rows
+    on ANOTHER layer's slabs.
+
+    The one-token step on the chip (``C == 1``, no mesh, the decode gate
+    of ``ops.attention``) hands the slabs AS STORED to the one-token
+    kernel (:mod:`~hetu_tpu.ops.pallas.decode_attention`), which fetches
+    only the key blocks below each sequence's length: the four heads that
+    read a key pair are four score rows with a softmax each, and ``A1 −
+    λ A2`` is taken of their normalised ``P @ V`` rows here.  A chunk, the
+    CPU and the full-sequence graph read the slabs whole through
+    ``jnp``."""
+    from .attention import (_decode_gate_reason, kv_slab_queries,
+                            kv_slab_to_rows)
     d = int(head_dim)
     b, chunk = ids.shape
+    g, _, lanes = k_slab.shape[1:]
+    pack = lanes // (2 * d)
+    q = _pairs(q, ids, 2 * d).reshape(b, chunk, -1, 2, d)
+    lam = _lambda(lq1, lk1, lq2, lk2, lam_init)
+    if (chunk == 1 and getattr(c, "mesh", None) is None
+            and _decode_gate_reason(k_slab.shape[2] * pack) is None):
+        from .pallas.decode_attention import decode_attention
+        rows = _diff_rows(q, g)[:, 0]                     # (B, G, R, 2, 2D)
+        a = decode_attention(
+            kv_slab_queries(rows, pack).reshape(b, g, -1, lanes).astype(
+                k_slab.dtype), k_slab, v_slab,
+            positions.astype(jnp.int32) + 1, pack=pack).reshape(rows.shape)
+        return _diff_norm((a[..., 0, :] - lam * a[..., 1, :])[:, None],
+                          norm_w, lam_init, eps)
     keys = kv_slab_to_rows(k_slab, 2 * d)
     vals = kv_slab_to_rows(v_slab, 2 * d)
-    q = _pairs(q, ids, 2 * d).reshape(b, chunk, -1, 2, d)
     at = positions.astype(jnp.int32)[:, None] \
         + jnp.arange(chunk, dtype=jnp.int32)[None, :]            # (B, C)
     seen = jnp.arange(keys.shape[2], dtype=jnp.int32)[None, None, :] \
         <= at[:, :, None]
-    return _diff_combine([_diff_scores(q, keys)], [seen], [vals],
-                         _lambda(lq1, lk1, lq2, lk2, lam_init), norm_w,
-                         lam_init, eps)
+    return _diff_combine([_diff_scores(q, keys)], [seen], [vals], lam,
+                         norm_w, lam_init, eps)
 
 
 diff_attention_kv_op = def_op("DiffAttentionKV", _diff_attention_kv)

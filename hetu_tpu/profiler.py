@@ -229,7 +229,7 @@ class HetuProfiler:
         """{family: {kind: count}} over EVERY counter family on the
         observability registry in one call (``hetu_tpu.metrics``
         ``all_counts``): flash_fallbacks, flash_calls,
-        emb_pallas_fallbacks, faults, elastic, autoparallel, cache, zero, step_cache, run_plan, serve,
+        decode_attn_calls, emb_pallas_fallbacks, faults, elastic, autoparallel, cache, zero, step_cache, run_plan, serve,
         decode, prefix_cache, decode_recovery, serve_rejection_reason,
         fleet, protocol, ps_rpc_bytes.  The per-family
         accessors below are thin slices of this — same registry, same
@@ -278,6 +278,15 @@ class HetuProfiler:
         the whole key range is one block, else dq + dkv).  Per trace."""
         from .metrics import flash_call_counts
         return flash_call_counts()
+
+    @staticmethod
+    def decode_attn_calls():
+        """{"<heads per program>x<block rows>": count} of traced
+        one-token attention calls over a KV slab by the geometry
+        ``ops/pallas/decode_attention.py`` chose for them.  Per trace;
+        empty where a decode program reads its slabs through jnp."""
+        from .metrics import decode_attn_call_counts
+        return decode_attn_call_counts()
 
     @staticmethod
     def emb_pallas_fallbacks():

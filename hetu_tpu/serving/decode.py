@@ -452,7 +452,8 @@ class DecodeEngine:
         self.len_ladder = tuple(b for b in default_buckets(self.max_len))
         # placeholder node -> executor feed key, by feed NAME
         self._fk = {name: self.iex._k(node) for name, node in feeds.items()}
-        kv = [n for n in self.cache_names if self._kinds[n] == "kv"]
+        kv = self._kv = [n for n in self.cache_names
+                         if self._kinds[n] == "kv"]
         ck0 = feeds[kv[0]] if kv else None
         # a KV slab's lanes hold ``_pack`` key rows of ``_head_dim`` each
         # (1 for a plain (B, H, L, D) placeholder, which says no more)
@@ -595,6 +596,25 @@ class DecodeEngine:
     @property
     def kv_bytes(self):
         return sum(self.state_bytes().values())
+
+    def _kv_rows(self, chunk):
+        """``(read, held)``: the key rows this step's attention fetches
+        of a KV slab, and the key rows the slab holds, summed over the
+        batch bucket's slots.  A one-token step on the kernel path reads
+        each slot's live key blocks (``ops.attention.kv_rows_read``: the
+        compiled geometry, from the slab's shape); a chunked step and
+        the jnp path read the slab whole.  One slab's worth: every KV
+        layer, and every reader of a shared slab, fetches the same."""
+        if not self._kv:
+            return 0, 0
+        rows = self._slab_rows(self.lb)
+        held = self.bb * rows * self._pack
+        if chunk > 1:
+            return held, held
+        from ..ops.attention import kv_rows_read
+        return kv_rows_read(
+            self.positions + 1, (self.bb, self._heads, rows, self._lanes),
+            self._pack, self._tails[self._kv[0]][1].itemsize), held
 
     # -- capacity ----------------------------------------------------------
 
@@ -956,6 +976,7 @@ class DecodeEngine:
                           > 1 for i in active)
             ph.meta(chunk=chunk, prefill=prefill)
             self._grow_len_if_needed(span=chunk)
+            kv_read, kv_held = self._kv_rows(chunk)
             if chunk > 1:
                 fn, ex, fk = self._chunk_step_fn(chunk), self.ciex, self._cfk
             else:
@@ -1029,6 +1050,8 @@ class DecodeEngine:
             # every row of the batch bucket computes ``chunk`` tokens,
             # whatever it holds: the denominator of the padding share
             record_decode("decode_padded_row_tokens", self.bb * chunk)
+            record_decode("decode_kv_rows_read", kv_read)
+            record_decode("decode_kv_rows_held", kv_held)
             if chunk > 1:
                 record_decode("decode_prefill_steps")
                 record_decode("decode_chunk_width", chunk)

@@ -154,11 +154,214 @@ def test_attention_over_slabs_matches_row_reference(head_dim, path,
         got = att.dispatch_sdpa_prefill(q, k_slab, v_slab, positions)
     else:
         if path == "kernel_blocks":
-            monkeypatch.setattr(da, "MAX_BLOCK_ROWS", 8)
-        got = da.decode_attention(q, k_slab, v_slab, positions + 1,
-                                  interpret=True)
+            # 8 slab rows of both heads a key block
+            monkeypatch.setattr(da, "BLOCK_BYTES", 8 * 2 * 128 * 4)
+            monkeypatch.setattr(da, "MIN_BLOCK_ROWS", 8)
+        pack = 128 // head_dim
+        rows = att.kv_slab_queries(q[:, :, 0] * head_dim ** -0.5, pack)
+        got = da.decode_attention(rows, k_slab, v_slab, positions + 1,
+                                  pack=pack, interpret=True)
     assert got.shape == q.shape
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-6)
+
+
+# the one-token kernel reads only what a sequence has written (ISSUE 30):
+# 256 key rows in key blocks of 64 keys (one block: the whole slab)
+_Q1_LENGTHS = {
+    "edges": [1, 63, 64, 65, 256],          # below / at / above a block edge
+    "ragged": [200, 1, 129, 17, 128],       # an idle slot (length 1) inside
+    "full": [256] * 5,
+}
+
+
+def _garbage_past(slab, lengths, pack, fill):
+    """``slab`` with every key row at or past its sequence's length set to
+    ``fill``: a dead block that is read, or a masked key that counts,
+    shows."""
+    rows = slab.shape[2]
+    key = (np.arange(rows)[:, None] * pack
+           + np.arange(slab.shape[3])[None, :] // (slab.shape[3] // pack))
+    dead = key[None] >= np.asarray(lengths)[:, None, None]    # (B, rows, l)
+    return np.where(dead[:, None], np.float32(fill), slab)
+
+
+@pytest.mark.parametrize("blocks", ["one_block", "blocks"])
+@pytest.mark.parametrize("mix", sorted(_Q1_LENGTHS))
+def test_one_token_kernel_over_packed_slabs(mix, blocks, monkeypatch):
+    """GPT-2's caller (float32, two keys a slab row, the two score rows
+    of a query sharing one softmax) against ``sdpa_slab_reference``:
+    lengths 1, one below / at / one above a block edge and the full
+    slab, a ragged batch with an idle slot, rows past every length
+    filled with large finite garbage."""
+    from hetu_tpu.ops import attention as att
+    from hetu_tpu.ops.pallas import decode_attention as da
+    lengths = np.array(_Q1_LENGTHS[mix], np.int32)
+    rng, _, k_slab = _slab_case(64, length=256, batch=5, seed=3)
+    _, _, v_slab = _slab_case(64, length=256, batch=5, seed=4)
+    k_slab = _garbage_past(k_slab, lengths, 2, 3.0e4)
+    v_slab = _garbage_past(v_slab, lengths, 2, -3.0e4)
+    q = rng.standard_normal((5, 2, 1, 64)).astype(np.float32)
+    want = np.asarray(att.sdpa_slab_reference(q, k_slab, v_slab,
+                                              lengths[:, None]))
+    if blocks == "blocks":
+        monkeypatch.setattr(da, "BLOCK_BYTES", 32 * 2 * 128 * 4)
+        monkeypatch.setattr(da, "MIN_BLOCK_ROWS", 8)
+    assert da.geometry(2, 128, 128, 4) == (
+        (2, 32) if blocks == "blocks" else (2, 128))
+    rows = att.kv_slab_queries(q[:, :, 0] * 0.125, 2)
+    got = da.decode_attention(rows, k_slab, v_slab, lengths, pack=2,
+                              interpret=True)
+    np.testing.assert_allclose(np.asarray(got), want[:, :, 0:1],
+                               rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("blocks", ["one_block", "blocks"])
+@pytest.mark.parametrize("mix", sorted(_Q1_LENGTHS))
+def test_one_token_kernel_over_paired_rows(mix, blocks, monkeypatch):
+    """The shared-KV readers' caller: ``_diff_attention_kv`` at ``C = 1``
+    through the kernel (bfloat16 paired rows as stored, ``r = 1``, two
+    query pairs a key pair: four score rows with a softmax each) against
+    its own ``jnp`` path over the same bfloat16 values.  The ``jnp`` side
+    reads them from float32 slabs (this CPU has no bfloat16 product at
+    these sizes), so its weights meet ``V`` unrounded, as the kernel's do
+    (``_pv``)."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    from hetu_tpu.ops import attention as att
+    from hetu_tpu.ops import ssm
+    from hetu_tpu.ops.pallas import decode_attention as da
+    lengths = np.array(_Q1_LENGTHS[mix], np.int32)
+    rng, _, k_slab = _slab_case(128, length=256, batch=5, seed=5)
+    _, _, v_slab = _slab_case(128, length=256, batch=5, seed=6)
+    k16 = jnp.asarray(_garbage_past(k_slab, lengths, 1, 3.0e4), jnp.bfloat16)
+    v16 = jnp.asarray(_garbage_past(v_slab, lengths, 1, -3.0e4),
+                      jnp.bfloat16)
+    # queries that are bfloat16 values, so that 1/8 of them are too
+    q = jnp.asarray(rng.standard_normal((5, 4 * 128)), jnp.bfloat16).astype(
+        jnp.float32)
+    lams = [jnp.asarray(0.1 * rng.standard_normal(64), jnp.float32)
+            for _ in range(4)]
+    norm_w = jnp.asarray(1.0 + 0.1 * rng.standard_normal(128), jnp.float32)
+    ids = jnp.zeros((5, 1), jnp.int32)
+    call = functools.partial(ssm._diff_attention_kv, None, q)
+    want = call(k16.astype(jnp.float32), v16.astype(jnp.float32),
+                lengths - 1, ids, *lams, norm_w)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(da, "decode_attention", functools.partial(
+        da.decode_attention, interpret=True))
+    if blocks == "blocks":
+        monkeypatch.setattr(da, "BLOCK_BYTES", 64 * 2 * 128 * 2)
+        monkeypatch.setattr(da, "MIN_BLOCK_ROWS", 8)
+    metrics.reset_all()
+    got = call(k16, v16, lengths - 1, ids, *lams, norm_w)
+    assert metrics.decode_attn_call_counts() == {
+        "2x64" if blocks == "blocks" else "2x256": 1}
+    assert got.shape == want.shape == (5, 4 * 128)
+    # read 2.0e-6 to 2.3e-6 over the cases, of outputs up to 0.74: the
+    # weights meet V as hi + lo bfloat16 rows and lose nothing (one
+    # rounding of each row's weights reads 1.3e-3 to 1.7e-3 here)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    assert float(jnp.max(jnp.abs(want))) > 0.5
+
+
+def test_a_chunk_and_the_cpu_keep_the_jnp_read(monkeypatch):
+    """``C > 1`` never enters the kernel, whatever the backend says, and
+    off the chip nothing does: no ``decode_attn_calls``."""
+    import jax
+    import jax.numpy as jnp
+    from hetu_tpu.ops import ssm
+    rng, _, slab = _slab_case(128, length=256, batch=2, seed=7)
+    lams = [jnp.zeros(64)] * 4
+    args = (jnp.asarray(slab), jnp.asarray(slab), np.array([5, 9]))
+    metrics.reset_all()
+    one = ssm._diff_attention_kv(
+        None, jnp.ones((2, 256)), *args, jnp.zeros((2, 1), jnp.int32),
+        *lams, jnp.ones(128))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    two = ssm._diff_attention_kv(
+        None, jnp.ones((4, 256)), *args, jnp.zeros((2, 2), jnp.int32),
+        *lams, jnp.ones(128))
+    assert one.shape == (2, 256) and two.shape == (4, 256)
+    assert metrics.decode_attn_call_counts() == {}
+
+
+def test_geometry_follows_the_calls_shape():
+    """All the heads of a slot in one program, key blocks sized to
+    ``BLOCK_BYTES``: the two cells' shapes, a slab too short to cut, heads
+    too many for one program, and rows with no aligned divisor."""
+    from hetu_tpu.ops.pallas.decode_attention import geometry
+    assert geometry(10, 4608, 128, 2) == (10, 256)       # the phi4 cell
+    assert geometry(16, 384, 128, 4) == (16, 64)         # the chat cell
+    assert geometry(2, 16, 128, 4) == (2, 16)
+    assert geometry(64, 1024, 128, 4) == (16, 64)
+    assert geometry(25, 512, 128, 4) == (5, 256)
+    assert geometry(4, 100, 128, 4) == (4, 100)
+
+
+_ROWS_CFG = GPT2Config.tiny(n_positions=256, batch_size=1, seq_len=16)
+
+
+def _serve_and_count():
+    """Two prompts (37 and 2 tokens, 4 new tokens each) through a
+    2 x 256 engine: the streams, the decode counters, the kernel's
+    geometries."""
+    feeds, logits, caches, _ = gpt2_decode_graph(_ROWS_CFG, max_len=256)
+    eng = DecodeEngine(feeds, logits, caches, seed=0, max_slots=2,
+                       max_len=256)
+    eng.reserve(2, 256)
+    metrics.reset_all()
+    with DecodeRouter(eng, start=False) as router:
+        streams = [router.submit(p, max_new_tokens=4)
+                   for p in (list(range(3, 40)), [5, 6])]
+        router.start()
+        tokens = [s.result(timeout=300) for s in streams]
+    return tokens, metrics.decode_counts(), HetuProfiler.decode_attn_calls()
+
+
+@pytest.fixture(scope="module")
+def jnp_rows_run():
+    return _serve_and_count()
+
+
+def test_engine_reads_its_slabs_whole_on_the_jnp_path(jnp_rows_run):
+    """Off the chip every step's attention is the jnp path:
+    ``decode_kv_rows_read`` equals ``decode_kv_rows_held`` (batch bucket x
+    slab rows, per step) and no ``decode_attn_calls`` is recorded."""
+    _, c, calls = jnp_rows_run
+    assert c["decode_steps"] == 37 + 3
+    assert c["decode_kv_rows_held"] == c["decode_steps"] * 2 * 256
+    assert c["decode_kv_rows_read"] == c["decode_kv_rows_held"]
+    assert calls == {}
+
+
+def test_engine_counts_the_kv_rows_the_kernel_fetches(jnp_rows_run,
+                                                      monkeypatch):
+    """``decode_kv_rows_read`` counts, per step and from the positions
+    alone, what the compiled geometry fetches — the live key blocks of
+    every slot of the batch bucket — and ``decode_attn_calls`` names the
+    geometry once per layer per trace: the kernel in interpret mode
+    behind a backend that says tpu, emitting the jnp path's tokens."""
+    import functools
+
+    import jax
+    from hetu_tpu.ops.pallas import decode_attention as da
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(da, "decode_attention", functools.partial(
+        da.decode_attention, interpret=True))
+    # 16 slab rows (32 keys) of every head a key block
+    monkeypatch.setattr(da, "BLOCK_BYTES", 16 * _ROWS_CFG.n_head * 128 * 4)
+    monkeypatch.setattr(da, "MIN_BLOCK_ROWS", 8)
+    tokens, c, calls = _serve_and_count()
+    assert tokens == jnp_rows_run[0]
+    assert c["decode_kv_rows_held"] == jnp_rows_run[1]["decode_kv_rows_held"]
+    # slot 0 holds 1..40 keys, slot 1 1..5 and then stays at its last
+    # position: one 32-key block each, two for slot 0 past 32 keys
+    assert c["decode_kv_rows_read"] == sum(
+        32 * (-(-n // 32) + 1) for n in range(1, 41))
+    assert calls == {f"{_ROWS_CFG.n_head}x16": _ROWS_CFG.n_layer}
+    assert HetuProfiler.all_counters()["decode_attn_calls"] == calls
 
 
 def test_slab_format_follows_head_dim_alone(decode_graph):

@@ -14,6 +14,7 @@ may load the TPU library, and every xdist worker imports every test file —
 so nothing here touches it at import, in ``skipif`` or in ``parametrize``),
 and all of these tests live in this one file so one worker runs them all.
 """
+import functools
 import os
 import re
 
@@ -145,19 +146,35 @@ def test_flash_decode_q1_compiles(chip):
 
 
 # ------------------------------------------------------- decode over KV slabs
-@pytest.mark.parametrize("head_dim,heads", [(64, 16), (128, 8), (32, 8)])
-def test_decode_attention_over_slabs_compiles(chip, head_dim, heads):
-    """The one-token kernel over the stored slabs: GPT-2's 64-wide heads
-    two to a lane row at the chat cell's 16 x 768, a lane-wide head on
-    plain rows, a 32-wide head four to a row — and no slab-shaped copy
-    in front of it (the slab is consumed as stored)."""
+@pytest.mark.parametrize("head_dim,heads,batch,length,dtype,queries", [
+    (64, 16, 16, 768, jnp.float32, 1),       # the chat cell's call
+    (128, 10, 64, 4608, jnp.bfloat16, 4),    # the phi4 cell's: paired rows
+    (128, 8, 16, 768, jnp.float32, 1),
+    (32, 8, 16, 768, jnp.float32, 1)],
+    ids=["chat", "phi4", "lane_wide", "four_to_a_row"])
+def test_decode_attention_over_slabs_compiles(chip, head_dim, heads, batch,
+                                              length, dtype, queries):
+    """The one-token kernel over the stored slabs at the shapes its two
+    callers compile in the serving cells — GPT-2's 64-wide heads two to a
+    lane row, float32, at 16 x 768; the shared-KV readers' bfloat16
+    paired rows at 64 x 4608, four score rows a key pair — and a
+    lane-wide head on plain rows, a 32-wide head four to a row: the live
+    schedule as the grid's traced bound, and no slab-shaped copy in front
+    of the call (the slab is consumed as stored)."""
+    from hetu_tpu import metrics
     from hetu_tpu.ops.attention import kv_slab_shape
-    from hetu_tpu.ops.pallas.decode_attention import decode_attention
-    slab = kv_slab_shape(16, heads, 768, head_dim)
+    from hetu_tpu.ops.pallas.decode_attention import (decode_attention,
+                                                      geometry)
+    slab = kv_slab_shape(batch, heads, length, head_dim)
+    pack = slab[3] // head_dim
+    want = "%dx%d" % geometry(heads, slab[2], slab[3],
+                              jnp.dtype(dtype).itemsize)
+    before = metrics.decode_attn_call_counts().get(want, 0)
     text = _compiles_with_kernel(
-        decode_attention, chip((16, heads, 1, head_dim), jnp.float32),
-        chip(slab, jnp.float32), chip(slab, jnp.float32),
-        chip((16,), jnp.int32))
+        lambda rows, k, v, n: decode_attention(rows, k, v, n, pack=pack),
+        chip((batch, heads, queries * pack, slab[3]), dtype),
+        chip(slab, dtype), chip(slab, dtype), chip((batch,), jnp.int32))
+    assert metrics.decode_attn_call_counts().get(want, 0) == before + 1
     assert not _slab_copies(text, slab)
 
 
@@ -231,6 +248,15 @@ def test_decode_steps_hold_no_slab_copy(chip, chat_engine, monkeypatch,
     assert "f32[16,16,384,128]{2," not in layout
 
 
+def _state_dims(eng, batch, length, name):
+    """Shape and type of state ``name`` at ``batch`` slots, a ``kv`` slab
+    with room for ``length`` key rows."""
+    tail, dtype = eng._tails[name]
+    if eng._kinds[name] == "kv":
+        tail = (tail[0], length, tail[2])
+    return (batch,) + tuple(tail), dtype
+
+
 @pytest.fixture(scope="module")
 def hybrid_engine():
     """The SambaY decode graphs at the published head width (64: a pair's
@@ -263,12 +289,7 @@ def test_hybrid_decode_steps_update_every_kind_of_state_in_place(
     eng = hybrid_engine
     iex, keys = (eng.iex, eng._fk) if chunk == 1 else (eng.ciex, eng._cfk)
     b, length = 16, 1024
-
-    def dims(name):
-        tail, dtype = eng._tails[name]
-        if eng._kinds[name] == "kv":
-            tail = (tail[0], length, tail[2])
-        return (b,) + tuple(tail), dtype
+    dims = functools.partial(_state_dims, eng, b, length)
     shapes = {eng._kinds[n]: dims(n) for n in eng.cache_names
               if not n.startswith("conv")}
     assert shapes == {"kv": ((16, 2, 1024, 128), jnp.bfloat16),
@@ -295,6 +316,45 @@ def test_hybrid_decode_steps_update_every_kind_of_state_in_place(
         assert tag + "{" + minor in layout, kind
 
 
+def test_shared_kv_readers_fetch_live_rows_only(chip, monkeypatch):
+    """ISSUE 30: the SambaY one-token program at the phi4 cell's attention
+    widths (40 / 20 heads of 64 over 2560, batch 64, cache 4608; eight
+    layers, the fewest that hold a ``full`` and a ``cross`` reader; a
+    narrow MLP and vocabulary), compiled for the described chip as the
+    engine jits it: both readers of the shared slabs are the one-token
+    kernel at the geometry the rule picks, no ``f32[64,10,...,4608]``
+    score or probability fusion is left of the whole-slab read, and no
+    slab-sized ``copy`` stands in front of a call."""
+    from hetu_tpu import metrics
+    from hetu_tpu.models import Phi4FlashConfig, phi4flash_decode_graph
+    from hetu_tpu.serving import DecodeEngine
+    cfg = Phi4FlashConfig(vocab_size=512, hidden_size=2560,
+                          intermediate_size=1024, num_hidden_layers=8,
+                          num_attention_heads=40, num_key_value_heads=20,
+                          param_dtype=jnp.bfloat16, cache_dtype=jnp.bfloat16)
+    assert [cfg.layer_kind(i) for i in (5, 7)] == ["full", "cross"]
+    feeds, logits, states, tokens = phi4flash_decode_graph(cfg, 4608)
+    eng = DecodeEngine(feeds, logits, states, tokens=tokens, max_slots=64,
+                       max_len=4608, seed=0)
+    b, slab = 64, (64, 10, 4608, 128)
+    dims = functools.partial(_state_dims, eng, b, 4608)
+    assert {dims(n) for n in eng._kv} == {(slab, jnp.dtype(jnp.bfloat16))}
+    keys = eng._fk
+    params = {k: chip(v.shape, v.dtype) for k, v in eng.iex.params.items()}
+    fed = ({keys["input_ids"]: chip((b, 1), jnp.int32),
+            keys["positions"]: chip((b,), jnp.int32)},
+           tuple(chip(*dims(n)) for n in eng.cache_names))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    before = metrics.decode_attn_call_counts().get("10x256", 0)
+    text = jax.jit(eng._program(eng.iex, keys), donate_argnums=(1,)).lower(
+        params, fed).compile().as_text()
+    assert metrics.decode_attn_call_counts().get("10x256", 0) == before + 2
+    assert text.count("tpu_custom_call") >= 2
+    assert not re.findall(r"f32\[64,10,[\d,]*4608", text)
+    assert not _slab_copies(text, slab)
+
+
+# ------------------------------------------------------------ moe dispatch
 # ------------------------------------------------------------ moe dispatch
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])

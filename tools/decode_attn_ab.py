@@ -1,0 +1,160 @@
+"""On-chip A/B of the one-token attention over a KV slab.
+
+Times ``ops/pallas/decode_attention.py`` at the shapes its two callers
+compile in the benchmark's serving cells — the shared-KV readers of
+``phi4-mini-flash.reason-c64`` ((64, 10, 4608, 128) bfloat16 paired rows,
+four score rows a key pair) and GPT-2's packed heads in
+``gpt2-medium.chat-c16`` ((16, 16, 384, 128) float32, two score rows a
+head) — with lengths drawn from each cell's own table (a slot holds
+request ``i`` for a time proportional to its prompt + output and sits at a
+uniform depth of it), against the whole-slab ``jnp`` read, and sweeps the
+geometry (heads per program x slab rows per key block) beside the one
+``decode_attention.geometry`` picks.  For the reader of PERF.md: the
+program reads nothing from what this prints.  A chip tool: run it on the
+machine with the chip.
+
+Every timed program makes ``READERS`` calls over the SAME slabs, as the
+phi4 step's eight readers do; the compiler merges the ``jnp`` calls' products
+over a float32 slab into one read of it, so the chat cell's ``jnp_whole``
+is an eighth of one read and says nothing about a layer of that cell.
+Lengths ``full`` price the slab read whole, ``one`` a grid step (one
+block a slot).
+"""
+import argparse
+import functools
+import json
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+READERS = 8          # calls a timed program makes (the phi4 step has eight)
+REPS, INNER = 3, 10
+
+CELLS = {
+    "phi4": {"traffic": "reason-c64", "slab": (64, 10, 4608, 128),
+             "dtype": "bfloat16", "rows": 4, "pack": 1,
+             "sweep": [(10, 256), (10, 512), (10, 128), (10, 1024),
+                       (5, 512), (2, 512), (1, 512)]},
+    "chat": {"traffic": "chat-c16", "slab": (16, 16, 384, 128),
+             "dtype": "float32", "rows": 2, "pack": 2,
+             "sweep": [(16, 64), (16, 128), (16, 32), (16, 192), (16, 384),
+                       (4, 384), (1, 384), (1, 128)]},
+}
+
+
+def cell_lengths(traffic, slots, seed):
+    """Key rows each of ``slots`` sequences holds at a random moment of
+    the cell's steady state."""
+    import numpy as np
+    with open(os.path.join(ROOT, "benchmarks", "traffic",
+                           traffic + ".json")) as f:
+        table = np.asarray(json.load(f)["table"], np.int64)
+    total = table.sum(axis=1)
+    rng = np.random.default_rng(seed)
+    held = rng.choice(len(table), size=slots, p=total / total.sum())
+    return np.maximum(1, (rng.random(slots) * total[held]).astype(np.int32))
+
+
+def _time(fn, *args):
+    import jax
+    step = jax.jit(fn)
+    jax.block_until_ready(step(*args))              # compile + warm
+    best = float("inf")
+    for _ in range(REPS):
+        t0 = time.perf_counter()
+        out = None
+        for _ in range(INNER):
+            out = step(*args)
+        jax.block_until_ready(out)
+        best = min(best, (time.perf_counter() - t0) / INNER)
+    return best * 1e3 / READERS                     # ms a call
+
+
+def _readers(call):
+    """``READERS`` calls in one program, each with its own queries."""
+    def run(rows, k, v, lengths):
+        return sum(call(rows * (1.0 + 0.01 * i), k, v, lengths)
+                   for i in range(READERS))
+    return run
+
+
+def _jnp_whole(pack):
+    """The whole-slab read the ``jnp`` paths make: scores against every
+    slab row, masked afterwards, one softmax per score row."""
+    import jax
+    import jax.numpy as jnp
+
+    def call(rows, k, v, lengths):
+        s = jnp.einsum("bhrl,bhml->bhrm", rows, k,
+                       preferred_element_type=jnp.float32)
+        key = (jnp.arange(k.shape[2])[None, :] * pack
+               + (jnp.arange(rows.shape[2]) % pack)[:, None])
+        seen = key[None, None] < lengths[:, None, None, None]
+        p = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
+        return jnp.einsum("bhrm,bhml->bhrl", p.astype(v.dtype), v,
+                          preferred_element_type=jnp.float32)
+    return call
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--cells", default="phi4,chat")
+    ap.add_argument("--seed", type=int, default=2860486313)
+    args = ap.parse_args(argv)
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from hetu_tpu.ops.pallas import decode_attention as da
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print(json.dumps({"error": f"needs the chip, found {dev.platform}"}))
+        return 2
+    for name in args.cells.split(","):
+        cell = CELLS[name]
+        b, h, slab_rows, lanes = cell["slab"]
+        dtype = jnp.dtype(cell["dtype"])
+        pack = cell["pack"]
+        key = jax.random.PRNGKey(args.seed % (2 ** 31))
+        kq, kk, kv = jax.random.split(key, 3)
+        rows = jax.random.normal(kq, (b, h, cell["rows"], lanes),
+                                 jnp.float32).astype(dtype)
+        k = jax.random.normal(kk, cell["slab"], jnp.float32).astype(dtype)
+        v = jax.random.normal(kv, cell["slab"], jnp.float32).astype(dtype)
+        mixes = {"cell": cell_lengths(cell["traffic"], b, args.seed),
+                 "full": np.full(b, slab_rows * pack, np.int32),
+                 "one": np.ones(b, np.int32)}
+        rule = da.geometry
+        picked = rule(h, slab_rows, lanes, dtype.itemsize)
+        for mix, lengths in mixes.items():
+            n = jnp.asarray(lengths, jnp.int32)
+            out = {"cell": name, "lengths": mix, "device": dev.device_kind,
+                   "mean_len": float(lengths.mean()), "picked": list(picked),
+                   "slab_keys": slab_rows * pack, "ms_per_call": {}}
+            out["ms_per_call"]["jnp_whole"] = _time(
+                _readers(_jnp_whole(pack)), rows, k, v, n)
+            sweep = cell["sweep"] if mix == "cell" else cell["sweep"][:2]
+            for geo in sweep:
+                # the rule is a function of the module: the sweep stands
+                # in for it, one geometry at a time
+                da.geometry = lambda *shape, geo=geo: geo
+                try:
+                    out["ms_per_call"][f"{geo[0]}x{geo[1]}"] = _time(
+                        _readers(functools.partial(da.decode_attention,
+                                                   pack=pack)), rows, k, v, n)
+                except Exception as e:  # noqa: BLE001 - a refused
+                    out["ms_per_call"][f"{geo[0]}x{geo[1]}"] = (  # geometry
+                        f"refused: {str(e).splitlines()[0][:120]}")  # is data
+                finally:
+                    da.geometry = rule
+            keys = picked[1] * pack
+            out["rows_read_pct"] = 100.0 * float(
+                (-(-lengths // keys) * keys).sum()) / (b * slab_rows * pack)
+            print(json.dumps(out), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
