@@ -358,7 +358,9 @@ def lookup_or_build_serve(iex, bucket, infer_fn):
         # inflate the counter the acceptance check compares to the
         # number of distinct buckets used
         record_serve("serve_bucket_compiles")
-        return jax.jit(infer_fn, donate_argnums=donate)
+        asked = iex.compiler_options()
+        return jax.jit(infer_fn, donate_argnums=donate,
+                       **({"compiler_options": asked} if asked else {}))
 
     if not enabled():
         return build()

@@ -132,6 +132,28 @@ def decode_attn_call_counts():
     return _decode_attn_calls.counts()
 
 
+# How a decode graph's expert layer multiplies (``ops.moe._moe_experts``):
+# the experts it holds of all the router chooses among, the experts a
+# token takes, and whether the grouped product is ``jax.lax.ragged_dot``
+# or a kernel.  Per TRACE, as the attention families above.
+_moe_calls = REGISTRY.counter_family(
+    "moe_calls",
+    "dropless expert-layer products by share and path, "
+    "\"<held>of<all>:top<k>:<ragged|kernel>\" (per jax trace)")
+
+
+def record_moe_call(held, n_experts, top_k, how="ragged"):
+    """Count one traced expert-layer product."""
+    if counters_suppressed():
+        return
+    _moe_calls.inc(f"{held}of{n_experts}:top{top_k}:{how}")
+
+
+def moe_call_counts():
+    """{"<held>of<all>:top<k>:<ragged|kernel>": count} snapshot."""
+    return _moe_calls.counts()
+
+
 # ---------------------------------------------- embedding Pallas fallbacks
 # The device-resident embedding-cache dispatchers
 # (``ops/pallas/emb_cache.py``) record WHY a gather / grad scatter-add
@@ -641,6 +663,13 @@ def reset_serve_counts():
 #                                jnp path
 #   ``decode_kv_rows_held``      key rows the slab holds for those slots:
 #                                the denominator of the share read
+#   ``moe_assignments``, ``moe_assignments_held``, ``moe_experts_touched``,
+#   ``moe_expert_load_max``     folded from a step's auxiliary fetch of chosen
+#                                expert ids (``DecodeEngine(aux_fold=)``;
+#                                ``SolarOpen2Config.choice_counters``): (token,
+#                                expert) pairs computed, those whose expert is
+#                                held here, held experts with a token (summed
+#                                over the layers), the fullest one's tokens
 # Surfaced by ``HetuProfiler.decode_counters()``; a process that never
 # decodes reports an empty dict.
 
@@ -1038,6 +1067,7 @@ _FAMILIES = {
     "flash_fallbacks": _flash,
     "flash_calls": _flash_calls,
     "decode_attn_calls": _decode_attn_calls,
+    "moe_calls": _moe_calls,
     "emb_pallas_fallbacks": _emb_pallas,
     "faults": _faults,
     "elastic": _elastic,

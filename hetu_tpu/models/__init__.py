@@ -27,3 +27,6 @@ from .xlnet import (XLNetConfig, xlnet_model, xlnet_plm_graph,
                     perm_masks_from_order, synthetic_plm_batch)
 from .phi4flash import (Phi4FlashConfig, phi4flash_decode_graph,
                         phi4flash_decode_chunked_graph, phi4flash_lm_graph)
+from .solar_open2 import (SolarOpen2Config, solar_open2_decode_graph,
+                          solar_open2_decode_chunked_graph,
+                          solar_open2_lm_graph)

@@ -34,7 +34,8 @@ from .moe import (topk_gate_op, ktop1_gate_op, sam_gate_op,
                   layout_transform_op, reverse_layout_transform_op,
                   hash_dispatch_op, balance_assignment_op, alltoall_op,
                   halltoall_op, topk_gate_sparse_op, sparse_dispatch_op,
-                  sparse_combine_op)
+                  sparse_combine_op, moe_route_op, moe_experts_op,
+                  moe_choices_op)
 from .attention import (sdpa_op, sdpa_masked_op, sdpa_bias_op,
                         sdpa_masked_bias_op, sdpa_varlen_op,
                         sdpa_decode_op, kv_cache_append_op,
@@ -49,6 +50,8 @@ from .ssm import (swiglu_op, silu_gate_op, greedy_token_op,
                   conv_state_shift_op, ssm_step_op, ssm_chunk_scan_op,
                   ring_append_op, diff_attention_kv_op,
                   diff_attention_ring_op, pair_rows_op, zeros_op)
+from .kda import (rms_norm_op, sigmoid_gate_op, kda_chunk_op, kda_out_op,
+                  gqa_rows_op, gqa_attention_kv_op)
 from .rnn import rnn_op, lstm_op, gru_op
 from .transform import clone_op, cumsum_op, group_topk_idx_op
 

@@ -405,3 +405,147 @@ def _sparse_combine_lower(c, buffers, gate_w, slot_of_token, token_of_slot,
 
 
 sparse_combine_op = def_op("SparseCombine", _sparse_combine_lower)
+
+
+# ------------------------------------------------- dropless serving layer
+# What a SERVED mixture of experts needs and the capacity gates above do
+# not give: no token is dropped, the scores are sigmoids with a selection
+# bias (the DeepSeek-V3 convention), and the layer is told which experts it
+# HOLDS — it routes over all of them and computes its own experts' part of
+# the result, what one chip of an expert-parallel group does before the
+# group's all-reduce.  ``hetu_tpu/models/solar_open2.py`` is the caller.
+
+def _route_pick(s, bias, k):
+    """The ``k`` experts whose BIASED score is highest, and their plain
+    scores: the bias selects and does not weigh."""
+    _, ids = jax.lax.top_k(s + bias, k)
+    return ids, jnp.take_along_axis(s, ids, axis=-1)
+
+
+def _route_norm(chosen):
+    """Weights normalised over the chosen (``norm_topk_prob``)."""
+    return chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+
+
+def _moe_route(c, y, w_r, bias, top_k=1):
+    """``s = sigmoid(y W_r)`` over ALL experts, in float32 from the float32
+    input at the highest matrix precision; chosen = the ``top_k`` of ``s +
+    bias``; weights ``s_e / Σ_chosen s``.  ``y``: (N, d); ``w_r``: (d, E);
+    ``bias``: (E,).  Returns ``(ids (N, k) int32, weights (N, k)
+    float32)``."""
+    f32 = jnp.float32
+    s = jax.nn.sigmoid(jnp.matmul(y.astype(f32), w_r.astype(f32),
+                                  precision=jax.lax.Precision.HIGHEST))
+    ids, chosen = _route_pick(s, bias.astype(f32), int(top_k))
+    return ids.astype(jnp.int32), _route_norm(chosen)
+
+
+_moe_route_node = def_op("MoERoute", _moe_route)
+
+
+def moe_route_op(y, w_r, bias, top_k, name=None):
+    """``(ids, weights)`` nodes of :func:`_moe_route`."""
+    return tuple_outputs(_moe_route_node(y, w_r, bias, top_k=top_k,
+                                         name=name), 2)
+
+
+def _held(local, count):
+    """Which (token, expert) pairs are this layer's: ``local`` the chosen
+    ids counted from the first held expert."""
+    return jnp.logical_and(local >= 0, local < count)
+
+
+#: row tile of the kernel path.  A group of a few rows pays one tile of
+#: MXU rows whatever its size; at 128 that stays under the time its
+#: weights take to cross (the compiler's own ragged-dot takes 512-row
+#: tiles and is bound by them at serving batch sizes)
+_ROW_TILE = 128
+#: bytes of one weight block (k tile x n tile) a grid step of the kernel
+#: path fetches, double-buffered under the default scoped VMEM
+_WEIGHT_BLOCK = 2 << 20
+
+
+def _grouped_how():
+    """The Pallas grouped matmul on the TPU; ``jax.lax.ragged_dot`` serves
+    the CPU only (tests, the reference's platform)."""
+    return "kernel" if jax.default_backend() == "tpu" else "ragged"
+
+
+def _weight_tiles(k, n, itemsize):
+    """(k tile, n tile) of the kernel path: the whole contraction in one
+    block while a 512-lane strip of it fits ``_WEIGHT_BLOCK``, then the
+    widest strip of whole 128-lane columns that divides ``n`` and fits."""
+    tk = k
+    while tk * 512 * itemsize > _WEIGHT_BLOCK and tk % 256 == 0:
+        tk //= 2
+    fits = [t for t in range(128, n + 1, 128)
+            if n % t == 0 and tk * t * itemsize <= _WEIGHT_BLOCK]
+    return tk, (max(fits) if fits else n)
+
+
+def _grouped_matmul(rows, w, sizes, how):
+    """``rows[group g's rows] @ w[g]`` for rows sorted by group, float32
+    out; rows behind the last group come back undefined.  ``rows``: (M,
+    k); ``w``: (G, k, n); ``sizes``: (G,) int32."""
+    if how == "ragged":
+        return jax.lax.ragged_dot(rows, w, sizes,
+                                  preferred_element_type=jnp.float32)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    m = rows.shape[0]
+    pad = -m % _ROW_TILE
+    if pad:
+        rows = jnp.pad(rows, ((0, pad), (0, 0)))
+    tk, tn = _weight_tiles(w.shape[1], w.shape[2], w.dtype.itemsize)
+    out = gmm(rows, w, sizes, preferred_element_type=jnp.float32,
+              tiling=(_ROW_TILE, tk, tn),
+              interpret=jax.default_backend() != "tpu")
+    return out[:m] if pad else out
+
+
+def _moe_experts(c, y, ids, weights, w_gu, w_d, first=0, n_experts=None):
+    """The routed part of a dropless expert layer, of the experts HELD:
+    ``Σ_{e chosen ∧ held} w_e W_d,e(silu(W_g,e y) ⊙ W_u,e y)``.  ``y``: (N,
+    d); ``ids`` / ``weights``: (N, k) over all ``n_experts``; ``w_gu``: (G,
+    d, 2f) the held experts' ``[gate | up]``, experts ``first .. first +
+    G``; ``w_d``: (G, f, d).  The step's (token, expert) pairs are sorted
+    by held expert and multiplied group by group — on the TPU by the
+    Pallas grouped matmul (``jax.experimental.pallas.ops.tpu.megablox``),
+    elsewhere by ``jax.lax.ragged_dot``: each held expert's weights cross
+    once, an expert nobody chose not at all; a pair whose expert is held
+    elsewhere sorts behind the last group, where nothing is computed."""
+    from ..metrics import record_moe_call
+    n, k = ids.shape
+    g, _, f2 = w_gu.shape
+    how = _grouped_how()
+    record_moe_call(g, n_experts or g, k, how)
+    local = ids - int(first)
+    held = _held(local, g)
+    key = jnp.where(held, local, g).reshape(-1)                  # (N*k,)
+    order = jnp.argsort(key, stable=True)        # sorted place -> pair
+    sizes = jnp.sum(key[:, None] == jnp.arange(g, dtype=key.dtype)[None, :],
+                    axis=0, dtype=jnp.int32)
+    rows = y.astype(w_gu.dtype)[order // k]
+    h = _grouped_matmul(rows, w_gu, sizes, how)
+    act = (jax.nn.silu(h[:, :f2 // 2]) * h[:, f2 // 2:]).astype(w_d.dtype)
+    out = _grouped_matmul(act, w_d, sizes, how)
+    # rows behind the last group are no group's: whatever lies there
+    live = jnp.arange(n * k, dtype=jnp.int32) < jnp.sum(sizes)
+    out = jnp.where(live[:, None], out, 0.0)
+    back = jnp.zeros((n * k,), jnp.int32).at[order].set(
+        jnp.arange(n * k, dtype=jnp.int32), unique_indices=True)
+    w = jnp.where(held, weights.astype(jnp.float32), 0.0)
+    return jnp.sum(out[back].reshape(n, k, -1) * w[..., None], axis=1)
+
+
+moe_experts_op = def_op("MoEExperts", _moe_experts)
+
+
+def _moe_choices(c, feed, *ids):
+    """The chosen expert ids of every expert layer, (B, C, layers, k)
+    int16, ``(B, C)`` from the ``feed`` of token ids: what a decode step
+    hands back beside its tokens."""
+    stacked = jnp.stack(ids, axis=1).astype(jnp.int16)     # (B*C, L, k)
+    return stacked.reshape(feed.shape + stacked.shape[1:])
+
+
+moe_choices_op = def_op("MoEChoices", _moe_choices)

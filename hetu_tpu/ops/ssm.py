@@ -85,11 +85,17 @@ def _conv_state_shift(c, u, state, w, bias, ids, valid=None):
 
 
 _conv_state_shift_node = def_op("ConvStateShift", _conv_state_shift)
+_conv_state_shift_plain_node = def_op(
+    "ConvStateShiftNoBias", lambda c, u, state, w, ids, valid=None:
+    _conv_state_shift(c, u, state, w, jnp.zeros((), jnp.float32), ids, valid))
 
 
-def conv_state_shift_op(*inputs, name=None):
-    """``(activated output, state')`` nodes of :func:`_conv_state_shift`."""
-    return tuple_outputs(_conv_state_shift_node(*inputs, name=name), 2)
+def conv_state_shift_op(*inputs, name=None, bias=True):
+    """``(activated output, state')`` nodes of :func:`_conv_state_shift`;
+    ``bias=False`` for a convolution without one (inputs ``u, state, w,
+    ids[, valid]``)."""
+    node = _conv_state_shift_node if bias else _conv_state_shift_plain_node
+    return tuple_outputs(node(*inputs, name=name), 2)
 
 
 def _ssm_scan(u, delta, a, bm, cm, d, state, count):

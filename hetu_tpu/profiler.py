@@ -229,7 +229,7 @@ class HetuProfiler:
         """{family: {kind: count}} over EVERY counter family on the
         observability registry in one call (``hetu_tpu.metrics``
         ``all_counts``): flash_fallbacks, flash_calls,
-        decode_attn_calls, emb_pallas_fallbacks, faults, elastic, autoparallel, cache, zero, step_cache, run_plan, serve,
+        decode_attn_calls, moe_calls, emb_pallas_fallbacks, faults, elastic, autoparallel, cache, zero, step_cache, run_plan, serve,
         decode, prefix_cache, decode_recovery, serve_rejection_reason,
         fleet, protocol, ps_rpc_bytes.  The per-family
         accessors below are thin slices of this — same registry, same
@@ -287,6 +287,15 @@ class HetuProfiler:
         empty where a decode program reads its slabs through jnp."""
         from .metrics import decode_attn_call_counts
         return decode_attn_call_counts()
+
+    @staticmethod
+    def moe_calls():
+        """{"<held>of<all>:top<k>:<ragged|kernel>": count} of traced
+        dropless expert-layer products (``ops.moe.moe_experts_op``): the
+        experts the layer holds of all the router scores, the experts a
+        token takes, and the grouped product's path.  Per trace."""
+        from .metrics import moe_call_counts
+        return moe_call_counts()
 
     @staticmethod
     def emb_pallas_fallbacks():

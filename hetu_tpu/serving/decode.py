@@ -158,6 +158,7 @@ class DecodeStream:
         self._lock = make_lock("DecodeStream._lock")
         self._futs = []
         self._tokens = []
+        self._aux = {}
         self._epoch = 0
         self._final = Future()
 
@@ -205,6 +206,17 @@ class DecodeStream:
         with self._lock:
             return list(self._tokens)
 
+    def aux(self, name):
+        """What the engine's auxiliary fetch ``name`` (``DecodeEngine(
+        aux=)``) held for every token this sequence has CONSUMED so far,
+        stacked in position order: row ``p`` is of the token at position
+        ``p`` — the prompt's tokens, then each generated token once it was
+        fed back (so ``prompt_len + n_tokens - 1`` rows when the sequence
+        has finished).  None for a name the engine does not fetch."""
+        with self._lock:
+            parts = list(self._aux.get(name, ()))
+        return np.concatenate(parts) if parts else None
+
     def __iter__(self):
         i = 0
         while True:
@@ -229,6 +241,8 @@ class DecodeStream:
         with self._lock:
             self._epoch += 1
             epoch, journal = self._epoch, list(self._tokens)
+            # the continuation consumes every position again
+            self._aux = {}
         if _PROTO.on:
             _PROTO.emit("decode", "detach", sid=self.sid, old=epoch - 1,
                         new=epoch, n=len(journal))
@@ -260,6 +274,16 @@ class DecodeStream:
         if fut.set_running_or_notify_cancel():
             fut.set_result(int(tok))
         return count
+
+    def _note_aux(self, parts, epoch=None):
+        """Keep the auxiliary fetches' slices ``{name: (n, ...)}`` of the
+        ``n`` tokens a step consumed for this sequence; fenced by the
+        replay epoch like an emission."""
+        with self._lock:
+            if epoch is not None and epoch != self._epoch:
+                return
+            for name, part in parts.items():
+                self._aux.setdefault(name, []).append(part)
 
     def _finish(self, epoch=None):
         with self._lock:
@@ -407,6 +431,20 @@ class DecodeEngine:
     :meth:`reserve` puts the engine at given buckets before the first
     request.
 
+    **Auxiliary fetches (ISSUE 31).**  ``aux={name: node}`` names further
+    fetches of the one-token graph, each ``(B, 1, ...)`` — something of
+    every token a row consumed that the host wants beside the token, as
+    the expert ids a mixture of experts chose — and ``chunked=`` then
+    takes their ``(B, C, ...)`` twins as a last element ``{name: node}``.
+    Every step brings them back with the token ids (a step in which no
+    row emits reads them alone), hands each row's slice of the columns it
+    consumed to its stream (:meth:`DecodeStream.aux`) and, in the
+    ``readback`` phase, folds the whole array into counters:
+    ``aux_fold={name: fn}``, ``fn(array) -> {counter: n}``, recorded with
+    :func:`~hetu_tpu.metrics.record_decode`.  A prefix store is refused
+    with them: a sequence seated past its prefix would lack the slices of
+    the positions it skipped.
+
     NOT thread-safe by design: the owning :class:`DecodeRouter` loop
     thread (or a single test thread) makes every call after
     construction.  Device calls happen with no lock held."""
@@ -415,7 +453,7 @@ class DecodeEngine:
                  max_slots=8, max_len=128, plan=None, mesh=None,
                  seed=0, donate=True, validate="error",
                  chunked=None, max_chunk=None, prefix_store=None,
-                 tokens=None):
+                 tokens=None, aux=None, aux_fold=None):
         self.cache_names = [n for n in feeds
                             if n not in ("input_ids", "positions")]
         #: per state: its kind, its shape past the batch and its type, as
@@ -439,10 +477,24 @@ class DecodeEngine:
                     "of the other kinds") +
                 " — build the engine without "
                 + ("prefix_store=" if prefix_store is not None else "plan="))
+        #: auxiliary fetches by name, and what folds each into counters
+        self._aux = list(aux or ())
+        self._aux_fold = dict(aux_fold or {})
+        if self._aux and prefix_store is not None:
+            raise ValueError(
+                f"this graph hands back {self._aux} of every token a "
+                f"sequence consumes: one seated past a stored prefix would "
+                f"lack them for the positions it skipped — build the "
+                f"engine without prefix_store=")
         #: fetches in front of the states: the greedy token ids when the
-        #: graph computes them (``tokens=``), then the logits
-        head = [logits] if tokens is None else [tokens, logits]
+        #: graph computes them (``tokens=``), then the logits, then the
+        #: auxiliary fetches
+        head = ([logits] if tokens is None else [tokens, logits]) \
+            + [aux[name] for name in self._aux]
         self._head = len(head)
+        #: what ``outs[0]`` is: the greedy ids, or the logits they are the
+        #: argmax of (``_head`` counts the auxiliary fetches too)
+        self._device_tokens = tokens is not None
         self.iex = InferenceExecutor(
             head + list(cache_fetches), weights=weights,
             buckets=default_buckets(max_slots), mesh=mesh, seed=seed,
@@ -473,9 +525,13 @@ class DecodeEngine:
                     "chunked prefill under a tp plan is not supported: "
                     "bind the plan to the one-token entry only")
             cfeeds, clogits, ccaches, *ctokens = chunked
-            if bool(ctokens) != (tokens is not None):
+            caux = ctokens.pop() if ctokens and isinstance(
+                ctokens[-1], dict) else {}
+            if bool(ctokens) != (tokens is not None) \
+                    or list(caux) != self._aux:
                 raise ValueError("the one-token and the chunked entry must "
-                                 "both fetch token ids, or neither")
+                                 "both fetch token ids, or neither, and "
+                                 "the same auxiliary fetches")
             # the chunked executor MUST serve the primary's exact weight
             # bytes: independent construction would re-init every
             # variable from fold_in(seed, topo_index) over a DIFFERENT
@@ -485,7 +541,8 @@ class DecodeEngine:
             w = {self.iex.var_names[n]: self.iex.params[self.iex._k(n)]
                  for n in self.iex.var_nodes}
             self.ciex = InferenceExecutor(
-                ctokens + [clogits] + list(ccaches), weights=w,
+                ctokens + [clogits] + [caux[name] for name in self._aux]
+                + list(ccaches), weights=w,
                 buckets=default_buckets(max_slots), mesh=mesh, seed=seed,
                 donate=donate, validate=validate, decode=True)
             top = int(max_chunk) if max_chunk else min(32, self.max_len)
@@ -1027,22 +1084,36 @@ class DecodeEngine:
             # or with ``tokens=`` the (batch,) greedy token ids the
             # program computed from them: the logits then stay where
             # they are (``last_logits`` fetches them on request)
-            if any(self.slots[i].ptr + int(consume[i])
-                   >= len(self.slots[i].req.prompt) for i in active):
+            emits = any(self.slots[i].ptr + int(consume[i])
+                        >= len(self.slots[i].req.prompt) for i in active)
+            # the auxiliary fetches are of every consumed token: read
+            # whether or not a row emits
+            back = ([outs[0]] if emits else []) + list(
+                outs[self._head - len(self._aux):self._head])
+            if back:
                 # the D2H is queued behind the step NOW, as np.asarray
                 # alone would queue it: waiting for the result first and
                 # asking for the copy after costs a host wake-up and a
                 # transfer dispatch per step with the chip idle
-                outs[0].copy_to_host_async()
+                for out in back:
+                    out.copy_to_host_async()
                 ph.mark("wait")
-                outs[0].block_until_ready()
+                back[-1].block_until_ready()
                 ph.mark("readback")
-                read = np.asarray(outs[0])
+                back = [np.asarray(out) for out in back]
+                read = back[0] if emits else None
+                aux = dict(zip(self._aux, back[len(back) - len(self._aux):]))
+                for name, fold in self._aux_fold.items():
+                    for counter, n in fold(aux[name]).items():
+                        record_decode(counter, n)
                 ph.mark("host")
-                self._logits = read if self._head == 1 else outs[1]
             else:
                 ph.mark("host")
-                read = self._logits = None
+                read, aux = None, {}
+            if emits:
+                self._logits = outs[1] if self._device_tokens else read
+            else:
+                self._logits = None
                 record_decode("decode_logits_skipped")
             for name, new in zip(self.cache_names, outs[self._head:]):
                 self.caches[name] = new
@@ -1066,6 +1137,10 @@ class DecodeEngine:
                 seq = self.slots[i]
                 n = int(consume[i])
                 self.positions[i] += n
+                if aux:
+                    seq.req.stream._note_aux(
+                        {name: a[i, :n] for name, a in aux.items()},
+                        seq.req.epoch)
                 plen = len(seq.req.prompt)
                 if seq.ptr + n < plen:
                     # still mid-prompt: next prompt token, nothing to
@@ -1083,7 +1158,8 @@ class DecodeEngine:
                 # this row's logits are live: greedy argmax
                 # (deterministic first-max tie-break keeps decode bitwise
                 # stable)
-                tok = int(np.argmax(read[i]) if self._head == 1 else read[i])
+                tok = int(read[i] if self._device_tokens
+                          else np.argmax(read[i]))
                 emitted += self._emit_token(i, seq, tok, now)
             # dropped here, not at return: freeing the device's logits
             # and the donated slabs' handles is the step's work too

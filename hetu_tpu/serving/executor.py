@@ -389,6 +389,19 @@ class InferenceExecutor:
             self._compiled[bucket] = fn
         return fn
 
+    def compiler_options(self):
+        """What the graph's nodes ask of THIS backend's compiler: the union
+        of every node's ``compiler_options[backend]`` (``ops.kda``: a chunk
+        scan wants the TPU's memory-space assignment off), handed to
+        ``jax.jit`` with the program.  Empty for a graph that asks
+        nothing, which is every graph on the CPU."""
+        import jax
+        asked = {}
+        for node in self.topo:
+            asked.update(getattr(node, "compiler_options", {}).get(
+                jax.default_backend(), {}))
+        return asked
+
     # -- inference ---------------------------------------------------------
 
     #: scatter-plan sentinel: batch-DERIVED but its leading dim does not

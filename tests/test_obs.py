@@ -184,6 +184,7 @@ def test_metrics_dump_roundtrips_every_counter_family():
     metrics.record_flash_fallback("test_reason")
     metrics.record_flash_call(512, 512, one_pass=True)
     metrics.record_decode_attn_call(10, 256)
+    metrics.record_moe_call(40, 320, 8)
     metrics.record_fault("test_fault", 2)
     metrics.record_elastic("elastic_shrink")
     metrics.record_concurrency("concurrency_preemptions")
@@ -212,6 +213,7 @@ def test_metrics_dump_roundtrips_every_counter_family():
         "flash_fallbacks": metrics.flash_fallback_counts(),
         "flash_calls": metrics.flash_call_counts(),
         "decode_attn_calls": metrics.decode_attn_call_counts(),
+        "moe_calls": metrics.moe_call_counts(),
         "emb_pallas_fallbacks": metrics.emb_pallas_fallback_counts(),
         "faults": metrics.fault_counts(),
         "elastic": metrics.elastic_counts(),
@@ -234,6 +236,7 @@ def test_metrics_dump_roundtrips_every_counter_family():
         assert dump["counters"][fam] == want, fam
     assert legacy["flash_calls"] == {"512x512:one_pass": 1}
     assert legacy["decode_attn_calls"] == {"10x256": 1}
+    assert legacy["moe_calls"] == {"40of320:top8:ragged": 1}
     assert legacy["faults"] == {"test_fault": 2}
     assert legacy["serve"]["serve_queue_depth_hw"] == 9
     assert legacy["decode"] == {"decode_tokens": 7,
