@@ -636,17 +636,25 @@ def reset_serve_counts():
 # skipped the (batch, vocab) logits D2H because no row was past its
 # prompt).  The step accounts for its own time (ISSUE 25), integer
 # microseconds summed over steps, the phases touching and not overlapping
-# from ``DecodeEngine.step``'s entry to its return:
+# inside one ``decode.step`` interval per loop iteration — under
+# ``DecodeEngine.step`` all six of one step; under the router, which
+# launches step n+1 before it collects step n (ISSUE 32), the first three
+# of the step launched and the last three of the step launched the
+# iteration before:
 #   ``decode_step_plan_us``      chunk pick, bucket growth, plan lookup
 #   ``decode_step_feed_us``      building the host feeds
-#   ``decode_step_dispatch_us``  the jitted call until it returns
-#   ``decode_step_wait_us``      until the logits are ready on the device
-#   ``decode_step_readback_us``  their (batch, vocab) D2H copy
-#   ``decode_step_host_us``      argmax, emission, callbacks, bookkeeping
-# (no wait/readback on a step that skips its logits; ``feed`` … ``host``
+#   ``decode_step_dispatch_us``  the jitted call until it returns, and the
+#                                host state the launch advances
+#   ``decode_step_wait_us``      until the token ids are ready on the device
+#   ``decode_step_readback_us``  their (batch,) D2H copy
+#   ``decode_step_host_us``      emission, callbacks, bookkeeping
+# (no wait/readback on a step that reads nothing back; ``feed`` … ``host``
 # is what the ``step`` latency histogram observes), and
-#   ``decode_between_steps_us``  the router loop from one step's return to
-#                                the next one's entry while rows are seated
+#   ``decode_launches_ahead``    steps launched while the step before was
+#                                still un-collected, over ``decode_steps``
+#                                (both count COLLECTED steps)
+#   ``decode_between_steps_us``  the router loop from one iteration's end to
+#                                the next one's start while rows are seated
 #   ``decode_join_wait_us``      submit -> seated, summed over
 #                                ``decode_joins`` (the ``join_wait``
 #                                histogram's observations)

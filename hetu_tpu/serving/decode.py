@@ -71,6 +71,15 @@ This module is the serving plane for that workload:
   argmax is deterministic — the same prompt decodes to the identical
   token stream whatever else shares the batch.
 
+* **One step ahead (ISSUE 32).**  Every step program hands back its
+  rows' greedy token ids and takes the previous step's as an operand, so
+  a generating row's next input never visits the host:
+  :meth:`DecodeEngine.launch` puts step n+1 on the device from host
+  state alone, :meth:`DecodeEngine.collect` reads a launched step's ids
+  back and emits them, and the router's loop calls them in that order —
+  the host's share of a step (read-back, emission, the clients'
+  callbacks, joins, the next plan) hides behind the chip's.
+
 * **Per-token streaming.**  :meth:`DecodeRouter.submit` returns a
   :class:`DecodeStream`: per-token ``concurrent.futures.Future``s
   (``stream.token(i)``), iteration (``for tok in stream``), and a
@@ -100,6 +109,7 @@ while acquiring the other, so the PR 14 witness hierarchy stays acyclic.
 from __future__ import annotations
 
 import collections
+import contextlib
 import itertools
 import threading
 import time
@@ -369,14 +379,43 @@ def _continuation(req):
 class _Sequence:
     """One in-flight sequence's slot state (router loop thread only)."""
 
-    __slots__ = ("req", "ptr", "emitted", "t_last", "fid")
+    __slots__ = ("req", "ptr", "launched", "emitted", "spent", "snap",
+                 "t_last", "fid")
 
     def __init__(self, req):
         self.req = req
         self.ptr = 0          # next prompt index to consume
-        self.emitted = 0
+        self.launched = 0     # tokens a step has been launched for
+        self.emitted = 0      # of those, tokens collected and on the stream
+        self.spent = False    # its last step is launched: no later one
+                              # carries it, its slot frees at that collect
+        self.snap = None      # prompt KV rows for the prefix store, taken
+                              # at the launch that ended the prompt
         self.t_last = time.monotonic()
         self.fid = None       # decode.join flow id (set at join)
+
+
+class _Launch:
+    """A step on the device whose answer the host has not read
+    (:meth:`DecodeEngine.launch` makes it, :meth:`DecodeEngine.collect`
+    consumes it).  ``rows``: per row it carried ``(slot, sequence, tokens
+    consumed, of them prompt tokens that emit nothing, emits)`` — the
+    pair ``(slot, sequence)`` is what :meth:`~DecodeEngine.collect` checks
+    before it believes the row's answer.  ``back``: the device arrays
+    whose copy to the host is under way (the token ids if a row emits,
+    then the auxiliary fetches), ``logits`` the (batch, vocab) logits
+    left on the device.  The rest is what the step's counters need:
+    whether a row emits, whether the step before was still un-collected,
+    the batch and chunk buckets, the KV rows ``(read, held)``."""
+
+    __slots__ = ("rows", "back", "logits", "emits", "ahead", "bb", "chunk",
+                 "kv_rows")
+
+    def __init__(self, rows, back, logits, emits, ahead, bb, chunk, kv_rows):
+        self.rows, self.back, self.logits, self.emits = \
+            rows, back, logits, emits
+        self.ahead, self.bb, self.chunk, self.kv_rows = \
+            ahead, bb, chunk, kv_rows
 
 
 class DecodeEngine:
@@ -424,12 +463,24 @@ class DecodeEngine:
     it finds.  ``decode_state_bytes_<kind>_hw`` gauge each kind,
     ``decode_kv_bytes_hw`` their sum.  ``prefix_store=`` and ``plan=``
     with ``ring`` or ``recurrent`` state raise at construction.
-    ``tokens=`` names a (B,) int32 fetch of each row's greedy token
-    (``chunked=`` then takes a fourth element, the chunked graph's): the
-    step brings back those ids and leaves the (B, vocab) logits on the
-    device (:attr:`last_logits` fetches them on request).
     :meth:`reserve` puts the engine at given buckets before the first
     request.
+
+    **The token loop closes on the device (ISSUE 32).**  Every step
+    program hands back each row's greedy token as a (B,) int32 array
+    beside the (B, vocab) logits: the graph's own fetch where ``tokens=``
+    names one (``chunked=`` then takes a fourth element, the chunked
+    graph's), else the ``argmax`` of the logits, computed by the engine's
+    program (first maximum, as ``np.argmax`` has).  A step brings back
+    those ids; the logits stay on the device and leave it only through
+    :attr:`last_logits`.  A generating row's next input id is the
+    PREVIOUS step's id array, an operand of the program that is never
+    donated: the host feeds ``-1`` for such a row and the program takes
+    the row's id from that array, so the next step can be launched before
+    the host has seen the token it consumes.  That is what
+    :meth:`launch` / :meth:`collect` are for (:meth:`step` is one after
+    the other); :class:`DecodeRouter` runs one step ahead of what it has
+    read back.
 
     **Auxiliary fetches (ISSUE 31).**  ``aux={name: node}`` names further
     fetches of the one-token graph, each ``(B, 1, ...)`` — something of
@@ -486,15 +537,15 @@ class DecodeEngine:
                 f"sequence consumes: one seated past a stored prefix would "
                 f"lack them for the positions it skipped — build the "
                 f"engine without prefix_store=")
-        #: fetches in front of the states: the greedy token ids when the
-        #: graph computes them (``tokens=``), then the logits, then the
-        #: auxiliary fetches
+        #: the graph's fetches in front of the states: the greedy token
+        #: ids where it computes them (``tokens=``), then the logits, then
+        #: the auxiliary fetches.  ``_program`` puts ids in front where
+        #: the graph has none, so a step's answer always starts ``(ids,
+        #: logits, *aux)``: ``_head`` entries
         head = ([logits] if tokens is None else [tokens, logits]) \
             + [aux[name] for name in self._aux]
-        self._head = len(head)
-        #: what ``outs[0]`` is: the greedy ids, or the logits they are the
-        #: argmax of (``_head`` counts the auxiliary fetches too)
-        self._device_tokens = tokens is not None
+        self._graph_tokens = tokens is not None
+        self._head = 2 + len(self._aux)
         self.iex = InferenceExecutor(
             head + list(cache_fetches), weights=weights,
             buckets=default_buckets(max_slots), mesh=mesh, seed=seed,
@@ -566,14 +617,19 @@ class DecodeEngine:
                        for name in self.cache_names}
         self._clear = None        # jitted zeroing of a slot's recurrent rows
         self._logits = None
+        #: steps launched and not collected, oldest first (two at most,
+        #: and only between a ``launch`` and the ``collect`` that follows)
+        self._flights = []
+        self._ids = None          # the newest launch's (bb,) device token ids
+        self._t_work = None       # where the open span's ``step`` sample starts
         self._note_kv_bytes()
 
     @property
     def last_logits(self):
-        """Host copy of the last step's (batch_bucket, vocab) logits, None
-        when no row read them — what a parity check compares.  With
-        ``tokens=`` the step brings back token ids only and the logits
-        stay on the device until this is asked for."""
+        """Host copy of the (batch_bucket, vocab) logits of the last step
+        COLLECTED, None when no row of it emitted — what a parity check
+        compares.  A step brings back token ids only; the logits stay on
+        the device until this is asked for."""
         if self._logits is not None \
                 and not isinstance(self._logits, np.ndarray):
             self._logits = np.asarray(self._logits)
@@ -681,7 +737,14 @@ class DecodeEngine:
 
     @property
     def idle(self):
-        return self.active == 0
+        """Nothing seated and no step to collect (an ``eos_id`` hit can
+        leave the step launched after it in flight with no row seated)."""
+        return self.active == 0 and not self._flights
+
+    @property
+    def in_flight(self):
+        """The newest step launched and not collected, or None."""
+        return self._flights[-1] if self._flights else None
 
     def capacity(self):
         """Free sequence slots, counting batch-ladder headroom."""
@@ -807,11 +870,17 @@ class DecodeEngine:
             seq.fid = _TR.flow_begin("decode.join", cat="decode")
         return slot
 
-    def _leave(self, slot):
-        seq = self.slots[slot]
+    def _vacate(self, slot):
+        """Slot ``slot`` holds no sequence from now on.  A step in flight
+        that carried one there finds the pair broken at its ``collect``
+        and drops the row's answer."""
         self.slots[slot] = None
         self.tokens[slot] = 0
         self.positions[slot] = 0
+
+    def _leave(self, slot):
+        seq = self.slots[slot]
+        self._vacate(slot)
         record_decode("decode_leaves")
         seq.req.stream._finish(seq.req.epoch)
 
@@ -821,11 +890,12 @@ class DecodeEngine:
         door already migrated to a survivor ignores this replica's
         abort — closing a dead replica must not kill its rescued
         streams."""
+        # a step in flight is dropped with its rows: nothing of it was
+        # emitted, and ``caches`` already holds its output handles
+        self._flights.clear()
         for i, seq in enumerate(self.slots):
             if seq is not None:
-                self.slots[i] = None
-                self.tokens[i] = 0
-                self.positions[i] = 0
+                self._vacate(i)
                 seq.req.stream._fail(exc, seq.req.epoch)
 
     def evict_expired(self, now=None):
@@ -834,18 +904,17 @@ class DecodeEngine:
         remaining token futures fail fast with
         ``ServeRejected('deadline')`` and the KV slot frees for the next
         join — instead of a stalled consumer holding a decode slot until
-        ``max_new``.  Counted as ``decode_deadline_evictions``.  Router
-        loop thread only, like every engine call.  Returns the number
-        evicted."""
+        ``max_new``.  Counted as ``decode_deadline_evictions``.  A step in
+        flight that carries the sequence drops its answer at
+        :meth:`collect`.  Router loop thread only, like every engine
+        call.  Returns the number evicted."""
         now = time.monotonic() if now is None else now
         evicted = 0
         for i, seq in enumerate(self.slots):
             if seq is None or seq.req.deadline is None:
                 continue
             if now >= seq.req.deadline:
-                self.slots[i] = None
-                self.tokens[i] = 0
-                self.positions[i] = 0
+                self._vacate(i)
                 record_decode("decode_leaves")
                 record_decode("decode_deadline_evictions")
                 seq.req.stream._fail(ServeRejected(
@@ -858,7 +927,7 @@ class DecodeEngine:
     # -- the decode step ---------------------------------------------------
 
     def _program(self, ex, fk):
-        """``fn(params, (feeds, slabs))`` over executor ``ex``: its
+        """``fn(params, (feeds, slabs), prev)`` over executor ``ex``: its
         serving step with the KV slabs handed over as a TUPLE in
         ``cache_names`` order — the order of the step's cache fetches.
         A donated input is paired with the first output of its shape, in
@@ -867,14 +936,32 @@ class DecodeEngine:
         ``s16``), each paired with ANOTHER layer's output, and the
         compiler has to copy nearly every slab, whole, in every step to
         honour the pairing.  In a tuple, slab ``i`` is donated to
-        updated slab ``i`` and the append is in place.  Captures the
-        keys, never the engine (the serve cache keeps it alive)."""
+        updated slab ``i`` and the append is in place.
+
+        ``prev`` is the (batch,) int32 token ids the step before handed
+        back, its own argument so that it is NOT donated (the host is
+        still reading it): where the host feeds ``-1`` in a row's first
+        column — a generating row whose token it has not seen — the row
+        takes ``prev``'s id, one ``select`` in front of the graph.  The
+        answer starts ``(ids, logits, *aux)`` for every graph: where the
+        graph fetches no ids they are the ``argmax`` of its logits, first
+        maximum.  Captures the keys, never the engine (the serve cache
+        keeps it alive)."""
+        import jax.numpy as jnp
         infer = ex._infer_fn()
         keys = [fk[name] for name in self.cache_names]
+        ids_key, graph_tokens = fk["input_ids"], self._graph_tokens
 
-        def step(params, fed):
+        def step(params, fed, prev):
             feeds, slabs = fed
-            return infer(params, {**feeds, **dict(zip(keys, slabs))})
+            ids = jnp.asarray(feeds[ids_key])
+            first = ids[:, 0]
+            ids = ids.at[:, 0].set(jnp.where(first < 0, prev, first))
+            outs = infer(params, {**feeds, ids_key: ids,
+                                  **dict(zip(keys, slabs))})
+            if not graph_tokens:
+                outs = [jnp.argmax(outs[0], axis=-1).astype(jnp.int32)] + outs
+            return outs
 
         return step
 
@@ -943,8 +1030,8 @@ class DecodeEngine:
         return c
 
     def _emit_token(self, i, seq, tok, now):
-        """Post-argmax bookkeeping shared by the one-token and chunked
-        paths: counters, latency (``token`` + first-token ``ttft``),
+        """What a collected token sets off, one-token and chunked steps
+        alike: counters, latency (``token`` + first-token ``ttft``),
         prefix-snapshot insert, stream emission, and the done check.
         Returns 1 (one token emitted), or 0 when the stream's replay
         epoch fenced the emission — the stream migrated to a survivor
@@ -952,9 +1039,7 @@ class DecodeEngine:
         dropped without touching the stream (exactly-once delivery)."""
         count = seq.req.stream._emit(tok, seq.req.epoch)
         if count is False:
-            self.slots[i] = None
-            self.tokens[i] = 0
-            self.positions[i] = 0
+            self._vacate(i)
             record_decode("decode_leaves")
             record_decode_recovery("decode_recovery_fenced")
             return 0
@@ -968,206 +1053,288 @@ class DecodeEngine:
             # exactly once, anchored to the original submit
             record_decode_latency(
                 "ttft", (now - seq.req.t_arrival) * 1e6)
-        if seq.emitted == 1 and self.prefix is not None:
-            self._prefix_insert(i, seq)
+        if seq.snap is not None:
+            self.prefix.insert(seq.req.prompt, seq.snap)
+            seq.snap = None
         seq.t_last = now
         if _TR.on and seq.fid is not None:
             _TR.flow_end("decode.join", seq.fid, cat="decode")
             seq.fid = None
-        self.tokens[i] = tok
-        done = (seq.emitted >= seq.req.max_new
-                or (seq.req.eos_id is not None
-                    and tok == seq.req.eos_id))
-        if not done and int(self.positions[i]) >= self.max_len:
-            done = True     # cache exhausted: stop cleanly
-        if done:
+        if seq.emitted == seq.launched and not seq.spent:
+            # no later launch carries the row: its next step is fed this
+            # id by the host (a later launch took it on the device)
+            self.tokens[i] = tok
+        # done by ``max_new`` or the cache's end was known at the launch
+        # (``spent``); an ``eos_id`` match only now, with the row maybe
+        # carried by the step in flight, whose answer ``collect`` drops
+        if (seq.spent and seq.emitted == seq.launched) or (
+                seq.req.eos_id is not None and tok == seq.req.eos_id):
             self._leave(i)
         return 1
 
-    def _prefix_insert(self, i, seq):
-        """Snapshot slot ``i``'s prompt KV rows into the prefix store —
-        called at the FIRST generated token, when rows ``0..P-1`` hold
-        exactly the prompt's KV (the sampled token is not yet written)
-        and, by the masked-append invariant, the same bytes whatever
-        ingestion path produced them."""
+    def _prefix_rows(self, i, seq):
+        """Slot ``i``'s prompt KV rows for the prefix store, sliced on
+        the device from the outputs of the launch that ended the prompt:
+        rows ``0..P-1`` then hold exactly the prompt's KV (the sampled
+        token is not yet written) and, by the masked-append invariant,
+        the same bytes whatever ingestion path produced them.  They go
+        into the store at the step's ``collect``, with its first token —
+        once the step is known to have run — whatever a later launch has
+        written to the slot by then.  None for a prompt the store does
+        not keep."""
         p = len(seq.req.prompt)
         if p < self.prefix.min_tokens:
-            return
+            return None
         from ..ops.attention import kv_slab_to_rows
-        rows = {name: kv_slab_to_rows(
+        return {name: kv_slab_to_rows(
             self.caches[name][i, :, :self._slab_rows(p), :],
             self._head_dim)[:, :p, :] for name in self.cache_names}
-        self.prefix.insert(seq.req.prompt, rows)
+
+    @contextlib.contextmanager
+    def stepping(self):
+        """The records of one loop iteration: a ``decode.step`` span for
+        the :meth:`launch` and the :meth:`collect` made inside it, in
+        that order, and a ``step`` latency sample from the first
+        boundary past ``plan`` to the end.  Its arguments: the step
+        launched (``rows`` 0 where none was) and the tokens collected."""
+        self._t_work = None
+        with _Phases("decode.step", record_decode, _STEP_PHASES,
+                     cat="decode") as ph:
+            ph.args = {"batch": self.bb, "len": self.lb, "chunk": 0,
+                       "rows": 0, "prefill": 0, "emitted": 0}
+            yield ph
+        if self._t_work is not None:
+            record_decode_latency("step", (ph.t1 - self._t_work) / 1e3)
+
+    def _mark_work(self, ph, phase):
+        t = ph.mark(phase)
+        if self._t_work is None:
+            self._t_work = t
 
     def step(self):
-        """Decode ONE batch step: every active slot consumes its pending
-        token(s), caches append in place, rows past their prompt emit.
+        """Decode ONE batch step, start to end: :meth:`launch` it,
+        :meth:`collect` it.  Every active slot consumes its pending
+        token(s), caches append in place, rows past their prompt emit,
+        and the tokens are on their streams when this returns.  Returns
+        the number of tokens emitted.
+
         With a chunked entry, steps where some row still owes multiple
         prompt tokens run the q_len=C chunked path (each active row
         consumes up to ``chunk`` pending tokens — its prompt remainder,
         or its one generated token at column 0 — and the caches take a
         masked multi-row append); otherwise the PR 16 one-token path
-        runs.  Only rows that finished their prompt read logits — a
-        pure-prefill step skips the D2H entirely.  Returns the number of
-        tokens emitted.
+        runs.  Only a step in which some row finished its prompt reads
+        anything back — a pure-prefill step skips the D2H entirely.
 
-        The step accounts for its own time (ISSUE 25): from entry to
-        return it is cut into the phases ``plan`` (chunk pick, bucket
-        growth, plan lookup), ``feed`` (host feeds), ``dispatch`` (the
-        jitted call until it returns), ``wait`` (until the logits are
-        ready on the device), ``readback`` (their D2H) and ``host``
-        (argmax, emission, stream callbacks, bookkeeping) — see
+        The step accounts for its own time (ISSUE 25): it is cut into
+        the phases ``plan`` (chunk pick, bucket growth, plan lookup),
+        ``feed`` (host feeds), ``dispatch`` (the jitted call until it
+        returns, and the host state the launch advances) — these three
+        are :meth:`launch` — and ``wait`` (until the token ids are ready
+        on the device), ``readback`` (their D2H) and ``host`` (emission,
+        stream callbacks, bookkeeping), which are :meth:`collect` — see
         :class:`~hetu_tpu.obs.trace.Phases` for the three records each
         boundary feeds.  The ``step`` latency histogram keeps its
-        boundaries: ``feed`` … ``host``."""
-        active = [i for i, s in enumerate(self.slots) if s is not None]
-        if not active:
+        boundaries: ``feed`` … ``host``.
+
+        :class:`DecodeRouter` makes the same two calls in another order
+        (ISSUE 32): inside one :meth:`stepping` span it launches step
+        n+1 and THEN collects step n, so the device runs n+1 while the
+        host reads n back, emits and runs the clients' callbacks.  Its
+        span's first three phases are of the step launched, the last
+        three of the step launched the iteration before; their sum is
+        still the interval between two tokens of a stream."""
+        if self.idle:
             return 0
-        with _Phases("decode.step", record_decode, _STEP_PHASES,
-                     cat="decode", rows=len(active)) as ph:
-            ph.mark("plan")
-            chunk = self._pick_chunk(active)
-            # rows still taking in their prompt (more than its last token
-            # is owed), beside the rows that generate
-            prefill = sum(len(self.slots[i].req.prompt) - self.slots[i].ptr
-                          > 1 for i in active)
-            ph.meta(chunk=chunk, prefill=prefill)
-            self._grow_len_if_needed(span=chunk)
-            kv_read, kv_held = self._kv_rows(chunk)
-            if chunk > 1:
-                fn, ex, fk = self._chunk_step_fn(chunk), self.ciex, self._cfk
-            else:
-                fn, ex, fk = self._step_fn(), self.iex, self._fk
-            t0 = ph.mark("feed")
-            # fed as COPIES: jax's CPU client may alias an aligned numpy
-            # feed zero-copy, and the engine mutates tokens/positions
-            # right after dispatch — without the logits D2H sync (skipped
-            # on pure-prefill steps) an aliased feed would race the
-            # device read
-            if chunk > 1:
-                ids = np.zeros((self.bb, chunk), np.int32)
-                consume = np.zeros(self.bb, np.int32)
-                for i in active:
-                    seq = self.slots[i]
-                    rem = len(seq.req.prompt) - seq.ptr
-                    if rem > 0:
-                        n = min(rem, chunk)
-                        ids[i, :n] = seq.req.prompt[seq.ptr:seq.ptr + n]
-                    else:
-                        n = 1
-                        ids[i, 0] = self.tokens[i]
-                    consume[i] = n
-                feeds = {fk["input_ids"]: ids,
-                         fk["positions"]: self.positions.copy(),
-                         fk["valid"]: consume}
-            else:
-                consume = [1] * self.bb
-                feeds = {
-                    fk["input_ids"]: self.tokens.reshape(self.bb, 1).copy(),
-                    fk["positions"]: self.positions.copy()}
-            # the caches are DONATED device arrays fed straight back from
-            # the previous step's fetches — no host round-trip
-            # (_place_feed's np.asarray would force one, so the engine
-            # bypasses infer_rows) — in the fetches' own order
-            # (``_program``)
-            slabs = tuple(self.caches[name] for name in self.cache_names)
-            ph.mark("dispatch")
-            with warnings.catch_warnings():
-                # ids/positions are int32 inputs with no matching output
-                # buffer; only the caches can (and do) donate
-                warnings.filterwarnings(
-                    "ignore", message="Some donated buffers were not usable")
-                outs = fn(ex.params, (feeds, slabs))
-            # the D2H is paid only when some row will read it — a
-            # pure-prefill step never looks at outs[0] (ISSUE 18
-            # satellite).  What comes back is the (batch, vocab) logits,
-            # or with ``tokens=`` the (batch,) greedy token ids the
-            # program computed from them: the logits then stay where
-            # they are (``last_logits`` fetches them on request)
-            emits = any(self.slots[i].ptr + int(consume[i])
-                        >= len(self.slots[i].req.prompt) for i in active)
-            # the auxiliary fetches are of every consumed token: read
-            # whether or not a row emits
-            back = ([outs[0]] if emits else []) + list(
-                outs[self._head - len(self._aux):self._head])
-            if back:
-                # the D2H is queued behind the step NOW, as np.asarray
-                # alone would queue it: waiting for the result first and
-                # asking for the copy after costs a host wake-up and a
-                # transfer dispatch per step with the chip idle
-                for out in back:
-                    out.copy_to_host_async()
-                ph.mark("wait")
-                back[-1].block_until_ready()
-                ph.mark("readback")
-                back = [np.asarray(out) for out in back]
-                read = back[0] if emits else None
-                aux = dict(zip(self._aux, back[len(back) - len(self._aux):]))
-                for name, fold in self._aux_fold.items():
-                    for counter, n in fold(aux[name]).items():
-                        record_decode(counter, n)
-                ph.mark("host")
-            else:
-                ph.mark("host")
-                read, aux = None, {}
-            if emits:
-                self._logits = outs[1] if self._device_tokens else read
-            else:
-                self._logits = None
-                record_decode("decode_logits_skipped")
-            for name, new in zip(self.cache_names, outs[self._head:]):
-                self.caches[name] = new
-            record_decode("decode_steps")
-            # every row of the batch bucket computes ``chunk`` tokens,
-            # whatever it holds: the denominator of the padding share
-            record_decode("decode_padded_row_tokens", self.bb * chunk)
-            record_decode("decode_kv_rows_read", kv_read)
-            record_decode("decode_kv_rows_held", kv_held)
-            if chunk > 1:
-                record_decode("decode_prefill_steps")
-                record_decode("decode_chunk_width", chunk)
-                # dispatches saved vs token-by-token: the widest row
-                # would have needed max(consume) one-token steps; this
-                # step is one
-                record_decode("decode_prefill_steps_saved",
-                              int(consume.max()) - 1)
-            emitted = 0
-            now = time.monotonic()
-            for i in active:
+        with self.stepping() as ph:
+            self.launch(ph)
+            # the one just launched — and, for a caller that takes over
+            # a router's engine, the one it left
+            return sum(self.collect(fl, ph) for fl in list(self._flights))
+
+    def launch(self, ph):
+        """Put the next step on the device and return without waiting
+        for it (a :class:`_Launch`, which :meth:`collect` takes), or None
+        where no seated row has a step to make — or where the batch
+        bucket changed since the step in flight, whose ids are of the old
+        shape: collect that one first.  ``ph`` is the open
+        :meth:`stepping` span.
+
+        Everything read here is host state that a launch itself advances:
+        ``positions``, a row's prompt pointer, the count of tokens it has
+        been launched for — so a row done by ``max_new`` or by the cache's
+        end is known here, without its token's value, and no later launch
+        carries it (``spent``; its slot frees at the collect).  A
+        generating row whose last token is still in flight is fed ``-1``:
+        the program takes its id from the previous launch's id array
+        (:meth:`_program`).  The state arrays are the previous launch's
+        output handles, donated on: queued behind it on the device."""
+        rows = [i for i, s in enumerate(self.slots)
+                if s is not None and not s.spent]
+        ahead = bool(self._flights)
+        if not rows or (ahead and self._flights[-1].bb != self.bb):
+            return None
+        ph.mark("plan")
+        chunk = self._pick_chunk(rows)
+        # rows still taking in their prompt (more than its last token
+        # is owed), beside the rows that generate
+        prefill = sum(len(self.slots[i].req.prompt) - self.slots[i].ptr
+                      > 1 for i in rows)
+        ph.meta(rows=len(rows), chunk=chunk, prefill=prefill)
+        self._grow_len_if_needed(span=chunk)
+        kv_rows = self._kv_rows(chunk)
+        if chunk > 1:
+            fn, ex, fk = self._chunk_step_fn(chunk), self.ciex, self._cfk
+        else:
+            fn, ex, fk = self._step_fn(), self.iex, self._fk
+        self._mark_work(ph, "feed")
+        # fed as COPIES: jax's CPU client may alias an aligned numpy
+        # feed zero-copy, and the engine mutates tokens/positions right
+        # after dispatch, with the device still reading
+        if chunk > 1:
+            ids = np.zeros((self.bb, chunk), np.int32)
+            consume = np.zeros(self.bb, np.int32)
+            for i in rows:
                 seq = self.slots[i]
-                n = int(consume[i])
-                self.positions[i] += n
-                if aux:
-                    seq.req.stream._note_aux(
-                        {name: a[i, :n] for name, a in aux.items()},
-                        seq.req.epoch)
-                plen = len(seq.req.prompt)
-                if seq.ptr + n < plen:
-                    # still mid-prompt: next prompt token, nothing to
-                    # emit yet
-                    seq.ptr += n
-                    self.tokens[i] = seq.req.prompt[seq.ptr]
-                    record_decode("decode_prefill_rows", n)
-                    continue
-                # prompt finished this step (n-1 of the consumed tokens
-                # were prefill rows, the last is the generate row) or the
-                # row was already generating (n == 1, zero prefill rows)
-                record_decode("decode_prefill_rows",
-                              (plen - seq.ptr - 1) if seq.ptr < plen else 0)
-                seq.ptr = plen
-                # this row's logits are live: greedy argmax
-                # (deterministic first-max tie-break keeps decode bitwise
-                # stable)
-                tok = int(read[i] if self._device_tokens
-                          else np.argmax(read[i]))
-                emitted += self._emit_token(i, seq, tok, now)
-            # dropped here, not at return: freeing the device's logits
-            # and the donated slabs' handles is the step's work too
-            del feeds, slabs, outs
-            ph.args = {"batch": self.bb, "len": self.lb, "chunk": chunk,
-                       "rows": len(active), "prefill": prefill,
-                       "emitted": emitted}
-        record_decode_latency("step", (ph.t1 - t0) / 1e3)
+                rem = len(seq.req.prompt) - seq.ptr
+                if rem > 0:
+                    n = min(rem, chunk)
+                    ids[i, :n] = seq.req.prompt[seq.ptr:seq.ptr + n]
+                else:
+                    n = 1
+                    ids[i, 0] = self.tokens[i]
+                consume[i] = n
+            feeds = {fk["input_ids"]: ids,
+                     fk["positions"]: self.positions.copy(),
+                     fk["valid"]: consume}
+        else:
+            consume = [1] * self.bb
+            feeds = {
+                fk["input_ids"]: self.tokens.reshape(self.bb, 1).copy(),
+                fk["positions"]: self.positions.copy()}
+        # the caches are DONATED device arrays fed straight back from
+        # the previous launch's fetches — no host round-trip
+        # (_place_feed's np.asarray would force one, so the engine
+        # bypasses infer_rows) — in the fetches' own order
+        # (``_program``); the previous ids likewise, not donated
+        slabs = tuple(self.caches[name] for name in self.cache_names)
+        prev = self._ids
+        if prev is None or prev.shape != (self.bb,):
+            prev = np.zeros(self.bb, np.int32)     # no row asks for one
+        ph.mark("dispatch")
+        with warnings.catch_warnings():
+            # ids/positions are int32 inputs with no matching output
+            # buffer; only the caches can (and do) donate
+            warnings.filterwarnings(
+                "ignore", message="Some donated buffers were not usable")
+            outs = fn(ex.params, (feeds, slabs), prev)
+        self._ids = outs[0]
+        for name, new in zip(self.cache_names, outs[self._head:]):
+            self.caches[name] = new
+        took, emits = [], False
+        for i in rows:
+            seq = self.slots[i]
+            n = int(consume[i])
+            self.positions[i] += n
+            plen = len(seq.req.prompt)
+            if seq.ptr + n < plen:
+                # still mid-prompt: next prompt token, nothing to emit
+                seq.ptr += n
+                self.tokens[i] = seq.req.prompt[seq.ptr]
+                took.append((i, seq, n, n, False))
+                continue
+            # prompt finished this step (n-1 of the consumed tokens were
+            # prefill rows, the last is the generate row) or the row was
+            # already generating (n == 1, zero prefill rows)
+            took.append((i, seq, n, max(0, plen - seq.ptr - 1), True))
+            seq.ptr = plen
+            seq.launched += 1
+            emits = True
+            if seq.launched == 1 and self.prefix is not None:
+                seq.snap = self._prefix_rows(i, seq)
+            if seq.launched >= seq.req.max_new \
+                    or int(self.positions[i]) >= self.max_len:
+                # its last token (or the cache exhausted: stop cleanly):
+                # an idle slot to the device from the next launch on
+                seq.spent = True
+                self.tokens[i] = self.positions[i] = 0
+            else:
+                self.tokens[i] = -1
+        # the D2H is paid only when some row will read it — a
+        # pure-prefill step never looks at the ids (ISSUE 18 satellite)
+        # — and the auxiliary fetches are of every consumed token: read
+        # whether or not a row emits.  It is queued behind the step NOW,
+        # as np.asarray alone would queue it: waiting for the result
+        # first and asking for the copy after costs a host wake-up and a
+        # transfer dispatch per step with the chip idle
+        back = ([outs[0]] if emits else []) + list(outs[2:self._head])
+        for out in back:
+            out.copy_to_host_async()
+        fl = _Launch(took, back, outs[1], emits, ahead, self.bb, chunk,
+                     kv_rows)
+        self._flights.append(fl)
+        ph.args.update(batch=self.bb, len=self.lb, chunk=chunk,
+                       rows=len(rows), prefill=prefill)
+        return fl
+
+    def collect(self, fl, ph):
+        """Wait for launched step ``fl``, read its token ids (and
+        auxiliary fetches) back and act on them: emission, the clients'
+        callbacks, latency records, ``aux_fold``, the prefix store, the
+        step's counters (``decode_steps`` and the rest count COLLECTED
+        steps).  A row is believed only while its slot still holds the
+        sequence the launch carried there: one that left since — an
+        ``eos_id`` matched at the collect before, a deadline eviction, a
+        fenced seat — is skipped, its token never emitted; the cache row
+        it wrote lies past what a later occupant reads before
+        overwriting it.  A device error of the step surfaces here.
+        Returns the number of tokens emitted (0 for ``fl`` None)."""
+        if fl is None:
+            return 0
+        self._flights.remove(fl)
+        read, aux = None, {}
+        if fl.back:
+            self._mark_work(ph, "wait")
+            fl.back[-1].block_until_ready()
+            ph.mark("readback")
+            back = [np.asarray(out) for out in fl.back]
+            read = back[0] if fl.emits else None
+            aux = dict(zip(self._aux, back[len(back) - len(self._aux):]))
+            for name, fold in self._aux_fold.items():
+                for counter, n in fold(aux[name]).items():
+                    record_decode(counter, n)
+        self._mark_work(ph, "host")
+        self._logits = fl.logits if fl.emits else None
+        if not fl.emits:
+            record_decode("decode_logits_skipped")
+        record_decode("decode_steps")
+        if fl.ahead:
+            record_decode("decode_launches_ahead")
+        # every row of the batch bucket computes ``chunk`` tokens,
+        # whatever it holds: the denominator of the padding share
+        record_decode("decode_padded_row_tokens", fl.bb * fl.chunk)
+        record_decode("decode_kv_rows_read", fl.kv_rows[0])
+        record_decode("decode_kv_rows_held", fl.kv_rows[1])
+        if fl.chunk > 1:
+            record_decode("decode_prefill_steps")
+            record_decode("decode_chunk_width", fl.chunk)
+            # dispatches saved vs token-by-token: the widest row would
+            # have needed that many one-token steps; this step is one
+            record_decode("decode_prefill_steps_saved",
+                          max(row[2] for row in fl.rows) - 1)
+        emitted = 0
+        now = time.monotonic()
+        for i, seq, n, pre, emits in fl.rows:
+            if self.slots[i] is not seq:
+                continue
+            if aux:
+                seq.req.stream._note_aux(
+                    {name: a[i, :n] for name, a in aux.items()},
+                    seq.req.epoch)
+            record_decode("decode_prefill_rows", pre)
+            if emits:
+                emitted += self._emit_token(i, seq, int(read[i]), now)
+        ph.args["emitted"] += emitted
         return emitted
 
 
@@ -1484,6 +1651,24 @@ class DecodeRouter:
                 self.hb_ts = time.monotonic()   # idle loop still beats
                 self._cv.wait(0.05)
 
+    def _step_ahead(self):
+        """One iteration of the loop on the engine: launch step n+1,
+        THEN collect step n (ISSUE 32).  The device runs n+1 — queued
+        behind n before n ended, so its launch latency hides too — while
+        this thread reads n's token ids back, emits, runs the clients'
+        callbacks (a closed-loop client's next ``submit`` among them),
+        and comes round to take joins and plan n+2.  The first step after
+        an idle engine is launched with nothing to collect; where nothing
+        can be launched (every seated row's last step is in flight, or
+        the batch bucket changed) the step in flight is collected alone.
+        A ``kill`` or a detach between the two calls loses an
+        un-collected step and nothing else: journals hold emitted tokens
+        only."""
+        with self.engine.stepping() as ph:
+            before = self.engine.in_flight
+            self.engine.launch(ph)
+            return self.engine.collect(before, ph)
+
     def _loop(self):
         # open from one step's return to the next one's entry while rows
         # stay seated (only this thread seats or evicts, so a step
@@ -1523,7 +1708,7 @@ class DecodeRouter:
                     self.engine.evict_expired()
                     if between is not None:
                         between.close()
-                    emitted = self.engine.step()
+                    emitted = self._step_ahead()
                 except Exception as e:    # noqa: BLE001 — every in-flight
                     self.engine.abort(e)  # stream must learn its fate; the
                                           # router keeps serving new work

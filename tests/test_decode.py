@@ -761,7 +761,8 @@ def test_one_token_step_is_cheaper_than_a_reprefill(wide_engine, length):
             eng._fk["positions"]: jax.ShapeDtypeStruct((1,), jnp.int32)},
            slabs)
     step = jax.jit(eng._program(eng.iex, eng._fk)).lower(
-        jax.tree_util.tree_map(sds, eng.iex.params), fed).compile()
+        jax.tree_util.tree_map(sds, eng.iex.params), fed,
+        jax.ShapeDtypeStruct((1,), jnp.int32)).compile()
 
     f2, _loss, logits2 = gpt2_lm_graph(_wide_cfg(length))
     full = InferenceExecutor([logits2], buckets=(1,), seed=0,
@@ -832,7 +833,7 @@ def test_an_all_kv_graph_is_allocated_grown_and_donated_as_before():
     cf, cl, cc, _ = gpt2_decode_chunked_graph(cfg, max_len=32)
     eng = DecodeEngine(feeds, logits, caches, max_slots=4, max_len=32,
                        seed=0, chunked=(cf, cl, cc), max_chunk=8)
-    assert set(eng._kinds.values()) == {"kv"} and eng._head == 1
+    assert set(eng._kinds.values()) == {"kv"} and eng._head == 2
     heads, lanes = eng._heads, eng._lanes
     n = len(eng.cache_names)
 
@@ -863,7 +864,8 @@ def test_an_all_kv_graph_is_allocated_grown_and_donated_as_before():
             eng._fk["positions"]: np.zeros(4, np.int32)},
            tuple(eng.caches[k] for k in eng.cache_names))
     text = jax.jit(eng._program(eng.iex, eng._fk),
-                   donate_argnums=(1,)).lower(eng.iex.params, fed).as_text()
+                   donate_argnums=(1,)).lower(
+                       eng.iex.params, fed, np.zeros(4, np.int32)).as_text()
     assert text.count("tf.aliasing_output") >= n
     # one set of weight buffers under both entries
     one = {eng.iex.var_names[v]: eng.iex.params[eng.iex._k(v)]
@@ -871,3 +873,124 @@ def test_an_all_kv_graph_is_allocated_grown_and_donated_as_before():
     two = {eng.ciex.var_names[v]: eng.ciex.params[eng.ciex._k(v)]
            for v in eng.ciex.var_nodes}
     assert one.keys() == two.keys() and all(one[k] is two[k] for k in one)
+
+
+# ------------------------------------------- one step ahead (ISSUE 32)
+# The router launches step n+1 before it collects step n; a loop of
+# ``engine.step()`` collects each step before the next.  Same programs,
+# same tokens.
+
+_AHEAD_SPECS = [([5, 9, 13], 8, None), ([2], 3, None),
+                ([7, 3, 11, 4, 1, 8, 6, 2, 9], 6, None), ([1, 1], 1, None),
+                ([9, 4, 1, 8], 10, None), ([3, 3, 3, 3, 3], 7, None)]
+
+
+def _ahead_engine(entry, decode_graph, **kw):
+    from hetu_tpu.models import gpt2_decode_chunked_graph
+    if entry == "chunked":
+        cf, cl, cc, _ = gpt2_decode_chunked_graph(_CFG, max_len=_MAX_LEN)
+        kw.update(chunked=(cf, cl, cc), max_chunk=4)
+    return _engine(decode_graph, **kw)
+
+
+@pytest.mark.parametrize("entry", ["one_token", "chunked"])
+def test_router_one_step_ahead_emits_the_serial_loops_streams(
+        decode_graph, entry):
+    """GPT-2's graphs fetch no token: the ids are the engine's own argmax
+    on the device.  Six requests through two slots (slot reuse, a
+    ``max_new`` of one, a batch bucket that grows under a step in flight):
+    the router's streams are the serial loop's, bit for bit."""
+    from decode_ahead import assert_same_streams
+    serial, ahead = assert_same_streams(
+        lambda: _ahead_engine(entry, decode_graph, max_slots=2),
+        _AHEAD_SPECS)
+    assert (ahead.get("decode_prefill_steps", 0) > 0) == (entry == "chunked")
+    assert ahead["decode_slot_recycles"] == serial["decode_slot_recycles"] \
+        == len(_AHEAD_SPECS) - 2
+
+
+def test_the_engines_own_ids_are_the_first_maximum_of_the_logits(
+        decode_graph):
+    """What replaced the host's ``np.argmax``: the program's ids are the
+    argmax of the logits it leaves on the device, first maximum on a tie,
+    and a row fed ``-1`` takes the previous step's id."""
+    import jax.numpy as jnp
+    eng = _engine(decode_graph, max_slots=2)
+    eng.reserve(2, 4)
+    fed = ({eng._fk["input_ids"]: np.array([[7], [-1]], np.int32),
+            eng._fk["positions"]: np.zeros(2, np.int32)},
+           tuple(eng.caches[n] for n in eng.cache_names))
+    outs = eng._program(eng.iex, eng._fk)(
+        eng.iex.params, fed, jnp.asarray([3, 7], jnp.int32))
+    ids, logits = np.asarray(outs[0]), np.asarray(outs[1])
+    assert ids.dtype == np.int32 and ids.shape == (2,)
+    assert np.array_equal(ids, np.argmax(logits, -1))
+    # row 1 was fed -1 and took id 7 from ``prev``: the same row as row 0
+    assert np.array_equal(logits[0], logits[1])
+    tie = jnp.asarray([[0.0, 2.0, 2.0, 1.0], [5.0, 5.0, 5.0, 5.0]])
+    assert jnp.argmax(tie, -1).tolist() == np.argmax(tie, -1).tolist() \
+        == [1, 0]
+
+
+def test_eos_hit_with_a_step_in_flight_drops_the_stray_token(decode_graph):
+    """``eos_id`` matches at the collect of step n with step n+1 launched
+    and carrying the row: that answer is dropped (one step more than the
+    serial loop, not one token more), and the slot's next occupant decodes
+    as if alone."""
+    from decode_ahead import serve_router, serve_serial
+    a, b = [5, 9, 13], [9, 4, 1, 8]
+    (free, after), _ = serve_serial(
+        _engine(decode_graph, max_slots=1), [(a, 10, None), (b, 6, None)])
+    toks = free.result(0)
+    k = max(i for i in range(len(toks) - 1) if toks[i] not in toks[:i])
+    specs = [(a, 10, toks[k]), (b, 6, None)]
+    serial, c_serial = serve_serial(_engine(decode_graph, max_slots=1), specs)
+    ahead, c_ahead = serve_router(_engine(decode_graph, max_slots=1), specs)
+    for s in (serial, ahead):
+        assert s[0].result(0) == toks[:k + 1]
+        assert s[1].result(0) == after.result(0)
+    assert c_ahead["decode_tokens"] == c_serial["decode_tokens"] == k + 1 + 6
+    assert c_ahead["decode_steps"] == c_serial["decode_steps"] + 1
+    assert c_ahead["decode_leaves"] == c_serial["decode_leaves"] == 2
+
+
+def test_no_launch_carries_a_row_done_by_max_new(decode_graph):
+    """``max_new`` is known when a step is launched: the row's last step
+    is its last launch, so the router makes exactly the serial loop's
+    steps, every one but the first launched ahead."""
+    from decode_ahead import serve_router, serve_serial
+    specs = [([5, 9, 13], 8, None)]
+    _, c_serial = serve_serial(_engine(decode_graph, max_slots=1), specs)
+    _, c_ahead = serve_router(_engine(decode_graph, max_slots=1), specs)
+    assert c_ahead["decode_steps"] == c_serial["decode_steps"] == 3 + 8 - 1
+    assert c_ahead["decode_padded_row_tokens"] \
+        == c_serial["decode_padded_row_tokens"]
+    assert c_ahead["decode_launches_ahead"] == c_ahead["decode_steps"] - 1
+
+
+def test_device_error_at_collect_with_a_later_step_launched(decode_graph):
+    """A step's error surfaces where its answer is waited for, with the
+    next step already launched: ``abort`` fails every seated stream and
+    drops both steps, and the router serves the next request as ever."""
+    eng = _engine(decode_graph, max_slots=2)
+    collect, fired = eng.collect, []
+
+    def failing(fl, ph):
+        if fl is not None and eng.in_flight is not fl and not fired:
+            fired.append(fl)
+            raise RuntimeError("device lost")
+        return collect(fl, ph)
+
+    eng.collect = failing
+    with DecodeRouter(eng) as router:
+        doomed = [router.submit(p, max_new_tokens=8)
+                  for p in ([5, 9, 13], [2, 4])]
+        for s in doomed:
+            with pytest.raises(RuntimeError, match="device lost"):
+                s.result(timeout=120)
+        assert fired and eng.in_flight is None
+        got = router.submit([5, 9, 13], max_new_tokens=8).result(timeout=120)
+    fresh = _engine(decode_graph, max_slots=2)
+    with DecodeRouter(fresh) as router:
+        want = router.submit([5, 9, 13], max_new_tokens=8).result(timeout=120)
+    assert got == want

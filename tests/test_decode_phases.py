@@ -4,6 +4,7 @@ always-on counter, a ``jax.profiler.TraceAnnotation`` and (``HETU_TRACE=1``)
 the ``obs`` ring: the counters add up to the wall time of the steps, the
 annotations nest in the profiler's own trace, the ring holds the same names.
 """
+import collections
 import os
 import sys
 import time
@@ -175,6 +176,7 @@ def test_phases_nest_in_the_profilers_own_trace(graphs, tmp_path):
     eng = _engine(graphs)
     _serve_two(_engine(graphs))          # compile outside the session
     assert not obs.enabled()
+    obs.clear_trace()                    # whatever this worker's earlier files left
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
@@ -237,6 +239,48 @@ def test_the_ring_holds_the_same_names_when_the_tracer_is_on(graphs):
             assert any(s["tid"] == e["tid"] and s["ts"] <= e["ts"] and
                        e["ts"] + e["dur"] <= s["ts"] + s["dur"] + 1e-3
                        for s in steps), e
+
+
+def test_a_router_iteration_is_one_span_launch_then_collect(graphs):
+    """ISSUE 32: the router launches step n+1 and THEN collects step n,
+    inside ONE ``decode.step`` span: its ``plan`` / ``feed`` / ``dispatch``
+    are of the step launched, its ``wait`` / ``readback`` / ``host`` of the
+    step launched the iteration before.  A run from an idle engine opens
+    with a launch alone and ends with a collect alone: one span more than
+    steps, one ``step`` sample a span, every launch but the first made
+    ahead, and no second span of that name for any step."""
+    eng = _engine(graphs)
+    _serve_two(_engine(graphs))          # compile first
+    obs.enable(False)
+    obs.clear_trace()
+    metrics.reset_decode_counts()
+    obs.enable(True)
+    try:
+        with DecodeRouter(eng) as router:
+            got = router.submit([3, 4], max_new_tokens=6).result(timeout=120)
+            assert router.drain(timeout=60)
+    finally:
+        obs.enable(False)
+    evs = [e for e in obs.trace_events() if e.get("ph") == "X"]
+    obs.clear_trace()
+    steps = sorted((e for e in evs if e["name"] == "decode.step"),
+                   key=lambda e: e["ts"])
+    kids = [[k["name"].rsplit(".", 1)[1] for k in sorted(
+        (e for e in evs if e["name"].startswith("decode.step.")
+         and s["ts"] <= e["ts"] and e["ts"] + e["dur"]
+         <= s["ts"] + s["dur"] + 1e-3), key=lambda e: e["ts"])]
+        for s in steps]
+    # the prompt's two tokens in one chunked step, then five one-token steps
+    assert len(got) == 6
+    assert kids == [list(PHASES[:3])] + [list(PHASES)] * 5 + [list(PHASES[3:])]
+    assert [s["args"]["rows"] for s in steps] == [1] * 6 + [0]
+    assert [s["args"]["emitted"] for s in steps] == [0] + [1] * 6
+    c = metrics.decode_counts()
+    assert c["decode_steps"] == 6 and c["decode_launches_ahead"] == 5
+    assert metrics.decode_latency_stats()["step"]["count"] == len(steps) == 7
+    # each phase ran once a STEP, whichever span held it
+    marked = collections.Counter(k for ks in kids for k in ks)
+    assert set(marked.values()) == {6} and set(marked) == set(PHASES)
 
 
 def test_phases_helper_counts_whole_microseconds_that_add_up():

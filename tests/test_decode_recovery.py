@@ -218,13 +218,13 @@ def test_crowded_kill_bitwise_parity_with_prefix_assist(graphs, ref):
     # live in-flight work
     release = threading.Event()
     watch = []
-    orig_step = routers[0].engine.step
-    def gated_step():
+    orig_collect = routers[0].engine.collect
+    def gated_collect(fl, ph):
         if watch and all(s.n_tokens >= 2 for s in watch) \
                 and not release.is_set():
             release.wait(timeout=60)
-        return orig_step()
-    routers[0].engine.step = gated_step
+        return orig_collect(fl, ph)
+    routers[0].engine.collect = gated_collect
     try:
         streams = [door.submit(p, max_new_tokens=max_new)
                    for p in prompts]
@@ -359,19 +359,20 @@ def test_wedged_replica_with_only_seated_work_is_ejected(graphs, ref):
     door, routers = _fleet(graphs, 2, chunked=False,
                            wedge_timeout_ms=75.0)
     release = threading.Event()
-    orig_step = routers[0].engine.step
+    orig_collect = routers[0].engine.collect
     holder = {}
 
-    def wedge_step():
-        # wedge AT the step boundary once the stream has a token out:
-        # the loop is "inside a device call" from the router's view, and
-        # the post-release step emits under the by-then-stale epoch
+    def wedge_collect(fl, ph):
+        # wedge where a step's answer is waited for, once the stream has
+        # a token out: the loop is "inside a device call" from the
+        # router's view (a later step already launched), and the
+        # post-release collect emits under the by-then-stale epoch
         s = holder.get("s")
         if s is not None and s.n_tokens >= 1 and not release.is_set():
             release.wait(timeout=60)
-        return orig_step()
+        return orig_collect(fl, ph)
 
-    routers[0].engine.step = wedge_step
+    routers[0].engine.collect = wedge_collect
     try:
         s = holder["s"] = door.submit(prompt, max_new_tokens=max_new)
         s.token(0).result(timeout=60)
@@ -398,6 +399,80 @@ def test_wedged_replica_with_only_seated_work_is_ejected(graphs, ref):
     # after the detach was fenced by the epoch, never re-delivered
     assert hmetrics.decode_recovery_counts().get(
         "decode_recovery_fenced", 0) >= 1
+
+
+# ---------------------------------- a step in flight (ISSUE 32)
+
+def test_deadline_eviction_with_a_step_in_flight(graphs, ref):
+    """The router evicts with step n+1 on the device: the evicted row's
+    answer is dropped at that step's collect — what was delivered stays
+    a prefix of the uninterrupted stream, nothing after the failure — and
+    the slot's next occupant decodes as ever."""
+    prompt, max_new = [3, 5, 9], 12
+    expect = ref(prompt, max_new)
+    hmetrics.reset_decode_counts()       # less the reference's own steps
+    eng = _engine(graphs, chunked=False, max_slots=1)
+    collect, held = eng.collect, []
+
+    def slow_collect(fl, ph):
+        # once two tokens are out, hold the loop past the deadline with a
+        # later step launched: the next iteration's evict finds the row
+        if held and held[0].n_tokens >= 2 and eng.in_flight is not fl:
+            time.sleep(0.3)
+        return collect(fl, ph)
+
+    eng.collect = slow_collect
+    with DecodeRouter(eng) as router:
+        warm = router.submit(prompt, max_new_tokens=max_new)
+        assert warm.result(timeout=60) == expect      # compiles first
+        s = router.submit(prompt, max_new_tokens=max_new, deadline_ms=250)
+        held.append(s)
+        with pytest.raises(ServeRejected) as e:
+            s.result(timeout=60)
+        assert e.value.reason == "deadline"
+        got = s.partial()
+        assert 2 <= len(got) < max_new and got == expect[:len(got)]
+        held.clear()
+        again = router.submit(prompt, max_new_tokens=max_new)
+        assert again.result(timeout=60) == expect
+        assert router.drain(timeout=60) and eng.in_flight is None
+    c = hmetrics.decode_counts()
+    assert c["decode_deadline_evictions"] == 1
+    assert c["decode_tokens"] == 2 * max_new + len(got)
+    # the evicted row's step in flight was collected (and counted), empty
+    assert s.n_tokens == len(got)
+
+
+def test_kill_with_a_step_in_flight_loses_no_token_and_repeats_none(
+        graphs, ref):
+    """``kill`` lands between a launch and its collect: the un-collected
+    step is lost with the replica and nothing else — the journal holds
+    emitted tokens only, so the continuation on the survivor resumes at
+    the next index and the stream is the uninterrupted one."""
+    prompt, max_new = [3, 5, 9], 12
+    expect = ref(prompt, max_new)
+    door, routers = _fleet(graphs, 2, chunked=False)
+    eng = routers[0].engine
+    collect, seen = eng.collect, []
+
+    def killing_collect(fl, ph):
+        emitted = collect(fl, ph)
+        if seen and seen[0].n_tokens >= 3 and eng.in_flight is not None \
+                and not routers[0].health()["killed"]:
+            routers[0].kill()            # a step launched, not collected
+        return emitted
+
+    eng.collect = killing_collect
+    try:
+        s = door.submit(prompt, max_new_tokens=max_new)
+        seen.append(s)
+        assert _poll_until_done(door, [s])
+        assert s.result(timeout=5) == expect
+        assert hmetrics.decode_recovery_counts()[
+            "decode_recovery_reseated"] == 1
+        assert eng.in_flight is not None     # dropped with the replica
+    finally:
+        door.close()
 
 
 # -------------------------------------------------- recovery vs close

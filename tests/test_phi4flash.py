@@ -211,6 +211,40 @@ def test_unzeroed_recurrent_state_serves_other_tokens(weights, monkeypatch):
     assert again != fresh
 
 
+@pytest.mark.parametrize("chunk", [0, 8], ids=["one_token", "chunked"])
+def test_router_one_step_ahead_emits_the_serial_loops_streams(weights, chunk):
+    """Step n+1 launched before step n is collected (ISSUE 32): the same
+    token streams as a loop of ``engine.step()``, bit for bit, with three
+    kinds of state; seven requests through three slots, so a slot's ring
+    and scan state are taken over under a step in flight."""
+    from decode_ahead import assert_same_streams
+    specs = [(p.astype(np.int32), n, None) for p, n in zip(
+        _prompts(21, [9, 2, 17, 5, 1, 12, 3]), [6, 9, 1, 12, 4, 7, 10])]
+    serial, ahead = assert_same_streams(
+        lambda: _engine(weights, chunk, slots=3), specs)
+    assert ahead["decode_state_clears"] == serial["decode_state_clears"] == 7
+
+
+def test_eos_hit_with_a_step_in_flight_leaves_a_clean_slot(weights):
+    """``eos_id`` matches with the next step launched and carrying the
+    row: its answer is dropped, and the one slot's next occupant finds the
+    recurrent state zeroed behind that stray step — the tokens of a fresh
+    engine."""
+    from decode_ahead import serve_router, serve_serial
+    a, b = (p.astype(np.int32) for p in _prompts(5, [11, 9]))
+    (free, after), _ = serve_serial(
+        _engine(weights, 8, slots=1), [(a, 14, None), (b, 12, None)])
+    toks = free.result(0)
+    k = max(i for i in range(len(toks) - 1) if toks[i] not in toks[:i])
+    specs = [(a, 14, toks[k]), (b, 12, None)]
+    _, c_serial = serve_serial(_engine(weights, 8, slots=1), specs)
+    ahead, c_ahead = serve_router(_engine(weights, 8, slots=1), specs)
+    assert ahead[0].result(0) == toks[:k + 1]
+    assert ahead[1].result(0) == after.result(0)
+    assert c_ahead["decode_tokens"] == c_serial["decode_tokens"]
+    assert c_ahead["decode_steps"] == c_serial["decode_steps"] + 1
+
+
 def test_router_serves_it_through_the_front_door(weights, ref_logits):
     eng = _engine(weights, 8)
     prompt = _prompts(8, [12])[0].astype(np.int32)
@@ -318,7 +352,8 @@ def test_mixers_lower_under_their_scopes(weights):
     feeds = {eng._fk["input_ids"]: np.zeros((1, 1), np.int32),
              eng._fk["positions"]: np.zeros(1, np.int32)}
     text = jax.jit(eng._program(eng.iex, eng._fk)).lower(
-        eng.iex.params, (feeds, tuple(eng.caches.values()))).as_text(
+        eng.iex.params, (feeds, tuple(eng.caches.values())),
+        np.zeros(1, np.int32)).as_text(
             debug_info=True)
     for scope in ("mix.ssm", "mix.swa", "mix.full", "mix.cross", "mix.gmu",
                   "mlp", "lm_head"):

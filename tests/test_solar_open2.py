@@ -358,19 +358,24 @@ def test_auxiliary_fetches_must_match_and_refuse_a_prefix_store():
 
 
 @pytest.mark.parametrize("chunked", [False, True], ids=["one_token", "chunked"])
-def test_auxiliary_fetches_beside_host_side_tokens(chunked):
-    """``aux=`` on a graph WITHOUT ``tokens=``: the step brings the logits
-    back for the host's argmax and the auxiliary array beside them, each
-    kept apart.  The fetch here is the token ids the step was fed, so a
-    stream's slices are the tokens it consumed, and the tokens served are
-    those of an engine that fetches nothing beside."""
+def test_auxiliary_fetches_beside_engine_derived_tokens(chunked):
+    """``aux=`` on a graph WITHOUT ``tokens=``: the step brings back the
+    ids the engine's program derives from the logits and the auxiliary
+    array beside them, each kept apart.  The fetch here is the token ids
+    the graph was fed, so a stream's slices are the tokens it consumed —
+    under the router too, where the host feeds ``-1`` for a generating row
+    and the program puts the previous step's id there — and the tokens
+    served are those of an engine that fetches nothing beside."""
+    from decode_ahead import assert_same_streams, serve_serial
     from hetu_tpu.models import (GPT2Config, gpt2_decode_chunked_graph,
                                  gpt2_decode_graph)
     g = GPT2Config(vocab_size=50, n_positions=32, n_embd=16, n_layer=1,
                    n_head=2, batch_size=1, seq_len=32)
-    prompts = [np.arange(3, 12, dtype=np.int32), np.arange(20, 22, dtype=np.int32)]
+    specs = [(np.arange(3, 12, dtype=np.int32), 5, None),
+             (np.arange(20, 22, dtype=np.int32), 5, None),
+             (np.arange(30, 34, dtype=np.int32), 7, None)]
 
-    def served(with_aux):
+    def engine(with_aux=True):
         f, lg, caches, _ = gpt2_decode_graph(g, max_len=32)
         more = {"aux": {"ids": f["input_ids"]}} if with_aux else {}
         if chunked:
@@ -378,23 +383,39 @@ def test_auxiliary_fetches_beside_host_side_tokens(chunked):
             more["chunked"] = (cf, cl, cc) + (
                 ({"ids": cf["input_ids"]},) if with_aux else ())
             more["max_chunk"] = 4
-        eng = DecodeEngine(f, lg, caches, max_slots=2, max_len=32, seed=3,
-                           **more)
-        reqs = [_DecodeRequest(p, 5, None, None) for p in prompts]
-        for r in reqs:
-            eng.join(r)
-        while not eng.idle:
-            eng.step()
-            assert eng.last_logits is None or eng.last_logits.shape[-1] == 50
-        return [r.stream for r in reqs]
+        return DecodeEngine(f, lg, caches, max_slots=2, max_len=32, seed=3,
+                            **more)
 
-    plain, fetched = served(False), served(True)
-    for p, a, b in zip(prompts, plain, fetched):
-        assert a.partial() == b.partial() and len(b.partial()) == 5
+    plain, _ = serve_serial(engine(with_aux=False), specs)
+    assert_same_streams(engine, specs, aux=("ids",))
+    eng = engine()
+    fetched, _ = serve_serial(eng, specs)
+    assert eng.last_logits.shape[-1] == 50
+    for (p, n, _), a, b in zip(specs, plain, fetched):
+        assert a.partial() == b.partial() and len(b.partial()) == n
         assert a.aux("ids") is None
         assert np.array_equal(
             b.aux("ids").reshape(-1),
             np.concatenate([p, np.asarray(b.partial()[:-1], np.int32)]))
+
+
+@pytest.mark.parametrize("chunk", [0, 8], ids=["one_token", "chunked"])
+def test_router_one_step_ahead_emits_the_serial_loops_streams(weights, chunk):
+    """Step n+1 launched before step n is collected (ISSUE 32): the same
+    token streams as a loop of ``engine.step()``, bit for bit, and each
+    stream's slices of chosen expert ids still line up with the tokens it
+    consumed; seven requests through three slots."""
+    from decode_ahead import assert_same_streams
+    specs = [(p.astype(np.int32), n, None) for p, n in zip(
+        _prompts(21, [9, 2, 17, 5, 1, 12, 3]), [6, 9, 1, 12, 4, 7, 10])]
+    serial, ahead = assert_same_streams(
+        lambda: _engine(weights, chunk, slots=3), specs,
+        aux=("moe_choices",))
+    # a slot is free at the COLLECT of its last step, one launch later
+    # than in the serial loop: a few steps more, each folded once
+    assert ahead["decode_steps"] >= serial["decode_steps"]
+    assert ahead["moe_assignments"] \
+        == ahead["decode_padded_row_tokens"] * 4 * 4    # layers x k
 
 
 def test_counters_fold_the_choices_of_every_step(weights):
@@ -456,7 +477,8 @@ def test_layers_lower_under_their_scopes(weights):
     feeds = {eng._fk["input_ids"]: np.zeros((1, 1), np.int32),
              eng._fk["positions"]: np.zeros(1, np.int32)}
     text = jax.jit(eng._program(eng.iex, eng._fk)).lower(
-        eng.iex.params, (feeds, tuple(eng.caches.values()))).as_text(
+        eng.iex.params, (feeds, tuple(eng.caches.values())),
+        np.zeros(1, np.int32)).as_text(
             debug_info=True)
     for scope in ("mix.gqa", "mix.kda", "moe.route", "moe.experts",
                   "moe.shared", "lm_head"):

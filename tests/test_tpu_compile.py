@@ -240,7 +240,7 @@ def test_decode_steps_hold_no_slab_copy(chip, chat_engine, monkeypatch,
     # the attention dispatch asks jax.default_backend() and must hear tpu
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     text = jax.jit(eng._program(iex, keys), donate_argnums=(1,)).lower(
-        params, fed).compile().as_text()
+        params, fed, chip((16,), jnp.int32)).compile().as_text()
     assert ("tpu_custom_call" in text) == (chunk == 1)
     assert not _slab_copies(text, slab)
     # and the slabs are fed and returned row-major, unpadded
@@ -305,7 +305,7 @@ def test_hybrid_decode_steps_update_every_kind_of_state_in_place(
            tuple(chip(*dims(n)) for n in eng.cache_names))
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     text = jax.jit(eng._program(iex, keys), donate_argnums=(1,)).lower(
-        params, fed).compile().as_text()
+        params, fed, chip((b,), jnp.int32)).compile().as_text()
     for kind, (shape, dtype) in shapes.items():
         tag = ("bf16" if dtype == jnp.bfloat16 else "f32") \
             + "[" + ",".join(map(str, shape)) + "]"
@@ -348,7 +348,7 @@ def test_shared_kv_readers_fetch_live_rows_only(chip, monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     before = metrics.decode_attn_call_counts().get("10x256", 0)
     text = jax.jit(eng._program(eng.iex, keys), donate_argnums=(1,)).lower(
-        params, fed).compile().as_text()
+        params, fed, chip((b,), jnp.int32)).compile().as_text()
     assert metrics.decode_attn_call_counts().get("10x256", 0) == before + 2
     assert text.count("tpu_custom_call") >= 2
     assert not re.findall(r"f32\[64,10,[\d,]*4608", text)
@@ -468,7 +468,8 @@ def test_solar_share_programs_fit_and_multiply_group_by_group(
     before = (metrics.decode_attn_call_counts().get("1x2048", 0),
               metrics.moe_call_counts().get("40of320:top8:kernel", 0))
     compiled = jax.jit(eng._program(iex, keys), donate_argnums=(1,)).lower(
-        params, fed).compile(compiler_options=asked or None)
+        params, fed, chip((b,), jnp.int32)).compile(
+            compiler_options=asked or None)
     text, mem = compiled.as_text(), compiled.memory_analysis()
     peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
