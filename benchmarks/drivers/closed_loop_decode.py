@@ -24,6 +24,7 @@ token ids, and the weights): two seeds serve the same multiset of lengths
 along different trajectories, and the spread across seeds is what the
 bounds are set from.
 """
+import bisect
 import functools
 import gc
 import json
@@ -50,6 +51,24 @@ def percentile(values, q):
     """Nearest-rank percentile of a non-empty list."""
     ordered = sorted(values)
     return ordered[min(len(ordered) - 1, int(q / 100.0 * len(ordered)))]
+
+
+def gap_profile(gaps):
+    """Where the gaps between a stream's tokens lie, in ms: the median, the
+    tails on both sides of the 95th percentile, the mean of the slowest
+    twentieth, and ``slow_pct``, the share of gaps more than a quarter over
+    the median.  A window whose steps are of two kinds (one-token, chunked)
+    has two populations of gaps, and a percentile is steady from seed to
+    seed only where it lies inside one of them on every seed: ``slow_pct``
+    says on which side of it the second population begins."""
+    ordered = sorted(gaps)
+    p50 = percentile(ordered, 50)
+    slow = len(ordered) - bisect.bisect_right(ordered, 1.25 * p50)
+    tail = ordered[int(0.95 * len(ordered)):]
+    out = {f"p{q}": 1e3 * percentile(ordered, q) for q in (50, 90, 95, 99)}
+    out["slowest5_mean"] = 1e3 * sum(tail) / len(tail)
+    out["slow_pct"] = 100.0 * slow / len(ordered)
+    return out
 
 
 def reduce_window(requests, t0, t1):
@@ -145,6 +164,19 @@ class Driver:
                 raise self.errors[0]
             time.sleep(min(0.05, max(0.0, until - time.perf_counter())))
 
+    def _settle_heap(self):
+        """No full collection from here to ``free``.  Set-up leaves millions
+        of tracked objects that live as long as the process, and the run
+        adds its own records (a future and a callback for every token, all
+        kept for the comparison): a full collection walks them all and
+        frees none, a third to half a second at a time with every stream
+        waiting, four or five times a window, and longer on a slower host
+        (PERF.md section 6, PR 35).  The young generations keep collecting;
+        ``free`` gives the old one its threshold back."""
+        gc.collect()
+        self._gc_thresholds = gc.get_threshold()
+        gc.set_threshold(*self._gc_thresholds[:2], 1 << 30)
+
     def _serve_alone(self, prompts, n_new):
         """Submit ``prompts`` at once and wait for all of them (set-up)."""
         reqs = [self._submit(p, n_new) for p in prompts]
@@ -177,6 +209,7 @@ class Driver:
         self.log(f"[serve] primed in {time.perf_counter() - t:.1f} s, "
                  f"{self.compiles.hits}/{self.compiles.requests} programs "
                  f"from the cache, counters {self.sys.counters()}")
+        self._settle_heap()
         self.submitting = True
         for client in range(clients):
             self._submit_next(client)
@@ -215,6 +248,9 @@ class Driver:
                  for k, v in after["counters"].items()}
         done = [r for r in self.requests if r.k is not None
                 and len(r.times) == r.n_new and t0 <= r.times[-1] < t1]
+        if not red["ttft"] or not red["gaps"]:
+            raise RuntimeError("the window saw no first token or no gap")
+        itl = gap_profile(red["gaps"])
         self.log("[serve] " + json.dumps({
             "kv_bytes": after["counters"].get("decode_kv_bytes_hw"),
             "steps": delta.get("decode_steps"),
@@ -222,6 +258,7 @@ class Driver:
             "prompt_tokens": delta.get("decode_prefill_rows"),
             "chunk_steps_saved": delta.get("decode_prefill_steps_saved"),
             "output_tokens": red["emitted"],
+            "itl_ms": {k: round(v, 4) for k, v in itl.items()},
             "submitted": red["attempted"], "first_tokened": len(red["ttft"]),
             "finished_in_window": len(done),
             "late_ms_mean": 1e3 * float(np.mean(self.lateness or [0])),
@@ -235,16 +272,17 @@ class Driver:
         if moved:
             raise RuntimeError(
                 f"the engine did not hold its state over the window: {moved}")
-        if not red["ttft"] or not red["gaps"]:
-            raise RuntimeError("the window saw no first token or no gap")
         self.sample = self._sample(done)
         return {
             "end_to_end": {
                 "serve_tokens_per_s": red["emitted"] / seconds,
-                "itl_p95_ms": 1e3 * percentile(red["gaps"], 95)},
+                # the 90th percentile, not the 95th: it lies inside the
+                # one-token steps' gaps on every seed (PERF.md section 2)
+                "itl_p90_ms": itl["p90"]},
             "attempted": red["attempted"], "failed": red["failed"],
             "window": {"seconds": seconds, "counters": delta,
-                       "ttft_s": red["ttft"], "gaps": len(red["gaps"])}}
+                       "ttft_s": red["ttft"], "gaps": len(red["gaps"]),
+                       "itl_ms": itl}}
 
     @staticmethod
     def _sample(done):
@@ -260,6 +298,7 @@ class Driver:
         self.sys.close()
         self.sys = None
         self.requests.clear()
+        gc.set_threshold(*self._gc_thresholds)
         gc.collect()
 
     def gaps(self, precision="highest", served=True):

@@ -77,6 +77,7 @@ class Driver(base.Driver):
         self.log(f"[serve] primed in {time.perf_counter() - t:.1f} s, "
                  f"{self.compiles.hits}/{self.compiles.requests} programs "
                  f"from the cache, counters {self.sys.counters()}")
+        self._settle_heap()
         self.submitting = True
         for client in range(clients):
             self._submit_next(client)

@@ -108,6 +108,11 @@ class Driver:
         del start, now
         self.sys.wait(self._run(int(self.mix["warmup_steps"])))
         self.log(f"[train] first losses {self.got['losses']}")
+        # no full collection from here to ``free``: what set-up built
+        # lives as long as the run, and walking it frees nothing
+        gc.collect()
+        self._gc_thresholds = gc.get_threshold()
+        gc.set_threshold(*self._gc_thresholds[:2], 1 << 30)
 
     def window(self, seconds, tracer):
         block = int(self.mix["block_steps"])
@@ -146,6 +151,7 @@ class Driver:
     def free(self):
         self.sys.close()
         self.sys = self.feeds = None
+        gc.set_threshold(*self._gc_thresholds)
         gc.collect()
 
     def check(self, control=False):

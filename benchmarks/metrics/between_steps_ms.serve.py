@@ -2,7 +2,7 @@
 return and the next one's entry while rows are seated: the loop's tail under
 its lock, taking and seating joins, deadline eviction
 (``decode_between_steps_us`` over ``decode_steps``)."""
-MOVES = "itl_p95_ms"
+MOVES = "itl_p90_ms"
 
 
 def read(run):

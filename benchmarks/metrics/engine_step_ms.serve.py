@@ -1,7 +1,7 @@
 """Mean of the program's own ``step`` latency histogram over the window
 (``DecodeEngine.step``: feeds, the jitted call, the logits read-back and
 the host's argmax and bookkeeping)."""
-MOVES = "itl_p95_ms"
+MOVES = "itl_p90_ms"
 
 
 def read(run):

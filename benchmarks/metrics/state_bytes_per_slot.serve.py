@@ -1,7 +1,7 @@
 """Device-resident per-sequence state a slot holds, in MB, all kinds
 together (the engine's ``decode_state_bytes_<kind>_hw`` gauges over its
 slots): what a step has to be able to read for one more row."""
-MOVES = "itl_p95_ms"
+MOVES = "itl_p90_ms"
 
 
 def read(run):

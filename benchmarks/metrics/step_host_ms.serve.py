@@ -3,7 +3,7 @@ and ``host`` phases of ``DecodeEngine.step`` (chunk pick and plan lookup,
 host feeds, the jitted call until it returns; argmax, emission, stream
 callbacks and bookkeeping) over ``decode_steps``, from the program's phase
 counters."""
-MOVES = "itl_p95_ms"
+MOVES = "itl_p90_ms"
 PHASES = ("plan", "feed", "dispatch", "host")
 
 

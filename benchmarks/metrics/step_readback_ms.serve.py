@@ -1,7 +1,7 @@
 """Time per engine step in the read-back of the (batch, vocab) logits from
 the device to the host once they are ready (``decode_step_readback_us`` over
 ``decode_steps``, the program's own phase counter)."""
-MOVES = "itl_p95_ms"
+MOVES = "itl_p90_ms"
 
 
 def read(run):

@@ -2,7 +2,7 @@
 to be ready on the device (``decode_step_wait_us`` over ``decode_steps``,
 the program's own phase counter): the device's share of a step as the host
 sees it.  A step that skips the logits has no such phase."""
-MOVES = "itl_p95_ms"
+MOVES = "itl_p90_ms"
 
 
 def read(run):

@@ -4,7 +4,15 @@ The only file that knows how the program builds and steps this model: the
 graph from ``bert_pretrain_graph``, Adam through ``opt.minimize``, steps
 through ``run_steps(sync=False)`` — the path ``chip_smoke.py train`` proved.
 """
+import os
+
 import numpy as np
+
+#: steps the executor lets run ahead of the oldest one it waits for, where
+#: the mix states none: five seconds of the bert cell's 108 ms steps, so
+#: that the chip stays fed while the host stands still (the program's own
+#: default, 4, is 0.4 s there: PERF.md section 6, PR 35)
+STEPS_IN_FLIGHT = 48
 
 
 class System:
@@ -29,8 +37,18 @@ class System:
         o = cfg["optimizer"]
         opt = ht.optim.AdamOptimizer(o["learning_rate"], o["beta1"],
                                      o["beta2"], o["epsilon"])
-        self.ex = ht.Executor({"train": [loss, opt.minimize(loss)]}, seed=0,
-                              compute_dtype=cfg["compute_dtype"])
+        # the executor reads its depth from the environment when built
+        depth = int(mix.get("steps_in_flight", STEPS_IN_FLIGHT))
+        was = os.environ.get("HETU_ASYNC_WINDOW")
+        os.environ["HETU_ASYNC_WINDOW"] = str(depth)
+        try:
+            self.ex = ht.Executor({"train": [loss, opt.minimize(loss)]},
+                                  seed=0, compute_dtype=cfg["compute_dtype"])
+        finally:
+            if was is None:
+                del os.environ["HETU_ASYNC_WINDOW"]
+            else:
+                os.environ["HETU_ASYNC_WINDOW"] = was
         trainable = {name for node, name in self.ex.var_names.items()
                      if getattr(node, "trainable", True)}
         if trainable != set(weights):

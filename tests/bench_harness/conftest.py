@@ -1,6 +1,7 @@
 """Shared by the benchmark's own tests: a throw-away benchmark root built
 from FILES ONLY (a tiny configuration, a tiny mix, limits, the real metric
 readers), which is how a later PR adds a cell."""
+import gc
 import json
 import os
 import shutil
@@ -23,7 +24,9 @@ REAL_OF = {TINY_TRAIN: "bert-base.mlm-s512-b32",
 
 def build_root(root):
     """A benchmark root under ``root`` whose two cells are the real cells
-    cut to CPU size, judged by the REAL cells' limits."""
+    cut to CPU size, judged by the REAL cells' limits.  A metric's list
+    keeps the cells this root stands in for and drops the others: a later
+    PR appends its cell to such lists."""
     data = os.path.join(root, "benchmarks")
     for d in ("configs", "traffic", "limits"):
         os.makedirs(os.path.join(data, d))
@@ -41,7 +44,6 @@ def build_root(root):
                     os.path.join(data, "limits", tiny + ".json"))
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    tiny_of = {v: k for k, v in REAL_OF.items()}
     bench["configs"] = [
         {"name": n, "file": f"benchmarks/configs/{n}.json"}
         for n in ("tiny-bert", "tiny-gpt2")]
@@ -50,7 +52,8 @@ def build_root(root):
          "chips": 1} for w in REAL_OF]
     for m in bench["end_to_end"] + bench["per_layer"]:
         if "workloads" in m:
-            m["workloads"] = [tiny_of[w] for w in m["workloads"]]
+            m["workloads"] = [tiny for tiny, real in REAL_OF.items()
+                              if real in m["workloads"]]
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)
     return root
@@ -59,3 +62,13 @@ def build_root(root):
 @pytest.fixture()
 def tiny_root(tmp_path):
     return build_root(str(tmp_path))
+
+
+@pytest.fixture(autouse=True)
+def _full_collections_back():
+    """A driver's set-up turns full collections off for its window and
+    ``free`` turns them on again; a test that stops short of ``free`` must
+    not leave the rest of the run without them."""
+    before = gc.get_threshold()
+    yield
+    gc.set_threshold(*before)

@@ -184,6 +184,62 @@ def test_serve_run_whose_cache_grows_inside_the_window_fails(tiny_root):
         _run(tiny_root, TINY_SERVE)
 
 
+@pytest.mark.parametrize("workload", [TINY_TRAIN, TINY_SERVE])
+def test_no_full_collection_from_set_up_to_free(tiny_root, monkeypatch,
+                                                workload):
+    """From the end of priming (before the first request of the warm-up
+    traffic) to ``free`` the old generation's threshold is out of reach: a
+    full collection walks what set-up built and the run's own records, with
+    every stream waiting, and frees none of it.  The young generations keep
+    their thresholds, and ``free`` restores the old one's before it
+    collects, so the program's cycles let go of their device memory."""
+    import gc
+    from benchmarks.drivers import closed_loop_decode
+    was = gc.get_threshold()
+    seen = []
+    real = closed_loop_decode.Driver._settle_heap
+
+    def settle(self):
+        seen.append((self.submitting, self.t0))
+        real(self)
+    monkeypatch.setattr(closed_loop_decode.Driver, "_settle_heap", settle)
+    d, _ = _driver(tiny_root, workload)
+    assert gc.get_threshold() == (*was[:2], 1 << 30)
+    if workload == TINY_SERVE:
+        assert seen == [(False, None)] and d.t0 is not None
+        d.window(0.2, None)
+    d.free()
+    assert gc.get_threshold() == was
+
+
+@pytest.mark.parametrize("stated", [None, 3])
+def test_the_training_system_keeps_seconds_of_steps_in_flight(tiny_root,
+                                                              stated):
+    """The executor is built to run ``steps_in_flight`` steps ahead of the
+    oldest it waits for (48 where the mix states none: five seconds of the
+    bert cell's steps, so a host that stands still leaves the chip fed),
+    and the process's environment is as it was."""
+    from benchmarks.systems import bert_mlm
+    if stated is not None:
+        path = os.path.join(tiny_root, "benchmarks", "traffic",
+                            "tiny-mlm.json")
+        with open(path) as f:
+            mix = json.load(f)
+        mix["steps_in_flight"] = stated
+        with open(path, "w") as f:
+            json.dump(mix, f)
+    assert "HETU_ASYNC_WINDOW" not in os.environ
+    d, _ = _driver(tiny_root, TINY_TRAIN)
+    assert d.sys.ex._async_window == (stated or bert_mlm.STEPS_IN_FLIGHT)
+    assert bert_mlm.STEPS_IN_FLIGHT * 0.108 > 4
+    assert "HETU_ASYNC_WINDOW" not in os.environ
+    # the window dispatches past its close and counts what it waited for
+    run = d.window(0.3, None)
+    assert run["window"]["steps"] % int(d.mix["block_steps"]) == 0
+    assert run["window"]["seconds"] >= 0.3
+    d.free()
+
+
 def test_run_py_refuses_a_backend_that_is_not_the_tpu():
     env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_RUN="ignored")
     p = subprocess.run(

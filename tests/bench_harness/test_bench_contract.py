@@ -1,5 +1,6 @@
 """``BENCHMARK.json`` against the contract's letter, and the data files
 against ``BENCHMARK.json``: what the driver refuses before a single run."""
+import copy
 import importlib.util
 import json
 import os
@@ -7,13 +8,18 @@ import re
 
 import pytest
 
+import conftest
 from conftest import BENCH, ROOT
+
+from benchmarks import harness
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
-WIDTH = re.compile(r"(_dim|_rank)$|hidden|intermediate|latent|state|proj|"
-                   r"head_size|head_dim|expansion|experts_per_tok")
+# ``hidden_size``, not a bare ``hidden``: ``num_hidden_layers`` is a DEPTH,
+# the contract's own example of a key that ``reduced`` may list
+WIDTH = re.compile(r"(_dim|_rank)$|hidden_size|intermediate|latent|state|"
+                   r"proj|head_size|head_dim|expansion|experts_per_tok")
 
 
 @pytest.fixture(scope="module")
@@ -43,6 +49,10 @@ def test_top_level_keys_and_sizes(bench):
 
 
 def test_names_units_and_entry_keys(bench):
+    assert not WIDTH.search("num_hidden_layers")
+    assert all(WIDTH.search(k) for k in (
+        "hidden_size", "intermediate_size", "kv_lora_rank", "head_dim",
+        "ssm_state_size", "num_experts_per_tok"))
     names = []
     for c in bench["configs"]:
         assert set(c) == {"name", "source", "file", "reduced", "why"}
@@ -97,10 +107,53 @@ def test_cells_configs_and_metrics_hang_together(bench):
         assert len(mine) >= 2            # setup_s and one other
         assert any(cell in m.get("workloads", cells)
                    for m in bench["per_layer"])
+
+
+def test_what_a_per_layer_metric_moves_is_reported_in_each_of_its_cells(
+        bench):
+    """As the harness finds them: a cell that reads the per-layer metric
+    prints the end-to-end metric it should move in its ``--trace 0`` line."""
+    files = harness.Files(ROOT)
+    ends = {m["name"] for m in bench["end_to_end"]}
     for m in bench["per_layer"]:
-        moved = e2e[m["moves"]]
-        assert set(m.get("workloads", cells)) \
-            <= set(moved.get("workloads", cells))
+        assert m["moves"] in ends and m["moves"] != "setup_s", m["name"]
+        mine = [w["name"] for w in bench["workloads"]
+                if m in files.metrics("per_layer", w["name"])]
+        assert mine, m["name"]
+        for cell in mine:
+            reported = {e["name"]
+                        for e in files.metrics("end_to_end", cell)}
+            assert m["moves"] in reported, (m["name"], cell)
+
+
+def test_a_cell_build_root_does_not_know_leaves_the_old_cells_standing(
+        tmp_path, monkeypatch):
+    """A later PR appends its cell to a metric's ``workloads`` list (it
+    cannot report an end-to-end metric otherwise): the tiny root keeps the
+    cells it stands in for and drops the stranger."""
+    later = copy.deepcopy(harness.Files(ROOT).bench)
+    later["workloads"].append({"name": "new-model.new-mix",
+                               "config": "new-model", "traffic": "new-mix",
+                               "chips": 1, "why": "a later PR's cell"})
+    listed = [m for m in later["end_to_end"] + later["per_layer"]
+              if "workloads" in m]
+    for m in listed:
+        m["workloads"].append("new-model.new-mix")
+    checkout = tmp_path / "checkout"
+    checkout.mkdir()
+    (checkout / "BENCHMARK.json").write_text(json.dumps(later))
+    monkeypatch.setattr(conftest, "ROOT", str(checkout))
+    root = tmp_path / "root"
+    root.mkdir()
+    tiny = harness.Files(conftest.build_root(str(root))).bench
+    known = set(conftest.REAL_OF)
+    assert {w["name"] for w in tiny["workloads"]} == known
+    for m, was in zip(tiny["end_to_end"] + tiny["per_layer"],
+                      later["end_to_end"] + later["per_layer"]):
+        if "workloads" in was:
+            assert set(m["workloads"]) == {
+                t for t, real in conftest.REAL_OF.items()
+                if real in was["workloads"]}
 
 
 def test_every_named_file_is_there_and_says_the_same(bench):

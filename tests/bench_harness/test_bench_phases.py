@@ -20,7 +20,7 @@ WANT = {
     "step_wait_ms.serve": 40.0, "step_readback_ms.serve": 3.5,
     "step_host_ms.serve": 2.97, "between_steps_ms.serve": 0.3,
     "queue_wait_ms.serve": 0.75, "row_token_fill_pct.serve": 85.0,
-    "chunk_width_mean.serve": 10.0}
+    "chunk_width_mean.serve": 10.0, "chunked_step_share_pct.serve": 5.0}
 NEEDS = {
     "step_wait_ms.serve": "decode_step_wait_us",
     "step_readback_ms.serve": "decode_step_readback_us",
@@ -28,7 +28,8 @@ NEEDS = {
     "between_steps_ms.serve": "decode_between_steps_us",
     "queue_wait_ms.serve": "decode_join_wait_us",
     "row_token_fill_pct.serve": "decode_padded_row_tokens",
-    "chunk_width_mean.serve": "decode_chunk_width"}
+    "chunk_width_mean.serve": "decode_chunk_width",
+    "chunked_step_share_pct.serve": "decode_prefill_steps"}
 
 
 def _read(root, name, counters):
@@ -83,4 +84,5 @@ def test_a_tiny_chat_window_holds_every_phase_counter(tiny_root):
     width = got.pop("chunk_width_mean.serve")
     assert width is None or 2 <= width <= 4
     assert all(v is not None and v >= 0 for v in got.values()), got
+    assert 0 < got["chunked_step_share_pct.serve"] <= 100
     assert 0 < got["row_token_fill_pct.serve"] <= 100

@@ -11,6 +11,7 @@ import pytest
 
 from conftest import ROOT
 import solar_root
+from test_bench_cells import _driver
 
 from benchmarks import harness, traffic
 
@@ -46,9 +47,12 @@ def test_the_cell_is_found_by_the_names_in_its_files():
     assert files.mix(cell["traffic"])["driver"] == "closed_loop_decode_routed"
     assert set(files.limits(CELL)) == {"logit_gap_max", "logit_gap_sq_mean",
                                        "route_margin_max"}
-    # no ``itl_p95_ms``: its spread over seeds is the cell's own bound
-    # and a new cell may spread by half (PERF.md section 6), so the cell is
-    # judged by its rate and the metrics that move the tail stay off it
+    # no inter-token metric: one gap in four holds a completion, and every
+    # percentile that could be judged lies on that shelf — ``itl_p90_ms``
+    # spread 0.26 % over six seeds and 0.91 % over the same six again, over a
+    # quarter of its 3 % bound; ``itl_p95_ms`` 2.8–4.0 % (PERF.md section 6,
+    # PR 35, PR 31).  The cell is judged by its rate, the metrics that move
+    # the gaps stay off it, and its gaps' profile is in every run's window
     ends = {m["name"] for m in files.metrics("end_to_end", CELL)}
     assert ends == {"serve_tokens_per_s", "setup_s"}
     layers = {m["name"] for m in files.metrics("per_layer", CELL)}
@@ -73,23 +77,6 @@ def test_the_cell_is_found_by_the_names_in_its_files():
                     "moe_tokens_per_expert.serve",
                     "gqa_attn_share_pct.serve"} \
             & {m["name"] for m in files.metrics("per_layer", old)}
-
-
-def test_the_contract_holds_once_the_depth_is_no_width(monkeypatch):
-    """``test_bench_contract.py`` refuses ``num_hidden_layers`` in a
-    ``reduced`` list through a bare ``hidden`` in its ``WIDTH`` pattern
-    (``tests/conftest.py`` marks that failure expected until a
-    ``benchmark`` PR writes ``hidden_size`` there); with that one word
-    written out, all the test asserts holds of ``BENCHMARK.json``."""
-    import re
-    import test_bench_contract as contract
-    if not contract.WIDTH.search("num_hidden_layers"):
-        pytest.skip("the pattern lets the depth through: delete this test")
-    monkeypatch.setattr(contract, "WIDTH", re.compile(
-        contract.WIDTH.pattern.replace("|hidden|", "|hidden_size|")))
-    assert contract.WIDTH.search("hidden_size")
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        contract.test_names_units_and_entry_keys(json.load(f))
 
 
 def test_table_is_what_the_mix_file_says_it_is(mix):
@@ -163,6 +150,27 @@ def test_the_cell_at_test_size_runs_and_is_correct(root):
     assert out["compared"]["logit_gap_sq_mean"]["value"] < 1e-9
     assert out["compared"]["route_margin_max"]["value"] < 1e-6
     json.dumps(out)
+
+
+def test_a_window_keeps_the_gaps_profile_though_none_of_it_is_judged(root):
+    """The result line of this cell names no inter-token metric; the
+    window's ``itl_ms`` block (and the ``[serve]`` line) still say where its
+    gaps lie, and the readers that move the gaps would find their counters
+    here the day the cell is put on their lists."""
+    files = harness.Files(root)
+    d, _ = _driver(root, solar_root.TINY, seed=5)
+    try:
+        run = d.window(0.5, None)
+    finally:
+        d.free()
+    itl = run["window"]["itl_ms"]
+    assert set(itl) == {"p50", "p90", "p95", "p99", "slowest5_mean",
+                        "slow_pct"}
+    assert 0 < itl["p50"] <= itl["p90"] <= itl["p99"]
+    for name in ("engine_step_ms.serve", "step_wait_ms.serve",
+                 "step_readback_ms.serve", "step_host_ms.serve",
+                 "between_steps_ms.serve", "state_bytes_per_slot.serve"):
+        assert files.reader(name)(run) > 0, name
 
 
 def _dropped_assignment(monkeypatch):
