@@ -671,6 +671,10 @@ def reset_serve_counts():
 #                                jnp path
 #   ``decode_kv_rows_held``      key rows the slab holds for those slots:
 #                                the denominator of the share read
+#   ``decode_kv_rows_live``      key rows the stepping sequences hold once
+#                                the step has appended (Σ position +
+#                                tokens consumed): what a step's attention
+#                                has to read, one slab's worth
 #   ``moe_assignments``, ``moe_assignments_held``, ``moe_experts_touched``,
 #   ``moe_expert_load_max``     folded from a step's auxiliary fetch of chosen
 #                                expert ids (``DecodeEngine(aux_fold=)``;

@@ -30,3 +30,6 @@ from .phi4flash import (Phi4FlashConfig, phi4flash_decode_graph,
 from .solar_open2 import (SolarOpen2Config, solar_open2_decode_graph,
                           solar_open2_decode_chunked_graph,
                           solar_open2_lm_graph)
+from .glm4_moe_lite import (Glm4MoeLiteConfig, glm4_moe_lite_decode_graph,
+                            glm4_moe_lite_decode_chunked_graph,
+                            glm4_moe_lite_lm_graph)

@@ -1,4 +1,6 @@
 """Shared model-graph helpers."""
+import numpy as np
+
 from .. import ops
 
 
@@ -94,3 +96,159 @@ def post_ln_encoder_stack(x, cfg, attn_factory, name):
         x = LayerNorm(cfg.hidden_size, cfg.layer_norm_eps,
                       ln + ".ln2")(x + h)
     return x
+
+
+# ------------------------------------------------ served decoder graphs
+# What the decode graphs of the expert models share (Solar-Open2,
+# GLM-4.7-Flash): one block definition under a one-token graph, a chunked
+# graph and a full-sequence graph, a float32 residual stream flattened to
+# ``(B*C, d)``, RMSNorm with a learned scale, weights stored in
+# ``cfg.param_dtype``, the dropless expert layer of one chip's share.
+
+class DecodeGraph:
+    """What the blocks of one graph share (as ``phi4flash._Graph``)."""
+
+    def __init__(self, cfg, ids, positions, valid, max_len, fed):
+        self.cfg, self.ids, self.positions = cfg, ids, positions
+        self.valid = () if valid is None else (valid,)
+        self.max_len, self.fed = int(max_len), fed
+        self.feeds, self.fetches, self.chosen = {}, [], []
+
+    def var(self, name, shape, mean=0.0, std=None):
+        from .. import initializers as init
+        from ..graph.node import Variable
+        std = self.cfg.initializer_range if std is None else std
+        return Variable(name, initializer=init.NormalInit(mean, std),
+                        shape=tuple(shape), dtype=self.cfg.param_dtype)
+
+    def dense(self, x, name, n_in, n_out):
+        """``x @ W`` over the weight as it is stored, float32 out."""
+        return ops.matmul_op(x, self.var(name + ".weight", (n_in, n_out)),
+                             out_dtype=np.float32)
+
+    def norm(self, x, name, width=None):
+        return ops.rms_norm_op(
+            x, self.var(name + ".scale",
+                        (width or self.cfg.hidden_size,), 1.0),
+            eps=self.cfg.rms_norm_eps)
+
+    def state(self, name, kind, shape, dtype, **slab):
+        if not self.fed:
+            if kind == "kv":
+                shape = ops.kv_slab_shape(**slab)
+            return ops.zeros_op(self.ids, tail=tuple(shape[1:]),
+                                dtype=np.dtype(dtype))
+        node = ops.state_placeholder(name, kind, shape, dtype, **slab)
+        self.feeds[name] = node
+        return node
+
+
+def cols(x, start, stop):
+    return ops.slice_op(x, begin=(0, start), end=(None, stop))
+
+
+def swiglu_mlp(g, y, name, width):
+    """``W_d(silu(W_g y) ⊙ W_u y)`` of ``width``, ``[gate | up]`` one
+    matrix."""
+    d = g.cfg.hidden_size
+    return g.dense(ops.swiglu_op(g.dense(y, name + ".gate_up", d, 2 * width)),
+                   name + ".down", width, d)
+
+
+def moe_block(g, x, name):
+    """``x + Σ_{chosen ∧ held} w_e E_e(n(x)) + E_shared(n(x))``, the
+    weights scaled by ``cfg.routed_scaling_factor``."""
+    from ..graph.node import name_scope
+    cfg = g.cfg
+    d, f = cfg.hidden_size, cfg.moe_intermediate_size
+    first, count = cfg.held
+    with name_scope("moe.route"):
+        y = g.norm(x, name + ".ln2")
+        ids, weights = ops.moe_route_op(
+            y, g.var(name + ".moe.router.weight", (d, cfg.n_routed_experts)),
+            g.var(name + ".moe.router.bias", (cfg.n_routed_experts,), 0.0,
+                  0.5 * cfg.initializer_range),
+            cfg.num_experts_per_tok,
+            scale=cfg.routed_scaling_factor)
+        g.chosen.append(ids)
+    with name_scope("moe.experts"):
+        routed = ops.moe_experts_op(
+            y, ids, weights,
+            g.var(name + ".moe.experts.gate_up", (count, d, 2 * f)),
+            g.var(name + ".moe.experts.down", (count, f, d)),
+            first=first, n_experts=cfg.n_routed_experts)
+    with name_scope("moe.shared"):
+        return x + routed + swiglu_mlp(g, y, name + ".moe.shared", f)
+
+
+def build_decoder(cfg, layer, chunk, max_len, name, fed=True,
+                  with_valid=True):
+    """The graph of ``cfg.num_hidden_layers`` blocks ``layer(g, x, i,
+    name) -> x`` between the embedding and the head: ``(g, logits, greedy
+    token ids, chosen expert ids)``.  ``fed=False``: zero states and
+    position 0 (the full-sequence graph)."""
+    from ..graph.node import name_scope, placeholder_op
+    b = cfg.batch_size
+    ids = placeholder_op("input_ids", shape=(b, chunk), dtype=np.int32)
+    if fed:
+        positions = placeholder_op("positions", shape=(b,), dtype=np.int32)
+    else:
+        positions = ops.zeros_op(ids, tail=(), dtype=np.dtype(np.int32))
+    valid = placeholder_op("valid", shape=(b,), dtype=np.int32) \
+        if with_valid else None
+    g = DecodeGraph(cfg, ids, positions, valid, max_len, fed)
+    g.feeds["input_ids"] = ids
+    if fed:
+        g.feeds["positions"] = positions
+    if valid is not None:
+        g.feeds["valid"] = valid
+    x = ops.array_reshape_op(                                # (B*C, d)
+        ops.embedding_lookup_op(
+            g.var(name + ".embed", (cfg.vocab_size, cfg.hidden_size)), ids,
+            dtype=np.float32),
+        output_shape=(-1, cfg.hidden_size))
+    for i in range(cfg.num_hidden_layers):
+        x = layer(g, x, i, f"{name}.l{i}")
+    with name_scope("moe.route"):
+        choices = ops.moe_choices_op(ids, *g.chosen)
+    with name_scope("lm_head"):
+        if valid is not None:
+            x = ops.chunk_emit_gather_op(x, ids, valid)
+        logits = g.dense(g.norm(x, name + ".ln_f"), name + ".lm_head",
+                         cfg.hidden_size, cfg.vocab_size)
+        tokens = ops.greedy_token_op(logits)
+    return g, logits, tokens, choices
+
+
+def choice_counters(held):
+    """``fold(choices) -> {counter: n}`` for ``DecodeEngine(aux_fold=)``:
+    what one step's chosen expert ids ``(rows, C, layers, k)`` say of the
+    expert layers' work where experts ``held = (first, count)`` are held —
+    ``moe_assignments`` (rows x k x layers), ``moe_assignments_held``
+    (those whose expert is held here), ``moe_experts_touched`` (held
+    experts with at least one token, summed over the layers) and
+    ``moe_expert_load_max`` (the most tokens one held expert of one layer
+    took this step; summed over steps like the others)."""
+    first, count = held
+
+    def fold(choices):
+        local = choices.astype(np.int32) - first
+        layers = local.shape[-2]
+        held = np.logical_and(local >= 0, local < count)
+        at = (local + count * np.arange(layers)[:, None])[held]
+        load = np.bincount(at, minlength=count * layers)
+        return {"moe_assignments": local.size,
+                "moe_assignments_held": int(held.sum()),
+                "moe_experts_touched": int(np.count_nonzero(load)),
+                "moe_expert_load_max": int(load.max())}
+
+    return fold
+
+
+def decoder_param_names(lm_graph, cfg, name):
+    """Checkpoint names and shapes of every variable of ``lm_graph(cfg, 2,
+    name)``, in graph order."""
+    from ..graph.node import PlaceholderOp, topo_sort
+    logits = lm_graph(cfg, 2, name)[1]
+    return {n.name: n.shape for n in topo_sort([logits])
+            if isinstance(n, PlaceholderOp) and n.is_variable}

@@ -45,10 +45,11 @@ import math
 
 import numpy as np
 
-from .. import initializers as init
 from .. import ops
-from ..graph.node import Variable, name_scope, placeholder_op
+from ..graph.node import name_scope
 from ..ops import kda, ssm
+from .common import (build_decoder, choice_counters, cols as _cols,
+                     decoder_param_names, moe_block)
 
 
 class SolarOpen2Config:
@@ -62,7 +63,8 @@ class SolarOpen2Config:
                  linear_head_dim=128, short_conv_kernel_size=4,
                  gate_rank=128, gqa_interval=3, moe_intermediate_size=1280,
                  n_routed_experts=320, held=None, num_experts_per_tok=8,
-                 rms_norm_eps=1e-5, initializer_range=0.02,
+                 routed_scaling_factor=1.0, rms_norm_eps=1e-5,
+                 initializer_range=0.02,
                  param_dtype=np.float32, cache_dtype=np.float32,
                  batch_size=1):
         if num_attention_heads % num_key_value_heads:
@@ -86,6 +88,7 @@ class SolarOpen2Config:
                              f"{self.n_routed_experts} routed experts")
         self.held = (int(first), int(count))
         self.num_experts_per_tok = int(num_experts_per_tok)
+        self.routed_scaling_factor = float(routed_scaling_factor)
         self.rms_norm_eps = float(rms_norm_eps)
         self.initializer_range = float(initializer_range)
         self.param_dtype = np.dtype(param_dtype)
@@ -108,67 +111,9 @@ class SolarOpen2Config:
         return "kda" if i % (self.gqa_interval + 1) else "gqa"
 
     def choice_counters(self):
-        """``fold(choices) -> {counter: n}`` for ``DecodeEngine(aux_fold=)``:
-        what one step's chosen expert ids ``(rows, C, layers, k)`` say of
-        the expert layers' work — ``moe_assignments`` (rows x k x layers),
-        ``moe_assignments_held`` (those whose expert is held here),
-        ``moe_experts_touched`` (held experts with at least one token,
-        summed over the layers) and ``moe_expert_load_max`` (the most
-        tokens one held expert of one layer took this step; summed over
-        steps like the others)."""
-        first, count = self.held
-
-        def fold(choices):
-            local = choices.astype(np.int32) - first
-            layers = local.shape[-2]
-            held = np.logical_and(local >= 0, local < count)
-            at = (local + count * np.arange(layers)[:, None])[held]
-            load = np.bincount(at, minlength=count * layers)
-            return {"moe_assignments": local.size,
-                    "moe_assignments_held": int(held.sum()),
-                    "moe_experts_touched": int(np.count_nonzero(load)),
-                    "moe_expert_load_max": int(load.max())}
-
-        return fold
-
-
-class _Graph:
-    """What the blocks of one graph share (``phi4flash._Graph``)."""
-
-    def __init__(self, cfg, ids, positions, valid, max_len, fed):
-        self.cfg, self.ids, self.positions = cfg, ids, positions
-        self.valid = () if valid is None else (valid,)
-        self.max_len, self.fed = int(max_len), fed
-        self.feeds, self.fetches, self.chosen = {}, [], []
-
-    def var(self, name, shape, mean=0.0, std=None):
-        std = self.cfg.initializer_range if std is None else std
-        return Variable(name, initializer=init.NormalInit(mean, std),
-                        shape=tuple(shape), dtype=self.cfg.param_dtype)
-
-    def dense(self, x, name, n_in, n_out):
-        """``x @ W`` over the weight as it is stored, float32 out."""
-        return ops.matmul_op(x, self.var(name + ".weight", (n_in, n_out)),
-                             out_dtype=np.float32)
-
-    def norm(self, x, name):
-        return kda.rms_norm_op(
-            x, self.var(name + ".scale", (self.cfg.hidden_size,), 1.0),
-            eps=self.cfg.rms_norm_eps)
-
-    def state(self, name, kind, shape, dtype, **slab):
-        if not self.fed:
-            if kind == "kv":
-                shape = ops.kv_slab_shape(**slab)
-            return ssm.zeros_op(self.ids, tail=tuple(shape[1:]),
-                                dtype=np.dtype(dtype))
-        node = ops.state_placeholder(name, kind, shape, dtype, **slab)
-        self.feeds[name] = node
-        return node
-
-
-def _cols(x, start, stop):
-    return ops.slice_op(x, begin=(0, start), end=(None, stop))
+        """``fold`` for ``DecodeEngine(aux_fold=)`` over the held experts
+        (:func:`~hetu_tpu.models.common.choice_counters`)."""
+        return choice_counters(self.held)
 
 
 def _mix_gqa(g, y, i, name):
@@ -223,72 +168,17 @@ def _mix_kda(g, y, i, name):
     return g.dense(normed, name + ".o", e, d)
 
 
-def _moe(g, x, name):
-    """``x + Σ_{chosen ∧ held} w_e E_e(n(x)) + E_shared(n(x))``."""
-    cfg = g.cfg
-    d, f = cfg.hidden_size, cfg.moe_intermediate_size
-    first, count = cfg.held
-    with name_scope("moe.route"):
-        y = g.norm(x, name + ".ln2")
-        ids, weights = ops.moe_route_op(
-            y, g.var(name + ".moe.router.weight", (d, cfg.n_routed_experts)),
-            g.var(name + ".moe.router.bias", (cfg.n_routed_experts,), 0.0,
-                  0.5 * cfg.initializer_range),
-            cfg.num_experts_per_tok)
-        g.chosen.append(ids)
-    with name_scope("moe.experts"):
-        routed = ops.moe_experts_op(
-            y, ids, weights,
-            g.var(name + ".moe.experts.gate_up", (count, d, 2 * f)),
-            g.var(name + ".moe.experts.down", (count, f, d)),
-            first=first, n_experts=cfg.n_routed_experts)
-    with name_scope("moe.shared"):
-        shared = g.dense(
-            ssm.swiglu_op(g.dense(y, name + ".moe.shared.gate_up", d, 2 * f)),
-            name + ".moe.shared.down", f, d)
-        return x + routed + shared
-
-
 def _layer(g, x, i, name):
     kind = g.cfg.layer_kind(i)
     with name_scope("mix." + kind):
         y = g.norm(x, name + ".ln1")
         x = x + (_mix_gqa(g, y, i, name + ".attn") if kind == "gqa"
                  else _mix_kda(g, y, i, name + ".kda"))
-    return _moe(g, x, name)
+    return moe_block(g, x, name)
 
 
-def _build(cfg, chunk, max_len, name, fed=True, with_valid=True):
-    b = cfg.batch_size
-    ids = placeholder_op("input_ids", shape=(b, chunk), dtype=np.int32)
-    if fed:
-        positions = placeholder_op("positions", shape=(b,), dtype=np.int32)
-    else:
-        positions = ssm.zeros_op(ids, tail=(), dtype=np.dtype(np.int32))
-    valid = placeholder_op("valid", shape=(b,), dtype=np.int32) \
-        if with_valid else None
-    g = _Graph(cfg, ids, positions, valid, max_len, fed)
-    g.feeds["input_ids"] = ids
-    if fed:
-        g.feeds["positions"] = positions
-    if valid is not None:
-        g.feeds["valid"] = valid
-    x = ops.array_reshape_op(                                # (B*C, d)
-        ops.embedding_lookup_op(
-            g.var(name + ".embed", (cfg.vocab_size, cfg.hidden_size)), ids,
-            dtype=np.float32),
-        output_shape=(-1, cfg.hidden_size))
-    for i in range(cfg.num_hidden_layers):
-        x = _layer(g, x, i, f"{name}.l{i}")
-    with name_scope("moe.route"):
-        choices = ops.moe_choices_op(ids, *g.chosen)
-    with name_scope("lm_head"):
-        if valid is not None:
-            x = ops.chunk_emit_gather_op(x, ids, valid)
-        logits = g.dense(g.norm(x, name + ".ln_f"), name + ".lm_head",
-                         cfg.hidden_size, cfg.vocab_size)
-        tokens = ssm.greedy_token_op(logits)
-    return g, logits, tokens, choices
+def _build(cfg, chunk, max_len, name, **kw):
+    return build_decoder(cfg, _layer, chunk, max_len, name, **kw)
 
 
 def solar_open2_decode_graph(cfg, max_len, name="solar"):
@@ -325,10 +215,7 @@ def solar_open2_lm_graph(cfg, seq_len, name="solar"):
 
 def param_names(cfg, name="solar"):
     """Checkpoint names and shapes of every variable, in graph order."""
-    from ..graph.node import PlaceholderOp, topo_sort
-    _, logits, _ = solar_open2_lm_graph(cfg, 2, name)
-    return {n.name: n.shape for n in topo_sort([logits])
-            if isinstance(n, PlaceholderOp) and n.is_variable}
+    return decoder_param_names(solar_open2_lm_graph, cfg, name)
 
 
 __all__ = ["SolarOpen2Config", "solar_open2_decode_graph",

@@ -413,7 +413,8 @@ sparse_combine_op = def_op("SparseCombine", _sparse_combine_lower)
 # bias (the DeepSeek-V3 convention), and the layer is told which experts it
 # HOLDS — it routes over all of them and computes its own experts' part of
 # the result, what one chip of an expert-parallel group does before the
-# group's all-reduce.  ``hetu_tpu/models/solar_open2.py`` is the caller.
+# group's all-reduce.  ``models/common.py:moe_block`` is the caller (the
+# Solar-Open2 and GLM-4.7-Flash graphs).
 
 def _route_pick(s, bias, k):
     """The ``k`` experts whose BIASED score is highest, and their plain
@@ -427,26 +428,29 @@ def _route_norm(chosen):
     return chosen / jnp.sum(chosen, axis=-1, keepdims=True)
 
 
-def _moe_route(c, y, w_r, bias, top_k=1):
+def _moe_route(c, y, w_r, bias, top_k=1, scale=1.0):
     """``s = sigmoid(y W_r)`` over ALL experts, in float32 from the float32
     input at the highest matrix precision; chosen = the ``top_k`` of ``s +
-    bias``; weights ``s_e / Σ_chosen s``.  ``y``: (N, d); ``w_r``: (d, E);
-    ``bias``: (E,).  Returns ``(ids (N, k) int32, weights (N, k)
-    float32)``."""
+    bias``; weights ``scale · s_e / Σ_chosen s`` (``routed_scaling_factor``;
+    at 1 nothing is multiplied).  ``y``: (N, d); ``w_r``: (d, E); ``bias``:
+    (E,).  Returns ``(ids (N, k) int32, weights (N, k) float32)``."""
     f32 = jnp.float32
     s = jax.nn.sigmoid(jnp.matmul(y.astype(f32), w_r.astype(f32),
                                   precision=jax.lax.Precision.HIGHEST))
     ids, chosen = _route_pick(s, bias.astype(f32), int(top_k))
-    return ids.astype(jnp.int32), _route_norm(chosen)
+    weights = _route_norm(chosen)
+    if scale != 1.0:
+        weights = weights * f32(scale)
+    return ids.astype(jnp.int32), weights
 
 
 _moe_route_node = def_op("MoERoute", _moe_route)
 
 
-def moe_route_op(y, w_r, bias, top_k, name=None):
+def moe_route_op(y, w_r, bias, top_k, scale=1.0, name=None):
     """``(ids, weights)`` nodes of :func:`_moe_route`."""
     return tuple_outputs(_moe_route_node(y, w_r, bias, top_k=top_k,
-                                         name=name), 2)
+                                         scale=float(scale), name=name), 2)
 
 
 def _held(local, count):

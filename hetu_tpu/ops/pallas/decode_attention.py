@@ -10,7 +10,9 @@ between the append and this call (a slab whose minor dimension is a whole
 number of lane rows is stored row-major and unpadded, the layout a
 ``pallas_call`` demands of its operands).
 
-Both one-token callers are this one call:
+Every one-token caller is this one call (a third, ``ops/kda.py``'s
+grouped-query read, hands the ``R`` heads of a key head as ``R`` rows; the
+fourth, ``ops/mla.py``'s latent read, is the latent mode below):
 
 * GPT-2's packed heads (``dispatch_sdpa_decode``; ``r = 2``, float32): a
   query becomes ``r`` rows (:func:`~hetu_tpu.ops.attention.
@@ -146,14 +148,18 @@ def _finish(o_ref, m_scr, l_scr, acc_scr, pack):
             num / jnp.where(den == 0.0, 1.0, den)).astype(o_ref.dtype)
 
 
-def _kernel(len_ref, slot_ref, blk_ref, q_ref, k_ref, v_ref, o_ref,
-            m_scr, l_scr, acc_scr, *, pack):
+def _kernel(len_ref, slot_ref, blk_ref, q_ref, k_ref, *rest, pack, v_lanes):
+    """``rest``: the value block, then the output and the scratch — or, in
+    the latent mode (``v_lanes``), no value block: the value is the first
+    ``v_lanes`` lanes of the key block already in VMEM."""
+    o_ref, m_scr, l_scr, acc_scr = rest[-4:]
     t = pl.program_id(1)
     ki = blk_ref[t]
     length = len_ref[slot_ref[t]]
     pl.when(ki == 0)(lambda: _init(m_scr, l_scr, acc_scr))
-    _block(q_ref[...], k_ref[...], v_ref[...], ki, length, m_scr, l_scr,
-           acc_scr, pack)
+    k = k_ref[...]
+    v = rest[0][...] if v_lanes is None else k[:, :, :v_lanes]
+    _block(q_ref[...], k, v, ki, length, m_scr, l_scr, acc_scr, pack)
     # the slot's last live block
     pl.when(ki == (length - 1) // (k_ref.shape[1] * pack))(
         lambda: _finish(o_ref, m_scr, l_scr, acc_scr, pack))
@@ -173,7 +179,7 @@ def _schedule(lengths, keys_per_block, num_kv):
 
 
 def decode_attention(rows, k_slab, v_slab, lengths, pack=1,
-                     interpret=False):
+                     interpret=False, v_lanes=None):
     """Attention of a few query rows per (sequence, KV head) over KV slabs.
 
     ``rows``: (B, H, n, lanes) score rows, scaled, in the slabs' dtype —
@@ -185,8 +191,19 @@ def decode_attention(rows, k_slab, v_slab, lengths, pack=1,
     query the softmax over its ``pack`` rows together, lanes ``[j*D,
     (j+1)*D)`` of row ``j`` of ``P @ V`` summed over ``j``.
     ``interpret=True`` runs the Pallas interpreter (the CPU tests exercise
-    the same body)."""
+    the same body).
+
+    **The latent mode** (``v_slab=None``, ``v_lanes``, ``pack == 1``;
+    ``ops/mla.py``): a cache row is a key whose first ``v_lanes`` lanes are
+    also its value, so the one slab is fetched once — a second operand of
+    the same slab would read it twice — and the result is (B, H, n,
+    ``v_lanes``).  The trace knows that call as ``mla_fwd_q1``."""
     b, h, n, lanes = rows.shape
+    latent = v_slab is None
+    if latent and (pack != 1 or not v_lanes):
+        raise ValueError("the latent mode reads plain rows (pack 1) and "
+                         "needs v_lanes")
+    out_lanes = int(v_lanes) if latent else lanes
     slab_rows = k_slab.shape[2]
     hb, block_k = geometry(h, slab_rows, lanes, k_slab.dtype.itemsize)
     from ...metrics import record_decode_attn_call
@@ -205,24 +222,26 @@ def decode_attention(rows, k_slab, v_slab, lengths, pack=1,
         return slot_ref[t], hi, blk_ref[t], 0
 
     kv_spec = pl.BlockSpec((None, hb, block_k, lanes), at_block)
+    slabs = (k_slab,) if latent else (k_slab, v_slab)
     return pl.pallas_call(
-        functools.partial(_kernel, pack=pack),
+        functools.partial(_kernel, pack=pack,
+                          v_lanes=out_lanes if latent else None),
         # the one-token call keeps the name the device trace knows it by
-        name="flash_fwd_q1",
+        name="mla_fwd_q1" if latent else "flash_fwd_q1",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             # the second bound is the traced count of live blocks
             grid=(h // hb, steps),
-            in_specs=[pl.BlockSpec((None, hb, padded, lanes), at_slot),
-                      kv_spec, kv_spec],
-            out_specs=pl.BlockSpec((None, hb, n // pack, lanes // pack),
+            in_specs=[pl.BlockSpec((None, hb, padded, lanes), at_slot)]
+            + [kv_spec] * len(slabs),
+            out_specs=pl.BlockSpec((None, hb, n // pack, out_lanes // pack),
                                    at_slot),
             scratch_shapes=[
                 pltpu.VMEM((hb, padded, 128), jnp.float32),    # running max
                 pltpu.VMEM((hb, padded, 128), jnp.float32),    # running sum
-                pltpu.VMEM((hb, padded, lanes), jnp.float32),  # P @ V rows
+                pltpu.VMEM((hb, padded, out_lanes), jnp.float32),  # P @ V
             ]),
         out_shape=jax.ShapeDtypeStruct(
-            (b, h, n // pack, lanes // pack), jnp.float32),
+            (b, h, n // pack, out_lanes // pack), jnp.float32),
         interpret=interpret,
-    )(lengths, slot, block, rows, k_slab, v_slab)
+    )(lengths, slot, block, rows, *slabs)

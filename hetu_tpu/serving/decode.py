@@ -406,7 +406,7 @@ class _Launch:
     then the auxiliary fetches), ``logits`` the (batch, vocab) logits
     left on the device.  The rest is what the step's counters need:
     whether a row emits, whether the step before was still un-collected,
-    the batch and chunk buckets, the KV rows ``(read, held)``."""
+    the batch and chunk buckets, the KV rows ``(read, held, live)``."""
 
     __slots__ = ("rows", "back", "logits", "emits", "ahead", "bb", "chunk",
                  "kv_rows")
@@ -1182,7 +1182,7 @@ class DecodeEngine:
                       > 1 for i in rows)
         ph.meta(rows=len(rows), chunk=chunk, prefill=prefill)
         self._grow_len_if_needed(span=chunk)
-        kv_rows = self._kv_rows(chunk)
+        read, held = self._kv_rows(chunk)
         if chunk > 1:
             fn, ex, fk = self._chunk_step_fn(chunk), self.ciex, self._cfk
         else:
@@ -1212,6 +1212,11 @@ class DecodeEngine:
             feeds = {
                 fk["input_ids"]: self.tokens.reshape(self.bb, 1).copy(),
                 fk["positions"]: self.positions.copy()}
+        # the key rows the stepping sequences hold once this step has
+        # appended: what its attention has to read, exactly
+        kv_rows = (read, held, int(
+            self.positions[rows].sum() + np.asarray(consume)[rows].sum())
+            if self._kv else 0)
         # the caches are DONATED device arrays fed straight back from
         # the previous launch's fetches — no host round-trip
         # (_place_feed's np.asarray would force one, so the engine
@@ -1315,6 +1320,7 @@ class DecodeEngine:
         record_decode("decode_padded_row_tokens", fl.bb * fl.chunk)
         record_decode("decode_kv_rows_read", fl.kv_rows[0])
         record_decode("decode_kv_rows_held", fl.kv_rows[1])
+        record_decode("decode_kv_rows_live", fl.kv_rows[2])
         if fl.chunk > 1:
             record_decode("decode_prefill_steps")
             record_decode("decode_chunk_width", fl.chunk)

@@ -382,23 +382,22 @@ def test_grouped_expert_product_compiles(chip, monkeypatch, rows, k, n):
 
 
 # ------------------------------------------- the Solar-Open2 cell's programs
-@functools.lru_cache(maxsize=None)
-def _solar_engine(layers):
-    """The engine of ``solar-open2.assist-c128`` as its system file builds
-    it, from the cell's own configuration and mix — 4096 wide, 40 experts
-    of 1280 held of 320, 8 + 1 heads of 128, 8 KDA heads, 24,576 rows of
-    vocabulary; ``layers`` 8 is the cell (two periods), 4 the one period at
-    which a scanned chunk first failed to return on the chip — over ABSTRACT
-    weights: billions of parameters are shapes here, never arrays."""
+def _share_engine(config, traffic, system, graphs, **cfg_over):
+    """The engine of a served share as its system file builds it, from the
+    cell's own configuration (``cfg_over`` laid over it) and mix, over
+    ABSTRACT weights: billions of parameters are shapes here, never
+    arrays.  ``system``: the module's name under ``benchmarks/systems``;
+    ``graphs``: the names of the one-token and the chunked graph
+    constructor in ``hetu_tpu.models``."""
+    import importlib
     import sys
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     if root not in sys.path:
         sys.path.insert(0, root)
     from benchmarks import harness
-    from benchmarks.systems import solar_open2_decode as system
-    from hetu_tpu.models import (solar_open2_decode_chunked_graph,
-                                 solar_open2_decode_graph)
+    from hetu_tpu import models
     from hetu_tpu.serving import DecodeEngine, InferenceExecutor
+    system = importlib.import_module("benchmarks.systems." + system)
 
     def shapes_only(self, weights):
         for node in self.var_nodes:
@@ -408,14 +407,12 @@ def _solar_engine(layers):
                        for n in self.var_nodes}
 
     files = harness.Files(root)
-    cfg, mix = files.config("solar-open2"), files.mix("assist-c128")
-    assert cfg["num_hidden_layers"] == 8
-    cfg["num_hidden_layers"] = layers
+    cfg, mix = dict(files.config(config), **cfg_over), files.mix(traffic)
     mcfg = system.model_config(cfg, system.storage(cfg))
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(InferenceExecutor, "_load_weights", shapes_only)
-        f, lg, st, tok, ch = solar_open2_decode_graph(mcfg, mix["max_len"])
-        cf, cl, cs, ctok, cch = solar_open2_decode_chunked_graph(
+        f, lg, st, tok, ch = getattr(models, graphs[0])(mcfg, mix["max_len"])
+        cf, cl, cs, ctok, cch = getattr(models, graphs[1])(
             mcfg, mix["max_len"])
         eng = DecodeEngine(
             f, lg, st, tokens=tok, aux={"moe_choices": ch},
@@ -423,6 +420,18 @@ def _solar_engine(layers):
             chunked=(cf, cl, cs, ctok, {"moe_choices": cch}),
             max_chunk=mix["max_chunk"], validate="off")
     return eng, mix
+
+
+@functools.lru_cache(maxsize=None)
+def _solar_engine(layers):
+    """The engine of ``solar-open2.assist-c128`` — 4096 wide, 40 experts
+    of 1280 held of 320, 8 + 1 heads of 128, 8 KDA heads, 24,576 rows of
+    vocabulary; ``layers`` 8 is the cell (two periods), 4 the one period at
+    which a scanned chunk first failed to return on the chip."""
+    return _share_engine(
+        "solar-open2", "assist-c128", "solar_open2_decode",
+        ("solar_open2_decode_graph", "solar_open2_decode_chunked_graph"),
+        num_hidden_layers=layers)
 
 
 @pytest.mark.parametrize("layers,chunk", [(8, 1), (8, 32), (4, 1), (4, 32)],
@@ -494,6 +503,98 @@ def test_solar_share_programs_fit_and_multiply_group_by_group(
     assert "bf16[40,4096,2560]" in text and "bf16[40,1280,4096]" in text
     for tag in ("f32[128,8,128,128]", "bf16[128,1,4096,128]"):
         assert not re.findall(r"= " + re.escape(tag) + r"\S* copy\(", text)
+
+
+# ------------------------------------------ the GLM-4.7-Flash cell's programs
+def test_the_standing_callers_keep_their_geometries():
+    """ISSUE 36: the latent mode added an operand-less value to the
+    one-token kernel; the chat, phi4 and solar calls compile the key blocks
+    they had, and the latent call takes 512 rows of 640 lanes."""
+    from hetu_tpu.ops.pallas.decode_attention import geometry
+    assert [geometry(*call) for call in (
+        (16, 384, 128, 4), (10, 4608, 128, 2), (1, 4096, 128, 2),
+        (1, 4096, 640, 2))] == [(16, 64), (10, 256), (1, 2048), (1, 512)]
+
+
+def test_latent_decode_attention_compiles(chip):
+    """The one-token kernel in its latent mode at the cell's call: 20 score
+    rows a slot over ONE slab of 640-lane bfloat16 rows, 128 x 4096, the
+    value the first 512 lanes of the key block — one slab operand, no copy
+    of it in front of the call, and the name the trace tells it by."""
+    from hetu_tpu import metrics
+    from hetu_tpu.ops.pallas.decode_attention import decode_attention
+    slab = (128, 1, 4096, 640)
+    before = metrics.decode_attn_call_counts().get("1x512", 0)
+    text = _compiles_with_kernel(
+        lambda rows, k, n: decode_attention(rows, k, None, n, v_lanes=512),
+        chip((128, 1, 20, 640), jnp.bfloat16), chip(slab, jnp.bfloat16),
+        chip((128,), jnp.int32))
+    assert metrics.decode_attn_call_counts().get("1x512", 0) == before + 1
+    assert not _slab_copies(text, slab)
+    assert "mla_fwd_q1" in text and "flash_fwd_q1" not in text
+    assert "f32[128,1,20,512]" in text
+
+
+@functools.lru_cache(maxsize=None)
+def _glm_engine():
+    """The engine of ``glm47-flash.think-c128``: 13 layers of 2048, 20
+    latent-attention heads, 8 experts of 1536 held of 64."""
+    return _share_engine(
+        "glm47-flash", "think-c128", "glm4_moe_lite_decode",
+        ("glm4_moe_lite_decode_graph", "glm4_moe_lite_decode_chunked_graph"))
+
+
+@pytest.mark.parametrize("chunk", [1, 32], ids=["one_token", "chunk32"])
+def test_glm_share_programs_fit_and_read_the_latent_cache_in_place(
+        chip, monkeypatch, chunk):
+    """ISSUE 36: the one-token and the chunk-32 program at the cell's sizes
+    (128 slots x 4096 rows, 13 layers), compiled for the described chip as
+    the engine jits them.  Weights (4.00 GB), the latent cache (13 slabs of
+    640-lane rows, 8.72 GB) and temporaries fit the chip together with room
+    — the chunk-32 program reads the slots group by group, else its
+    float32 scores alone are 1.34 GB a layer and the program 15.6 GB; the
+    one-token program reads each slab through the latent kernel, 13 calls;
+    the 12 expert layers multiply group by group over the 8 held experts;
+    no program holds a copy of a slab's size."""
+    from hetu_tpu import metrics
+    eng, mix = _glm_engine()
+    iex, keys = (eng.iex, eng._fk) if chunk == 1 else (eng.ciex, eng._cfk)
+    b, length = mix["max_slots"], mix["max_len"]
+    slab = (128, 1, 4096, 640)
+    assert {_state_dims(eng, b, length, n) for n in eng.cache_names} \
+        == {(slab, jnp.dtype(jnp.bfloat16))} and len(eng.cache_names) == 13
+    feeds = {"input_ids": ((b, chunk), jnp.int32),
+             "positions": ((b,), jnp.int32)}
+    if chunk > 1:
+        feeds["valid"] = ((b,), jnp.int32)
+    params = {k: chip(v.shape, v.dtype) for k, v in iex.params.items()}
+    assert sum(v.size for v in params.values()) == 2001017856
+    fed = ({keys[name]: chip(d, t) for name, (d, t) in feeds.items()},
+           tuple(chip(slab, jnp.bfloat16) for _ in eng.cache_names))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert iex.compiler_options() == {}
+    before = (metrics.decode_attn_call_counts().get("1x512", 0),
+              metrics.moe_call_counts().get("8of64:top4:kernel", 0))
+    compiled = jax.jit(eng._program(iex, keys), donate_argnums=(1,)).lower(
+        params, fed, chip((b,), jnp.int32)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert 12.7e9 < peak < 14.5e9, peak                      # of 16 GB
+    assert metrics.moe_call_counts()["8of64:top4:kernel"] == before[1] + 12
+    assert metrics.decode_attn_call_counts().get("1x512", 0) \
+        == before[0] + (13 if chunk == 1 else 0)
+    assert ("mla_fwd_q1" in text) == (chunk == 1)
+    assert "flash_fwd_q1" not in text and "ragged-dot" not in text
+    assert len(re.findall(
+        r'op_name="[^"]*moe\.experts/jit\(gmm\)/pallas_call"', text)) == 24
+    assert not _slab_copies(text, slab)
+    # the held experts cross as stored; a slab is fed and returned
+    # row-major, unpadded
+    assert "bf16[8,2048,3072]" in text and "bf16[8,1536,2048]" in text
+    layout = re.search(r"entry_computation_layout=\{(.*)\}", text).group(1)
+    assert "bf16[128,1,4096,640]{3,2,1,0:T(8,128)(2,1)}" in layout
+    assert "bf16[128,1,4096,640]{2," not in layout
 
 
 # ------------------------------------------------------------ moe dispatch
