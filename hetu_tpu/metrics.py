@@ -132,6 +132,29 @@ def decode_attn_call_counts():
     return _decode_attn_calls.counts()
 
 
+# How a decode step writes its new cache rows (``ops.attention.
+# _kv_cache_append``, ``ops.ssm._ring_put``): the block of the state
+# buffer one program rewrites, and whether the write is the aliased
+# kernel (``ops/pallas/kv_append.py``) or the loop over the batch that
+# every backend but the TPU runs.  Per TRACE, as the families above.
+_kv_append_calls = REGISTRY.counter_family(
+    "kv_append_calls",
+    "cache-row appends by block and path, "
+    "\"<block rows>x<lanes>:<kernel|loop>\" (per jax trace)")
+
+
+def record_kv_append_call(block_rows, lanes, how):
+    """Count one traced cache-row append."""
+    if counters_suppressed():
+        return
+    _kv_append_calls.inc(f"{block_rows}x{lanes}:{how}")
+
+
+def kv_append_call_counts():
+    """{"<block rows>x<lanes>:<kernel|loop>": count} snapshot."""
+    return _kv_append_calls.counts()
+
+
 # How a decode graph's expert layer multiplies (``ops.moe._moe_experts``):
 # the experts it holds of all the router chooses among, the experts a
 # token takes, and whether the grouped product is ``jax.lax.ragged_dot``
@@ -1079,6 +1102,7 @@ _FAMILIES = {
     "flash_fallbacks": _flash,
     "flash_calls": _flash_calls,
     "decode_attn_calls": _decode_attn_calls,
+    "kv_append_calls": _kv_append_calls,
     "moe_calls": _moe_calls,
     "emb_pallas_fallbacks": _emb_pallas,
     "faults": _faults,

@@ -554,9 +554,14 @@ def _kv_cache_append(c, cache, new, positions, valid=None):
     off the shapes, 1 for a plain (B, H, L, D) cache): key row ``p``
     lives in slab row ``p // r`` at lanes ``[(p % r)*D, (p % r + 1)*D)``.
     The write is a read-modify-write of the few slab rows the chunk
-    touches — per sequence a dynamic_slice, a select and a
-    dynamic_update_slice on the donated buffer, which XLA performs in
-    place — so every lane it does not own keeps its bytes.
+    touches, so every lane it does not own keeps its bytes.  On the TPU
+    it is ONE call of :func:`~hetu_tpu.ops.pallas.kv_append.kv_append`,
+    whose grid walks the slots and rewrites one sublane tile of the
+    donated slab each, aliased onto its output; every other backend
+    (Pallas TPU kernels do not run there) takes a loop over the batch —
+    per sequence a dynamic_slice, a select and a dynamic_update_slice —
+    which is also what the kernel is held bitwise equal to.
+    ``kv_append_calls`` counts either path per trace.
 
     ``valid`` (optional 4th graph input, (B,) int): rows ``>= valid[b]``
     of the chunk are NOT written — the old cache bytes are preserved by
@@ -568,13 +573,29 @@ def _kv_cache_append(c, cache, new, positions, valid=None):
     never exceeds the rows the slab holds (past it the window clamps
     under XLA dynamic-slice semantics and the write would shift)."""
     positions = jnp.asarray(positions, jnp.int32)
+    chunk = new.shape[2]
+    count = (jnp.full(positions.shape, chunk, jnp.int32) if valid is None
+             else jnp.minimum(jnp.asarray(valid, jnp.int32), chunk))
+    if jax.default_backend() == "tpu":
+        from .pallas.kv_append import kv_append
+        return _partitioned(c, kv_append, cache, new, positions, count)
+    return _kv_append_loop(cache, new, positions, count)
+
+
+def _kv_append_loop(cache, new, positions, count):
+    """:func:`_kv_cache_append` as XLA ops, one sequence after another:
+    the path of every backend but the TPU, and what the kernel's bytes
+    are compared with.  ``count``: (B,) int32, at most C."""
     heads, chunk, d = new.shape[1:]
     slab_rows, lanes = cache.shape[2:]
     r = lanes // d
+    from ..metrics import record_kv_append_call
+    from .pallas.kv_append import geometry
+    record_kv_append_call(
+        geometry(chunk, slab_rows, lanes, d, cache.dtype.itemsize)[0],
+        lanes, "loop")
     # slab rows a chunk can touch, wherever in a slab row it starts
     win = min((chunk + r - 2) // r + 1, slab_rows)
-    count = (jnp.full(positions.shape, chunk, jnp.int32) if valid is None
-             else jnp.minimum(jnp.asarray(valid, jnp.int32), chunk))
     # key row (counted from the window's first) of every window element
     at = (jnp.arange(win, dtype=jnp.int32)[:, None] * r
           + jnp.arange(lanes, dtype=jnp.int32)[None, :] // d)

@@ -310,15 +310,31 @@ def _diff_attention_ring(c, q, k_new, v_new, k_ring, v_ring, positions, ids,
         [seen_ring, seen_new], [v_ring, vn],
         _lambda(lq1, lk1, lq2, lk2, lam_init), norm_w, lam_init, eps)
     count = _count(ids, valid)
-    return (out, _ring_write(k_ring, kn, p, count),
-            _ring_write(v_ring, vn, p, count))
+    return (out, _ring_put(c, k_ring, kn, p, count),
+            _ring_put(c, v_ring, vn, p, count))
+
+
+def _ring_put(c, ring, new, p, count):
+    """:func:`_ring_write` as a step runs it.  A one-token step's row —
+    every step a window serves — is on the TPU the KV slabs' aliased
+    write (:func:`~hetu_tpu.ops.pallas.kv_append.kv_append`: plain rows,
+    key row ``p mod W``), one tile of the donated ring rewritten per
+    slot; a chunk (set-up's prompts) and every other backend take the
+    select, which is what the kernel is held bitwise equal to."""
+    if new.shape[2] == 1 and jax.default_backend() == "tpu":
+        from .attention import _partitioned
+        from .pallas.kv_append import kv_append
+        return _partitioned(c, kv_append, ring, new,
+                            jnp.mod(p, ring.shape[2]), count)
+    return _ring_write(ring, new, p, count)
 
 
 def _ring_write(ring, new, p, count):
     """``ring`` (B, G, W, 2D) with rows ``j < count[b]`` of ``new`` (B, G,
     C, 2D) written at slots ``(p[b] + j) mod W``; where a chunk longer
     than the ring maps several rows to a slot, the last one stays.  One
-    select over the whole ring: no loop over the batch, no scatter."""
+    select over the whole ring (all of it read and rewritten): no loop
+    over the batch, no scatter."""
     w, chunk = ring.shape[2], new.shape[2]
     slots = jnp.arange(w, dtype=jnp.int32)
     if chunk == 1:
@@ -336,8 +352,8 @@ def _ring_write(ring, new, p, count):
 def _ring_append(c, ring, new, positions, ids, valid=None):
     """The ring write alone (``new``: (B, G, C, 2D) rows, as
     ``pair_rows_op`` gives them)."""
-    return _ring_write(ring, new.astype(ring.dtype),
-                       positions.astype(jnp.int32), _count(ids, valid))
+    return _ring_put(c, ring, new.astype(ring.dtype),
+                     positions.astype(jnp.int32), _count(ids, valid))
 
 
 ring_append_op = def_op("RingAppend", _ring_append)

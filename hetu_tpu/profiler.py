@@ -229,7 +229,7 @@ class HetuProfiler:
         """{family: {kind: count}} over EVERY counter family on the
         observability registry in one call (``hetu_tpu.metrics``
         ``all_counts``): flash_fallbacks, flash_calls,
-        decode_attn_calls, moe_calls, emb_pallas_fallbacks, faults, elastic, autoparallel, cache, zero, step_cache, run_plan, serve,
+        decode_attn_calls, kv_append_calls, moe_calls, emb_pallas_fallbacks, faults, elastic, autoparallel, cache, zero, step_cache, run_plan, serve,
         decode, prefix_cache, decode_recovery, serve_rejection_reason,
         fleet, protocol, ps_rpc_bytes.  The per-family
         accessors below are thin slices of this — same registry, same
@@ -291,6 +291,19 @@ class HetuProfiler:
         reads its slabs through jnp."""
         from .metrics import decode_attn_call_counts
         return decode_attn_call_counts()
+
+    @staticmethod
+    def kv_append_calls():
+        """{"<block rows>x<lanes>:<kernel|loop>": count} of traced
+        cache-row appends (``kv_cache_append_op``, a window's one-row
+        ring write): the block of the state buffer one program rewrites
+        — one sublane tile, all heads — and whether the write is the
+        aliased kernel of ``ops/pallas/kv_append.py`` (the TPU) or the
+        loop over the batch (every other backend).  A chat one-token
+        program reads ``{"8x128:kernel": 48}``, glm's ``{"16x640:kernel":
+        13}``.  Per trace."""
+        from .metrics import kv_append_call_counts
+        return kv_append_call_counts()
 
     @staticmethod
     def moe_calls():

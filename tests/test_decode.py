@@ -342,14 +342,19 @@ def test_engine_counts_the_kv_rows_the_kernel_fetches(jnp_rows_run,
     alone, what the compiled geometry fetches — the live key blocks of
     every slot of the batch bucket — and ``decode_attn_calls`` names the
     geometry once per layer per trace: the kernel in interpret mode
-    behind a backend that says tpu, emitting the jnp path's tokens."""
+    behind a backend that says tpu, emitting the jnp path's tokens.  The
+    rows were appended by the aliased kernel (``kv_append_calls``: K and
+    V of every layer, one tile of 8 float32 slab rows a program)."""
     import functools
 
     import jax
     from hetu_tpu.ops.pallas import decode_attention as da
+    from hetu_tpu.ops.pallas import kv_append as ka
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(da, "decode_attention", functools.partial(
         da.decode_attention, interpret=True))
+    monkeypatch.setattr(ka, "kv_append", functools.partial(
+        ka.kv_append, interpret=True))
     # 16 slab rows (32 keys) of every head a key block
     monkeypatch.setattr(da, "BLOCK_BYTES", 16 * _ROWS_CFG.n_head * 128 * 4)
     monkeypatch.setattr(da, "MIN_BLOCK_ROWS", 8)
@@ -362,6 +367,8 @@ def test_engine_counts_the_kv_rows_the_kernel_fetches(jnp_rows_run,
         32 * (-(-n // 32) + 1) for n in range(1, 41))
     assert calls == {f"{_ROWS_CFG.n_head}x16": _ROWS_CFG.n_layer}
     assert HetuProfiler.all_counters()["decode_attn_calls"] == calls
+    assert HetuProfiler.all_counters()["kv_append_calls"] == {
+        "8x128:kernel": 2 * _ROWS_CFG.n_layer}
 
 
 def test_slab_format_follows_head_dim_alone(decode_graph):

@@ -184,6 +184,7 @@ def test_metrics_dump_roundtrips_every_counter_family():
     metrics.record_flash_fallback("test_reason")
     metrics.record_flash_call(512, 512, one_pass=True)
     metrics.record_decode_attn_call(10, 256)
+    metrics.record_kv_append_call(16, 640, "kernel")
     metrics.record_moe_call(40, 320, 8)
     metrics.record_fault("test_fault", 2)
     metrics.record_elastic("elastic_shrink")
@@ -213,6 +214,7 @@ def test_metrics_dump_roundtrips_every_counter_family():
         "flash_fallbacks": metrics.flash_fallback_counts(),
         "flash_calls": metrics.flash_call_counts(),
         "decode_attn_calls": metrics.decode_attn_call_counts(),
+        "kv_append_calls": metrics.kv_append_call_counts(),
         "moe_calls": metrics.moe_call_counts(),
         "emb_pallas_fallbacks": metrics.emb_pallas_fallback_counts(),
         "faults": metrics.fault_counts(),
@@ -236,6 +238,7 @@ def test_metrics_dump_roundtrips_every_counter_family():
         assert dump["counters"][fam] == want, fam
     assert legacy["flash_calls"] == {"512x512:one_pass": 1}
     assert legacy["decode_attn_calls"] == {"10x256": 1}
+    assert legacy["kv_append_calls"] == {"16x640:kernel": 1}
     assert legacy["moe_calls"] == {"40of320:top8:ragged": 1}
     assert legacy["faults"] == {"test_fault": 2}
     assert legacy["serve"]["serve_queue_depth_hw"] == 9

@@ -173,6 +173,35 @@ def test_one_token_path_and_chunked_path_serve_the_same(weights):
     assert slow == fast
 
 
+def test_states_written_by_the_aliased_kernel_serve_the_same(
+        weights, monkeypatch):
+    """ISSUE 37: behind a backend that says tpu every one-token step
+    writes its slab rows AND its ring rows through ``kv_append`` (here in
+    interpret mode), every chunked step its slab rows: the streams, and
+    every byte of every state at the end, are the CPU path's — so each
+    window layer's attention read its ring before the aliased write."""
+    import functools
+
+    from hetu_tpu.ops.pallas import kv_append as ka
+    prompts = _prompts(4, [11, 30, 5])
+    plain = _engine(weights, 8)
+    want, _ = _serve(plain, prompts, 10)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(ka, "kv_append", functools.partial(
+        ka.kv_append, interpret=True))
+    metrics.reset_all()
+    eng = _engine(weights, 8)
+    got, _ = _serve(eng, prompts, 10)
+    assert got == want
+    for name, state in eng.caches.items():
+        assert np.array_equal(np.asarray(state),
+                              np.asarray(plain.caches[name])), name
+    calls = metrics.kv_append_call_counts()
+    assert calls and all(k.endswith(":kernel") for k in calls)
+    # the rings' one-row writes among them (tiny heads: 16 lanes a pair)
+    assert "8x16:kernel" in calls
+
+
 def test_reseated_slot_serves_what_a_fresh_engine_serves(weights):
     """A slot that held a longer sequence: its recurrent state is zeroed
     at ``join``, its rings and slabs are read by position only."""

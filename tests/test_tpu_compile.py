@@ -192,6 +192,52 @@ def _slab_copies(text, slab):
     return hits
 
 
+def _appends_in_place(text):
+    """How many ``kv_append`` calls an optimized HLO holds — each with its
+    output aliased to operand 3, the state buffer (ISSUE 37)."""
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "/kv_append/pallas_call" in line]
+    assert all("output_to_operand_aliasing={{}: (3, {})}" in line
+               for line in calls)
+    return len(calls)
+
+
+def _loops(text):
+    """``op_name`` of every ``while`` of an optimized HLO but the binary
+    search in front of the one-token kernel (its live-block schedule): an
+    append that walks the batch is one of these, beside the scans."""
+    names = re.findall(r' while\([^\n]*op_name="([^"]*)"', text)
+    assert len(names) == len(re.findall(r" while\(", text))
+    return [n for n in names if "searchsorted" not in n]
+
+
+#: memory peak (arguments + outputs - aliased + temporaries, bytes) of the
+#: programs below compiled from the PARENT of ISSUE 37, the append a loop
+#: over the batch and the ring's write a select.  The programs at a
+#: cell's sizes (phi4, solar, glm) may not pass it by more than ``_ROOM``
+#: (the phi4 one-token program reads 21,504 bytes over, the kernel's
+#: counts and laid-out rows; solar's and glm's read 32 KB to 2 MB UNDER).
+#: The four- and eight-layer programs at toy depth get ``_TOY_ROOM``: a
+#: chunk's rows are handed to the kernel row-major and repeated along the
+#: lanes (4 MB a slab at the chat shape, chunk 32: +14.1 MB), and where
+#: VMEM has room the compiler's memory-space assignment carries whole
+#: states through it (+11.1 / +13.8 MB in the hybrid programs)
+_ROOM, _TOY_ROOM = 1 << 16, 1 << 24
+_PEAK_BEFORE_37 = {
+    ("chat", 1): 621012992, ("chat", 2): 623736832, ("chat", 32): 622795264,
+    ("hybrid", 1): 81092608, ("hybrid", 2): 81093632, ("hybrid", 32): 81100800,
+    ("phi4", 1): 2519435264,
+    ("solar", 8, 1): 12054712320, ("solar", 8, 32): 13737291776,
+    ("solar", 4, 1): 6227587584, ("solar", 4, 32): 7607236608,
+    ("glm", 1): 12843913728, ("glm", 32): 13488839680}
+
+
+def _peak(compiled):
+    mem = compiled.memory_analysis()
+    return (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+
+
 @pytest.fixture(scope="module")
 def chat_engine():
     """GPT-2 medium's widths (1024 wide, 16 heads of 64), four layers
@@ -239,10 +285,21 @@ def test_decode_steps_hold_no_slab_copy(chip, chat_engine, monkeypatch,
            tuple(chip(slab, jnp.float32) for _ in eng.cache_names))
     # the attention dispatch asks jax.default_backend() and must hear tpu
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    text = jax.jit(eng._program(iex, keys), donate_argnums=(1,)).lower(
-        params, fed, chip((16,), jnp.int32)).compile().as_text()
-    assert ("tpu_custom_call" in text) == (chunk == 1)
+    compiled = jax.jit(eng._program(iex, keys), donate_argnums=(1,)).lower(
+        params, fed, chip((16,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert ("flash_fwd_q1" in text) == (chunk == 1)
     assert not _slab_copies(text, slab)
+    # ISSUE 37: K and V of every layer appended by the aliased kernel,
+    # straight onto the donated parameter; no loop walks the batch
+    assert _appends_in_place(text) == 2 * 4 and _loops(text) == []
+    if chunk == 1:
+        # (a chunked program of four layers has room in VMEM, and the
+        # compiler's memory-space assignment carries whole slabs through
+        # it, as it did the loop's)
+        assert len(re.findall(r"custom-call\([^\n]*%fed_1__\d+_[.\d]*\), "
+                              r"[^\n]*/kv_append/", text)) == 2 * 4
+    assert _peak(compiled) <= _PEAK_BEFORE_37["chat", chunk] + _TOY_ROOM
     # and the slabs are fed and returned row-major, unpadded
     layout = re.search(r"entry_computation_layout=\{(.*)\}", text).group(1)
     assert "f32[16,16,384,128]{3,2,1,0:T(8,128)}" in layout
@@ -304,8 +361,18 @@ def test_hybrid_decode_steps_update_every_kind_of_state_in_place(
     fed = ({keys[name]: chip(d, t) for name, (d, t) in feeds.items()},
            tuple(chip(*dims(n)) for n in eng.cache_names))
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    text = jax.jit(eng._program(iex, keys), donate_argnums=(1,)).lower(
-        params, fed, chip((b,), jnp.int32)).compile().as_text()
+    compiled = jax.jit(eng._program(iex, keys), donate_argnums=(1,)).lower(
+        params, fed, chip((b,), jnp.int32)).compile()
+    text = compiled.as_text()
+    # ISSUE 37: every slab's rows, and in the one-token step every ring's
+    # row, written by the aliased kernel — the ring AFTER the attention
+    # that reads it as it was, with no copy of it in between (below); a
+    # chunk's ring rows keep their select.  The loops left are the scans.
+    kinds = [eng._kinds[n] for n in eng.cache_names]
+    assert _appends_in_place(text) == kinds.count("kv") + (
+        kinds.count("ring") if chunk == 1 else 0)
+    assert all("mix.ssm" in name for name in _loops(text))
+    assert _peak(compiled) <= _PEAK_BEFORE_37["hybrid", chunk] + _TOY_ROOM
     for kind, (shape, dtype) in shapes.items():
         tag = ("bf16" if dtype == jnp.bfloat16 else "f32") \
             + "[" + ",".join(map(str, shape)) + "]"
@@ -347,12 +414,25 @@ def test_shared_kv_readers_fetch_live_rows_only(chip, monkeypatch):
            tuple(chip(*dims(n)) for n in eng.cache_names))
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     before = metrics.decode_attn_call_counts().get("10x256", 0)
-    text = jax.jit(eng._program(eng.iex, keys), donate_argnums=(1,)).lower(
-        params, fed, chip((b,), jnp.int32)).compile().as_text()
+    compiled = jax.jit(eng._program(eng.iex, keys),
+                       donate_argnums=(1,)).lower(
+        params, fed, chip((b,), jnp.int32)).compile()
+    text = compiled.as_text()
     assert metrics.decode_attn_call_counts().get("10x256", 0) == before + 2
     assert text.count("tpu_custom_call") >= 2
     assert not re.findall(r"f32\[64,10,[\d,]*4608", text)
     assert not _slab_copies(text, slab)
+    # ISSUE 37, at the cell's state sizes: the slabs' and the rings' rows
+    # through the aliased kernel, no ring of 84 MB copied or rewritten by
+    # a select, no loop over the batch
+    ring = (64, 10, 512, 128)
+    assert {dims(n) for n in eng.cache_names
+            if eng._kinds[n] == "ring"} == {(ring, jnp.dtype(jnp.bfloat16))}
+    kinds = [eng._kinds[n] for n in eng.cache_names]
+    assert _appends_in_place(text) == kinds.count("kv") + kinds.count("ring")
+    assert not _slab_copies(text, ring) and _loops(text) == []
+    assert not re.findall(r"= bf16\[64,10,512,128\]\S* select\(", text)
+    assert _peak(compiled) <= _PEAK_BEFORE_37["phi4", 1] + _ROOM
 
 
 # ---------------------------------------------------- grouped expert product
@@ -479,10 +559,13 @@ def test_solar_share_programs_fit_and_multiply_group_by_group(
     compiled = jax.jit(eng._program(iex, keys), donate_argnums=(1,)).lower(
         params, fed, chip((b,), jnp.int32)).compile(
             compiler_options=asked or None)
-    text, mem = compiled.as_text(), compiled.memory_analysis()
-    peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    text, peak = compiled.as_text(), _peak(compiled)
     assert 6.0e9 * periods < peak < 8.0e9 * periods, peak   # of 16 GB
+    assert peak <= _PEAK_BEFORE_37["solar", layers, chunk] + _ROOM
+    # ISSUE 37: the attention layer's two slabs appended by the aliased
+    # kernel; no loop but the delta rule's scans (below)
+    assert _appends_in_place(text) == 2 * periods
+    assert all("mix.kda" in name for name in _loops(text))
     assert metrics.moe_call_counts()["40of320:top8:kernel"] \
         == before[1] + layers
     assert metrics.decode_attn_call_counts().get("1x2048", 0) \
@@ -577,10 +660,14 @@ def test_glm_share_programs_fit_and_read_the_latent_cache_in_place(
               metrics.moe_call_counts().get("8of64:top4:kernel", 0))
     compiled = jax.jit(eng._program(iex, keys), donate_argnums=(1,)).lower(
         params, fed, chip((b,), jnp.int32)).compile()
-    text, mem = compiled.as_text(), compiled.memory_analysis()
-    peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    text, peak = compiled.as_text(), _peak(compiled)
     assert 12.7e9 < peak < 14.5e9, peak                      # of 16 GB
+    assert peak <= _PEAK_BEFORE_37["glm", chunk] + _ROOM
+    # ISSUE 37: every layer's latent rows appended by the aliased kernel,
+    # 128 programs of one 16-row tile of 640 lanes; no loop over the batch
+    # (a chunk's read walks its groups of slots, one loop a layer)
+    assert _appends_in_place(text) == 13
+    assert len(_loops(text)) == (0 if chunk == 1 else 13)
     assert metrics.moe_call_counts()["8of64:top4:kernel"] == before[1] + 12
     assert metrics.decode_attn_call_counts().get("1x512", 0) \
         == before[0] + (13 if chunk == 1 else 0)
