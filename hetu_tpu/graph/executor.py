@@ -111,9 +111,15 @@ COMPILE_CACHE_DIR = os.path.join(
 #: MAX_SIZE``, 192 MiB) and evicts least-recently-used entries; with every
 #: program cached, one ``chip_smoke.py`` pass wrote more than the cap and
 #: the entry worth most — the BERT-base step, 53 MiB for 75 s of compile —
-#: was always the one evicted, so nothing ever hit.  The 22 decode programs
-#: (about 5 MiB and 2-3 s each) are what overflowed it: they are cheaper to
-#: recompile than to keep.
+#: was always the one evicted, so nothing ever hit; the 22 small decode
+#: programs of that pass are what overflowed it.  What the rule costs the
+#: benchmark's decode programs was first READ in PR 39 (``obs/compile_
+#: log.py``; ``PERF.md`` section 5, "where a set-up goes"): gpt2-medium's
+#: one-token program compiles in 3.4-4.4 s and its chunk programs in
+#: 4.3-18 s, so three of the cell's thirteen fall under the threshold in
+#: a first process and — 8-10 s each there — over it in the next, and a
+#: program that IS kept is read back in about 2 s.  A miss that is not stored is in
+#: ``compile_counts()`` as ``<owner>:unstored`` / ``unstored_us``.
 COMPILE_CACHE_MIN_COMPILE_SECS = 5.0
 
 _compile_cache_configured = False
@@ -135,6 +141,10 @@ def configure_compile_cache():
         return
     import jax
     _compile_cache_configured = True
+    # every program the process compiles from here on leaves a record
+    # (obs/compile_log.py) — a CPU process's too
+    from ..obs import compile_log
+    compile_log.install()
     if jax.default_backend() == "cpu":
         # XLA:CPU compiles in seconds, and its loader logs an error (a
         # target-feature mismatch with itself) for every entry read back
@@ -1499,7 +1509,9 @@ class Executor:
                  matmul_precision=None, **kwargs):
         import jax
         import os as _os
+        from ..obs.compile_log import SetupPhase
         configure_compile_cache()
+        graph = SetupPhase("setup.graph").start()
         if isinstance(eval_node_dict, dict):
             self.eval_node_dict = dict(eval_node_dict)
         else:
@@ -1728,6 +1740,7 @@ class Executor:
             self._async_window = 4
 
         self._validate_graphs()
+        graph.stop()
 
         if self._auto_resume and self.auto_save_dir:
             self.resume(self.auto_save_dir)
@@ -1843,13 +1856,16 @@ class Executor:
         would make restoring a 50-param bucket pay 50 full slab
         gather+scatter trips — and on a multi-process mesh every fetch is
         a collective)."""
+        from ..obs.compile_log import SetupPhase
         from ..parallel import zero as _zero
         by_bucket = {}
+        placed = SetupPhase("setup.weights").start()
         for node, val in items.items():
             b = self._zero_covered.get(node)
+            val = np.asarray(val)
+            placed.nbytes += val.nbytes
             if b is None:
-                self.var_values[node] = self._place_param(
-                    np.asarray(val), node)
+                self.var_values[node] = self._place_param(val, node)
             else:
                 by_bucket.setdefault(b.key, (b, {}))[1][node] = val
         for key, (b, vals) in by_bucket.items():
@@ -1864,6 +1880,7 @@ class Executor:
             self._zero_slabs[key] = self._global_put(
                 slab, _zero.slab_sharding(self.mesh))
             self._slab_fetch_cache.pop(key, None)
+        placed.stop()
 
     def _set_var_host(self, node, val):
         self._set_vars_host({node: val})

@@ -39,6 +39,7 @@ from collections import OrderedDict
 import numpy as np
 
 from ..metrics import record_step_cache
+from ..obs.compile_log import name_program
 from ..obs.lock_witness import make_lock as _make_lock
 
 _CACHE = OrderedDict()          # signature -> jitted step
@@ -310,6 +311,8 @@ def lookup_or_build(sub, step_fn):
     """Return a jitted step for ``sub``: a cached one when an identical
     build exists, else ``jax.jit(step_fn)`` (stored for the next build)."""
     import jax
+    # the name jax reports this program under (obs/compile_log.py)
+    name_program(step_fn, "train", sub.name)
     if not enabled():
         return jax.jit(step_fn, donate_argnums=(0, 2))
     sig = signature(sub)
@@ -351,12 +354,22 @@ def lookup_or_build_serve(iex, bucket, infer_fn):
     import jax
     from ..metrics import record_serve
     donate = (1,) if iex.donate else ()
+    # a tuple is the decode plane's key: (batch, len) for the one-token
+    # entry, (batch, chunk, len) for a chunked one
+    if isinstance(bucket, (tuple, list)):
+        bb, *chunk, lb = bucket
+        name_program(infer_fn, "decode",
+                     f"b{bb}:c{chunk[0] if chunk else 1}:l{lb}")
+    else:
+        name_program(infer_fn, "serve", f"b{bucket}")
 
     def build():
-        # the compile-once evidence: recorded HERE, on real builds only
-        # — a cross-rebuild cache hit below builds nothing and must not
+        # the compile-once evidence: one count a jit WRAPPER constructed
+        # — a cross-rebuild cache hit below constructs none and must not
         # inflate the counter the acceptance check compares to the
-        # number of distinct buckets used
+        # number of distinct buckets used.  The XLA compile happens at
+        # the wrapper's first call and leaves its own record
+        # (obs/compile_log.py: ``compile_counts()`` "<owner>:programs")
         record_serve("serve_bucket_compiles")
         asked = iex.compiler_options()
         return jax.jit(infer_fn, donate_argnums=donate,

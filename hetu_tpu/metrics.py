@@ -25,6 +25,7 @@ import threading
 
 import numpy as np
 
+from .obs.compile_log import OWNERS as _OWNERS
 from .obs.registry import REGISTRY
 from .obs.trace import TRACER as _TR
 
@@ -531,6 +532,127 @@ def reset_step_cache_counts():
     _step_cache.reset()
 
 
+# ------------------------------------------------ compile / set-up counters
+# What jax did to every program of the process, folded from its own
+# ``jax.monitoring`` events by ``obs/compile_log.py`` (one record a
+# program; ``HetuProfiler.compile_log()`` keeps the newest).  Keys are
+# ``<owner>:<what>``: the owner is ``train`` (a ``SubExecutor`` step),
+# ``serve`` (an ``InferenceExecutor`` bucket), ``decode`` (a
+# ``DecodeEngine`` program) — ``graph/step_cache.py`` names what it jits
+# — or ``other`` (every other ``jit``); what is
+#   ``programs``       programs that reached the backend's compiler
+#   ``trace_us``       Python tracing of the function (jaxpr)
+#   ``lower_us``       jaxpr -> MLIR module
+#   ``backend_us``     the backend interval: the XLA compile, or the read
+#                      from the persistent cache in its place
+#   ``cache_hits``     programs read back from the persistent cache
+#   ``cache_misses``   programs compiled while the cache was on
+#   ``cache_read_us``  retrieval time of the hits (part of backend_us)
+#   ``unstored``       misses jax did NOT write (its rule: compile time
+#                      under jax_persistent_cache_min_compile_time_secs,
+#                      host callbacks, process > 0) ...
+#   ``unstored_us``    ... and their backend time: what EVERY later process
+#                      pays again
+# A steady process adds nothing here: a count that climbs across steps
+# is a program compiling again.  ``setup_us`` / ``setup_bytes`` hold the
+# program's own set-up phases (``obs.compile_log.SetupPhase``):
+# ``setup.graph`` an executor's construction (topology, lints, plan),
+# ``setup.weights`` host arrays to the device, ``setup.state`` a decode
+# engine's slabs, rings and recurrent state.  Surfaced by
+# ``HetuProfiler.compile_counters()`` / ``setup_counters()``.
+
+_compile = REGISTRY.counter_family(
+    "compile",
+    "programs compiled or read from the persistent cache, by "
+    "<owner>:<what> (us of tracing / lowering / backend, cache hits and "
+    "misses, misses not stored)")
+_setup_us = REGISTRY.counter_family(
+    "setup_us",
+    "us of the program's own set-up phases (setup.graph / setup.weights "
+    "/ setup.state), compilation apart")
+_setup_bytes = REGISTRY.counter_family(
+    "setup_bytes",
+    "bytes the set-up phases moved to or allocated on the device")
+
+SETUP_PHASES = ("setup.graph", "setup.weights", "setup.state")
+
+
+def record_compile(rec):
+    """Fold one ``obs.compile_log`` record into ``compile_counts()``
+    (and a ``decode`` program's seconds into ``decode_step_compile_us``:
+    it compiled inside a step's ``dispatch`` phase)."""
+    owner = rec["owner"]
+    add = {"programs": 1, "trace_us": rec["trace_us"],
+           "lower_us": rec["lower_us"], "backend_us": rec["backend_us"]}
+    if rec["cache"] == "hit":
+        add.update(cache_hits=1, cache_read_us=rec["cache_read_us"])
+    elif rec["cache"] == "miss":
+        add["cache_misses"] = 1
+        if not rec["stored"]:
+            add.update(unstored=1, unstored_us=rec["backend_us"])
+    for what, n in add.items():
+        if n:
+            _compile.inc(f"{owner}:{what}", int(n))
+    if owner == "decode":
+        record_decode("decode_step_compile_us", rec["trace_us"]
+                      + rec["lower_us"] + rec["backend_us"])
+
+
+def compile_counts():
+    """{"<owner>:<what>": n} snapshot of the compile counters."""
+    return _compile.counts()
+
+
+def record_setup(phase, us, nbytes=0):
+    """One set-up phase ended: its microseconds and bytes."""
+    _setup_us.inc(str(phase), int(us))
+    if nbytes:
+        _setup_bytes.inc(str(phase), int(nbytes))
+
+
+def setup_counts():
+    """{"us": {phase: us}, "bytes": {phase: bytes}} of the set-up
+    phases."""
+    return {"us": _setup_us.counts(), "bytes": _setup_bytes.counts()}
+
+
+def setup_breakdown(owners=_OWNERS):
+    """Where the program's share of a set-up went, in the five numbers a
+    ``setup_s`` reading is explained by — over ``owners`` (the program's
+    own programs; ``other`` holds helpers and whatever else the process
+    jits), each None where nothing was recorded::
+
+        compile_s              backend_us: compiling the programs, or
+                               reading them back (cache_read_us is PART
+                               of a hit's backend interval, not beside it)
+        trace_lower_s          trace_us + lower_us
+        compile_cache_hit_pct  100 * hits / (hits + misses); None with
+                               the persistent cache off
+        compile_unstored_s     unstored_us: compiled, and not kept
+        program_build_s        setup.graph + setup.weights + setup.state
+    """
+    c = compile_counts()
+
+    def total(what):
+        return sum(c.get(f"{o}:{what}", 0) for o in owners)
+
+    out = dict.fromkeys(("compile_s", "trace_lower_s",
+                         "compile_cache_hit_pct", "compile_unstored_s",
+                         "program_build_s"))
+    if total("programs"):
+        out["compile_s"] = total("backend_us") / 1e6
+        out["trace_lower_s"] = (total("trace_us") + total("lower_us")) / 1e6
+        out["compile_unstored_s"] = total("unstored_us") / 1e6
+        asked = total("cache_hits") + total("cache_misses")
+        if asked:
+            out["compile_cache_hit_pct"] = 100.0 * total("cache_hits") / asked
+    us = _setup_us.counts()
+    if us:
+        out["program_build_s"] = sum(
+            us.get(p, 0) for p in SETUP_PHASES) / 1e6
+    return out
+
+
 # ------------------------------------------------------ run-plan counters
 # The executor's cached run plans (``graph/run_plan.py``) record the
 # dispatch-path behaviour here: ``plan_cache_hit`` / ``plan_cache_miss``
@@ -595,9 +717,12 @@ def reset_run_plan_counts():
 # (``serve_rejections`` — the backpressure path), PS failovers absorbed
 # MID-SERVE (``serve_failovers``), dispatched batches re-run ONCE after
 # a transient device-call failure before their futures fail
-# (``serve_batch_retries``, ISSUE 19), per-bucket executable builds
+# (``serve_batch_retries``, ISSUE 19), per-bucket jit wrappers constructed
 # (``serve_bucket_compiles`` — the compile-once claim is exactly "this
-# equals the number of distinct buckets used"), read-only embedding
+# equals the number of distinct buckets used"; it counts
+# ``step_cache.lookup_or_build_serve`` making a ``jax.jit``, NOT the XLA
+# compilation, which happens at the wrapper's first call and is counted,
+# with its seconds, under ``compile_counts()``), read-only embedding
 # refreshes (``serve_emb_refresh_rows``), and the queue-depth high-water
 # mark (``serve_queue_depth_hw`` — gauge semantics: the recorded value is
 # the MAX ever seen, not a sum).  Surfaced by
@@ -668,6 +793,12 @@ def reset_serve_counts():
 #   ``decode_step_feed_us``      building the host feeds
 #   ``decode_step_dispatch_us``  the jitted call until it returns, and the
 #                                host state the launch advances
+#   ``decode_step_compile_us``   the PART of ``dispatch`` in which jax traced,
+#                                lowered and compiled (or read back) a
+#                                ``decode`` program: a bucket's first call.
+#                                0 over a steady window; the newest record
+#                                of ``HetuProfiler.compile_log()`` names
+#                                the program that moved it
 #   ``decode_step_wait_us``      until the token ids are ready on the device
 #   ``decode_step_readback_us``  their (batch,) D2H copy
 #   ``decode_step_host_us``      emission, callbacks, bookkeeping
@@ -1113,6 +1244,9 @@ _FAMILIES = {
     "cache": _cache,
     "zero": _zero,
     "step_cache": _step_cache,
+    "compile": _compile,
+    "setup_us": _setup_us,
+    "setup_bytes": _setup_bytes,
     "run_plan": _run_plan,
     "serve": _serve,
     "decode": _decode,

@@ -229,7 +229,9 @@ class HetuProfiler:
         """{family: {kind: count}} over EVERY counter family on the
         observability registry in one call (``hetu_tpu.metrics``
         ``all_counts``): flash_fallbacks, flash_calls,
-        decode_attn_calls, kv_append_calls, moe_calls, emb_pallas_fallbacks, faults, elastic, autoparallel, cache, zero, step_cache, run_plan, serve,
+        decode_attn_calls, kv_append_calls, moe_calls,
+        emb_pallas_fallbacks, faults, elastic, autoparallel, cache, zero,
+        step_cache, compile, setup_us, setup_bytes, run_plan, serve,
         decode, prefix_cache, decode_recovery, serve_rejection_reason,
         fleet, protocol, ps_rpc_bytes.  The per-family
         accessors below are thin slices of this — same registry, same
@@ -424,6 +426,48 @@ class HetuProfiler:
         return step_cache_counts()
 
     @staticmethod
+    def compile_counters():
+        """{"<owner>:<what>": n} of what jax did to every program of the
+        process (``hetu_tpu.metrics`` registry, folded from
+        ``jax.monitoring`` by ``obs/compile_log.py``): owner ``train`` /
+        ``serve`` / ``decode`` (the steps ``graph/step_cache.py`` jits)
+        or ``other``; what ``programs``, ``trace_us``, ``lower_us``,
+        ``backend_us`` (the XLA compile, or the read from the persistent
+        cache in its place), ``cache_hits``, ``cache_misses``,
+        ``cache_read_us``, ``unstored`` / ``unstored_us`` (misses jax's
+        rule did not write: the next process compiles them again).  A
+        steady process adds nothing; :meth:`compile_log` names the
+        programs."""
+        from .metrics import compile_counts
+        return compile_counts()
+
+    @staticmethod
+    def compile_log():
+        """The newest 256 compile records, oldest first — what to print
+        after a slow start or a stall: ``{owner, program, t_end,
+        trace_us, lower_us, backend_us, cache: hit|miss|off,
+        cache_read_us, saved_us, stored}`` a program (``program`` is the
+        subgraph's name, a serving bucket ``b<batch>``, a decode bucket
+        key ``b<batch>:c<chunk>:l<len>``, or the jitted function's own
+        name under owner ``other``).  A ``miss`` with ``stored`` false is
+        compiled again by every later process."""
+        from .obs import compile_log
+        return compile_log.records()
+
+    @staticmethod
+    def setup_counters():
+        """{"us": {phase: us}, "bytes": {phase: bytes}} of the program's
+        own set-up phases, compilation apart: ``setup.graph`` (an
+        executor's construction), ``setup.weights`` (host arrays to the
+        device: ``InferenceExecutor`` weights, ``Executor.load_dict`` /
+        ``load``), ``setup.state`` (a ``DecodeEngine``'s slabs, rings and
+        recurrent state: construction, ``reserve`` and every growth).
+        ``metrics.setup_breakdown()`` reduces these and
+        :meth:`compile_counters` to five numbers."""
+        from .metrics import setup_counts
+        return setup_counts()
+
+    @staticmethod
     def run_plan_counters():
         """{kind: count} of cached-run-plan / async-dispatch events
         (``hetu_tpu.metrics`` registry): ``plan_cache_hit`` /
@@ -449,9 +493,10 @@ class HetuProfiler:
         padding) of which ``serve_pad_rows`` were padding (the micro-
         batcher's bucket waste), queue-full rejections (backpressure), queue-depth high-water
         (``serve_queue_depth_hw`` — a max gauge, not a sum), PS
-        failovers absorbed mid-serve, per-bucket executable builds
+        failovers absorbed mid-serve, per-bucket jit wrappers constructed
         (``serve_bucket_compiles`` — compile-once means this equals the
-        number of distinct buckets used), and read-only embedding
+        number of distinct buckets used; the compilations themselves are
+        :meth:`compile_counters`), and read-only embedding
         refresh rows.  A process that never serves reports an empty
         dict."""
         from .metrics import serve_counts
@@ -467,7 +512,9 @@ class HetuProfiler:
         engine steps (``decode_steps`` — one jitted call per token
         batch) with their per-row prefill/generate split
         (``decode_prefill_rows`` / ``decode_generate_rows``), bucket
-        ladder growths (``decode_batch_grows`` / ``decode_len_grows`` —
+        ladder growths (``decode_batch_grows`` / ``decode_len_grows``;
+        ``decode_step_compile_us`` is the part of
+        ``decode_step_dispatch_us`` that compiled a program —
         each at most one fresh compile), queue-full rejections, the
         device-resident KV-cache footprint high-water mark
         (``decode_kv_bytes_hw`` — a max gauge, not a sum) with the slab

@@ -27,7 +27,9 @@ This module is the serving plane for that workload:
   (:func:`~hetu_tpu.serving.default_buckets`: powers of two, then
   multiples of 128).  One jitted step exists per ``(batch_bucket,
   len_bucket)`` pair — built through the process-wide serve cache
-  (``serve_bucket_compiles`` counts real builds) and dispatched through a
+  (``serve_bucket_compiles`` counts the jit wrappers constructed; the XLA
+  compile happens at a wrapper's first call and leaves a ``decode`` record,
+  ``HetuProfiler.compile_log()``) and dispatched through a
   per-engine :class:`~hetu_tpu.graph.run_plan.KeyedPlanCache`
   (``plan_cache_hit`` is the steady-state proof: after warmup every
   token batch dispatches with zero Python planning and zero compiles).
@@ -125,6 +127,7 @@ from ..graph.run_plan import KeyedPlanCache
 from ..graph import step_cache
 from ..metrics import (record_decode, record_decode_latency,
                        record_decode_recovery)
+from ..obs.compile_log import SetupPhase
 from ..obs.lock_witness import make_condition, make_lock
 from ..obs.trace import Phases as _Phases
 from ..obs.trace import TRACER as _TR
@@ -613,8 +616,10 @@ class DecodeEngine:
         self._used = [False] * self.bb       # slot served a sequence before
         self.tokens = np.zeros(self.bb, np.int32)
         self.positions = np.zeros(self.bb, np.int32)
-        self.caches = {name: self._alloc(name, self.bb, self.lb)
-                       for name in self.cache_names}
+        with SetupPhase("setup.state") as state:
+            self.caches = {name: self._alloc(name, self.bb, self.lb)
+                           for name in self.cache_names}
+            state.nbytes = self.kv_bytes
         self._clear = None        # jitted zeroing of a slot's recurrent rows
         self._logits = None
         #: steps launched and not collected, oldest first (two at most,
@@ -658,12 +663,15 @@ class DecodeEngine:
         import jax.numpy as jnp
         rows = self._slab_rows(lb) - self._slab_rows(self.lb) \
             if self._pack else 0
-        for name, c in self.caches.items():
-            pad = [(0, bb - self.bb)] + [(0, 0)] * (c.ndim - 1)
-            if self._kinds[name] == "kv":
-                pad[2] = (0, rows)
-            if any(p != (0, 0) for p in pad):
-                self.caches[name] = self.iex._place(jnp.pad(c, pad))
+        with SetupPhase("setup.state") as state:
+            before = self.kv_bytes
+            for name, c in self.caches.items():
+                pad = [(0, bb - self.bb)] + [(0, 0)] * (c.ndim - 1)
+                if self._kinds[name] == "kv":
+                    pad[2] = (0, rows)
+                if any(p != (0, 0) for p in pad):
+                    self.caches[name] = self.iex._place(jnp.pad(c, pad))
+            state.nbytes = self.kv_bytes - before
         grow = bb - self.bb
         self.slots += [None] * grow
         self._used += [False] * grow
@@ -969,7 +977,9 @@ class DecodeEngine:
         """The jitted step for the CURRENT (batch_bucket, len_bucket):
         dispatched through the keyed plan cache (hit = zero planning),
         built at most once per pair through the process-wide serve cache
-        (``serve_bucket_compiles`` counts real builds)."""
+        (``serve_bucket_compiles`` counts the jit wrappers constructed: the
+        compile itself happens at the wrapper's first call, inside that
+        step's ``dispatch`` phase — ``decode_step_compile_us``)."""
         key = (self.bb, self.lb)
 
         def build():

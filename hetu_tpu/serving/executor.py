@@ -117,7 +117,9 @@ class InferenceExecutor:
                  plan=None, decode=False):
         import jax
         from ..graph.executor import configure_compile_cache
+        from ..obs.compile_log import SetupPhase
         configure_compile_cache()
+        graph = SetupPhase("setup.graph").start()
         if isinstance(fetches, Op):
             fetches = [fetches]
         self.fetches = list(fetches)
@@ -175,7 +177,9 @@ class InferenceExecutor:
         self._key = jax.random.key(self.seed)
         self.params = {}
         self.var_names = {}
-        self._load_weights(weights)
+        graph.stop()
+        with SetupPhase("setup.weights") as placed:
+            placed.nbytes = self._load_weights(weights)
         self._compiled = {}     # bucket -> jitted serving step
         self._fetch_rows = {}   # (bucket, feed schema) -> scatter plan
 
@@ -309,6 +313,9 @@ class InferenceExecutor:
                 f"serving their seeded INITIALIZER values",
                 RuntimeWarning)
         self.params = {self._k(n): self._place(v) for n, v in vals.items()}
+        # host bytes that went to the device (a device array stayed put)
+        return sum(v.nbytes for v in vals.values()
+                   if not isinstance(v, jax.Array))
 
     def _place(self, val, node=None):
         import jax
@@ -374,8 +381,10 @@ class InferenceExecutor:
     def compiled(self, bucket):
         """The jitted serving step for one bucket — built AT MOST once
         per (graph, bucket) per process (``serve_bucket_compiles`` counts
-        builds; the process-wide serve cache makes rebuilds reuse the
-        same executable)."""
+        the jit wrappers made; the process-wide serve cache makes
+        rebuilds reuse the same executable; the compile at the wrapper's
+        first call is a ``serve:b<bucket>`` record of
+        ``HetuProfiler.compile_log()``)."""
         if bucket not in self.buckets:
             raise ValueError(f"{bucket} is not a legal bucket "
                              f"{self.buckets}")

@@ -581,7 +581,7 @@ def decode_scenario(n_requests=16, seed=0):
       **token-by-token** ingestion and **request-level** batching (joins
       only into an EMPTY engine): all three token streams BITWISE equal;
     * the compile-once steady state over the chunked stream, by counters:
-      real compiles + serve-cache reuses == dispatch-plan misses ==
+      jit wrappers made + serve-cache reuses == dispatch-plan misses ==
       distinct bucket keys (``(batch, len)`` pairs and ``(batch, chunk,
       len)`` triples), every other step a ``plan_cache_hit``;
     * a popularity-skewed pool stream decoded cold and with a
@@ -731,15 +731,28 @@ def decode_scenario(n_requests=16, seed=0):
     prev_inj = chaos_mod.install(chaos_mod.ChaosInjector.from_spec(
         f"{seed}:kill:replica@0:tok3"))
     exhausted, zs_partials_ok = 0, True
+    # the lone replica's loop starts once all three streams are queued:
+    # it seats them in ONE join, they emit in lockstep, and the kill at
+    # the engine's third token finds one token in every journal and the
+    # second step launched ahead.  Submitted at a running loop, a stream
+    # seated an iteration or two behind the others (the submits race the
+    # loop's thread) had emitted nothing when the clock struck — one run
+    # in 25 read an EMPTY partial and failed ``partial >= 1`` (ISSUE 39)
+    lone = []
+
+    def held_replica(idx):
+        lone.append(DecodeRouter(mk_engine(True), queue_limit=16,
+                                 name=f"recz{idx}", start=False))
+        return lone[-1]
+
     PROTO.start()
     try:
-        door = FrontDoor(
-            lambda idx: DecodeRouter(mk_engine(True), queue_limit=16,
-                                     name=f"recz{idx}"),
-            1, health_every_ms=1e9, wedge_timeout_ms=1e9)
+        door = FrontDoor(held_replica, 1, health_every_ms=1e9,
+                         wedge_timeout_ms=1e9)
         try:
             zs = [door.submit(np.full(4, 3 + i, np.int32),
                               max_new_tokens=gen_cap) for i in range(3)]
+            lone[0].start()
             poll_fleet(door, zs, timeout=120.0)
             for s in zs:
                 try:

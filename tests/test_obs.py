@@ -194,6 +194,10 @@ def test_metrics_dump_roundtrips_every_counter_family():
     metrics.record_cache("emb_cache_hit_rows", 5)
     metrics.record_zero("zero_pad_bytes", 64)
     metrics.record_step_cache("step_cache_hit")
+    metrics.record_compile({
+        "owner": "serve", "trace_us": 10, "lower_us": 20, "backend_us": 300,
+        "cache": "miss", "stored": False, "cache_read_us": 0})
+    metrics.record_setup("setup.weights", 40, 4096)
     metrics.record_run_plan("plan_cache_hit", 3)
     metrics.record_run_plan("feed_pipeline_depth_hw", 2)
     metrics.record_serve("serve_requests", 4)
@@ -225,6 +229,9 @@ def test_metrics_dump_roundtrips_every_counter_family():
         "cache": metrics.cache_counts(),
         "zero": metrics.zero_counts(),
         "step_cache": metrics.step_cache_counts(),
+        "compile": metrics.compile_counts(),
+        "setup_us": metrics.setup_counts()["us"],
+        "setup_bytes": metrics.setup_counts()["bytes"],
         "run_plan": metrics.run_plan_counts(),
         "serve": metrics.serve_counts(),
         "decode": metrics.decode_counts(),
@@ -241,6 +248,12 @@ def test_metrics_dump_roundtrips_every_counter_family():
     assert legacy["kv_append_calls"] == {"16x640:kernel": 1}
     assert legacy["moe_calls"] == {"40of320:top8:ragged": 1}
     assert legacy["faults"] == {"test_fault": 2}
+    assert legacy["compile"] == {
+        "serve:programs": 1, "serve:trace_us": 10, "serve:lower_us": 20,
+        "serve:backend_us": 300, "serve:cache_misses": 1,
+        "serve:unstored": 1, "serve:unstored_us": 300}
+    assert (legacy["setup_us"], legacy["setup_bytes"]) == (
+        {"setup.weights": 40}, {"setup.weights": 4096})
     assert legacy["serve"]["serve_queue_depth_hw"] == 9
     assert legacy["decode"] == {"decode_tokens": 7,
                                 "decode_kv_bytes_hw": 4096}
