@@ -818,9 +818,10 @@ def reset_serve_counts():
 #                                ``decode_prefill_steps``
 #   ``decode_kv_rows_read``      key rows a step's attention fetches of a
 #                                KV slab, summed over the batch bucket's
-#                                slots: each slot's live key blocks where
-#                                the one-token kernel serves
-#                                (``ops.attention.kv_rows_read``), the
+#                                slots: each slot's rows as far as its
+#                                sequence reaches, rounded up to a copy's
+#                                tile, where the one-token kernel serves
+#                                (``ops.attention.kv_rows_fetched``), the
 #                                whole slab on a chunked step and on the
 #                                jnp path
 #   ``decode_kv_rows_held``      key rows the slab holds for those slots:

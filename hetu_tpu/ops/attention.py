@@ -489,19 +489,34 @@ def _decode_gate_reason(s_kv):
 
 
 def kv_rows_read(lengths, slab_shape, pack, itemsize):
-    """Key rows a ONE-TOKEN step's attention fetches of ``(B, H, L/r,
-    lanes)`` slabs (``r = pack``) for sequences of ``lengths`` keys: the
-    live key blocks of the kernel's geometry, whole, where the decode
-    gate lets the kernel in; every row the slabs hold on the jnp path.
-    Host arithmetic over shapes — what ``DecodeEngine`` counts a step
-    by (``decode_kv_rows_read``)."""
+    """Key rows of the key blocks a ONE-TOKEN step's attention WALKS of
+    ``(B, H, L/r, lanes)`` slabs (``r = pack``) for sequences of
+    ``lengths`` keys: the live key blocks of the geometry of a call over
+    a K and a V slab, whole, where the decode gate lets the kernel in;
+    every row the slabs hold on the jnp path.  No counter reads it: what
+    the kernel copies of those blocks is :func:`kv_rows_fetched` (kept
+    for ``tests/bench_harness``, PERF.md §7)."""
+    return _kv_rows(lengths, slab_shape, pack, itemsize, whole=True)
+
+
+def kv_rows_fetched(lengths, slab_shape, pack, itemsize):
+    """Key rows a ONE-TOKEN step's attention FETCHES of those slabs: of a
+    sequence's last live block only the rows below its length, rounded up
+    to what one copy moves (``decode_attention._tail``).  Host arithmetic
+    over shapes — what ``DecodeEngine`` counts a step by
+    (``decode_kv_rows_read``)."""
+    return _kv_rows(lengths, slab_shape, pack, itemsize, whole=False)
+
+
+def _kv_rows(lengths, slab_shape, pack, itemsize, whole):
     b, heads, slab_rows, lanes = slab_shape
     if _decode_gate_reason(slab_rows * pack) is not None:
         return b * slab_rows * pack
-    from .pallas.decode_attention import geometry
-    keys = geometry(heads, slab_rows, lanes, itemsize)[1] * pack
-    live = -(-np.clip(lengths, 1, slab_rows * pack) // keys)
-    return int(live.sum()) * keys
+    from .pallas.decode_attention import _tail, geometry
+    block = geometry(heads, slab_rows, lanes, itemsize)[1]
+    unit = block if whole else _tail(block, itemsize)[0] or block
+    rows = -(-np.clip(lengths, 1, slab_rows * pack) // pack)
+    return int((-(-rows // unit) * unit).sum()) * pack
 
 
 def dispatch_sdpa_decode(q, k_cache, v_cache, positions, scale=None):

@@ -31,17 +31,32 @@ sum_j e^(m_j - m) l_j``, which for ``r = 1`` is the row's own ``acc / l``.
 
 Geometry (:func:`geometry`, from the call's shape and dtype alone): one
 program holds ALL the heads of a slot over a block of key rows sized to
-``BLOCK_BYTES`` — a grid step costs a third of a microsecond whatever it
-moves (PERF.md §6, PR 30), so a program per (slot, head) spends its time
-on steps — and the grid walks a schedule of the LIVE (slot, key block)
-pairs only: the schedule and its length are computed from ``lengths`` in
-front of the call and ride as scalar prefetch and as the grid's traced
-bound, so a block past a sequence's length is neither fetched, computed
-nor stepped over, and the pipeline prefetches the next slot's first block
-behind the current slot's last.  Measured against a static ``(B, key
-blocks)`` grid that skips dead steps (28 % slower at the phi4 cell's
-lengths) and against a hand-written double-buffered copy loop per slot
-(5 % faster, three times the code) before it was chosen.
+``BLOCK_BYTES``, and the grid walks a schedule of the LIVE (slot, key
+block) pairs only: the schedule and its length are computed from
+``lengths`` in front of the call and ride as scalar prefetch and as the
+grid's traced bound, so a block past a sequence's length is neither
+fetched, computed nor stepped over.
+
+The slabs stay in HBM (``memory_space=pl.ANY``) and the kernel copies its
+blocks itself into a ring of ``DEPTH`` buffers a slab, the copies of the
+next ``DEPTH - 1`` schedule entries started before this entry's products
+— across a slot's end too.  Of a sequence's LAST live block a copy moves
+only the rows below the sequence's length, rounded up to a sublane tile
+of the slab's dtype, and ONE product takes only the sub-blocks of
+``SUB_ROWS`` rows that hold a copied row (a branch a count, so that every
+operand's size is static; the rows between the copy's end and the
+sub-block's are zeroed: they hold what an earlier block left there, or
+nothing yet, and a weight of zero times a NaN is a NaN).  So neither the
+bytes nor the products of a step depend on where a block's edge falls,
+and a block is as long as VMEM lets it be.  Measured on the chip
+(PERF.md §6, PR 41) against the BlockSpec pipeline over whole blocks it
+replaced (1.46 times the time at the latent shape, 1.11 to 1.61 at the
+others), a product a sub-block in a loop (each pays the softmax's chain
+of dependent steps, 0.38 us: 1.75 times the time at 128 rows), one copy
+ahead (the lengths of neighbouring slots differ too much: 1.26 times)
+and a program a slot with a loop over its blocks (level with one copy
+ahead; the entry two ahead is a walk over the lengths there where the
+schedule makes it a lookup).
 """
 import functools
 
@@ -52,24 +67,39 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import NEG_INF
 
-#: bytes of one key block, all the heads of a program together (K and V,
-#: double-buffered, hold four of them in VMEM).  Measured on the chip at
-#: both cells' shapes (``tools/decode_attn_ab.py``, PERF.md §6 PR 30): half
-#: of it costs more in grid steps than it saves in dead rows, twice of it
-#: the reverse
-BLOCK_BYTES = 640 * 1024
+#: bytes of one key block: all the heads of a program, every slab operand
+#: of the call (K and V; the latent read's one slab takes rows twice as
+#: long) — ``DEPTH`` of them wait in VMEM.  A block's product costs 0.4 us
+#: whatever it multiplies (the softmax's chain of dependent steps) and a
+#: grid step a third of one, and with a sequence's last block copied and
+#: multiplied only as far as the sequence reaches a longer block wastes
+#: nothing: measured on the chip at the four cells' shapes
+#: (``tools/decode_attn_ab.py``, PERF.md §6 PR 41), half of it reads 4 %
+#: slower at glm's shape and 10 to 19 % at chat's (phi4's and solar's the
+#: same), and ``DEPTH`` blocks of twice of it do not fit a kernel's VMEM
+BLOCK_BYTES = 2560 * 1024
+#: key blocks in VMEM at once, one multiplied and the others on their way:
+#: the lengths of neighbouring slots differ by tens to one, so with ONE
+#: copy ahead a long block's copy waits on a short block's product and the
+#: reverse (glm's call 0.41 ms at 2, 0.34 at 3, 0.325 at 4: 10 of the 16
+#: MiB a kernel may use)
+DEPTH = 4
+#: slab rows to a sub-block: the products of a sequence's last block stop
+#: at the first sub-block boundary past its rows (at 128 twice the
+#: branches and 1 to 7 % slower)
+SUB_ROWS = 256
 #: a program takes fewer heads before its key blocks get shorter than this
 MIN_BLOCK_ROWS = 64
 
 
-def geometry(heads, slab_rows, lanes, itemsize):
+def geometry(heads, slab_rows, lanes, itemsize, slabs=2):
     """``(heads per program, slab rows per key block)`` of a call over
-    ``(B, heads, slab_rows, lanes)`` slabs: every head of a slot in one
-    program while a block of ``MIN_BLOCK_ROWS`` rows of them fits
-    ``BLOCK_BYTES`` (else their largest divisor that does), and the
+    ``slabs`` slabs of ``(B, heads, slab_rows, lanes)``: every head of a
+    slot in one program while a block of ``MIN_BLOCK_ROWS`` rows of them
+    fits ``BLOCK_BYTES`` (else their largest divisor that does), and the
     largest sublane-aligned divisor of the slab's rows that keeps the
     block inside it."""
-    row = lanes * itemsize
+    row = lanes * itemsize * slabs
     floor = min(slab_rows, MIN_BLOCK_ROWS)
     hb = max((h for h in range(1, heads + 1)
               if heads % h == 0 and h * floor * row <= BLOCK_BYTES),
@@ -81,24 +111,35 @@ def geometry(heads, slab_rows, lanes, itemsize):
     return hb, rows
 
 
+def _tail(block_k, itemsize):
+    """``(copy tile, sub-block)`` of a key block of ``block_k`` slab rows:
+    a sequence's last block is copied as far as the sequence reaches
+    rounded up to a sublane tile of the slab's dtype (``None``: a block
+    that is no whole number of tiles is copied whole), and multiplied in
+    sub-blocks of ``SUB_ROWS`` rows (a block that is no whole number of
+    them: in one)."""
+    tile = 32 // itemsize
+    return (tile if block_k % tile == 0 else None,
+            SUB_ROWS if block_k % SUB_ROWS == 0 else block_k)
+
+
 def _init(m_scr, l_scr, acc_scr):
     m_scr[...] = jnp.full_like(m_scr, NEG_INF)
     l_scr[...] = jnp.zeros_like(l_scr)
     acc_scr[...] = jnp.zeros_like(acc_scr)
 
 
-def _block(q, k, v, ki, length, m_scr, l_scr, acc_scr, pack):
-    """One key block into the running softmax of every score row.  ``q``:
-    (heads, rows, lanes); ``k`` / ``v``: (heads, block_k, lanes), slab
-    rows ``ki * block_k ...``."""
-    block_k = k.shape[1]
+def _block(q, k, v, first, length, m_scr, l_scr, acc_scr, pack):
+    """Some key rows into the running softmax of every score row.  ``q``:
+    (heads, rows, lanes); ``k`` / ``v``: (heads, n, lanes), slab rows
+    ``first ...``."""
     s = jax.lax.dot_general(
         q, k, (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32)          # (heads, rows, block_k)
     row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-    # row i, column m of this block scores key (ki*block_k + m)*r + i % r
-    valid = (col + ki * block_k) * pack + row % pack < length
+    # row i, column m scores key (first + m)*r + i % r
+    valid = (col + first) * pack + row % pack < length
     s = jnp.where(valid, s, NEG_INF)
     m_prev = m_scr[:, :, :1]                         # (heads, rows, 1)
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
@@ -148,21 +189,108 @@ def _finish(o_ref, m_scr, l_scr, acc_scr, pack):
             num / jnp.where(den == 0.0, 1.0, den)).astype(o_ref.dtype)
 
 
-def _kernel(len_ref, slot_ref, blk_ref, q_ref, k_ref, *rest, pack, v_lanes):
-    """``rest``: the value block, then the output and the scratch — or, in
-    the latent mode (``v_lanes``), no value block: the value is the first
-    ``v_lanes`` lanes of the key block already in VMEM."""
-    o_ref, m_scr, l_scr, acc_scr = rest[-4:]
-    t = pl.program_id(1)
+def _live_rows(len_ref, slot, ki, block_k, pack, tile):
+    """Slab rows a copy moves of key block ``ki`` of ``slot``: the block's
+    rows below the sequence's length, rounded up to a sublane tile
+    (``tile``; ``None``: the block whole)."""
+    if not tile:
+        return jnp.int32(block_k)
+    left = (len_ref[slot] - 1) // pack + 1 - ki * block_k
+    return pl.multiple_of(
+        jnp.minimum(block_k, (left + tile - 1) // tile * tile), tile)
+
+
+def _fetch(hi, slot, ki, place, rows, slabs, bufs, sems):
+    """The copies, one a slab, of the first ``rows`` rows of key block
+    ``ki`` of ``slot`` (head group ``hi``) into ``place`` of the ring."""
+    hb, block_k = bufs[0].shape[1:3]
+    return [pltpu.make_async_copy(
+        slab.at[slot, pl.ds(hi * hb, hb), pl.ds(ki * block_k, rows), :],
+        buf.at[place, :, pl.ds(0, rows), :], sems.at[place, i])
+        for i, (slab, buf) in enumerate(zip(slabs, bufs))]
+
+
+def _zero_past(buf, place, rows, sub):
+    """Zeros over the rows of the last sub-block past the ``rows`` copied:
+    they hold what an earlier block left there, or nothing yet, and a
+    weight of zero times a NaN is a NaN."""
+    @pl.when(rows % sub != 0)
+    def _():
+        j = pl.multiple_of(rows // sub * sub, sub)
+        blk = buf[place, :, pl.ds(j, sub), :]
+        row = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 1)
+        buf[place, :, pl.ds(j, sub), :] = jnp.where(
+            row < rows - j, blk, jnp.zeros_like(blk))
+
+
+def _products(q, bufs, place, rows, first, length, scr, pack, v_lanes, sub):
+    """The ``rows`` copied rows at ``place`` of the ring (slab rows
+    ``first ...``) into the running softmax: ONE product over as
+    many sub-blocks of ``sub`` rows as hold a copied row — a product a
+    sub-block would pay the softmax's chain of dependent steps each time
+    (0.4 us, PERF.md §6 PR 41) — so one branch a count, every operand's
+    size static."""
+    kbuf, vbuf = bufs[0], bufs[-1]
+    _zero_past(vbuf, place, rows, sub)
+    taken = (rows + sub - 1) // sub
+    for n in range(1, kbuf.shape[2] // sub + 1):
+        @pl.when(taken == n)
+        def _(n=n):
+            k = kbuf[place, :, :n * sub, :]
+            v = (k[:, :, :v_lanes] if v_lanes
+                 else vbuf[place, :, :n * sub, :])
+            _block(q, k, v, first, length, *scr, pack)
+
+
+def _kernel(len_ref, slot_ref, blk_ref, q_ref, *rest, pack, v_lanes, tile,
+            sub):
+    """``rest``: the slabs where they lie (K, V — or, in the latent mode
+    (``v_lanes``), ONE: the value is the first ``v_lanes`` lanes of the key
+    block already in VMEM), the output, then the scratch: a ring of block
+    buffers a slab, their semaphores, and the running softmax."""
+    n = 1 if v_lanes else 2
+    slabs, o_ref, bufs = rest[:n], rest[n], rest[n + 1:2 * n + 1]
+    sems, *scr = rest[2 * n + 1:]
+    hi, t = pl.program_id(0), pl.program_id(1)
+    steps = pl.num_programs(1)
+    total = pl.num_programs(0) * steps
+    at = hi * steps + t
+    depth, _, block_k = bufs[0].shape[:3]
+
+    def fetch(at):
+        """The copies of schedule entry ``at`` into its place in the ring,
+        and the rows they move."""
+        hi, t = jax.lax.div(at, steps), jax.lax.rem(at, steps)
+        rows = _live_rows(len_ref, slot_ref[t], blk_ref[t], block_k, pack,
+                          tile)
+        return _fetch(hi, slot_ref[t], blk_ref[t], jax.lax.rem(at, depth),
+                      rows, slabs, bufs, sems), rows
+
+    def start(at):
+        @pl.when(at < total)
+        def _():
+            for c in fetch(at)[0]:
+                c.start()
+
+    # the blocks of the NEXT entries are on their way before this one's
+    # products: across a slot's end too, and a head group's
+    @pl.when(at == 0)
+    def _():
+        for ahead in range(depth - 1):
+            start(at + ahead)
+    start(at + depth - 1)
+
     ki = blk_ref[t]
     length = len_ref[slot_ref[t]]
-    pl.when(ki == 0)(lambda: _init(m_scr, l_scr, acc_scr))
-    k = k_ref[...]
-    v = rest[0][...] if v_lanes is None else k[:, :, :v_lanes]
-    _block(q_ref[...], k, v, ki, length, m_scr, l_scr, acc_scr, pack)
+    pl.when(ki == 0)(lambda: _init(*scr))
+    mine, rows = fetch(at)
+    for c in mine:
+        c.wait()
+    _products(q_ref[...], bufs, jax.lax.rem(at, depth), rows, ki * block_k,
+              length, scr, pack, v_lanes, sub)
     # the slot's last live block
-    pl.when(ki == (length - 1) // (k_ref.shape[1] * pack))(
-        lambda: _finish(o_ref, m_scr, l_scr, acc_scr, pack))
+    pl.when(ki == (length - 1) // (block_k * pack))(
+        lambda: _finish(o_ref, *scr, pack))
 
 
 def _schedule(lengths, keys_per_block, num_kv):
@@ -190,8 +318,9 @@ def decode_attention(rows, k_slab, v_slab, lengths, pack=1,
     to ``[1, L]``.  Returns (B, H, n / pack, lanes / pack) float32: per
     query the softmax over its ``pack`` rows together, lanes ``[j*D,
     (j+1)*D)`` of row ``j`` of ``P @ V`` summed over ``j``.
-    ``interpret=True`` runs the Pallas interpreter (the CPU tests exercise
-    the same body).
+    ``interpret=True`` runs the TPU's Pallas interpreter with VMEM that
+    reads NaN until written (the CPU tests exercise the same body; a
+    ``pltpu.InterpretParams`` is passed through).
 
     **The latent mode** (``v_slab=None``, ``v_lanes``, ``pack == 1``;
     ``ops/mla.py``): a cache row is a key whose first ``v_lanes`` lanes are
@@ -203,14 +332,42 @@ def decode_attention(rows, k_slab, v_slab, lengths, pack=1,
     if latent and (pack != 1 or not v_lanes):
         raise ValueError("the latent mode reads plain rows (pack 1) and "
                          "needs v_lanes")
-    out_lanes = int(v_lanes) if latent else lanes
-    slab_rows = k_slab.shape[2]
-    hb, block_k = geometry(h, slab_rows, lanes, k_slab.dtype.itemsize)
+    if lanes % 128:
+        # plain rows narrower than a lane row (no caller stores them so:
+        # ``kv_slab_shape`` packs them) lie padded in HBM, where a copy
+        # cannot cut them: widened with zeros, which score and weigh nothing
+        if pack != 1:
+            raise ValueError("packed rows fill whole lane rows")
+        wide = (lambda x: x if x is None else jnp.pad(
+            x, ((0, 0),) * 3 + ((0, -lanes % 128),)))
+        out = decode_attention(wide(rows), wide(k_slab), wide(v_slab),
+                               lengths, 1, interpret, v_lanes)
+        return out if latent else out[..., :lanes]
+    slabs = (k_slab,) if latent else (k_slab, v_slab)
+    itemsize = k_slab.dtype.itemsize
+    hb, block_k = geometry(h, k_slab.shape[2], lanes, itemsize, len(slabs))
     from ...metrics import record_decode_attn_call
     record_decode_attn_call(hb, block_k)
-    keys = block_k * pack
-    lengths = jnp.clip(jnp.asarray(lengths, jnp.int32), 1, slab_rows * pack)
-    slot, block, steps = _schedule(lengths, keys, slab_rows // block_k)
+    return _call(jnp.asarray(lengths, jnp.int32), rows, *slabs, hb=hb,
+                 block_k=block_k, tail=_tail(block_k, itemsize), depth=DEPTH,
+                 pack=pack, v_lanes=int(v_lanes) if latent else None,
+                 interpret=interpret)
+
+
+# jitted so that the calls of one program that share a shape — every
+# layer's, every reader's of a shared slab: 8 to 24 in a one-token program
+# — are traced and lowered to ONE kernel: lowered one by one, its branches
+# added 4.5 s to every start of the glm cell (PERF.md section 6, PR 41)
+@functools.partial(jax.jit, static_argnames=(
+    "hb", "block_k", "tail", "depth", "pack", "v_lanes", "interpret"))
+def _call(lengths, rows, *slabs, hb, block_k, tail, depth, pack, v_lanes,
+          interpret):
+    b, h, n, lanes = rows.shape
+    slab_rows = slabs[0].shape[2]
+    out_lanes = v_lanes or lanes
+    lengths = jnp.clip(lengths, 1, slab_rows * pack)
+    slot, block, steps = _schedule(lengths, block_k * pack,
+                                   slab_rows // block_k)
     tile = 32 // rows.dtype.itemsize             # a whole sublane tile
     padded = -(-n // tile) * tile
     rows = jnp.pad(rows, ((0, 0), (0, 0), (0, padded - n), (0, 0)))
@@ -218,30 +375,30 @@ def decode_attention(rows, k_slab, v_slab, lengths, pack=1,
     def at_slot(hi, t, len_ref, slot_ref, blk_ref):
         return slot_ref[t], hi, 0, 0
 
-    def at_block(hi, t, len_ref, slot_ref, blk_ref):
-        return slot_ref[t], hi, blk_ref[t], 0
-
-    kv_spec = pl.BlockSpec((None, hb, block_k, lanes), at_block)
-    slabs = (k_slab,) if latent else (k_slab, v_slab)
     return pl.pallas_call(
-        functools.partial(_kernel, pack=pack,
-                          v_lanes=out_lanes if latent else None),
+        functools.partial(_kernel, pack=pack, tile=tail[0], sub=tail[1],
+                          v_lanes=v_lanes),
         # the one-token call keeps the name the device trace knows it by
-        name="mla_fwd_q1" if latent else "flash_fwd_q1",
+        name="mla_fwd_q1" if v_lanes else "flash_fwd_q1",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             # the second bound is the traced count of live blocks
             grid=(h // hb, steps),
             in_specs=[pl.BlockSpec((None, hb, padded, lanes), at_slot)]
-            + [kv_spec] * len(slabs),
+            # the slabs stay where they lie: the kernel copies its blocks
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(slabs),
             out_specs=pl.BlockSpec((None, hb, n // pack, out_lanes // pack),
                                    at_slot),
-            scratch_shapes=[
-                pltpu.VMEM((hb, padded, 128), jnp.float32),    # running max
-                pltpu.VMEM((hb, padded, 128), jnp.float32),    # running sum
-                pltpu.VMEM((hb, padded, out_lanes), jnp.float32),  # P @ V
-            ]),
+            scratch_shapes=[pltpu.VMEM((depth, hb, block_k, lanes),
+                                       slabs[0].dtype) for _ in slabs]
+            + [pltpu.SemaphoreType.DMA((depth, len(slabs))),
+               pltpu.VMEM((hb, padded, 128), jnp.float32),    # running max
+               pltpu.VMEM((hb, padded, 128), jnp.float32),    # running sum
+               pltpu.VMEM((hb, padded, out_lanes), jnp.float32)]),  # P @ V
         out_shape=jax.ShapeDtypeStruct(
             (b, h, n // pack, out_lanes // pack), jnp.float32),
-        interpret=interpret,
+        # a copy of a traced number of rows needs the TPU's own
+        # interpreter; it hands out VMEM that reads NaN until written
+        interpret=(pltpu.InterpretParams(uninitialized_memory="nan")
+                   if interpret is True else interpret),
     )(lengths, slot, block, rows, *slabs)

@@ -286,10 +286,10 @@ class HetuProfiler:
         """{"<heads per program>x<block rows>": count} of traced
         one-token attention calls over a KV slab by the geometry
         ``ops/pallas/decode_attention.py`` chose for them — its four
-        callers alike: GPT-2's packed heads (``16x64`` in the chat cell),
-        the shared-KV readers (``10x256``), the grouped-query read
-        (``1x2048``) and the latent read of ``ops/mla.py`` (``1x512``: one
-        head of 640-lane rows).  Per trace; empty where a decode program
+        callers alike: GPT-2's packed heads (``16x128`` in the chat cell),
+        the shared-KV readers (``10x512``), the grouped-query read
+        (``1x4096``) and the latent read of ``ops/mla.py`` (``1x2048``:
+        one head of 640-lane rows).  Per trace; empty where a decode program
         reads its slabs through jnp."""
         from .metrics import decode_attn_call_counts
         return decode_attn_call_counts()

@@ -722,9 +722,10 @@ class DecodeEngine:
         """``(read, held)``: the key rows this step's attention fetches
         of a KV slab, and the key rows the slab holds, summed over the
         batch bucket's slots.  A one-token step on the kernel path reads
-        each slot's live key blocks (``ops.attention.kv_rows_read``: the
-        compiled geometry, from the slab's shape); a chunked step and
-        the jnp path read the slab whole.  One slab's worth: every KV
+        each slot's rows as far as the sequence reaches, rounded up to a
+        copy's tile (``ops.attention.kv_rows_fetched``: the compiled
+        geometry, from the slab's shape); a chunked step and the jnp
+        path read the slab whole.  One slab's worth: every KV
         layer, and every reader of a shared slab, fetches the same."""
         if not self._kv:
             return 0, 0
@@ -732,8 +733,8 @@ class DecodeEngine:
         held = self.bb * rows * self._pack
         if chunk > 1:
             return held, held
-        from ..ops.attention import kv_rows_read
-        return kv_rows_read(
+        from ..ops.attention import kv_rows_fetched
+        return kv_rows_fetched(
             self.positions + 1, (self.bb, self._heads, rows, self._lanes),
             self._pack, self._tails[self._kv[0]][1].itemsize), held
 

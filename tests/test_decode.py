@@ -102,6 +102,14 @@ def _slab_case(head_dim, length=32, batch=4, heads=2, seed=0):
     return rng, rows, slab
 
 
+def _cut(monkeypatch, block_rows, heads, lanes, itemsize, slabs=2):
+    """Key blocks of ``block_rows`` slab rows of all ``heads``."""
+    from hetu_tpu.ops.pallas import decode_attention as da
+    monkeypatch.setattr(da, "BLOCK_BYTES",
+                        block_rows * heads * lanes * itemsize * slabs)
+    monkeypatch.setattr(da, "MIN_BLOCK_ROWS", 8)
+
+
 @pytest.mark.parametrize("masked", [False, True], ids=["all", "valid"])
 @pytest.mark.parametrize("chunk", [1, 4])
 @pytest.mark.parametrize("head_dim", [64, 128])
@@ -127,23 +135,28 @@ def test_kv_append_is_bitwise_the_row_append(head_dim, chunk, masked):
     assert np.array_equal(np.asarray(kv_slab_to_rows(got, head_dim)), want)
 
 
-@pytest.mark.parametrize("path", ["kernel", "kernel_blocks", "jnp",
-                                  "jnp_chunk"])
+@pytest.mark.parametrize("path", ["kernel", "kernel_blocks",
+                                  "kernel_head_groups", "kernel_plain_rows",
+                                  "jnp", "jnp_chunk"])
 @pytest.mark.parametrize("head_dim", [64, 128])
 def test_attention_over_slabs_matches_row_reference(head_dim, path,
                                                     monkeypatch):
     """The one-token attention over the stored slabs — the Pallas body in
-    interpret mode (whole cache in one key block, and cut into blocks so
-    the length-clamped block index is exercised) and the jnp path the
-    CPU serves — agrees with ``sdpa_reference`` over the unpacked rows
-    for ragged positions, position 0 and an odd position included; so
-    does the chunked steps' attention."""
+    interpret mode (whole cache in one key block; cut into blocks so
+    the length-clamped block index is exercised; four heads in two
+    programs' worth, so the copy ahead crosses a head group's end) and
+    the jnp path the CPU serves — agrees with ``sdpa_reference`` over the
+    unpacked rows for ragged positions, position 0 and an odd position
+    included; so does the chunked steps' attention, and so does the
+    kernel over the UNPACKED (B, H, L, D) rows (64 lanes wide: widened
+    with zeros, a copy cannot cut a padded lane row)."""
     from hetu_tpu.ops import attention as att
     from hetu_tpu.ops.pallas import decode_attention as da
-    rng, k_rows, k_slab = _slab_case(head_dim, seed=1)
-    _, v_rows, v_slab = _slab_case(head_dim, seed=2)
+    heads = 4 if path == "kernel_head_groups" else 2
+    rng, k_rows, k_slab = _slab_case(head_dim, heads=heads, seed=1)
+    _, v_rows, v_slab = _slab_case(head_dim, heads=heads, seed=2)
     chunk = 4 if path == "jnp_chunk" else 1
-    q = rng.standard_normal((4, 2, chunk, head_dim)).astype(np.float32)
+    q = rng.standard_normal((4, heads, chunk, head_dim)).astype(np.float32)
     positions = np.array([0, 5, 32 - chunk, 17], np.int32)
     seen = positions[:, None] + 1 + np.arange(chunk)[None, :]   # (B, C)
     mask = np.arange(32)[None, None, None, :] < seen[:, None, :, None]
@@ -152,12 +165,16 @@ def test_attention_over_slabs_matches_row_reference(head_dim, path,
         got = att.dispatch_sdpa_decode(q, k_slab, v_slab, positions)
     elif path == "jnp_chunk":
         got = att.dispatch_sdpa_prefill(q, k_slab, v_slab, positions)
+    elif path == "kernel_plain_rows":
+        got = da.decode_attention(
+            q[:, :, :1] * head_dim ** -0.5, k_rows, v_rows,
+            positions + 1, interpret=True)
     else:
-        if path == "kernel_blocks":
-            # 8 slab rows of both heads a key block
-            monkeypatch.setattr(da, "BLOCK_BYTES", 8 * 2 * 128 * 4)
-            monkeypatch.setattr(da, "MIN_BLOCK_ROWS", 8)
         pack = 128 // head_dim
+        if path != "kernel":
+            # 8 slab rows of two heads a key block
+            _cut(monkeypatch, 8, 2, 128, 4)
+            assert da.geometry(heads, 32 // pack, 128, 4) == (2, 8)
         rows = att.kv_slab_queries(q[:, :, 0] * head_dim ** -0.5, pack)
         got = da.decode_attention(rows, k_slab, v_slab, positions + 1,
                                   pack=pack, interpret=True)
@@ -185,39 +202,31 @@ def _garbage_past(slab, lengths, pack, fill):
     return np.where(dead[:, None], np.float32(fill), slab)
 
 
-@pytest.mark.parametrize("blocks", ["one_block", "blocks"])
-@pytest.mark.parametrize("mix", sorted(_Q1_LENGTHS))
-def test_one_token_kernel_over_packed_slabs(mix, blocks, monkeypatch):
+def _packed_read(lengths, keys, block_rows, monkeypatch):
     """GPT-2's caller (float32, two keys a slab row, the two score rows
-    of a query sharing one softmax) against ``sdpa_slab_reference``:
-    lengths 1, one below / at / one above a block edge and the full
-    slab, a ragged batch with an idle slot, rows past every length
-    filled with large finite garbage."""
+    of a query sharing one softmax) against ``sdpa_slab_reference``, rows
+    past every length filled with large finite garbage: ``(got, want)``."""
     from hetu_tpu.ops import attention as att
     from hetu_tpu.ops.pallas import decode_attention as da
-    lengths = np.array(_Q1_LENGTHS[mix], np.int32)
-    rng, _, k_slab = _slab_case(64, length=256, batch=5, seed=3)
-    _, _, v_slab = _slab_case(64, length=256, batch=5, seed=4)
+    b = len(lengths)
+    rng, _, k_slab = _slab_case(64, length=keys, batch=b, seed=3)
+    _, _, v_slab = _slab_case(64, length=keys, batch=b, seed=4)
     k_slab = _garbage_past(k_slab, lengths, 2, 3.0e4)
     v_slab = _garbage_past(v_slab, lengths, 2, -3.0e4)
-    q = rng.standard_normal((5, 2, 1, 64)).astype(np.float32)
+    q = rng.standard_normal((b, 2, 1, 64)).astype(np.float32)
     want = np.asarray(att.sdpa_slab_reference(q, k_slab, v_slab,
                                               lengths[:, None]))
-    if blocks == "blocks":
-        monkeypatch.setattr(da, "BLOCK_BYTES", 32 * 2 * 128 * 4)
-        monkeypatch.setattr(da, "MIN_BLOCK_ROWS", 8)
-    assert da.geometry(2, 128, 128, 4) == (
-        (2, 32) if blocks == "blocks" else (2, 128))
+    if block_rows:
+        _cut(monkeypatch, block_rows, 2, 128, 4)
+    assert da.geometry(2, keys // 2, 128, 4) == (
+        2, block_rows or keys // 2)
     rows = att.kv_slab_queries(q[:, :, 0] * 0.125, 2)
     got = da.decode_attention(rows, k_slab, v_slab, lengths, pack=2,
                               interpret=True)
-    np.testing.assert_allclose(np.asarray(got), want[:, :, 0:1],
-                               rtol=2e-5, atol=2e-6)
+    return np.asarray(got), want[:, :, 0:1]
 
 
-@pytest.mark.parametrize("blocks", ["one_block", "blocks"])
-@pytest.mark.parametrize("mix", sorted(_Q1_LENGTHS))
-def test_one_token_kernel_over_paired_rows(mix, blocks, monkeypatch):
+def _paired_read(lengths, keys, block_rows, monkeypatch):
     """The shared-KV readers' caller: ``_diff_attention_kv`` at ``C = 1``
     through the kernel (bfloat16 paired rows as stored, ``r = 1``, two
     query pairs a key pair: four score rows with a softmax each) against
@@ -229,41 +238,145 @@ def test_one_token_kernel_over_paired_rows(mix, blocks, monkeypatch):
 
     import jax
     import jax.numpy as jnp
-    from hetu_tpu.ops import attention as att
     from hetu_tpu.ops import ssm
     from hetu_tpu.ops.pallas import decode_attention as da
-    lengths = np.array(_Q1_LENGTHS[mix], np.int32)
-    rng, _, k_slab = _slab_case(128, length=256, batch=5, seed=5)
-    _, _, v_slab = _slab_case(128, length=256, batch=5, seed=6)
+    b = len(lengths)
+    rng, _, k_slab = _slab_case(128, length=keys, batch=b, seed=5)
+    _, _, v_slab = _slab_case(128, length=keys, batch=b, seed=6)
     k16 = jnp.asarray(_garbage_past(k_slab, lengths, 1, 3.0e4), jnp.bfloat16)
     v16 = jnp.asarray(_garbage_past(v_slab, lengths, 1, -3.0e4),
                       jnp.bfloat16)
     # queries that are bfloat16 values, so that 1/8 of them are too
-    q = jnp.asarray(rng.standard_normal((5, 4 * 128)), jnp.bfloat16).astype(
+    q = jnp.asarray(rng.standard_normal((b, 4 * 128)), jnp.bfloat16).astype(
         jnp.float32)
     lams = [jnp.asarray(0.1 * rng.standard_normal(64), jnp.float32)
             for _ in range(4)]
     norm_w = jnp.asarray(1.0 + 0.1 * rng.standard_normal(128), jnp.float32)
-    ids = jnp.zeros((5, 1), jnp.int32)
+    ids = jnp.zeros((b, 1), jnp.int32)
     call = functools.partial(ssm._diff_attention_kv, None, q)
     want = call(k16.astype(jnp.float32), v16.astype(jnp.float32),
                 lengths - 1, ids, *lams, norm_w)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(da, "decode_attention", functools.partial(
         da.decode_attention, interpret=True))
-    if blocks == "blocks":
-        monkeypatch.setattr(da, "BLOCK_BYTES", 64 * 2 * 128 * 2)
-        monkeypatch.setattr(da, "MIN_BLOCK_ROWS", 8)
+    if block_rows:
+        _cut(monkeypatch, block_rows, 2, 128, 2)
     metrics.reset_all()
     got = call(k16, v16, lengths - 1, ids, *lams, norm_w)
     assert metrics.decode_attn_call_counts() == {
-        "2x64" if blocks == "blocks" else "2x256": 1}
-    assert got.shape == want.shape == (5, 4 * 128)
+        "2x%d" % (block_rows or keys): 1}
+    assert got.shape == want.shape == (b, 4 * 128)
+    assert float(jnp.max(jnp.abs(want))) > 0.5
+    return np.asarray(got), np.asarray(want)
+
+
+def _latent_read(lengths, keys, block_rows, monkeypatch):
+    """The latent mode as ``ops/mla.py`` calls it — 20 score rows over ONE
+    slab of bfloat16 rows whose first lanes (128 of 256) are the value
+    too — against attention written out in float64 over the same
+    bfloat16 values."""
+    import jax.numpy as jnp
+    from hetu_tpu.ops.pallas import decode_attention as da
+    rng = np.random.default_rng(6)
+    b, n, lanes, v_lanes = len(lengths), 20, 256, 128
+    dead = np.arange(keys)[None, :] >= lengths[:, None]
+    slab = jnp.asarray(np.where(
+        dead[:, None, :, None], 3.0e4,
+        rng.standard_normal((b, 1, keys, lanes))), jnp.bfloat16)
+    q = jnp.asarray(rng.standard_normal((b, 1, n, lanes)) * 0.2,
+                    jnp.bfloat16)
+    if block_rows:
+        _cut(monkeypatch, block_rows, 1, lanes, 2, slabs=1)
+    metrics.reset_all()
+    got = np.asarray(da.decode_attention(q, slab, None, lengths,
+                                         v_lanes=v_lanes, interpret=True))
+    assert metrics.decode_attn_call_counts() == {
+        "1x%d" % (block_rows or keys): 1}
+    assert got.shape == (b, 1, n, v_lanes)
+    want = np.zeros(got.shape)
+    for i in range(b):
+        live = np.asarray(slab, np.float64)[i, 0, :lengths[i]]
+        s = np.asarray(q, np.float64)[i, 0] @ live.T
+        p = np.exp(s - s.max(-1, keepdims=True))
+        want[i, 0] = (p / p.sum(-1, keepdims=True)) @ live[:, :v_lanes]
+    return got, want
+
+
+@pytest.mark.parametrize("blocks", ["one_block", "blocks"])
+@pytest.mark.parametrize("mix", sorted(_Q1_LENGTHS))
+def test_one_token_kernel_over_packed_slabs(mix, blocks, monkeypatch):
+    """GPT-2's caller against ``sdpa_slab_reference``: lengths 1, one
+    below / at / one above a block edge and the full slab, a ragged batch
+    with an idle slot, rows past every length filled with large finite
+    garbage."""
+    got, want = _packed_read(np.array(_Q1_LENGTHS[mix], np.int32), 256,
+                             32 if blocks == "blocks" else None, monkeypatch)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("blocks", ["one_block", "blocks"])
+@pytest.mark.parametrize("mix", sorted(_Q1_LENGTHS))
+def test_one_token_kernel_over_paired_rows(mix, blocks, monkeypatch):
+    """The shared-KV readers' caller against its own ``jnp`` path."""
+    got, want = _paired_read(np.array(_Q1_LENGTHS[mix], np.int32), 256,
+                             64 if blocks == "blocks" else None, monkeypatch)
     # read 2.0e-6 to 2.3e-6 over the cases, of outputs up to 0.74: the
     # weights meet V as hi + lo bfloat16 rows and lose nothing (one
     # rounding of each row's weights reads 1.3e-3 to 1.7e-3 here)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
-    assert float(jnp.max(jnp.abs(want))) > 0.5
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+# a sequence's last key block is copied and multiplied only as far as the
+# sequence reaches (ISSUE 41).  Slab rows a slot holds, of 1024 in key
+# blocks of 512 rows, a product's sub-block 256 rows, a copy's tile 8
+# (float32) or 16 (bfloat16) rows: on both sides of each edge
+_TAIL_ROWS = {
+    "tile": [1, 7, 8, 9, 15, 16],
+    "sub_block": [17, 255, 256, 257, 767, 769],
+    "block": [511, 512, 513, 768, 1023, 2],
+    "full": [1024] * 6,
+    "ragged": [800, 1, 257, 17, 600, 1],       # idle slots inside
+    # a one-block slot behind a many-block slot: its block was on its way
+    # while the slots before it were multiplied
+    "after_many": [1024, 5, 600, 1, 520, 3],
+}
+_TAIL_READS = {                  # mode -> (the read, keys a slab row, atol)
+    "packed_f32": (_packed_read, 2, 2e-6),
+    "paired_bf16": (_paired_read, 1, 2e-5),
+    "latent_bf16": (_latent_read, 1, 2e-5),
+}
+
+
+@pytest.mark.parametrize("mix", sorted(_TAIL_ROWS))
+@pytest.mark.parametrize("mode", sorted(_TAIL_READS))
+def test_one_token_kernel_stops_where_the_sequence_does(mode, mix,
+                                                        monkeypatch):
+    """All three modes of the kernel over slabs of 1024 rows: every length
+    of ``_TAIL_ROWS`` reads what the reference reads, with the slabs' rows
+    past each length garbage and the VMEM the kernel copies into NaN
+    wherever nothing was copied (the TPU interpreter's
+    ``uninitialized_memory="nan"``): a product that took a row past the
+    copy would show as NaN."""
+    read, pack, atol = _TAIL_READS[mode]
+    rows = np.array(_TAIL_ROWS[mix], np.int32)
+    # the odd slots stop on the FIRST key of their last slab row
+    lengths = rows * pack - (np.arange(len(rows)) % 2) * (pack - 1)
+    got, want = read(lengths, 1024 * pack, 512, monkeypatch)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=atol)
+
+
+def test_the_poison_would_show(monkeypatch):
+    """What the test above rests on: with the rows past a copy left as
+    they were, the same read IS NaN — the buffers do start poisoned."""
+    import jax
+    from hetu_tpu.ops.pallas import decode_attention as da
+    monkeypatch.setattr(da, "_zero_past", lambda *a: None)
+    jax.clear_caches()              # the kernel's call is jitted by shape
+    got, _ = _paired_read(np.array([1, 7, 256], np.int32), 512, 256,
+                          monkeypatch)
+    assert np.isnan(got[:2]).all() and np.isfinite(got[2]).all()
+    jax.clear_caches()
 
 
 def test_a_chunk_and_the_cpu_keep_the_jnp_read(monkeypatch):
@@ -289,15 +402,62 @@ def test_a_chunk_and_the_cpu_keep_the_jnp_read(monkeypatch):
 
 def test_geometry_follows_the_calls_shape():
     """All the heads of a slot in one program, key blocks sized to
-    ``BLOCK_BYTES``: the two cells' shapes, a slab too short to cut, heads
-    too many for one program, and rows with no aligned divisor."""
+    ``BLOCK_BYTES`` over the call's slabs together: the four cells'
+    shapes (the latent read's ONE slab takes rows twice as long), a slab
+    too short to cut, heads too many for one program, and rows with no
+    aligned divisor."""
     from hetu_tpu.ops.pallas.decode_attention import geometry
-    assert geometry(10, 4608, 128, 2) == (10, 256)       # the phi4 cell
-    assert geometry(16, 384, 128, 4) == (16, 64)         # the chat cell
+    assert geometry(1, 4096, 640, 2, 1) == (1, 2048)     # the glm cell
+    assert geometry(10, 4608, 128, 2) == (10, 512)       # the phi4 cell
+    assert geometry(16, 384, 128, 4) == (16, 128)        # the chat cell
+    assert geometry(1, 4096, 128, 2) == (1, 4096)        # the solar cell
     assert geometry(2, 16, 128, 4) == (2, 16)
-    assert geometry(64, 1024, 128, 4) == (16, 64)
-    assert geometry(25, 512, 128, 4) == (5, 256)
+    assert geometry(64, 1024, 128, 4) == (32, 64)
+    assert geometry(25, 512, 128, 4) == (25, 64)
     assert geometry(4, 100, 128, 4) == (4, 100)
+    assert geometry(128, 1024, 128, 4) == (32, 64)
+
+
+# the four serving cells' calls: slab, keys a slab row, bytes an element
+_CELL_CALLS = {
+    "glm": ((128, 1, 4096, 640), 1, 2),
+    "phi4": ((64, 10, 4608, 128), 1, 2),
+    "chat": ((16, 16, 384, 128), 2, 4),
+    "solar": ((128, 1, 4096, 128), 1, 2),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELL_CALLS))
+def test_rows_fetched_end_a_tile_past_the_rows_live(cell, monkeypatch):
+    """``kv_rows_fetched`` at each cell's call: every live row, under one
+    copy tile (8 float32 or 16 bfloat16 slab rows) more a slot, never more
+    than the whole blocks the grid walks (``kv_rows_read``, multiples of
+    the geometry's block); off the kernel's gate, the slab whole."""
+    import jax
+    from hetu_tpu.ops import attention as att
+    from hetu_tpu.ops.pallas.decode_attention import _tail, geometry
+    slab, pack, itemsize = _CELL_CALLS[cell]
+    b, heads, slab_rows, lanes = slab
+    block = geometry(heads, slab_rows, lanes, itemsize)[1]
+    tile = 32 // itemsize
+    assert _tail(block, itemsize)[0] == tile and slab_rows % block == 0
+    call = (slab, pack, itemsize)
+    whole = b * slab_rows * pack
+    assert att.kv_rows_fetched(np.ones(b, np.int64), *call) == whole
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rng = np.random.default_rng(41)
+    for lengths in (rng.integers(1, slab_rows * pack + 1, b),
+                    np.ones(b, np.int64),                  # idle slots
+                    np.full(b, slab_rows * pack)):
+        live = int(lengths.sum())
+        fetched = att.kv_rows_fetched(lengths, *call)
+        walked = att.kv_rows_read(lengths, *call)
+        assert live <= fetched <= walked <= whole
+        assert fetched - live < b * tile * pack
+        assert walked % (block * pack) == 0 and fetched % (tile * pack) == 0
+    assert att.kv_rows_fetched(np.ones(b, np.int64), *call) \
+        == b * tile * pack
+    assert att.kv_rows_fetched(np.full(b, slab_rows * pack), *call) == whole
 
 
 _ROWS_CFG = GPT2Config.tiny(n_positions=256, batch_size=1, seq_len=16)
@@ -339,15 +499,18 @@ def test_engine_reads_its_slabs_whole_on_the_jnp_path(jnp_rows_run):
 def test_engine_counts_the_kv_rows_the_kernel_fetches(jnp_rows_run,
                                                       monkeypatch):
     """``decode_kv_rows_read`` counts, per step and from the positions
-    alone, what the compiled geometry fetches — the live key blocks of
-    every slot of the batch bucket — and ``decode_attn_calls`` names the
-    geometry once per layer per trace: the kernel in interpret mode
-    behind a backend that says tpu, emitting the jnp path's tokens.  The
-    rows were appended by the aliased kernel (``kv_append_calls``: K and
-    V of every layer, one tile of 8 float32 slab rows a program)."""
+    alone, what the compiled geometry fetches — of every slot of the
+    batch bucket the rows its sequence reaches, rounded up to a copy's
+    tile: ``kv_rows_fetched`` summed over the steps — and
+    ``decode_attn_calls`` names the geometry once per layer per trace:
+    the kernel in interpret mode behind a backend that says tpu, emitting
+    the jnp path's tokens.  The rows were appended by the aliased kernel
+    (``kv_append_calls``: K and V of every layer, one tile of 8 float32
+    slab rows a program)."""
     import functools
 
     import jax
+    from hetu_tpu.ops import attention as att
     from hetu_tpu.ops.pallas import decode_attention as da
     from hetu_tpu.ops.pallas import kv_append as ka
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -356,14 +519,20 @@ def test_engine_counts_the_kv_rows_the_kernel_fetches(jnp_rows_run,
     monkeypatch.setattr(ka, "kv_append", functools.partial(
         ka.kv_append, interpret=True))
     # 16 slab rows (32 keys) of every head a key block
-    monkeypatch.setattr(da, "BLOCK_BYTES", 16 * _ROWS_CFG.n_head * 128 * 4)
-    monkeypatch.setattr(da, "MIN_BLOCK_ROWS", 8)
+    _cut(monkeypatch, 16, _ROWS_CFG.n_head, 128, 4)
     tokens, c, calls = _serve_and_count()
     assert tokens == jnp_rows_run[0]
     assert c["decode_kv_rows_held"] == jnp_rows_run[1]["decode_kv_rows_held"]
     # slot 0 holds 1..40 keys, slot 1 1..5 and then stays at its last
-    # position: one 32-key block each, two for slot 0 past 32 keys
+    # position: a tile of 8 slab rows (16 keys) each, and another of
+    # slot 0 for every 16 keys more — where the blocks the grid WALKS
+    # (``kv_rows_read``) are 32 keys each
+    slab = (2, _ROWS_CFG.n_head, 128, 128)
+    steps = [np.array([n, min(n, 5)]) for n in range(1, 41)]
     assert c["decode_kv_rows_read"] == sum(
+        att.kv_rows_fetched(n, slab, 2, 4) for n in steps) == sum(
+        16 * (-(-n // 16) + 1) for n in range(1, 41))
+    assert sum(att.kv_rows_read(n, slab, 2, 4) for n in steps) == sum(
         32 * (-(-n // 32) + 1) for n in range(1, 41))
     assert calls == {f"{_ROWS_CFG.n_head}x16": _ROWS_CFG.n_layer}
     assert HetuProfiler.all_counters()["decode_attn_calls"] == calls

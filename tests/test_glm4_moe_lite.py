@@ -336,7 +336,7 @@ def test_one_token_kernel_reads_the_latent_rows(blocks, monkeypatch):
     want = mla._mla_attention_kv(None, q, slab.astype(jnp.float32), w,
                                  lengths - 1, ids, **sizes)
     # the cell's call: one head of 640-lane rows, 4096 of them, bfloat16
-    assert da.geometry(1, 4096, 640, 2) == (1, 512)
+    assert da.geometry(1, 4096, 640, 2, 1) == (1, 2048)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(da, "decode_attention", functools.partial(
         da.decode_attention, interpret=True))

@@ -655,12 +655,12 @@ def test_one_token_kernel_is_the_gqa_layers_read(blocks, monkeypatch):
                                  slabs[1].astype(jnp.float32), lengths - 1,
                                  ids, head_dim=d)
     # the cell's call: one key head of 128, 4096 rows, bfloat16
-    assert da.geometry(1, 4096, 128, 2) == (1, 2048)
+    assert da.geometry(1, 4096, 128, 2) == (1, 4096)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(da, "decode_attention", functools.partial(
         da.decode_attention, interpret=True))
     if blocks == "blocks":
-        monkeypatch.setattr(da, "BLOCK_BYTES", 64 * 128 * 2)
+        monkeypatch.setattr(da, "BLOCK_BYTES", 2 * 64 * 128 * 2)
         monkeypatch.setattr(da, "MIN_BLOCK_ROWS", 8)
     metrics.reset_all()
     got = kda._gqa_attention_kv(None, q, *slabs, lengths - 1, ids, head_dim=d)

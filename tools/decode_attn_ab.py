@@ -1,17 +1,23 @@
 """On-chip A/B of the one-token attention over a KV slab.
 
-Times ``ops/pallas/decode_attention.py`` at the shapes its two callers
-compile in the benchmark's serving cells — the shared-KV readers of
+Times ``ops/pallas/decode_attention.py`` at the shapes its four callers
+compile in the benchmark's serving cells — the latent read of
+``glm47-flash.think-c128`` ((128, 1, 4096, 640) bfloat16, 20 score rows,
+the value the first 512 lanes of the key), the shared-KV readers of
 ``phi4-mini-flash.reason-c64`` ((64, 10, 4608, 128) bfloat16 paired rows,
-four score rows a key pair) and GPT-2's packed heads in
+four score rows a key pair), GPT-2's packed heads in
 ``gpt2-medium.chat-c16`` ((16, 16, 384, 128) float32, two score rows a
-head) — with lengths drawn from each cell's own table (a slot holds
-request ``i`` for a time proportional to its prompt + output and sits at a
-uniform depth of it), against the whole-slab ``jnp`` read, and sweeps the
-geometry (heads per program x slab rows per key block) beside the one
-``decode_attention.geometry`` picks.  For the reader of PERF.md: the
-program reads nothing from what this prints.  A chip tool: run it on the
-machine with the chip.
+head) and the grouped-query read of ``solar-open2.assist-c128`` ((128, 1,
+4096, 128) bfloat16, 8 score rows) — with lengths drawn from each cell's
+own table (a slot holds request ``i`` for a time proportional to its
+prompt + output and sits at a uniform depth of it), against the whole-slab
+``jnp`` read, and sweeps the geometry (heads per program x slab rows per
+key block) beside the one ``decode_attention.geometry`` picks.  At the
+picked geometry it also reads the kernel's two halves alone: ``copy`` (the
+blocks fetched, no product taken) and ``products`` (the products over
+buffers nothing was copied into).  For the reader of PERF.md: the program
+reads nothing from what this prints.  A chip tool: run it on the machine
+with the chip.
 
 Every timed program makes ``READERS`` calls over the SAME slabs, as the
 phi4 step's eight readers do; the compiler merges the ``jnp`` calls' products
@@ -34,14 +40,20 @@ READERS = 8          # calls a timed program makes (the phi4 step has eight)
 REPS, INNER = 3, 10
 
 CELLS = {
+    "glm": {"traffic": "think-c128", "slab": (128, 1, 4096, 640),
+            "dtype": "bfloat16", "rows": 20, "pack": 1, "v_lanes": 512,
+            "sweep": [(1, 2048), (1, 1024), (1, 512), (1, 4096)]},
     "phi4": {"traffic": "reason-c64", "slab": (64, 10, 4608, 128),
              "dtype": "bfloat16", "rows": 4, "pack": 1,
-             "sweep": [(10, 256), (10, 512), (10, 128), (10, 1024),
-                       (5, 512), (2, 512), (1, 512)]},
+             "sweep": [(10, 512), (10, 256), (10, 128), (10, 1152),
+                       (5, 512)]},
     "chat": {"traffic": "chat-c16", "slab": (16, 16, 384, 128),
              "dtype": "float32", "rows": 2, "pack": 2,
-             "sweep": [(16, 64), (16, 128), (16, 32), (16, 192), (16, 384),
-                       (4, 384), (1, 384), (1, 128)]},
+             "sweep": [(16, 128), (16, 64), (16, 32), (16, 192),
+                       (16, 384)]},
+    "solar": {"traffic": "assist-c128", "slab": (128, 1, 4096, 128),
+              "dtype": "bfloat16", "rows": 8, "pack": 1,
+              "sweep": [(1, 4096), (1, 2048), (1, 1024)]},
 }
 
 
@@ -81,13 +93,14 @@ def _readers(call):
     return run
 
 
-def _jnp_whole(pack):
+def _jnp_whole(pack, v_lanes):
     """The whole-slab read the ``jnp`` paths make: scores against every
     slab row, masked afterwards, one softmax per score row."""
     import jax
     import jax.numpy as jnp
 
     def call(rows, k, v, lengths):
+        v = k[..., :v_lanes] if v is None else v
         s = jnp.einsum("bhrl,bhml->bhrm", rows, k,
                        preferred_element_type=jnp.float32)
         key = (jnp.arange(k.shape[2])[None, :] * pack
@@ -99,14 +112,38 @@ def _jnp_whole(pack):
     return call
 
 
+def _stood_in(da, **names):
+    """Time under stand-ins for names of the kernel's module (its geometry
+    rule, one of its halves): they are functions of the module, looked up
+    when the kernel's jitted call is traced, so what was traced under
+    other names is dropped before and after."""
+    import jax
+
+    def timed(call, *args):
+        kept = {name: getattr(da, name) for name in names}
+        try:
+            for name, value in names.items():
+                setattr(da, name, value)
+            jax.clear_caches()
+            return _time(_readers(call), *args)
+        except Exception as e:  # noqa: BLE001 - a refused geometry is data
+            return f"refused: {str(e).splitlines()[0][:120]}"
+        finally:
+            for name, value in kept.items():
+                setattr(da, name, value)
+            jax.clear_caches()
+    return timed
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--cells", default="phi4,chat")
+    ap.add_argument("--cells", default="glm,phi4,chat,solar")
     ap.add_argument("--seed", type=int, default=2860486313)
     args = ap.parse_args(argv)
     import jax
     import jax.numpy as jnp
     import numpy as np
+    from hetu_tpu.ops.attention import kv_rows_fetched
     from hetu_tpu.ops.pallas import decode_attention as da
     dev = jax.devices()[0]
     if dev.platform != "tpu":
@@ -116,42 +153,43 @@ def main(argv=None):
         cell = CELLS[name]
         b, h, slab_rows, lanes = cell["slab"]
         dtype = jnp.dtype(cell["dtype"])
-        pack = cell["pack"]
+        pack, v_lanes = cell["pack"], cell.get("v_lanes")
         key = jax.random.PRNGKey(args.seed % (2 ** 31))
         kq, kk, kv = jax.random.split(key, 3)
         rows = jax.random.normal(kq, (b, h, cell["rows"], lanes),
                                  jnp.float32).astype(dtype)
         k = jax.random.normal(kk, cell["slab"], jnp.float32).astype(dtype)
-        v = jax.random.normal(kv, cell["slab"], jnp.float32).astype(dtype)
+        v = None if v_lanes else jax.random.normal(
+            kv, cell["slab"], jnp.float32).astype(dtype)
+        kernel = functools.partial(da.decode_attention, pack=pack,
+                                   v_lanes=v_lanes)
         mixes = {"cell": cell_lengths(cell["traffic"], b, args.seed),
                  "full": np.full(b, slab_rows * pack, np.int32),
                  "one": np.ones(b, np.int32)}
-        rule = da.geometry
-        picked = rule(h, slab_rows, lanes, dtype.itemsize)
+        picked = da.geometry(h, slab_rows, lanes, dtype.itemsize,
+                             1 if v_lanes else 2)
         for mix, lengths in mixes.items():
             n = jnp.asarray(lengths, jnp.int32)
             out = {"cell": name, "lengths": mix, "device": dev.device_kind,
                    "mean_len": float(lengths.mean()), "picked": list(picked),
                    "slab_keys": slab_rows * pack, "ms_per_call": {}}
             out["ms_per_call"]["jnp_whole"] = _time(
-                _readers(_jnp_whole(pack)), rows, k, v, n)
+                _readers(_jnp_whole(pack, v_lanes)), rows, k, v, n)
             sweep = cell["sweep"] if mix == "cell" else cell["sweep"][:2]
-            for geo in sweep:
-                # the rule is a function of the module: the sweep stands
-                # in for it, one geometry at a time
-                da.geometry = lambda *shape, geo=geo: geo
-                try:
-                    out["ms_per_call"][f"{geo[0]}x{geo[1]}"] = _time(
-                        _readers(functools.partial(da.decode_attention,
-                                                   pack=pack)), rows, k, v, n)
-                except Exception as e:  # noqa: BLE001 - a refused
-                    out["ms_per_call"][f"{geo[0]}x{geo[1]}"] = (  # geometry
-                        f"refused: {str(e).splitlines()[0][:120]}")  # is data
-                finally:
-                    da.geometry = rule
-            keys = picked[1] * pack
-            out["rows_read_pct"] = 100.0 * float(
-                (-(-lengths // keys) * keys).sum()) / (b * slab_rows * pack)
+            for geo in [tuple(picked)] + [g for g in sweep if g != picked]:
+                out["ms_per_call"][f"{geo[0]}x{geo[1]}"] = _stood_in(
+                    da, geometry=lambda *shape, geo=geo: geo)(
+                        kernel, rows, k, v, n)
+            # the picked geometry's halves: the blocks copied and nothing
+            # multiplied; the products over buffers nothing was copied into
+            out["ms_per_call"]["copy_alone"] = _stood_in(
+                da, _products=lambda *a, **kw: None)(kernel, rows, k, v, n)
+            out["ms_per_call"]["products_alone"] = _stood_in(
+                da, _fetch=lambda *a, **kw: [])(
+                    kernel, rows, k, v, n)
+            out["rows_fetched_pct"] = 100.0 * kv_rows_fetched(
+                lengths, cell["slab"], pack, dtype.itemsize
+            ) / (b * slab_rows * pack)
             print(json.dumps(out), flush=True)
     return 0
 
