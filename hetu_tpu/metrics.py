@@ -178,6 +178,29 @@ def moe_call_counts():
     return _moe_calls.counts()
 
 
+# How a decode graph's block-sparse layer reads its slabs
+# (``ops.sparse_attention._sparse_attention_kv``): the blocks a head group
+# reads past ``dense_len`` and their rows, and whether the read is the
+# selected-block kernel (``ops/pallas/decode_attention.py``) or the masked
+# read of the whole slab through jnp.  Per TRACE, as the families above.
+_sparse_attn_calls = REGISTRY.counter_family(
+    "sparse_attn_calls",
+    "block-sparse attention reads by selection and path, "
+    "\"<blocks>x<rows>:<kernel|jnp>\" (per jax trace)")
+
+
+def record_sparse_attn_call(blocks, rows, how):
+    """Count one traced block-sparse read."""
+    if counters_suppressed():
+        return
+    _sparse_attn_calls.inc(f"{blocks}x{rows}:{how}")
+
+
+def sparse_attn_call_counts():
+    """{"<blocks>x<rows>:<kernel|jnp>": count} snapshot."""
+    return _sparse_attn_calls.counts()
+
+
 # ---------------------------------------------- embedding Pallas fallbacks
 # The device-resident embedding-cache dispatchers
 # (``ops/pallas/emb_cache.py``) record WHY a gather / grad scatter-add
@@ -1236,6 +1259,7 @@ _FAMILIES = {
     "decode_attn_calls": _decode_attn_calls,
     "kv_append_calls": _kv_append_calls,
     "moe_calls": _moe_calls,
+    "sparse_attn_calls": _sparse_attn_calls,
     "emb_pallas_fallbacks": _emb_pallas,
     "faults": _faults,
     "elastic": _elastic,

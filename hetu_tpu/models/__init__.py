@@ -33,3 +33,6 @@ from .solar_open2 import (SolarOpen2Config, solar_open2_decode_graph,
 from .glm4_moe_lite import (Glm4MoeLiteConfig, glm4_moe_lite_decode_graph,
                             glm4_moe_lite_decode_chunked_graph,
                             glm4_moe_lite_lm_graph)
+from .minicpm_sala import (MiniCPMSALAConfig, minicpm_sala_decode_graph,
+                           minicpm_sala_decode_chunked_graph,
+                           minicpm_sala_lm_graph)

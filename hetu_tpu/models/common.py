@@ -134,7 +134,9 @@ class DecodeGraph:
 
     def state(self, name, kind, shape, dtype, **slab):
         if not self.fed:
-            if kind == "kv":
+            if kind in ("kv", "index"):
+                slab = dict(slab)
+                slab["length"] = -(-slab["length"] // slab.pop("stride", 1))
                 shape = ops.kv_slab_shape(**slab)
             return ops.zeros_op(self.ids, tail=tuple(shape[1:]),
                                 dtype=np.dtype(dtype))
@@ -182,11 +184,15 @@ def moe_block(g, x, name):
 
 
 def build_decoder(cfg, layer, chunk, max_len, name, fed=True,
-                  with_valid=True):
+                  with_valid=True, embed_scale=1.0, logit_scale=1.0,
+                  chosen=None):
     """The graph of ``cfg.num_hidden_layers`` blocks ``layer(g, x, i,
     name) -> x`` between the embedding and the head: ``(g, logits, greedy
     token ids, chosen expert ids)``.  ``fed=False``: zero states and
-    position 0 (the full-sequence graph)."""
+    position 0 (the full-sequence graph).  ``embed_scale`` / ``logit_scale``
+    multiply the embedding and the logits (a muP-scaled model's);
+    ``chosen(ids, *g.chosen) -> node`` stacks what the layers left in
+    ``g.chosen`` in place of ``ops.moe_choices_op``."""
     from ..graph.node import name_scope, placeholder_op
     b = cfg.batch_size
     ids = placeholder_op("input_ids", shape=(b, chunk), dtype=np.int32)
@@ -207,15 +213,22 @@ def build_decoder(cfg, layer, chunk, max_len, name, fed=True,
             g.var(name + ".embed", (cfg.vocab_size, cfg.hidden_size)), ids,
             dtype=np.float32),
         output_shape=(-1, cfg.hidden_size))
+    if embed_scale != 1.0:
+        x = x * float(embed_scale)
     for i in range(cfg.num_hidden_layers):
         x = layer(g, x, i, f"{name}.l{i}")
-    with name_scope("moe.route"):
-        choices = ops.moe_choices_op(ids, *g.chosen)
+    if chosen is not None:
+        choices = chosen(ids, *g.chosen)
+    else:
+        with name_scope("moe.route"):
+            choices = ops.moe_choices_op(ids, *g.chosen)
     with name_scope("lm_head"):
         if valid is not None:
             x = ops.chunk_emit_gather_op(x, ids, valid)
         logits = g.dense(g.norm(x, name + ".ln_f"), name + ".lm_head",
                          cfg.hidden_size, cfg.vocab_size)
+        if logit_scale != 1.0:
+            logits = logits * float(logit_scale)
         tokens = ops.greedy_token_op(logits)
     return g, logits, tokens, choices
 

@@ -376,7 +376,7 @@ def kv_slab_placeholder(name, batch, heads, length, head_dim,
 
 
 #: what a decode graph's state placeholder can be (``state_placeholder``)
-STATE_KINDS = ("kv", "ring", "recurrent")
+STATE_KINDS = ("kv", "index", "ring", "recurrent")
 
 
 def state_placeholder(name, kind, shape=None, dtype=np.float32, **slab):
@@ -389,6 +389,11 @@ def state_placeholder(name, kind, shape=None, dtype=np.float32, **slab):
       (:func:`kv_slab_placeholder`); its rows axis walks the
       length ladder, and a row is read only below its sequence's
       position, so a re-seated slot needs no clearing.
+    * ``"index"`` — a slab as ``"kv"``'s that grows at ONE ROW PER
+      ``stride`` POSITIONS (give ``stride`` with the slab's sizes,
+      ``length`` still in positions): the compressed keys of a sparse
+      layer's indexer.  Read below what its sequence has completed, so
+      never cleared; it walks the length ladder at ``1 / stride``.
     * ``"ring"`` — a fixed ``(B, heads, window, width)`` buffer written
       at ``position mod window`` and read by position, never grown along
       the window and never cleared.
@@ -402,6 +407,11 @@ def state_placeholder(name, kind, shape=None, dtype=np.float32, **slab):
                          f"{STATE_KINDS}")
     if kind == "kv":
         node = kv_slab_placeholder(name, dtype=dtype, **slab)
+    elif kind == "index":
+        stride = int(slab.pop("stride"))
+        node = kv_slab_placeholder(name, dtype=dtype, **dict(
+            slab, length=-(-int(slab["length"]) // stride)))
+        node.attrs["stride"] = stride
     else:
         from ..graph.node import placeholder_op
         node = placeholder_op(name, dtype=dtype, shape=tuple(shape))

@@ -148,6 +148,17 @@ def _kda_out(c, o, gate, scale, eps=1e-5):
 kda_out_op = def_op("KDAOutGate", _kda_out)
 
 
+def _head_norm(c, x, scale, eps=1e-5):
+    """RMSNorm of every ``D``-wide head of ``x`` (rows, H * D) on its own,
+    ``scale`` (D,) shared by the heads (a ``qk_norm``)."""
+    d = scale.shape[0]
+    return _rms(_f32(x).reshape(x.shape[0], -1, d), _f32(scale),
+                eps).reshape(x.shape)
+
+
+head_norm_op = def_op("HeadRMSNorm", _head_norm)
+
+
 # ------------------------------------------------- grouped-query attention
 
 def _gqa_rows(c, t, ids, head_dim=128):

@@ -139,7 +139,13 @@ def _block(q, k, v, first, length, m_scr, l_scr, acc_scr, pack):
     row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
     # row i, column m scores key (first + m)*r + i % r
-    valid = (col + first) * pack + row % pack < length
+    _fold(s, (col + first) * pack + row % pack < length, v, m_scr, l_scr,
+          acc_scr)
+
+
+def _fold(s, valid, v, m_scr, l_scr, acc_scr):
+    """Scores ``s`` (heads, rows, n), counted where ``valid``, and their
+    values ``v`` (heads, n, lanes) into the running softmax."""
     s = jnp.where(valid, s, NEG_INF)
     m_prev = m_scr[:, :, :1]                         # (heads, rows, 1)
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
@@ -402,3 +408,163 @@ def _call(lengths, rows, *slabs, hb, block_k, tail, depth, pack, v_lanes,
         interpret=(pltpu.InterpretParams(uninitialized_memory="nan")
                    if interpret is True else interpret),
     )(lengths, slot, block, rows, *slabs)
+
+
+# ------------------------------------------------------ selected blocks
+# The fifth caller (``ops/sparse_attention.py``): a sequence reads only the
+# key blocks an indexer chose for it, per key head.  The schedule is the
+# chosen block ids themselves, sorted, per (slot, key head), scalar-
+# prefetched with their count; the grid walks (slot, key head, group of
+# ``SEL_BLOCKS`` chosen blocks) and a program copies its group's blocks from
+# the slabs in HBM into ONE buffer a slab, side by side, and multiplies them
+# in one product — a product costs the softmax's chain of dependent steps
+# whatever it multiplies.  The copies of the NEXT program's group are started
+# before this one's products, across a head's and a slot's end.
+
+#: chosen key blocks a program copies and multiplies at once
+SEL_BLOCKS = 16
+
+
+def _sel_kernel(len_ref, cnt_ref, blk_ref, q_ref, k_hbm, v_hbm, o_ref,
+                kbuf, vbuf, sems, *scr, rows, per, width):
+    """One (slot, key head, group) program.  ``blk_ref``: the chosen block
+    ids, ``width`` a (slot, key head), the first ``cnt_ref`` of them real;
+    ``q_ref`` / ``o_ref``: (1, score rows, lanes); ``kbuf`` / ``vbuf``: (2,
+    1, per * rows, lanes), this program's group and the next one's."""
+    heads, groups = pl.num_programs(1), pl.num_programs(2)
+    at = (pl.program_id(0) * heads + pl.program_id(1)) * groups \
+        + pl.program_id(2)
+    total = pl.num_programs(0) * heads * groups
+
+    def entry(at):
+        """``(slot, key head, group, its (slot, head)'s count)``."""
+        pair, group = jax.lax.div(at, groups), jax.lax.rem(at, groups)
+        return (jax.lax.div(pair, heads), jax.lax.rem(pair, heads), group,
+                cnt_ref[pair])
+
+    def block_of(at, i):
+        """The ``i``-th block id of entry ``at``: past the count the last
+        real one again, which the mask drops — every row of the buffer is
+        copied, none reads what an earlier program left."""
+        pair, group = jax.lax.div(at, groups), jax.lax.rem(at, groups)
+        return blk_ref[pair * width + jnp.minimum(group * per + i,
+                                                  cnt_ref[pair] - 1)]
+
+    def copies(at):
+        slot, head, _, _ = entry(at)
+        place = jax.lax.rem(at, 2)
+        return [pltpu.make_async_copy(
+            hbm.at[slot, pl.ds(head, 1),
+                   pl.ds(pl.multiple_of(block_of(at, i) * rows, rows), rows),
+                   :],
+            buf.at[place, :, pl.ds(i * rows, rows), :], sems.at[place, j])
+            for i in range(per)
+            for j, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf)))]
+
+    def live(at):
+        _, _, group, count = entry(at)
+        return group * per < count
+
+    def start(at):
+        @pl.when(jnp.logical_and(at < total, live(jnp.minimum(at,
+                                                              total - 1))))
+        def _():
+            for c in copies(at):
+                c.start()
+
+    pl.when(at == 0)(lambda: start(at))
+    start(at + 1)
+
+    slot, _, group, count = entry(at)
+    pl.when(group == 0)(lambda: _init(*scr))
+
+    @pl.when(live(at))
+    def _():
+        for c in copies(at):
+            c.wait()
+        place = jax.lax.rem(at, 2)
+        k, v = kbuf[place], vbuf[place]
+        s = jax.lax.dot_general(
+            q_ref[...], k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)      # (1, score rows, n)
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, 1, per * rows), 2)
+        which = col // rows
+        first = jnp.zeros_like(col)
+        for i in range(per):
+            first = jnp.where(which == i, block_of(at, i) * rows, first)
+        seen = jnp.logical_and(first + col % rows < len_ref[slot],
+                               group * per + which < count)
+        _fold(s, seen, v, *scr)
+
+    pl.when(group == (count - 1) // per)(lambda: _finish(o_ref, *scr, 1))
+
+
+def decode_attention_blocks(rows, k_slab, v_slab, lengths, blocks, counts,
+                            block_rows=64, interpret=False):
+    """Attention of a few query rows per (sequence, KV head) over CHOSEN
+    key blocks of plain-row KV slabs.
+
+    ``rows``: (B, H, n, lanes) score rows, scaled, in the slabs' dtype;
+    ``k_slab`` / ``v_slab``: (B, H, L, lanes); ``lengths``: (B,) — keys at
+    positions ``>= lengths[b]`` are invisible; ``blocks``: (B, H, W) int —
+    the ids of the ``block_rows``-row key blocks head ``h`` of sequence
+    ``b`` reads, the first ``counts[b, h]`` (>= 1) of them real, distinct
+    and sorted (what is past the count is not read); ``W`` a multiple of
+    ``SEL_BLOCKS``.  Returns (B, H, n, lanes) float32, the softmax over the
+    keys of the chosen blocks below the length.  The trace knows the call as
+    ``sparse_fwd_q1``."""
+    b, h, n, lanes = rows.shape
+    width = blocks.shape[-1]
+    if lanes % 128 or k_slab.shape[2] % block_rows or width % SEL_BLOCKS:
+        raise ValueError(
+            f"the selected-block read takes whole lane rows, a slab of whole "
+            f"blocks and a schedule of whole groups; got lanes {lanes}, "
+            f"{k_slab.shape[2]} rows of blocks of {block_rows}, width "
+            f"{width} (groups of {SEL_BLOCKS})")
+    from ...metrics import record_decode_attn_call
+    record_decode_attn_call(1, f"{SEL_BLOCKS}x{block_rows}")
+    return _sel_call(jnp.asarray(lengths, jnp.int32),
+                     jnp.asarray(counts, jnp.int32).reshape(-1),
+                     jnp.asarray(blocks, jnp.int32).reshape(-1), rows,
+                     k_slab, v_slab, block_rows=int(block_rows),
+                     interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
+def _sel_call(lengths, counts, blocks, rows, k_slab, v_slab, block_rows,
+              interpret):
+    b, h, n, lanes = rows.shape
+    width = blocks.shape[0] // (b * h)
+    lengths = jnp.clip(lengths, 1, k_slab.shape[2])
+    counts = jnp.clip(counts, 1, width)
+    tile = 32 // rows.dtype.itemsize             # a whole sublane tile
+    padded = -(-n // tile) * tile
+    rows = jnp.pad(rows, ((0, 0), (0, 0), (0, padded - n), (0, 0)))
+
+    def at_head(bi, hi, gi, *_):
+        return bi, hi, 0, 0
+
+    return pl.pallas_call(
+        functools.partial(_sel_kernel, rows=block_rows, per=SEL_BLOCKS,
+                          width=width),
+        name="sparse_fwd_q1",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, h, width // SEL_BLOCKS),
+            in_specs=[pl.BlockSpec((None, 1, padded, lanes), at_head),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((None, 1, n, lanes), at_head),
+            scratch_shapes=[
+                pltpu.VMEM((2, 1, SEL_BLOCKS * block_rows, lanes),
+                           k_slab.dtype),
+                pltpu.VMEM((2, 1, SEL_BLOCKS * block_rows, lanes),
+                           v_slab.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((1, padded, 128), jnp.float32),    # running max
+                pltpu.VMEM((1, padded, 128), jnp.float32),    # running sum
+                pltpu.VMEM((1, padded, lanes), jnp.float32)]),  # P @ V
+        out_shape=jax.ShapeDtypeStruct((b, h, n, lanes), jnp.float32),
+        interpret=(pltpu.InterpretParams(uninitialized_memory="nan")
+                   if interpret is True else interpret),
+    )(lengths, counts, blocks, rows, k_slab, v_slab)

@@ -230,6 +230,7 @@ class HetuProfiler:
         observability registry in one call (``hetu_tpu.metrics``
         ``all_counts``): flash_fallbacks, flash_calls,
         decode_attn_calls, kv_append_calls, moe_calls,
+        sparse_attn_calls,
         emb_pallas_fallbacks, faults, elastic, autoparallel, cache, zero,
         step_cache, compile, setup_us, setup_bytes, run_plan, serve,
         decode, prefix_cache, decode_recovery, serve_rejection_reason,
@@ -315,6 +316,16 @@ class HetuProfiler:
         token takes, and the grouped product's path.  Per trace."""
         from .metrics import moe_call_counts
         return moe_call_counts()
+
+    @staticmethod
+    def sparse_attn_calls():
+        """{"<blocks>x<rows>:<kernel|jnp>": count} of traced block-sparse
+        attention reads (``ops.sparse_attention``): the blocks a head group
+        reads past ``dense_len``, their rows, and whether the read is the
+        selected-block kernel (a one-token step on the chip) or the masked
+        read of the whole slab (a chunk, the CPU).  Per trace."""
+        from .metrics import sparse_attn_call_counts
+        return sparse_attn_call_counts()
 
     @staticmethod
     def emb_pallas_fallbacks():

@@ -131,6 +131,7 @@ from ..obs.compile_log import SetupPhase
 from ..obs.lock_witness import make_condition, make_lock
 from ..obs.trace import Phases as _Phases
 from ..obs.trace import TRACER as _TR
+from ..obs.trace import span as _span
 from .executor import InferenceExecutor, default_buckets
 from .router import ServeRejected
 
@@ -172,6 +173,7 @@ class DecodeStream:
         self._futs = []
         self._tokens = []
         self._aux = {}
+        self._aux_from = 0
         self._epoch = 0
         self._final = Future()
 
@@ -225,10 +227,19 @@ class DecodeStream:
         stacked in position order: row ``p`` is of the token at position
         ``p`` — the prompt's tokens, then each generated token once it was
         fed back (so ``prompt_len + n_tokens - 1`` rows when the sequence
-        has finished).  None for a name the engine does not fetch."""
+        has finished).  A sequence seated from a prefix store consumed
+        nothing of the prefix: its rows start at position
+        :attr:`aux_from`.  None for a name the engine does not fetch."""
         with self._lock:
             parts = list(self._aux.get(name, ()))
         return np.concatenate(parts) if parts else None
+
+    @property
+    def aux_from(self):
+        """The position of :meth:`aux`'s first row: 0, or the rows a
+        prefix store seated this sequence with."""
+        with self._lock:
+            return self._aux_from
 
     def __iter__(self):
         i = 0
@@ -255,7 +266,7 @@ class DecodeStream:
             self._epoch += 1
             epoch, journal = self._epoch, list(self._tokens)
             # the continuation consumes every position again
-            self._aux = {}
+            self._aux, self._aux_from = {}, 0
         if _PROTO.on:
             _PROTO.emit("decode", "detach", sid=self.sid, old=epoch - 1,
                         new=epoch, n=len(journal))
@@ -287,6 +298,13 @@ class DecodeStream:
         if fut.set_running_or_notify_cancel():
             fut.set_result(int(tok))
         return count
+
+    def _seated_at(self, m, epoch=None):
+        """A prefix store seated the sequence with its first ``m``
+        positions: its auxiliary rows start there."""
+        with self._lock:
+            if epoch is None or epoch == self._epoch:
+                self._aux_from = int(m)
 
     def _note_aux(self, parts, epoch=None):
         """Keep the auxiliary fetches' slices ``{name: (n, ...)}`` of the
@@ -332,9 +350,11 @@ class DecodeStream:
 
 class _DecodeRequest:
     __slots__ = ("prompt", "max_new", "eos_id", "stream", "t_arrival",
-                 "fid", "deadline", "epoch", "retries", "detached_ts")
+                 "fid", "deadline", "epoch", "retries", "detached_ts",
+                 "keep_prefix")
 
-    def __init__(self, prompt, max_new, eos_id, fid, deadline=None):
+    def __init__(self, prompt, max_new, eos_id, fid, deadline=None,
+                 keep_prefix=True):
         self.prompt = prompt
         self.max_new = int(max_new)
         self.eos_id = eos_id
@@ -345,6 +365,7 @@ class _DecodeRequest:
         self.epoch = 0             # stream replay epoch this req emits under
         self.retries = 0           # continuation builds for this stream
         self.detached_ts = None    # set on continuations: detach time
+        self.keep_prefix = bool(keep_prefix)   # snapshot the prompt's state
 
 
 def _continuation(req):
@@ -373,6 +394,7 @@ def _continuation(req):
     cont.epoch = epoch
     cont.retries = req.retries + 1
     cont.detached_ts = time.monotonic()
+    cont.keep_prefix = req.keep_prefix
     record_decode_recovery("decode_recovery_detached")
     if cont.retries > 1:
         record_decode_recovery("decode_recovery_retries")
@@ -409,7 +431,8 @@ class _Launch:
     then the auxiliary fetches), ``logits`` the (batch, vocab) logits
     left on the device.  The rest is what the step's counters need:
     whether a row emits, whether the step before was still un-collected,
-    the batch and chunk buckets, the KV rows ``(read, held, live)``."""
+    the batch and chunk buckets, the KV rows ``(read, held, live, index
+    rows live)``."""
 
     __slots__ = ("rows", "back", "logits", "emits", "ahead", "bb", "chunk",
                  "kv_rows")
@@ -458,16 +481,29 @@ class DecodeEngine:
     is (:func:`~hetu_tpu.ops.state_placeholder`, ``attrs["state_kind"]``;
     a KV slab without one is ``kv``) and the engine allocates, grows,
     seats, clears and accounts each by kind: ``kv`` slabs walk the
-    length ladder; a ``ring`` is a fixed ``window``-row buffer its graph
+    length ladder; an ``index`` slab walks it at one row per ``stride``
+    positions (a sparse layer's compressed keys: slabs of two geometries
+    in one engine); a ``ring`` is a fixed ``window``-row buffer its graph
     writes at ``position mod window`` and reads by position; a
     ``recurrent`` state is fixed-shape and its slot's rows are ZEROED at
     :meth:`join` (``decode_state_clears``) — a slab or a ring is read
     only where the seated sequence wrote, a recurrence folds in whatever
     it finds.  ``decode_state_bytes_<kind>_hw`` gauge each kind,
-    ``decode_kv_bytes_hw`` their sum.  ``prefix_store=`` and ``plan=``
-    with ``ring`` or ``recurrent`` state raise at construction.
-    :meth:`reserve` puts the engine at given buckets before the first
-    request.
+    ``decode_kv_bytes_hw`` their sum.  ``plan=`` with any state but
+    ``kv`` raises at construction.  :meth:`reserve` puts the engine at
+    given buckets before the first request.
+
+    **What a prefix store snapshots (ISSUE 42).**  With ``prefix_store=``
+    a prompt's snapshot holds, beside its KV rows, its ``index`` rows and
+    its ``recurrent`` state at the prompt's last position, and
+    :meth:`join` seats all of them in place (one donated call,
+    ``decode_prefix_seats`` / ``decode_prefix_seat_us``, span
+    ``decode.join.seat``).  A recurrence cannot be rolled back to a
+    shared partial depth: with any state but ``kv`` a lookup hits only an
+    entry whose WHOLE key is a proper prefix of the prompt (a kv-only
+    graph keeps partial-overlap reuse).  Which prompts are snapshotted is
+    the caller's to say (``DecodeRouter.submit(keep_prefix=)``).
+    ``ring`` state beside a store raises at construction.
 
     **The token loop closes on the device (ISSUE 32).**  Every step
     program hands back each row's greedy token as a (B,) int32 array
@@ -495,9 +531,10 @@ class DecodeEngine:
     consumed to its stream (:meth:`DecodeStream.aux`) and, in the
     ``readback`` phase, folds the whole array into counters:
     ``aux_fold={name: fn}``, ``fn(array) -> {counter: n}``, recorded with
-    :func:`~hetu_tpu.metrics.record_decode`.  A prefix store is refused
-    with them: a sequence seated past its prefix would lack the slices of
-    the positions it skipped.
+    :func:`~hetu_tpu.metrics.record_decode`.  Beside a prefix store a
+    sequence seated past its prefix has no slices of the positions it
+    skipped: its :meth:`DecodeStream.aux` starts at
+    :attr:`DecodeStream.aux_from`.
 
     NOT thread-safe by design: the owning :class:`DecodeRouter` loop
     thread (or a single test thread) makes every call after
@@ -519,27 +556,31 @@ class DecodeEngine:
             for n in self.cache_names}
         self._recurrent = [n for n in self.cache_names
                            if self._kinds[n] == "recurrent"]
+        #: the states with a rows axis that walks the length ladder, and
+        #: the positions one of their rows stands for (1 for a ``kv`` slab)
+        self._strides = {n: int(feeds[n].attrs.get("stride", 1))
+                         for n in self.cache_names
+                         if self._kinds[n] in ("kv", "index")}
         other = sorted({k for k in self._kinds.values() if k != "kv"})
-        if other and (prefix_store is not None or plan is not None):
+        if "ring" in other and prefix_store is not None:
             raise ValueError(
                 f"this graph keeps {' and '.join(other)} state beside its "
-                f"KV slabs: " + (
-                    "a prefix store snapshots and seats KV rows only, and "
-                    "a sequence seated past its prefix would find its "
-                    "other state empty" if prefix_store is not None else
-                    "a tp plan shards KV slabs by head and says nothing "
-                    "of the other kinds") +
-                " — build the engine without "
-                + ("prefix_store=" if prefix_store is not None else "plan="))
+                f"KV slabs: a prefix store snapshots slab rows and "
+                f"recurrent state, and a ring written at position mod "
+                f"window cannot be cut at a prefix — build the engine "
+                f"without prefix_store=")
+        if other and plan is not None:
+            raise ValueError(
+                f"this graph keeps {' and '.join(other)} state beside its "
+                f"KV slabs: a tp plan shards KV slabs by head and says "
+                f"nothing of the other kinds — build the engine without "
+                f"plan=")
+        #: a state that cannot be cut at a shared partial depth: a lookup
+        #: hits only an entry whose WHOLE key is a prefix of the prompt
+        self._whole_hits = bool(other)
         #: auxiliary fetches by name, and what folds each into counters
         self._aux = list(aux or ())
         self._aux_fold = dict(aux_fold or {})
-        if self._aux and prefix_store is not None:
-            raise ValueError(
-                f"this graph hands back {self._aux} of every token a "
-                f"sequence consumes: one seated past a stored prefix would "
-                f"lack them for the positions it skipped — build the "
-                f"engine without prefix_store=")
         #: the graph's fetches in front of the states: the greedy token
         #: ids where it computes them (``tokens=``), then the logits, then
         #: the auxiliary fetches.  ``_program`` puts ids in front where
@@ -569,6 +610,9 @@ class DecodeEngine:
             self._pack = self._lanes // self._head_dim
         else:
             self._heads = self._lanes = self._head_dim = self._pack = 0
+        #: ``(block rows, blocks, dense_len)`` where a one-token step reads
+        #: chosen blocks of the slabs only (``ops/sparse_attention.py``)
+        self._selected = ck0.attrs.get("selected") if kv else None
         self.ciex = None
         self.chunk_ladder = (1,)
         self.chunk_top = 1
@@ -621,6 +665,7 @@ class DecodeEngine:
                            for name in self.cache_names}
             state.nbytes = self.kv_bytes
         self._clear = None        # jitted zeroing of a slot's recurrent rows
+        self._seat = None         # jitted write of a snapshot into a slot
         self._logits = None
         #: steps launched and not collected, oldest first (two at most,
         #: and only between a ``launch`` and the ``collect`` that follows)
@@ -642,33 +687,35 @@ class DecodeEngine:
 
     # -- memory ------------------------------------------------------------
 
-    def _slab_rows(self, n):
-        """Slab rows that hold ``n`` key rows."""
-        return -(-int(n) // self._pack)
+    def _slab_rows(self, n, name=None):
+        """Slab rows of state ``name`` (a ``kv`` slab where None) that hold
+        ``n`` positions: ``ceil(n / stride)`` rows, ``pack`` to a slab
+        row."""
+        rows = -(-int(n) // self._strides.get(name, 1))
+        return -(-rows // self._pack)
 
     def _alloc(self, name, bb, lb):
-        """Zeros for state ``name`` at batch bucket ``bb``: a ``kv`` slab
-        with room for ``lb`` key rows, any other kind at its declared
-        shape."""
+        """Zeros for state ``name`` at batch bucket ``bb``: a ``kv`` or
+        ``index`` slab with room for ``lb`` positions, any other kind at
+        its declared shape."""
         import jax.numpy as jnp
         tail, dtype = self._tails[name]
-        if self._kinds[name] == "kv":
-            tail = (tail[0], self._slab_rows(lb), tail[2])
+        if name in self._strides:
+            tail = (tail[0], self._slab_rows(lb, name), tail[2])
         return self.iex._place(jnp.zeros((bb,) + tail, dtype))
 
     def _resize(self, bb, lb):
         """Every state zero-padded to batch bucket ``bb``, the ``kv``
-        slabs also to ``lb`` key rows (``ring`` and ``recurrent`` state
-        has no length)."""
+        and ``index`` slabs also to ``lb`` positions (``ring`` and
+        ``recurrent`` state has no length)."""
         import jax.numpy as jnp
-        rows = self._slab_rows(lb) - self._slab_rows(self.lb) \
-            if self._pack else 0
         with SetupPhase("setup.state") as state:
             before = self.kv_bytes
             for name, c in self.caches.items():
                 pad = [(0, bb - self.bb)] + [(0, 0)] * (c.ndim - 1)
-                if self._kinds[name] == "kv":
-                    pad[2] = (0, rows)
+                if name in self._strides:
+                    pad[2] = (0, self._slab_rows(lb, name)
+                              - self._slab_rows(self.lb, name))
                 if any(p != (0, 0) for p in pad):
                     self.caches[name] = self.iex._place(jnp.pad(c, pad))
             state.nbytes = self.kv_bytes - before
@@ -733,7 +780,14 @@ class DecodeEngine:
         held = self.bb * rows * self._pack
         if chunk > 1:
             return held, held
-        from ..ops.attention import kv_rows_fetched
+        from ..ops.attention import _decode_gate_reason, kv_rows_fetched
+        if self._selected and _decode_gate_reason(rows * self._pack) is None:
+            # the selected-block kernel: every live block below
+            # ``dense_len`` keys, the chosen blocks whatever the length past
+            block, blocks, dense_len = self._selected
+            n = self.positions.astype(np.int64) + 1
+            return int(np.where(n < dense_len, -(-n // block),
+                                blocks).sum()) * block, held
         return kv_rows_fetched(
             self.positions + 1, (self.bb, self._heads, rows, self._lanes),
             self._pack, self._tails[self._kv[0]][1].itemsize), held
@@ -834,9 +888,8 @@ class DecodeEngine:
         seq = _Sequence(req)
         m, rows = 0, None
         if self.prefix is not None:
-            m, rows = self.prefix.lookup(req.prompt)
+            m, rows = self.prefix.lookup(req.prompt, whole=self._whole_hits)
         self.slots[slot] = seq
-        self._clear_recurrent(slot)
         seq.ptr = m
         self.tokens[slot] = req.prompt[m]
         self.positions[slot] = m
@@ -844,13 +897,10 @@ class DecodeEngine:
             # the snapshot rows land at 0..m-1: grow the length bucket
             # first (the fresh padding is all-zero, like a cold slot)
             self._grow_len_if_needed()
-            from ..ops.attention import kv_slab_from_rows
-            for name in self.cache_names:
-                # whole slab rows: the last one zero past row m, rows no
-                # key of this sequence has reached yet
-                self.caches[name] = self.iex._place(
-                    self.caches[name].at[slot, :, :self._slab_rows(m), :]
-                    .set(kv_slab_from_rows(rows[name], self._lanes)))
+            self._seat_snapshot(slot, m, rows)
+            req.stream._seated_at(m, req.epoch)
+        else:
+            self._clear_recurrent(slot)
         if self._used[slot]:
             record_decode("decode_slot_recycles")
         self._used[slot] = True
@@ -1084,22 +1134,70 @@ class DecodeEngine:
         return 1
 
     def _prefix_rows(self, i, seq):
-        """Slot ``i``'s prompt KV rows for the prefix store, sliced on
-        the device from the outputs of the launch that ended the prompt:
-        rows ``0..P-1`` then hold exactly the prompt's KV (the sampled
-        token is not yet written) and, by the masked-append invariant,
-        the same bytes whatever ingestion path produced them.  They go
-        into the store at the step's ``collect``, with its first token —
-        once the step is known to have run — whatever a later launch has
-        written to the slot by then.  None for a prompt the store does
-        not keep."""
+        """Slot ``i``'s state at the end of its prompt for the prefix
+        store, sliced on the device from the outputs of the launch that
+        ended the prompt: rows ``0..P-1`` of a ``kv`` slab then hold
+        exactly the prompt's KV (the sampled token is not yet written)
+        and, by the masked-append invariant, the same bytes whatever
+        ingestion path produced them; an ``index`` slab its first
+        ``ceil(P / stride)`` rows; a ``recurrent`` state the slot's rows
+        whole — the state after exactly ``P`` tokens.  They go into the
+        store at the step's ``collect``, with its first token — once the
+        step is known to have run — whatever a later launch has written to
+        the slot by then.  None for a prompt the store does not keep."""
         p = len(seq.req.prompt)
-        if p < self.prefix.min_tokens:
+        if p < self.prefix.min_tokens or not seq.req.keep_prefix:
             return None
         from ..ops.attention import kv_slab_to_rows
-        return {name: kv_slab_to_rows(
-            self.caches[name][i, :, :self._slab_rows(p), :],
-            self._head_dim)[:, :p, :] for name in self.cache_names}
+        out = {}
+        for name in self.cache_names:
+            state = self.caches[name]
+            if name in self._strides:
+                n = -(-p // self._strides[name])
+                out[name] = kv_slab_to_rows(
+                    state[i, :, :self._slab_rows(p, name), :],
+                    self._head_dim)[:, :n, :]
+            else:
+                out[name] = state[i]
+        return out
+
+    def _seat_snapshot(self, slot, m, rows):
+        """Write a stored snapshot of ``m`` positions into slot ``slot``:
+        slab rows ``0 ...`` of every ``kv`` and ``index`` state (whole
+        slab rows: the last one zero past the snapshot's rows, which no
+        key of this sequence has reached yet), a ``recurrent`` state's
+        rows whole.  One jitted, donated call for all of them, the slot a
+        traced scalar: in place, queued behind the step in flight, traced
+        once per snapshot length."""
+        if self._seat is None:
+            import jax
+            from ..ops.attention import kv_slab_from_rows
+            slabs = [name in self._strides for name in self.cache_names]
+
+            def seat(states, rows, slot):
+                out = []
+                for state, new, slab in zip(states, rows, slabs):
+                    if slab:
+                        new = kv_slab_from_rows(new, state.shape[-1])
+                    out.append(jax.lax.dynamic_update_slice(
+                        state, new[None].astype(state.dtype),
+                        (slot,) + (0,) * (state.ndim - 1)))
+                return tuple(out)
+
+            self._seat = jax.jit(seat, donate_argnums=(0,))
+        t0 = time.perf_counter()
+        nbytes = sum(int(rows[n].nbytes) for n in self.cache_names)
+        with _span("decode.join.seat", cat="decode", rows=int(m),
+                   bytes=nbytes):
+            new = self._seat(
+                tuple(self.caches[n] for n in self.cache_names),
+                tuple(rows[n] for n in self.cache_names), np.int32(slot))
+        self.caches.update(zip(self.cache_names, new))
+        record_decode("decode_prefix_seats")
+        record_decode("decode_prefix_seat_rows", int(m))
+        record_decode("decode_prefix_seat_bytes", nbytes)
+        record_decode("decode_prefix_seat_us",
+                      int((time.perf_counter() - t0) * 1e6))
 
     @contextlib.contextmanager
     def stepping(self):
@@ -1225,9 +1323,13 @@ class DecodeEngine:
                 fk["positions"]: self.positions.copy()}
         # the key rows the stepping sequences hold once this step has
         # appended: what its attention has to read, exactly
-        kv_rows = (read, held, int(
-            self.positions[rows].sum() + np.asarray(consume)[rows].sum())
-            if self._kv else 0)
+        after = self.positions[rows] + np.asarray(consume)[rows]
+        # and of an ``index`` slab, a row per ``stride`` positions (one
+        # slab's worth, as the KV rows)
+        stride = next((s for n, s in self._strides.items()
+                       if self._kinds[n] == "index"), 0)
+        kv_rows = (read, held, int(after.sum()) if self._kv else 0,
+                   int((after // stride).sum()) if stride else 0)
         # the caches are DONATED device arrays fed straight back from
         # the previous launch's fetches — no host round-trip
         # (_place_feed's np.asarray would force one, so the engine
@@ -1332,6 +1434,8 @@ class DecodeEngine:
         record_decode("decode_kv_rows_read", fl.kv_rows[0])
         record_decode("decode_kv_rows_held", fl.kv_rows[1])
         record_decode("decode_kv_rows_live", fl.kv_rows[2])
+        if fl.kv_rows[3]:
+            record_decode("decode_index_rows_live", fl.kv_rows[3])
         if fl.chunk > 1:
             record_decode("decode_prefill_steps")
             record_decode("decode_chunk_width", fl.chunk)
@@ -1579,7 +1683,7 @@ class DecodeRouter:
     # -- admission ---------------------------------------------------------
 
     def submit(self, prompt_ids, max_new_tokens=16, eos_id=None,
-               deadline_ms=None):
+               deadline_ms=None, keep_prefix=True):
         """Admit one prompt (1-D int token ids).  Returns a
         :class:`DecodeStream`.  Raises
         :class:`~hetu_tpu.serving.ServeRejected` when the queue is full
@@ -1590,7 +1694,13 @@ class DecodeRouter:
         A request still queued past it fails fast at seat time; a seated
         sequence that outlives it is EVICTED mid-generation — remaining
         futures fail with reason ``deadline`` and the KV slot frees for
-        the next join (``decode_deadline_evictions``)."""
+        the next join (``decode_deadline_evictions``).
+
+        ``keep_prefix``: whether the engine's prefix store (if it has one)
+        snapshots this prompt's state when its ingestion ends — the
+        client's mark of a prompt worth keeping, as the cache breakpoints
+        of public serving APIs.  ``False`` inserts nothing; the request
+        still hits what the store holds."""
         prompt = np.asarray(prompt_ids, np.int32).reshape(-1)
         if prompt.size < 1:
             raise ValueError("empty prompt")
@@ -1607,7 +1717,8 @@ class DecodeRouter:
             else time.monotonic() + float(deadline_ms) / 1e3
         fid = _TR.flow_begin("decode.request", cat="decode") \
             if _TR.on else None
-        req = _DecodeRequest(prompt, max_new, eos_id, fid, deadline)
+        req = _DecodeRequest(prompt, max_new, eos_id, fid, deadline,
+                             keep_prefix)
         with self._cv:
             if self._stop or self._killed:
                 record_decode("decode_rejections")

@@ -43,6 +43,8 @@ bytes high-water).
 """
 from __future__ import annotations
 
+import numpy as np
+
 from ..metrics import record_prefix_cache
 from ..obs.lock_witness import make_lock
 
@@ -52,17 +54,20 @@ class _Entry:
 
     def __init__(self, key, rows, nbytes, tick):
         self.key = key          # tuple of int token ids, the full prefix
-        self.rows = rows        # {cache_name: (heads, len(key), head_dim)}
+        self.rows = rows        # {state name: array}: KV rows (heads,
+                                # len(key), head_dim), and whatever else
+                                # the engine snapshots beside them
         self.nbytes = nbytes
         self.tick = tick
 
 
 class _Node:
-    __slots__ = ("kids", "owner")
+    __slots__ = ("kids", "owner", "ends")
 
     def __init__(self):
         self.kids = {}          # token id -> _Node
         self.owner = None       # key of ONE entry passing through here
+        self.ends = False       # an entry's key ends exactly here
 
 
 class PrefixKVStore:
@@ -100,13 +105,19 @@ class PrefixKVStore:
 
     # -- lookup ------------------------------------------------------------
 
-    def lookup(self, prompt):
+    def lookup(self, prompt, whole=False):
         """Longest usable stored prefix of ``prompt``: returns
         ``(m, rows)`` where ``rows[name]`` holds the first ``m`` KV rows
         (``(heads, m, head_dim)``), or ``(0, None)`` on a miss.  ``m``
         is capped at ``len(prompt) - 1`` — at least one prompt token
-        must still be fed to produce the first-token logits."""
-        toks = [int(t) for t in prompt]
+        must still be fed to produce the first-token logits.
+
+        ``whole=True``: only an entry whose WHOLE key is a prefix of
+        ``prompt`` hits, and its snapshot comes back as stored — for a
+        graph whose snapshots hold state that cannot be cut at a shared
+        partial depth (``index`` rows, ``recurrent`` state: whatever the
+        engine put in beside the KV rows)."""
+        toks = np.asarray(prompt).reshape(-1).tolist()
         limit = len(toks) - 1
         with self._lock:
             node, depth = self._root, 0
@@ -116,7 +127,11 @@ class PrefixKVStore:
                 if node is None:
                     break
                 depth += 1
-                if node.owner is not None and node.owner in self._entries:
+                if whole:
+                    key = tuple(toks[:depth]) if node.ends else None
+                    if key in self._entries:
+                        best_key, best_m = key, depth
+                elif node.owner is not None and node.owner in self._entries:
                     best_key, best_m = node.owner, depth
             if best_key is None:
                 record_prefix_cache("prefix_cache_misses")
@@ -142,7 +157,7 @@ class PrefixKVStore:
         Returns True when stored, False when skipped (too short, larger
         than the whole capacity, or an exact-key duplicate — duplicates
         just refresh the LRU tick)."""
-        key = tuple(int(t) for t in prompt)
+        key = tuple(np.asarray(prompt).reshape(-1).tolist())
         if len(key) < self.min_tokens:
             return False
         nbytes = sum(int(r.nbytes) for r in rows.values())
@@ -162,6 +177,7 @@ class PrefixKVStore:
             for t in key:
                 node = node.kids.setdefault(t, _Node())
                 node.owner = key
+            node.ends = True
             record_prefix_cache("prefix_cache_inserts")
             while self._bytes > self.capacity_bytes:
                 self._evict_locked()
@@ -182,11 +198,13 @@ class PrefixKVStore:
             if node is None:
                 break
             path.append(node)
+        if len(path) == len(victim.key) + 1:
+            path[-1].ends = False
         for depth in range(len(path) - 1, 0, -1):
             node = path[depth]
             if node.owner == victim.key:
                 node.owner = None
-            if not node.kids and node.owner is None:
+            if not node.kids and node.owner is None and not node.ends:
                 del path[depth - 1].kids[victim.key[depth - 1]]
 
     def clear(self):

@@ -495,10 +495,28 @@ def test_router_one_step_ahead_emits_the_serial_loops_streams(weights, chunk):
     assert ahead["decode_launches_ahead"] > 0
 
 
-def test_engine_refuses_a_prefix_store(weights):
+def test_engine_seats_latent_rows_from_a_prefix_store(weights):
+    """A prefix store beside the chosen expert ids (refused until PR 42):
+    this graph keeps KV state only, so a prompt that shares only its first
+    12 tokens with a stored one is seated with those 12 latent rows, is
+    served the cold engine's tokens, and its expert ids start at position
+    12."""
     from hetu_tpu.serving import PrefixKVStore
-    with pytest.raises(ValueError, match="positions it skipped"):
-        _engine(weights, 8, prefix_store=PrefixKVStore())
+    eng = _engine(weights, 8, prefix_store=PrefixKVStore())
+    first = _prompts(30, [20])[0]
+    _serve(eng, [first], 3)
+    other = np.concatenate([first[:12], _prompts(31, [9])[0]])
+    reqs = []
+    for e in (eng, _engine(weights, 8)):
+        req = _DecodeRequest(other.astype(np.int32), 8, None, None)
+        e.join(req)
+        while not e.idle:
+            e.step()
+        reqs.append(req.stream)
+    seated, cold = reqs
+    assert seated.result(0) == cold.result(0)
+    assert (seated.aux_from, cold.aux_from) == (12, 0)
+    assert np.array_equal(seated.aux(CHOICES), cold.aux(CHOICES)[12:])
 
 
 def test_counters_fold_the_choices_and_count_the_live_rows(weights):

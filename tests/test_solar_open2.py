@@ -85,7 +85,7 @@ def _engine(weights, max_chunk=8, slots=4, cfg=None, **kw):
                         max_chunk=max_chunk or None, **kw)
 
 
-def _serve(eng, prompts, new, ref_logits=None):
+def _serve(eng, prompts, new, ref_logits=None, seated=0):
     """Drive ``prompts`` through ``eng`` to the end; returns the token
     streams and the worst gap between a served row's logits and the
     reference's at that position.  The expert ids each stream was handed
@@ -110,8 +110,10 @@ def _serve(eng, prompts, new, ref_logits=None):
             worst = max(worst,
                         float(np.abs(got[slot[id(r)]] - want[-1]).max()))
             assert int(np.argmax(want[-1])) == toks[n - 1]
+            # a stream a prefix store seated holds the ids from there on
+            assert r.stream.aux_from == seated
             assert np.array_equal(np.sort(r.stream.aux("moe_choices"), -1),
-                                  np.sort(chosen, -1))
+                                  np.sort(chosen[seated:], -1))
     return [r.stream.result(0) for r in reqs], worst
 
 
@@ -334,23 +336,54 @@ def test_router_serves_it_through_the_front_door(weights, ref_logits):
     assert stream.aux("nothing") is None
 
 
-def test_engine_refuses_a_prefix_store(weights):
+def test_engine_seats_its_recurrent_state_from_a_prefix_store(
+        weights, ref_logits):
+    """A prefix store beside KDA state and convolution windows (refused
+    until PR 42): a prompt that extends a stored one WHOLE is seated with
+    its KV rows and its recurrent state, and is served what the reference
+    says at every position it consumed."""
     from hetu_tpu.serving import PrefixKVStore
-    with pytest.raises(ValueError, match="recurrent state"):
-        _engine(weights, 8, prefix_store=PrefixKVStore())
+    store = PrefixKVStore()
+    eng = _engine(weights, 8, prefix_store=store)
+    first = _prompts(30, [16])[0]
+    _serve(eng, [first], 3, ref_logits)
+    assert len(store) == 1
+    metrics.reset_decode_counts()
+    longer = np.concatenate([first, _prompts(31, [7])[0]])
+    _, worst = _serve(eng, [longer], 9, ref_logits, seated=16)
+    assert worst < TOL
+    c = metrics.decode_counts()
+    assert (c["decode_prefix_seats"], c["decode_prefix_seat_rows"]) == (1, 16)
+    assert c["decode_prefill_rows"] == len(longer) - 16 - 1
 
 
-def test_auxiliary_fetches_must_match_and_refuse_a_prefix_store():
-    """The engine's own rule, on a graph that keeps KV state only."""
+def test_auxiliary_fetches_must_match_and_start_where_a_store_seated():
+    """The engine's own rules, on a graph that keeps KV state only: both
+    entries fetch the same; beside a prefix store (refused until PR 42) a
+    seated stream's slices start at the first position it consumed, and
+    the stream says where."""
     from hetu_tpu.models import (GPT2Config, gpt2_decode_chunked_graph,
                                  gpt2_decode_graph)
     from hetu_tpu.serving import PrefixKVStore
     g = GPT2Config(vocab_size=50, n_positions=32, n_embd=16, n_layer=1,
                    n_head=2, batch_size=1, seq_len=32)
     f, lg, caches, _ = gpt2_decode_graph(g, max_len=32)
-    with pytest.raises(ValueError, match="positions it skipped"):
-        DecodeEngine(f, lg, caches, max_len=32, aux={"ids": f["input_ids"]},
-                     prefix_store=PrefixKVStore())
+    eng = DecodeEngine(f, lg, caches, max_len=32, aux={"ids": f["input_ids"]},
+                       prefix_store=PrefixKVStore())
+    first = np.arange(3, 13, dtype=np.int32)
+    longer = np.concatenate([first[:8], [40, 41, 42]]).astype(np.int32)
+    streams = []
+    for prompt in (first, longer):
+        req = _DecodeRequest(prompt, 4, None, None)
+        eng.join(req)
+        while not eng.idle:
+            eng.step()
+        streams.append(req.stream)
+    cold, seated = streams
+    assert (cold.aux_from, seated.aux_from) == (0, 8)   # a partial overlap
+    fed = np.concatenate([longer, seated.result(0)[:-1]])
+    assert seated.aux("ids").tolist() == fed[8:].tolist()
+    assert len(cold.aux("ids")) == len(first) + 3
     cf, cl, cc, _ = gpt2_decode_chunked_graph(g, max_len=32)
     with pytest.raises(ValueError, match="same auxiliary fetches"):
         DecodeEngine(f, lg, caches, max_len=32, aux={"ids": f["input_ids"]},

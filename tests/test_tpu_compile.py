@@ -695,6 +695,101 @@ def test_glm_share_programs_fit_and_read_the_latent_cache_in_place(
     assert "bf16[128,1,4096,640]{2," not in layout
 
 
+# ------------------------------------------- the MiniCPM-SALA cell's programs
+def test_selected_block_read_compiles(chip):
+    """The one-token kernel in its selected-block mode at the cell's call:
+    16 score rows over ONE key head, 64 slots x 2 key heads, a schedule of
+    128 block ids a (slot, head) walked in groups of 16 — both slabs left
+    where they lie (no copy in front of the call), and the name the trace
+    tells it by."""
+    from hetu_tpu import metrics
+    from hetu_tpu.ops.pallas.decode_attention import decode_attention_blocks
+    slab = (64, 2, 32768, 128)
+    before = metrics.decode_attn_call_counts().get("1x16x64", 0)
+    text = _compiles_with_kernel(
+        decode_attention_blocks, chip((64, 2, 16, 128), jnp.bfloat16),
+        chip(slab, jnp.bfloat16), chip(slab, jnp.bfloat16),
+        chip((64,), jnp.int32), chip((64, 2, 128), jnp.int32),
+        chip((64, 2), jnp.int32))
+    assert metrics.decode_attn_call_counts().get("1x16x64", 0) == before + 1
+    assert not _slab_copies(text, slab)
+    assert "sparse_fwd_q1" in text and "flash_fwd_q1" not in text
+    assert "f32[64,2,16,128]" in text
+
+
+@functools.lru_cache(maxsize=None)
+def _sala_engine():
+    """The engine of ``minicpm-sala.docqa-c64``: 8 layers of 4096 (sparse,
+    6 x Lightning, sparse), 64 slots x 32,768 positions."""
+    return _share_engine(
+        "minicpm-sala", "docqa-c64", "minicpm_sala_decode",
+        ("minicpm_sala_decode_graph", "minicpm_sala_decode_chunked_graph"))
+
+
+@pytest.mark.parametrize("chunk", [1, 32], ids=["one_token", "chunk32"])
+def test_sala_cut_programs_fit_and_update_every_state_in_place(
+        chip, monkeypatch, chunk):
+    """ISSUE 42: the one-token and the chunk-32 program at the cell's sizes
+    (64 slots x 32,768 positions, 8 layers), compiled for the described chip
+    as the engine jits them.  Weights (5.64 GB) and state (5.23 GB: K and V
+    slabs of two key heads, compressed-key slabs of a row per 16 positions,
+    pooling sums, six Lightning states) fit the chip with room for the
+    store's documents; the one-token program reads each sparse layer through
+    the selected-block kernel, the chunk-32 program slot by slot through
+    jnp; every slab row is appended by the aliased kernel; no program holds
+    a copy of a slab's or a Lightning state's size."""
+    import math
+    from hetu_tpu import metrics
+    eng, mix = _sala_engine()
+    iex, keys = (eng.iex, eng._fk) if chunk == 1 else (eng.ciex, eng._cfk)
+    b, length = mix["max_slots"], mix["max_len"]
+
+    def dims(name):
+        tail, dtype = eng._tails[name]
+        if name in eng._strides:
+            tail = (tail[0], eng._slab_rows(length, name), tail[2])
+        return (b,) + tuple(tail), dtype
+
+    kinds = [eng._kinds[n] for n in eng.cache_names]
+    assert [kinds.count(k) for k in ("kv", "index", "recurrent")] == [4, 2, 8]
+    slab, state = (64, 2, 32768, 128), (64, 32, 128, 128)
+    assert dims("k_cache_0") == (slab, jnp.dtype(jnp.bfloat16))
+    assert dims("index_7") == ((64, 2, 2048, 128), jnp.dtype(jnp.bfloat16))
+    assert dims("lightning_3") == (state, jnp.dtype(jnp.float32))
+    assert sum(math.prod(d) * t.itemsize
+               for d, t in map(dims, eng.cache_names)) == 5234753536
+    feeds = {"input_ids": ((b, chunk), jnp.int32),
+             "positions": ((b,), jnp.int32)}
+    if chunk > 1:
+        feeds["valid"] = ((b,), jnp.int32)
+    params = {k: chip(v.shape, v.dtype) for k, v in iex.params.items()}
+    assert sum(v.size for v in params.values()) == 2820545280
+    fed = ({keys[name]: chip(d, t) for name, (d, t) in feeds.items()},
+           tuple(chip(*dims(n)) for n in eng.cache_names))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    before = (metrics.decode_attn_call_counts().get("1x16x64", 0),
+              metrics.sparse_attn_call_counts().get("96x64:kernel", 0),
+              metrics.sparse_attn_call_counts().get("96x64:jnp", 0))
+    compiled = jax.jit(eng._program(iex, keys), donate_argnums=(1,)).lower(
+        params, fed, chip((b,), jnp.int32)).compile()
+    text, peak = compiled.as_text(), _peak(compiled)
+    assert 10.8e9 < peak < 12.0e9, peak                      # of 16 GB
+    # K, V and the compressed keys of both sparse layers, by the aliased
+    # kernel
+    assert _appends_in_place(text) == 6
+    kernel = chunk == 1
+    assert metrics.decode_attn_call_counts().get("1x16x64", 0) \
+        == before[0] + 2 * kernel
+    assert metrics.sparse_attn_call_counts().get("96x64:kernel", 0) \
+        == before[1] + 2 * kernel
+    assert metrics.sparse_attn_call_counts().get("96x64:jnp", 0) \
+        == before[2] + 2 * (not kernel)
+    assert ("sparse_fwd_q1" in text) == kernel
+    assert "flash_fwd_q1" not in text and "mla_fwd_q1" not in text
+    assert not _slab_copies(text, slab)
+    assert not re.findall(r"= f32\[64,32,128,128\]\S* copy\(", text)
+
+
 # ------------------------------------------------------------ moe dispatch
 # ------------------------------------------------------------ moe dispatch
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
