@@ -8,7 +8,7 @@ Every artifact a tool writes carries ``git_sha`` (HEAD when it was made),
 ``workload`` (the knobs that define it — canonical; no loose duplicates
 elsewhere in the artifact) and ``workload_hash`` (sha256[:12] of the
 canonical workload JSON).  Artifacts whose own schema already exposes the
-knobs top-level for programmatic consumers (flash_ab's resume check) embed
+knobs top-level for programmatic consumers (flash_ab's geometry) embed
 only the hash.  ``artifacts/README.md`` lists the committed artifacts,
 each with its writer and its reader.
 """

@@ -539,6 +539,9 @@ _ATTN_OPS = {
     "ScaledDotProductAttentionMasked": (1, 3, None),
     "ScaledDotProductAttentionBias": (1, None, 3),
     "ScaledDotProductAttentionMaskedBias": (1, 3, 4),
+    # packed (B, S, H·D) operands: lengths are dims -2 as well; its mask
+    # is a key-padding mask by the layer's rule (no per-head shape to check)
+    "ScaledDotProductAttentionPacked": (1, None, None),
     "RingAttention": (1, None, 3),
     "UlyssesAttention": (1, None, 3),
     "RingAttentionMasked": (1, 3, 4),
@@ -799,6 +802,7 @@ _DECODE_INCOMPATIBLE_SEQ = {
     "ScaledDotProductAttentionBias",
     "ScaledDotProductAttentionMaskedBias",
     "ScaledDotProductAttentionVarlen",
+    "ScaledDotProductAttentionPacked",
     "RingAttention",
     "RingAttentionMasked",
     "UlyssesAttention",

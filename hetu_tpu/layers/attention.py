@@ -16,7 +16,7 @@ from __future__ import annotations
 from .base import BaseLayer
 from .core import Linear, DropOut
 from .. import ops
-from ..ops.attention import sdpa_op
+from ..ops.attention import packed_layout_reason, sdpa_op, sdpa_packed_op
 
 
 class MultiHeadAttention(BaseLayer):
@@ -38,6 +38,30 @@ class MultiHeadAttention(BaseLayer):
     def _split(self, x, batch, seq):
         x = ops.array_reshape_op(x, output_shape=(batch, seq, self.h, self.dk))
         return ops.transpose_op(x, perm=(0, 2, 1, 3))
+
+    def _head_major_reason(self, mask, bias):
+        """Why a call builds the (B, H, S, D) graph — a transpose on each
+        side of its attention op — and None where it takes the PACKED one:
+        q, k, v stay (B, S, H·D) as the projections leave them and the
+        flash kernels read column blocks of heads out of that layout
+        (``ops.attention.sdpa_packed_op``), which spares BERT's step 15
+        whole-tensor copies a layer (PERF.md §6, PR 44).  Decided by what
+        the call can observe and nothing else: the head size and count
+        (:func:`~hetu_tpu.ops.attention.packed_layout_reason`), no
+        context-parallel schedule (ring and Ulysses slice head-major
+        chunks), no bias, and a mask only of the key-padding form
+        (B|1, 1, 1, S_kv) — a dense mask or bias is laid out per head."""
+        if self.context_parallel is not None:
+            return f"context_parallel:{self.context_parallel}"
+        if bias is not None:
+            return "bias"
+        reason = packed_layout_reason(self.h, self.dk)
+        if reason is None and mask is not None:
+            from ..analysis.shapes import infer_graph
+            shape = infer_graph([mask]).shape(mask)
+            if shape is None or len(shape) != 4 or shape[1:3] != (1, 1):
+                reason = f"mask_shape:{shape}"
+        return reason
 
     def __call__(self, x, batch, seq, kv=None, kv_seq=None, mask=None,
                  bias=None, scale=None):
@@ -68,6 +92,18 @@ class MultiHeadAttention(BaseLayer):
                                      sdpa_masked_bias_op)
         kv = x if kv is None else kv
         kv_seq = seq if kv_seq is None else kv_seq
+        reason = self._head_major_reason(mask, bias)
+        if reason is None:
+            def rows(t, n):
+                return ops.array_reshape_op(
+                    t, output_shape=(batch, n, self.hidden))
+            o = sdpa_packed_op(
+                rows(self.q(x), seq), rows(self.k(kv), kv_seq),
+                rows(self.v(kv), kv_seq), *(() if mask is None else (mask,)),
+                head_dim=self.dk, causal=self.causal, scale=scale)
+            return self._project_out(o, batch, seq)
+        from ..metrics import record_flash_head_major
+        record_flash_head_major(reason)
         q = self._split(self.q(x), batch, seq)
         k = self._split(self.k(kv), batch, kv_seq)
         v = self._split(self.v(kv), batch, kv_seq)
@@ -109,7 +145,11 @@ class MultiHeadAttention(BaseLayer):
             o = cp_attn(q, k, v, causal=self.causal, scale=scale)
         else:
             o = sdpa_op(q, k, v, causal=self.causal, scale=scale)
-        o = ops.transpose_op(o, perm=(0, 2, 1, 3))
+        return self._project_out(ops.transpose_op(o, perm=(0, 2, 1, 3)),
+                                 batch, seq)
+
+    def _project_out(self, o, batch, seq):
+        """(B, S, …) attention output → the (B·S, hidden) stream."""
         o = ops.array_reshape_op(o, output_shape=(batch * seq, self.hidden))
         o = self.o(o)
         if self.drop is not None:

@@ -93,21 +93,47 @@ def reset_flash_fallbacks():
 # them silent under an abstract shape trace.
 _flash_calls = REGISTRY.counter_family(
     "flash_calls",
-    "flash-attention calls by block shape and backward kind, "
-    "\"<block_q>x<block_k>:<one_pass|two_pass>\" (per jax trace)")
+    "flash-attention calls by block shape, backward kind and layout, "
+    "\"<block_q>x<block_k>:<one_pass|two_pass>[:packed]\" (per jax trace)")
 
 
-def record_flash_call(block_q, block_k, one_pass):
-    """Count one traced flash-attention call by the geometry it runs."""
+def record_flash_call(block_q, block_k, one_pass, packed=False):
+    """Count one traced flash-attention call by the geometry it runs;
+    ``packed``: operands (B, S, H·D) as the projections leave them, no
+    head-major copy of any (the key without the tag is a (B, H, S, D)
+    call)."""
     if counters_suppressed():
         return
     _flash_calls.inc(f"{block_q}x{block_k}:"
-                     f"{'one_pass' if one_pass else 'two_pass'}")
+                     f"{'one_pass' if one_pass else 'two_pass'}"
+                     f"{':packed' if packed else ''}")
 
 
 def flash_call_counts():
-    """{"<block_q>x<block_k>:<one_pass|two_pass>": count} snapshot."""
+    """{"<block_q>x<block_k>:<one_pass|two_pass>[:packed]": count}
+    snapshot."""
     return _flash_calls.counts()
+
+
+# Why an attention layer or op kept the head-major (B, H, S, D) layout —
+# and with it the transposes around the kernel — where the packed entry
+# would have spared them: counted like a fallback, per graph build (the
+# layer's rule) or per jax trace (the op's, under a mesh).
+_flash_head_major = REGISTRY.counter_family(
+    "flash_head_major",
+    "attention calls that kept the (B, H, S, D) layout, by reason")
+
+
+def record_flash_head_major(reason):
+    """Count one attention call that could not take the packed layout."""
+    if counters_suppressed():
+        return
+    _flash_head_major.inc(str(reason))
+
+
+def flash_head_major_counts():
+    """{reason: count} of calls that kept the head-major layout."""
+    return _flash_head_major.counts()
 
 
 # Which geometry the one-token attention over a KV slab compiled with
@@ -1256,6 +1282,7 @@ def run_gauges():
 _FAMILIES = {
     "flash_fallbacks": _flash,
     "flash_calls": _flash_calls,
+    "flash_head_major": _flash_head_major,
     "decode_attn_calls": _decode_attn_calls,
     "kv_append_calls": _kv_append_calls,
     "moe_calls": _moe_calls,

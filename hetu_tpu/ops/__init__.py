@@ -38,6 +38,7 @@ from .moe import (topk_gate_op, ktop1_gate_op, sam_gate_op,
                   moe_choices_op)
 from .attention import (sdpa_op, sdpa_masked_op, sdpa_bias_op,
                         sdpa_masked_bias_op, sdpa_varlen_op,
+                        sdpa_packed_op,
                         sdpa_decode_op, kv_cache_append_op,
                         kv_slab_placeholder, kv_slab_shape,
                         state_placeholder,

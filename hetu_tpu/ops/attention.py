@@ -7,6 +7,7 @@ flash-attention kernel (:mod:`hetu_tpu.ops.pallas.flash_attention`) on TPU,
 with a reference jnp lowering for CPU tests; ring/blockwise variants live in
 :mod:`hetu_tpu.parallel.ring_attention`.
 """
+import functools
 import os
 
 import jax
@@ -138,7 +139,18 @@ def dispatch_sdpa(q, k, v, causal=False, scale=None):
     return sdpa_reference(q, k, v, causal=causal, scale=scale)
 
 
-def _partitioned(c, fn, q, k, v, *extras):
+def _partition_mesh(c):
+    """The executor's mesh where an attention op has to partition itself
+    (:func:`_partitioned`): on TPU, over several devices, not already
+    inside a ``shard_map``; else None."""
+    mesh = getattr(c, "mesh", None)
+    if (mesh is None or mesh.size == 1 or jax.default_backend() != "tpu"
+            or jax.sharding.get_abstract_mesh().manual_axes):
+        return None
+    return mesh
+
+
+def _partitioned(c, fn, q, k, v, *extras, head_dim=None):
     """``fn(q, k, v, *extras)`` — a ``dispatch_*`` entry — under the
     executor's mesh.  XLA's SPMD partitioner refuses a Mosaic kernel it
     meets in a multi-device program ("cannot be automatically
@@ -150,13 +162,15 @@ def _partitioned(c, fn, q, k, v, *extras):
     lengths and positions follow their batch / head dims; broadcast (size
     1) dims stay replicated.  Inside an enclosing ``shard_map`` (ring /
     Ulysses steps, pipeline stages) the call is already manual and runs
-    as is."""
-    mesh = getattr(c, "mesh", None)
-    if (mesh is None or mesh.size == 1 or jax.default_backend() != "tpu"
-            or jax.sharding.get_abstract_mesh().manual_axes):
+    as is.  ``head_dim``: q, k, v and the result are PACKED (B, S, H·D)
+    with heads of that size, and ``tp`` shards their last axis."""
+    mesh = _partition_mesh(c)
+    if mesh is None:
         return fn(q, k, v, *extras)
     from jax.sharding import PartitionSpec as P
-    b, h = q.shape[:2]
+    packed = head_dim is not None
+    b = q.shape[0]
+    h = q.shape[2] // head_dim if packed else q.shape[1]
 
     def axis(name, n):
         ok = name in mesh.axis_names and mesh.shape[name] > 1 \
@@ -169,14 +183,17 @@ def _partitioned(c, fn, q, k, v, *extras):
         dims = [None] * x.ndim
         if x.shape[0] == b:
             dims[0] = dp
-        if x.ndim == 4 and x.shape[1] == h:
+        if packed and x.ndim == 3:
+            dims[2] = tp
+        elif x.ndim == 4 and x.shape[1] == h:
             dims[1] = tp
         return P(*dims)
 
     args = (q, k, v) + extras
     return jax.shard_map(fn, mesh=mesh,
                          in_specs=tuple(spec(x) for x in args),
-                         out_specs=P(dp, tp, None, None),
+                         out_specs=P(dp, None, tp) if packed
+                         else P(dp, tp, None, None),
                          check_vma=False)(*args)
 
 
@@ -194,8 +211,9 @@ def _split_mask_kinds(mask, q):
 
     (B|1, 1, 1, S_kv) masks are pure key-padding masks — O(S) memory as the
     kernel's ``key_mask`` column strips; anything else rides the blockwise
-    full-mask path.  Returns (key_mask, full_mask) with exactly one set."""
-    b, h, s_q, _ = q.shape
+    full-mask path.  Returns (key_mask, full_mask) with exactly one set.
+    ``q``: head-major or packed — only its batch dim is read."""
+    b = q.shape[0]
     if mask.ndim == 4 and mask.shape[1] == 1 and mask.shape[2] == 1:
         km = mask.reshape(mask.shape[0], mask.shape[-1])
         if km.shape[0] == 1:
@@ -327,6 +345,101 @@ def _sdpa_varlen(c, q, k, v, lengths, causal=False, scale=None):
 
 
 sdpa_varlen_op = def_op("ScaledDotProductAttentionVarlen", _sdpa_varlen)
+
+
+# ------------------------------------------------------------ packed layout
+# q, k, v as (B, S, H·D): what ``x @ W`` leaves after a free reshape, and
+# what the output projection wants back.  The training flash kernels read
+# and write that layout in lane-aligned column blocks of heads
+# (``flash_attention(..., heads=H)``), so a layer that can take it pays no
+# transpose and no relayout around its attention — at BERT's shape 15
+# whole-tensor copies a layer (PERF.md §6, PR 44).
+def packed_layout_reason(heads, head_dim):
+    """Why ``heads`` heads of ``head_dim`` cannot cross the flash kernels
+    packed (None = they can): the head size has to divide the 128 lanes or
+    be a multiple of them, and ``heads · head_dim`` to be whole column
+    blocks."""
+    from .pallas.flash_attention import packed_width
+    width = packed_width(head_dim)
+    if width is None:
+        return f"head_dim:{head_dim}"
+    if (heads * head_dim) % width:
+        return f"column_block:{heads}x{head_dim}%{width}"
+    return None
+
+
+def _split_heads(x, head_dim):
+    """(B, S, H·D) → (B, H, S, D)."""
+    b, s, lanes = x.shape
+    return x.reshape(b, s, lanes // head_dim, head_dim).transpose(0, 2, 1, 3)
+
+
+def _merge_heads(x):
+    """(B, H, S, D) → (B, S, H·D)."""
+    b, h, s, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+
+
+def _head_major(plain, masked, q, k, v, mask, head_dim, **kw):
+    """A (B, H, S, D) entry — ``plain(q, k, v)``, or ``masked(q, k, v,
+    mask)`` where there is one — around packed operands: the transposes
+    the packed kernel spares, for every call it cannot serve."""
+    q, k, v = (_split_heads(x, head_dim) for x in (q, k, v))
+    return _merge_heads(plain(q, k, v, **kw) if mask is None
+                        else masked(q, k, v, mask, **kw))
+
+
+def dispatch_sdpa_packed(q, k, v, mask=None, head_dim=None, causal=False,
+                         scale=None):
+    """Backend-dispatched attention over PACKED (B, S, H·D) operands →
+    (B, S_q, H·D).  Where the flash gate passes, the kernel's packed
+    entry, with a (B|1, 1, 1, S_kv) ``mask`` as its key-mask strips.
+    Everything else is the head-major dispatch between two transposes —
+    the CPU's ``sdpa_reference`` (same numbers as a graph that transposes
+    for itself), a call below the gate, a full per-query mask — each
+    counted as that dispatch counts it."""
+    km, full = (None, None) if mask is None else _split_mask_kinds(mask, q)
+    if full is not None:
+        from ..metrics import record_flash_head_major
+        record_flash_head_major(f"mask_shape:{tuple(mask.shape)}")
+    elif _use_flash(q, k) and _causal_bucketable(q, k, causal):
+        from .pallas.flash_attention import flash_attention
+        return flash_attention(q, k, v, causal=causal, scale=scale,
+                               key_mask=km, heads=q.shape[-1] // head_dim)
+    return _head_major(dispatch_sdpa, dispatch_sdpa_masked, q, k, v, mask,
+                       head_dim, causal=causal, scale=scale)
+
+
+def _sdpa_packed(c, q, k, v, mask=None, head_dim=None, causal=False,
+                 scale=None):
+    """Attention of packed (B, S, H·D) q / k / v (optional 4th graph
+    input: a (B|1, 1, 1, S_kv) key-padding mask) — what
+    ``MultiHeadAttention`` builds where its rule passes.  Under a mesh
+    the last axis shards over ``tp`` only where each shard keeps whole
+    column blocks of heads; where it would cut one, the heads shard
+    head-major as before, counted in ``flash_head_major``."""
+    mesh = _partition_mesh(c)
+    tp = mesh.shape["tp"] if mesh is not None \
+        and "tp" in mesh.axis_names else 1
+    heads = q.shape[-1] // head_dim
+    if tp > 1 and heads % tp == 0 \
+            and packed_layout_reason(heads // tp, head_dim) is not None:
+        from ..metrics import record_flash_head_major
+        record_flash_head_major(
+            f"tp_splits_column_block:{heads}x{head_dim}/{tp}")
+        return _head_major(
+            functools.partial(_sdpa, c), functools.partial(_sdpa_masked, c),
+            q, k, v, mask, head_dim, causal=causal, scale=scale)
+
+    def local(q, k, v, *mask):
+        return dispatch_sdpa_packed(q, k, v, *mask, head_dim=head_dim,
+                                    causal=causal, scale=scale)
+    return _partitioned(c, local, q, k, v,
+                        *(() if mask is None else (mask,)),
+                        head_dim=head_dim)
+
+
+sdpa_packed_op = def_op("ScaledDotProductAttentionPacked", _sdpa_packed)
 
 
 # ------------------------------------------------------------ KV slabs

@@ -8,14 +8,32 @@ TPU-native replacement: blockwise online-softmax attention that keeps scores
 in VMEM, with a custom VJP whose backward recomputes scores per block
 (flash-attention-2 style), so memory is O(S·D) instead of O(S²).
 
-Layout: inputs are (B, H, S, D); the kernel runs on (B·H, S, D) with a
-sequential TPU grid (bh, q_block, kv_block) — accumulators live in VMEM
+Layout: TWO entries, one set of kernels.  Head-major inputs (B, H, S, D)
+run as (B·H, S, D), one head a program.  PACKED inputs (B, S, H·D) — what a
+projection ``x @ W`` leaves after a free reshape, ``flash_attention(...,
+heads=H)`` — are read and written as they are: a BlockSpec selects a
+lane-aligned COLUMN BLOCK of heads out of the last axis
+(:func:`packed_width`: 128 lanes = two heads at d = 64, four at d = 32; the
+head itself where d is a multiple of 128), so no transposed or 64-lane-padded
+copy of q, k, v, the output or a gradient exists in HBM (at BERT's shape the
+head-major layout cost 15 whole-tensor ``copy`` operations a layer, 14.4 ms of
+a 107.75 ms step: PERF.md §6, PR 44).  A program takes its heads one after
+another: a head's scores contract a block of q in which every other head's
+lanes are zeroed against the whole block of k (exact: it adds zeros; on a
+128 × 128 MXU a contraction over 64 lanes already costs a full pass), and a
+product with the whole block of v / k / q / dO is right in that head's output
+lanes, which a lane select keeps — the same MXU passes as head-major, no lane
+shuffle.  The head-major entry IS the packed kernel with one head a row and
+the block the whole last axis; where the block shapes are equal the two
+agree to the last bit.  The grid is sequential, (program, q_block,
+kv_block), program = (batch row, column block) — accumulators live in VMEM
 scratch and persist across the minor-most kv grid steps; outputs are written
 once on the final kv step (standard TPU revisiting-grid pattern).
 
 Block shapes come from ONE rule, :func:`_pick_blocks`, that reads only what
-the call can observe (both lengths, the head size, the operand item size,
-``causal`` and how many dense (S_q, S_kv) extras ride along) against a
+the call can observe (both lengths, the width of a row block — the head
+size, or a packed call's column block — the operand item size, ``causal``
+and how many dense (S_q, S_kv) extras ride along) against a
 stated VMEM budget; explicit ``block_q=`` / ``block_k=`` still win.  No
 table, no environment variable, no model name chooses a tile (a grid step
 costs ≈0.3 µs on a v5e, as much as a 128 × 128 tile's work: PERF.md §6,
@@ -30,7 +48,8 @@ PR 28).  When one block holds the whole key range (``num_kv == 1``: BERT's
   kernels (``flash_bwd_dq`` + ``flash_bwd_dkv``, kept for key ranges that
   do not fit one block) spend 7; ``delta`` is computed in the kernel.
 
-Row statistics (``lse``, ``delta``) cross HBM lane-oriented, (B·H, 1, S): a
+Row statistics (``lse``, ``delta``) cross HBM lane-oriented, (B·H, 1, S) in
+both layouts (a packed program writes its heads' rows side by side): a
 (B·H, S, 1) f32 array is stored padded to 128 lanes (100 MB a layer where
 0.8 MB is data at BERT's shape: compile rehearsal, PR 28); the kernels turn
 a column of statistics into a row and back in VMEM (:func:`_col_to_row`).
@@ -96,18 +115,42 @@ _VMEM_BUDGET = 8 * 2 ** 20
 #: f32 (block_q, block_k) values a backward program holds at once: s, p,
 #: dp, t, ds and the bf16 copies of p and ds that feed the MXU
 _SCORE_TEMPS = 6
-def _block_bytes(block_q, block_k, d, itemsize, dense_tiles):
+#: the Mosaic lane width: a packed call's column block of heads
+_LANES = 128
+
+
+def packed_width(d):
+    """Lanes of one column block of a packed (B, S, H·D) operand: 128
+    where the head size divides them (``128 // d`` heads a block), the head
+    itself where it is a multiple of them; ``None`` for any other head
+    size (a block would cut a head in two or leave the lane tiling)."""
+    if _LANES % d == 0:
+        return _LANES
+    return d if d % _LANES == 0 else None
+
+
+def _block_bytes(block_q, block_k, width, itemsize, dense_tiles, num_q=2,
+                 num_kv=2):
     """VMEM one program takes at these blocks, counted from shapes: the
-    score-shaped f32 temporaries, each dense extra (bias, full mask, the
-    ``dbias`` output: 4-byte (block_q, block_k) tiles, double-buffered),
-    the row and key blocks (q, o, do, dq over block_q; k, v, dk, dv over
-    block_k; double-buffered) and the f32 accumulators."""
+    score-shaped f32 temporaries (one head's at a time), each dense extra
+    (bias, full mask, the ``dbias`` output: 4-byte (block_q, block_k)
+    tiles, double-buffered), the row and key blocks of ``width`` lanes —
+    the head size, or ``heads_per_block · d`` of a packed call — (q, o,
+    do, dq over block_q; k, v, dk, dv over block_k; double-buffered) and
+    the f32 accumulators of the sums that cross grid steps: dq and the
+    forward's output over ``num_kv`` key blocks, dk and dv over ``num_q``
+    query blocks (the dkv kernel keeps its two whenever there are key
+    blocks).  A program that holds both ranges whole sums across nothing:
+    BERT's 512 × 512 in the packed layout counts 8 MiB to the byte, and
+    takes 1.317 ms a layer forward + backward where 256 × 512 takes 1.481
+    (chip run, PR 44, PERF.md §6)."""
     tiles = block_q * block_k * 4 * (_SCORE_TEMPS + 2 * dense_tiles)
-    rows = 2 * itemsize * d * 4 * (block_q + block_k)
-    return tiles + rows + 4 * d * (block_q + 2 * block_k)
+    rows = 2 * itemsize * width * 4 * (block_q + block_k)
+    sums = block_q * (num_kv > 1) + 2 * block_k * (num_q > 1 or num_kv > 1)
+    return tiles + rows + 4 * width * sums
 
 
-def _pick_blocks(s_q, s_kv, d, itemsize, causal=False, dense_tiles=0):
+def _pick_blocks(s_q, s_kv, width, itemsize, causal=False, dense_tiles=0):
     """(block_q, block_k) from what the call can observe — the ONE place
     a tile is chosen (callers' explicit blocks are honoured before this).
 
@@ -127,8 +170,8 @@ def _pick_blocks(s_q, s_kv, d, itemsize, causal=False, dense_tiles=0):
     fits = [(bq, bk)
             for bq in range(128, s_q + 1, 128) if s_q % bq == 0
             for bk in range(128, s_kv + 1, 128) if s_kv % bk == 0
-            and _block_bytes(bq, bk, d, itemsize, dense_tiles)
-            <= _VMEM_BUDGET]
+            and _block_bytes(bq, bk, width, itemsize, dense_tiles,
+                             s_q // bq, s_kv // bk) <= _VMEM_BUDGET]
     whole = [c for c in fits if c[1] == s_kv]
     if whole:
         return max(whole)
@@ -151,6 +194,42 @@ def _row_to_col(x):
     return jnp.broadcast_to(x, (128, x.shape[1])).T[:, :1]
 
 
+# ------------------------------------------------- the heads of one block
+def _only(x, j, d):
+    """A (rows, width) block with every lane outside head ``j``'s
+    ``[j·d, (j+1)·d)`` zeroed, so a contraction over the whole block is
+    head ``j``'s alone (it adds exact zeros).  ``x`` itself when the block
+    is one head."""
+    if x.shape[-1] == d:
+        return x
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    return jnp.where(jnp.logical_and(lane >= j * d, lane < (j + 1) * d), x,
+                     jnp.zeros_like(x))
+
+
+def _merge(parts, d, shape):
+    """One ``shape`` = (rows, width) block from a value per head of it:
+    head ``j``'s lanes from ``parts[j]``, each a full-width product that
+    is right in those lanes, or a (rows, 1) column of head ``j``'s
+    statistics.  ``parts[0]`` itself when the block is one head."""
+    out = parts[0]
+    for j, part in enumerate(parts[1:], 1):
+        lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        out = jnp.where(lane >= j * d, part, out)
+    return out
+
+
+def _operand_spec(rows, width, cols, at):
+    """BlockSpec of a (1, rows, width) block of a (·, S, cols · width)
+    operand.  ``at(*grid)`` → (program, row block); program ``n`` reads
+    column block ``n % cols`` of batch row ``n // cols`` (head-major: one
+    column block, the whole last axis)."""
+    def index(*g):
+        n, r = at(*g)
+        return (n, r, 0) if cols == 1 else (n // cols, r, n % cols)
+    return pl.BlockSpec((1, rows, width), index)
+
+
 # ------------------------------------------------------------- index maps
 def _g_index(gmode, heads):
     """Map the flattened (b·h) grid index to a broadcast-group row for a
@@ -169,8 +248,10 @@ def _extra_specs(order, heads, gmode_mask, gmode_bias, gmode_kbias, block_q,
                  block_k, *, has_lengths, has_kmask, has_kbias, has_fmask,
                  has_bias):
     """BlockSpecs for the optional inputs, in kernel-argument order.
-    ``order`` maps grid indices to (bh, qi, ki) — the dkv kernel iterates
-    (bh, ki, qi)."""
+    ``order`` maps grid indices to (program, qi, ki) — the dkv kernel
+    iterates (program, ki, qi).  ``heads``: programs per batch row — the
+    heads, which is what a program is wherever a dense extra rides along
+    (one head a block), or a packed call's column blocks."""
     specs = []
     if has_lengths:
         # stored (bh, 1, 1): block (1, 1, 1) keeps the last two dims equal
@@ -278,12 +359,14 @@ def _unpack(refs, *, has_lengths, has_kmask, has_kbias, has_fmask, has_bias):
 
 
 # ---------------------------------------------------------------- forward
-def _fwd_kernel(*refs, scale, flags, geom, num_kv):
+def _fwd_kernel(*refs, scale, flags, geom, num_kv, d):
     (q_ref, k_ref, v_ref), extras, rest = _unpack(refs, **flags)
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     logits = functools.partial(_block_logits, qi, ki, scale=scale, **extras,
                                **geom)
+    shape = q_ref.shape[1:]                            # (bq, width)
+    heads = range(shape[1] // d)                       # of this block
 
     if num_kv == 1:
         # the block IS the key range: a straight softmax.  Same numbers
@@ -291,19 +374,24 @@ def _fwd_kernel(*refs, scale, flags, geom, num_kv):
         # a zero accumulator); a row with no valid key still reads zero
         # (p * valid), so a dead block needs no pruning predicate
         o_ref, lse_ref = rest
+        q = q_ref[0]
+        k = k_ref[0]
         v = v_ref[0]
-        s, valid = logits(q_ref[0], k_ref[0])
-        m = jnp.max(s, axis=-1, keepdims=True)
-        p = jnp.exp(s - m)
-        if valid is not None:
-            p = p * valid
-        l = jnp.sum(p, axis=-1, keepdims=True)
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        acc = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
-        lse_ref[0] = _col_to_row(m + jnp.log(l_safe))
+        outs = []
+        for j in heads:
+            s, valid = logits(_only(q, j, d), k)
+            m = jnp.max(s, axis=-1, keepdims=True)
+            p = jnp.exp(s - m)
+            if valid is not None:
+                p = p * valid
+            l = jnp.sum(p, axis=-1, keepdims=True)
+            l_safe = jnp.where(l == 0.0, 1.0, l)
+            acc = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            outs.append(acc / l_safe)
+            lse_ref[j] = _col_to_row(m + jnp.log(l_safe))
+        o_ref[0] = _merge(outs, d, shape).astype(o_ref.dtype)
         return
 
     o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
@@ -318,29 +406,38 @@ def _fwd_kernel(*refs, scale, flags, geom, num_kv):
 
     @pl.when(live)
     def _block():
-        v = v_ref[0]                                   # (bk, d)
-        s, valid = logits(q_ref[0], k_ref[0])          # (bq, bk)
-        m_prev = m_scr[:, :1]                          # (bq, 1)
-        l_prev = l_scr[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                          # (bq, bk)
-        if valid is not None:
-            p = p * valid                               # no all-masked leak
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        q = q_ref[0]
+        k = k_ref[0]
+        v = v_ref[0]                                   # (bk, width)
+        alphas, pvs = [], []
+        for j in heads:
+            s, valid = logits(_only(q, j, d), k)       # (bq, bk)
+            m_prev = m_scr[j, :, :1]                   # (bq, 1)
+            l_prev = l_scr[j, :, :1]
+            m_cur = jnp.max(s, axis=-1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)                      # (bq, bk)
+            if valid is not None:
+                p = p * valid                           # no all-masked leak
+            l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+            alphas.append(alpha)
+            pvs.append(jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+            m_scr[j] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            l_scr[j] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+        acc_scr[:] = acc_scr[:] * _merge(alphas, d, shape) \
+            + _merge(pvs, d, shape)
 
     @pl.when(ki == num_kv - 1)
     def _finish():
-        l = l_scr[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
-        lse_ref[0] = _col_to_row(m_scr[:, :1] + jnp.log(l_safe))
+        sums = []
+        for j in heads:
+            l = l_scr[j, :, :1]
+            sums.append(jnp.where(l == 0.0, 1.0, l))
+            lse_ref[j] = _col_to_row(m_scr[j, :, :1] + jnp.log(sums[j]))
+        o_ref[0] = (acc_scr[:] / _merge(sums, d, shape)).astype(o_ref.dtype)
 
 
 def _flags(lengths, kmask, kbias, fmask, bias):
@@ -349,50 +446,69 @@ def _flags(lengths, kmask, kbias, fmask, bias):
                 has_bias=bias is not None)
 
 
+def _geometry(q, heads, d):
+    """→ ``(d, width, cols, groups, bh)`` of operands (rows, S, lanes):
+    the head size (the whole last axis when ``d`` is None: head-major,
+    rows = B·H), the lanes of a program's column block, column blocks a
+    row, programs per batch row and rows of statistics (one per head)."""
+    lanes = q.shape[2]
+    d = d or lanes
+    width = lanes if lanes == d else packed_width(d)
+    return (d, width, lanes // width, heads // (width // d),
+            q.shape[0] * (lanes // d))
+
+
+# The two entries below are jitted so that the calls of one program that
+# share a shape and a specialization — every layer of a model — are traced
+# and lowered to ONE kernel each: a two-head body traced 24 times put 1.4 s
+# on the bert cell's set-up (PERF.md §6, PR 44; ``kv_append._call``, PR 37)
+@functools.partial(jax.jit, static_argnums=tuple(range(8, 19)))
 def _flash_fwd(q, k, v, lengths, kmask, kbias, fmask, bias, scale, causal,
                gmode_mask, gmode_bias, gmode_kbias, heads, block_q, block_k,
-               interpret, name="flash_fwd"):
-    """→ ``(out (bh, s_q, d), lse (bh, s_q, 1) f32)``.  The kernel writes
-    ``lse`` lane-oriented, (bh, 1, s_q); the reshape to the column form
-    callers combine with (``parallel/ring_flash.py``) moves no data."""
+               interpret, name="flash_fwd", d=None):
+    """→ ``(out, lse (bh, s_q, 1) f32)``, ``out`` shaped like ``q``:
+    (B·H, s_q, d) head-major, or with ``d`` = the head size a packed
+    (B, s_q, H·d).  The kernel writes ``lse`` lane-oriented, (bh, 1, s_q);
+    the reshape to the column form callers combine with
+    (``parallel/ring_flash.py``) moves no data."""
     # ``name=`` on each pallas_call: the device trace calls the kernel's
     # instruction after its place in jax's name stack, so without one the
     # forward reads ``jvp__`` or ``infer`` after whatever traced it and
     # the backward kernels share ``transpose_jvp___`` (ISSUE 25)
-    bh, s_q, d = q.shape
-    s_kv = k.shape[1]
+    d, width, cols, groups, bh = _geometry(q, heads, d)
+    s_q, s_kv = q.shape[1], k.shape[1]
     num_q = s_q // block_q
     num_kv = s_kv // block_k
-    grid = (bh, num_q, num_kv)
+    grid = (q.shape[0] * cols, num_q, num_kv)
     flags = _flags(lengths, kmask, kbias, fmask, bias)
     inputs = [q, k, v] + [x for x in (lengths, kmask, kbias, fmask, bias)
                           if x is not None]
+    qspec = _operand_spec(block_q, width, cols, lambda n, i, j: (n, i))
+    kspec = _operand_spec(block_k, width, cols, lambda n, i, j: (n, j))
 
     out, lse = pl.pallas_call(
         functools.partial(
-            _fwd_kernel, scale=scale, flags=flags, num_kv=num_kv,
+            _fwd_kernel, scale=scale, flags=flags, num_kv=num_kv, d=d,
             geom=dict(causal=causal, block_q=block_q, block_k=block_k,
                       kv_off=s_kv - s_q)),
         name=name,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-        ] + _extra_specs(lambda b, i, j: (b, i, j), heads, gmode_mask,
-                         gmode_bias, gmode_kbias, block_q, block_k, **flags),
+        in_specs=[qspec, kspec, kspec]
+        + _extra_specs(lambda n, i, j: (n, i, j), groups, gmode_mask,
+                       gmode_bias, gmode_kbias, block_q, block_k, **flags),
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
+            qspec,
+            pl.BlockSpec((width // d, 1, block_q), lambda n, i, j: (n, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s_q, d), q.dtype),
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct((bh, 1, s_q), jnp.float32),
         ],
         scratch_shapes=[] if num_kv == 1 else [
-            pltpu.VMEM((block_q, 128), jnp.float32),   # running max
-            pltpu.VMEM((block_q, 128), jnp.float32),   # running sum
-            pltpu.VMEM((block_q, d), jnp.float32),     # output accumulator
+            # running max and sum of each head, the output accumulator
+            pltpu.VMEM((width // d, block_q, 128), jnp.float32),
+            pltpu.VMEM((width // d, block_q, 128), jnp.float32),
+            pltpu.VMEM((block_q, width), jnp.float32),
         ],
         interpret=interpret,
     )(*inputs)
@@ -414,44 +530,50 @@ def _grad_logits(logits, q, k, v, do, lse, delta):
     return p, p * (dp - delta)
 
 
-def _bwd_kernel(*refs, scale, flags, geom, emit_dbias, emit_dkbias, num_q):
-    """One pass over a (b, h) whose whole key range is one block: grid
-    (bh, num_q, 1), K/V (and the dk/dv output blocks) keep their index
-    over ``qi`` — fetched once, written once — and every score-shaped
-    value is computed once for dq, dk and dv."""
+def _bwd_kernel(*refs, scale, flags, geom, emit_dbias, emit_dkbias, num_q, d):
+    """One pass over a program's heads whose whole key range is one block:
+    grid (program, num_q, 1), K/V (and the dk/dv output blocks) keep their
+    index over ``qi`` — fetched once, written once — and every
+    score-shaped value is computed once for dq, dk and dv."""
     (q_ref, k_ref, v_ref), extras, rest = _unpack(refs, **flags)
     o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref = rest[:6]
     rest = rest[6:]
     dbias_ref = rest.pop(0) if emit_dbias else None
     dkb_ref = rest.pop(0) if emit_dkbias else None
     qi = pl.program_id(1)
-    q = q_ref[0]                                        # (bq, d)
-    k = k_ref[0]                                        # (bk, d)
+    q = q_ref[0]                                        # (bq, width)
+    k = k_ref[0]                                        # (bk, width)
+    v = v_ref[0]
     do = do_ref[0]
-    # delta_i = rowsum(dO ⊙ O), in the kernel: it never crosses HBM
-    delta = jnp.sum(do.astype(jnp.float32) * o_ref[0].astype(jnp.float32),
-                    axis=-1, keepdims=True)             # (bq, 1)
-    p, t = _grad_logits(
-        functools.partial(_block_logits, qi, 0, scale=scale, **extras,
-                          **geom),
-        q, k, v_ref[0], do, _row_to_col(lse_ref[0]), delta)
-    if emit_dbias:
-        dbias_ref[0] = t.astype(dbias_ref.dtype)
-    ds = (t * scale).astype(k.dtype)                    # (bq, bk)
-    dq_ref[0] = jax.lax.dot_general(
-        ds, k, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(dq_ref.dtype)
-    sums = [
-        # dV = Pᵀ·dO, dK = dSᵀ·Q
-        (dv_ref, jax.lax.dot_general(
+    o = o_ref[0].astype(jnp.float32)
+    logits = functools.partial(_block_logits, qi, 0, scale=scale, **extras,
+                               **geom)
+    dqs, dvs, dks = [], [], []
+    for j in range(q.shape[1] // d):
+        do_j = _only(do, j, d)
+        # delta_i = rowsum(dO ⊙ O), in the kernel: it never crosses HBM
+        delta = jnp.sum(do_j.astype(jnp.float32) * o,
+                        axis=-1, keepdims=True)         # (bq, 1)
+        p, t = _grad_logits(logits, _only(q, j, d), k, v, do_j,
+                            _row_to_col(lse_ref[j]), delta)
+        if emit_dbias:
+            dbias_ref[0] = t.astype(dbias_ref.dtype)
+        ds = (t * scale).astype(k.dtype)                # (bq, bk)
+        # each right in head j's lanes: dQ = dS·K, dV = Pᵀ·dO, dK = dSᵀ·Q
+        dqs.append(jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32))
+        dvs.append(jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)),
-        (dk_ref, jax.lax.dot_general(
+            preferred_element_type=jnp.float32))
+        dks.append(jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)),
-    ]
+            preferred_element_type=jnp.float32))
+    dq_ref[0] = _merge(dqs, d, q.shape).astype(dq_ref.dtype)
+    sums = [(dv_ref, _merge(dvs, d, k.shape)),
+            (dk_ref, _merge(dks, d, k.shape))]
     if emit_dkbias:
-        # d(key-bias)[k] = column sums of t
+        # d(key-bias)[k] = column sums of t (one head a block)
         sums.append((dkb_ref, jnp.sum(t, axis=0, keepdims=True)))
     if num_q == 1:
         for ref, val in sums:
@@ -470,38 +592,52 @@ def _bwd_kernel(*refs, scale, flags, geom, emit_dbias, emit_dkbias, num_q):
             ref[0] = scr[:].astype(ref.dtype)
 
 
-def _dq_kernel(*refs, scale, flags, geom, emit_dbias, num_kv):
+def _dq_kernel(*refs, scale, flags, geom, emit_dbias, num_kv, d):
+    """dq of one query block over the key blocks — and ``delta``, each
+    head's rowsum(dO ⊙ O), computed at the first key block into an output
+    the later steps (and the dkv kernel) read: it crosses HBM once, as
+    (heads, 1, rows) statistics, and no XLA reduction over a packed
+    operand's 64-lane heads stands beside the kernels."""
     (q_ref, k_ref, v_ref), extras, rest = _unpack(refs, **flags)
-    do_ref, lse_ref, delta_ref = rest[:3]
-    rest = rest[3:]
-    if emit_dbias:
-        dq_ref, dbias_ref, dq_scr = rest
-    else:
-        dq_ref, dq_scr = rest
-        dbias_ref = None
+    o_ref, do_ref, lse_ref, dq_ref, delta_ref = rest[:5]
+    dbias_ref = rest[5] if emit_dbias else None
+    dq_scr = rest[-1]
     qi = pl.program_id(1)
     ki = pl.program_id(2)
+    heads = range(q_ref.shape[2] // d)
 
     @pl.when(ki == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
+        do = do_ref[0]
+        o = o_ref[0].astype(jnp.float32)
+        for j in heads:
+            delta_ref[j] = _col_to_row(jnp.sum(
+                _only(do, j, d).astype(jnp.float32) * o, axis=-1,
+                keepdims=True))
 
     live = _live(qi, ki, extras["len_ref"], **geom)
     live_static = live is True
 
     def _body(write_dbias):
+        q = q_ref[0]
         k = k_ref[0]
-        _, t = _grad_logits(
-            functools.partial(_block_logits, qi, ki, scale=scale, **extras,
-                              **geom),
-            q_ref[0], k, v_ref[0], do_ref[0], _row_to_col(lse_ref[0]),
-            _row_to_col(delta_ref[0]))
-        if write_dbias:
-            dbias_ref[0] = t.astype(dbias_ref.dtype)
-        ds = t * scale
-        dq_scr[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        v = v_ref[0]
+        do = do_ref[0]
+        logits = functools.partial(_block_logits, qi, ki, scale=scale,
+                                   **extras, **geom)
+        dqs = []
+        for j in heads:
+            _, t = _grad_logits(
+                logits, _only(q, j, d), k, v, _only(do, j, d),
+                _row_to_col(lse_ref[j]), _row_to_col(delta_ref[j]))
+            if write_dbias:
+                dbias_ref[0] = t.astype(dbias_ref.dtype)
+            ds = t * scale
+            dqs.append(jax.lax.dot_general(
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+        dq_scr[:] += _merge(dqs, d, q.shape)
 
     if live_static:
         _body(emit_dbias)
@@ -520,7 +656,7 @@ def _dq_kernel(*refs, scale, flags, geom, emit_dbias, num_kv):
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(*refs, scale, flags, geom, emit_dkbias, num_q):
+def _dkv_kernel(*refs, scale, flags, geom, emit_dkbias, num_q, d):
     (q_ref, k_ref, v_ref), extras, rest = _unpack(refs, **flags)
     if emit_dkbias:
         (do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dkb_ref,
@@ -542,28 +678,34 @@ def _dkv_kernel(*refs, scale, flags, geom, emit_dkbias, num_q):
 
     @pl.when(live)
     def _block():
-        q = q_ref[0]                                    # (bq, d)
+        q = q_ref[0]                                    # (bq, width)
+        k = k_ref[0]
+        v = v_ref[0]
         do = do_ref[0]
-        p, t = _grad_logits(
-            functools.partial(_block_logits, qi, ki, scale=scale, **extras,
-                              **geom),
-            q, k_ref[0], v_ref[0], do, _row_to_col(lse_ref[0]),
-            _row_to_col(delta_ref[0]))
-        # dV += P^T @ dO
-        dv_scr[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if emit_dkbias:
-            # d(key-bias)[k] = sum over query rows of t — accumulated
-            # across this ki column's q blocks (broadcast over the scratch
-            # sublanes; row 0 is written out)
-            dkb_scr[:] += jnp.broadcast_to(
-                jnp.sum(t, axis=0, keepdims=True), dkb_scr.shape)
-        ds = t * scale                                   # (bq, bk)
-        # dK += dS^T @ Q
-        dk_scr[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        logits = functools.partial(_block_logits, qi, ki, scale=scale,
+                                   **extras, **geom)
+        dvs, dks = [], []
+        for j in range(q.shape[1] // d):
+            p, t = _grad_logits(
+                logits, _only(q, j, d), k, v, _only(do, j, d),
+                _row_to_col(lse_ref[j]), _row_to_col(delta_ref[j]))
+            # dV += P^T @ dO
+            dvs.append(jax.lax.dot_general(
+                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+            if emit_dkbias:
+                # d(key-bias)[k] = sum over query rows of t — accumulated
+                # across this ki column's q blocks (broadcast over the
+                # scratch sublanes; row 0 is written out)
+                dkb_scr[:] += jnp.broadcast_to(
+                    jnp.sum(t, axis=0, keepdims=True), dkb_scr.shape)
+            ds = t * scale                               # (bq, bk)
+            # dK += dS^T @ Q
+            dks.append(jax.lax.dot_general(
+                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+        dv_scr[:] += _merge(dvs, d, k.shape)
+        dk_scr[:] += _merge(dks, d, k.shape)
 
     @pl.when(qi == num_q - 1)
     def _finish():
@@ -573,17 +715,20 @@ def _dkv_kernel(*refs, scale, flags, geom, emit_dkbias, num_q):
             dkb_ref[0] = dkb_scr[:1]
 
 
+@functools.partial(jax.jit, static_argnums=tuple(range(11, 21)))
 def _flash_bwd(q, k, v, lengths, kmask, kbias, fmask, bias, out, lse, do,
                scale, causal, gmode_mask, gmode_bias, gmode_kbias, heads,
-               block_q, block_k, interpret):
+               block_q, block_k, interpret, d=None):
     """→ ``(dq, dk, dv, dbias, dkbias)`` from the forward's ``out`` and an
     ``lse`` (bh, s_q, 1) that need not be this call's own (the ring hands
-    in its global one).  One pass when ``block_k`` is the whole key range,
-    else the dq and dkv kernels."""
-    bh, s_q, d = q.shape
-    s_kv = k.shape[1]
+    in its global one); operands and gradients in ``q``'s layout
+    (:func:`_flash_fwd`).  One pass when ``block_k`` is the whole key
+    range, else the dq and dkv kernels."""
+    d, width, cols, groups, bh = _geometry(q, heads, d)
+    s_q, s_kv = q.shape[1], k.shape[1]
     num_q = s_q // block_q
     num_kv = s_kv // block_k
+    programs = q.shape[0] * cols
     flags = _flags(lengths, kmask, kbias, fmask, bias)
     geom = dict(causal=causal, block_q=block_q, block_k=block_k,
                 kv_off=s_kv - s_q)
@@ -593,20 +738,21 @@ def _flash_bwd(q, k, v, lengths, kmask, kbias, fmask, bias, out, lse, do,
               if x is not None]
     lse = lse.reshape(bh, 1, s_q)             # lane-oriented, no data moved
 
-    qspec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    kspec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
-    rowspec = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i))
+    qspec = _operand_spec(block_q, width, cols, lambda n, i, j: (n, i))
+    kspec = _operand_spec(block_k, width, cols, lambda n, i, j: (n, j))
+    rowspec = pl.BlockSpec((width // d, 1, block_q),
+                           lambda n, i, j: (n, 0, i))
     extra_specs = _extra_specs(
-        lambda b, i, j: (b, i, j), heads, gmode_mask, gmode_bias,
+        lambda n, i, j: (n, i, j), groups, gmode_mask, gmode_bias,
         gmode_kbias, block_q, block_k, **flags)
     # dbias is dense — O(B·H·S²) like the score matrix; unavoidable, the
     # bias gradient has that shape before broadcast-reduction
     dbias_spec = pl.BlockSpec((1, block_q, block_k),
-                              lambda b, i, j: (b, i, j))
+                              lambda n, i, j: (n, i, j))
     dbias_shape = jax.ShapeDtypeStruct((bh, s_q, s_kv), jnp.float32)
-    dq_shape = jax.ShapeDtypeStruct((bh, s_q, d), q.dtype)
-    dkv_shapes = [jax.ShapeDtypeStruct((bh, s_kv, d), k.dtype),
-                  jax.ShapeDtypeStruct((bh, s_kv, d), v.dtype)]
+    dq_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
+    dkv_shapes = [jax.ShapeDtypeStruct(k.shape, k.dtype),
+                  jax.ShapeDtypeStruct(v.shape, v.dtype)]
     # d(key-bias): O(S) per bh, a column strip reduced over the broadcast
     # group by the VJP wrapper
     dkb_shape = jax.ShapeDtypeStruct((bh, 1, s_kv), jnp.float32)
@@ -614,20 +760,20 @@ def _flash_bwd(q, k, v, lengths, kmask, kbias, fmask, bias, out, lse, do,
     if num_kv == 1:
         outs = [(qspec, dq_shape), (kspec, dkv_shapes[0]),
                 (kspec, dkv_shapes[1])]
-        scratch = [pltpu.VMEM((s_kv, d), jnp.float32),
-                   pltpu.VMEM((s_kv, d), jnp.float32)]
+        scratch = [pltpu.VMEM((s_kv, width), jnp.float32),
+                   pltpu.VMEM((s_kv, width), jnp.float32)]
         if emit_dbias:
             outs.append((dbias_spec, dbias_shape))
         if emit_dkbias:
             outs.append((pl.BlockSpec((1, 1, s_kv),
-                                      lambda b, i, j: (b, 0, 0)), dkb_shape))
+                                      lambda n, i, j: (n, 0, 0)), dkb_shape))
             scratch.append(pltpu.VMEM((1, s_kv), jnp.float32))
         res = pl.pallas_call(
             functools.partial(_bwd_kernel, scale=scale, flags=flags,
                               geom=geom, emit_dbias=emit_dbias,
-                              emit_dkbias=emit_dkbias, num_q=num_q),
+                              emit_dkbias=emit_dkbias, num_q=num_q, d=d),
             name="flash_bwd",
-            grid=(bh, num_q, 1),
+            grid=(programs, num_q, 1),
             in_specs=[qspec, kspec, kspec] + extra_specs
             + [qspec, qspec, rowspec],
             out_specs=[spec for spec, _ in outs],
@@ -641,58 +787,47 @@ def _flash_bwd(q, k, v, lengths, kmask, kbias, fmask, bias, out, lse, do,
         dkbias = rest.pop(0) if emit_dkbias else None
         return dq, dk, dv, dbias, dkbias
 
-    # delta_i = rowsum(dO ⊙ O): tiny elementwise+reduce — XLA fuses it.
-    # Lane-oriented (bh, 1, s_q) like lse.
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)[:, None, :]
+    stat_shape = jax.ShapeDtypeStruct((bh, 1, s_q), jnp.float32)
     res = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, flags=flags, geom=geom,
-                          emit_dbias=emit_dbias, num_kv=num_kv),
+                          emit_dbias=emit_dbias, num_kv=num_kv, d=d),
         name="flash_bwd_dq",
-        grid=(bh, num_q, num_kv),
+        grid=(programs, num_q, num_kv),
         in_specs=[qspec, kspec, kspec] + extra_specs
-        + [qspec, rowspec, rowspec],
-        out_specs=[qspec, dbias_spec] if emit_dbias else qspec,
-        out_shape=[dq_shape, dbias_shape] if emit_dbias else dq_shape,
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        + [qspec, qspec, rowspec],
+        out_specs=[qspec, rowspec] + [dbias_spec] * emit_dbias,
+        out_shape=[dq_shape, stat_shape] + [dbias_shape] * emit_dbias,
+        scratch_shapes=[pltpu.VMEM((block_q, width), jnp.float32)],
         interpret=interpret,
-    )(q, k, v, *extras, do, lse, delta)
-    if emit_dbias:
-        dq, dbias = res
-    else:
-        dq, dbias = res, None
+    )(q, k, v, *extras, out, do, lse)
+    dq, delta = res[:2]
+    dbias = res[2] if emit_dbias else None
 
-    # dkv iterates (bh, kv_block, q_block): remap grid→(bh, qi, ki)
-    order = lambda b, j, i: (b, i, j)                    # noqa: E731
-    dkv_outs = [
-        pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-    ]
+    # dkv iterates (program, kv_block, q_block): remap grid→(n, qi, ki)
+    order = lambda n, j, i: (n, i, j)                    # noqa: E731
+    qspec = _operand_spec(block_q, width, cols, lambda n, j, i: (n, i))
+    kspec = _operand_spec(block_k, width, cols, lambda n, j, i: (n, j))
+    rowspec = pl.BlockSpec((width // d, 1, block_q),
+                           lambda n, j, i: (n, 0, i))
+    dkv_outs = [kspec, kspec]
     dkv_scratch = [
-        pltpu.VMEM((block_k, d), jnp.float32),
-        pltpu.VMEM((block_k, d), jnp.float32),
+        pltpu.VMEM((block_k, width), jnp.float32),
+        pltpu.VMEM((block_k, width), jnp.float32),
     ]
     if emit_dkbias:
         dkv_outs.append(pl.BlockSpec((1, 1, block_k),
-                                     lambda b, j, i: (b, 0, j)))
+                                     lambda n, j, i: (n, 0, j)))
         dkv_shapes.append(dkb_shape)
         dkv_scratch.append(pltpu.VMEM((8, block_k), jnp.float32))
     res2 = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, flags=flags, geom=geom,
-                          emit_dkbias=emit_dkbias, num_q=num_q),
+                          emit_dkbias=emit_dkbias, num_q=num_q, d=d),
         name="flash_bwd_dkv",
-        grid=(bh, num_kv, num_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        ] + _extra_specs(order, heads, gmode_mask, gmode_bias, gmode_kbias,
-                         block_q, block_k, **flags)
-        + [
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i)),
-            pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i)),
-        ],
+        grid=(programs, num_kv, num_q),
+        in_specs=[qspec, kspec, kspec]
+        + _extra_specs(order, groups, gmode_mask, gmode_bias, gmode_kbias,
+                       block_q, block_k, **flags)
+        + [qspec, rowspec, rowspec],
         out_specs=dkv_outs,
         out_shape=dkv_shapes,
         scratch_shapes=dkv_scratch,
@@ -725,36 +860,36 @@ def _group_reduce(d, gmode, b, heads, shape, dtype):
     return d.reshape(shape).astype(dtype)
 
 
-_STATIC = (8, 9, 10, 11, 12, 13, 14, 15, 16, 17)
+_STATIC = tuple(range(8, 19))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=_STATIC)
 def _flash(q3, k3, v3, lengths, kmask, kbias, fmask, bias, scale, causal,
            gmode_mask, gmode_bias, gmode_kbias, heads, block_q, block_k,
-           interpret, fwd_name):
+           interpret, fwd_name, d):
     out, _ = _flash_fwd(q3, k3, v3, lengths, kmask, kbias, fmask, bias,
                         scale, causal, gmode_mask, gmode_bias, gmode_kbias,
-                        heads, block_q, block_k, interpret, fwd_name)
+                        heads, block_q, block_k, interpret, fwd_name, d)
     return out
 
 
 def _flash_vjp_fwd(q3, k3, v3, lengths, kmask, kbias, fmask, bias, scale,
                    causal, gmode_mask, gmode_bias, gmode_kbias, heads,
-                   block_q, block_k, interpret, fwd_name):
+                   block_q, block_k, interpret, fwd_name, d):
     out, lse = _flash_fwd(q3, k3, v3, lengths, kmask, kbias, fmask, bias,
                           scale, causal, gmode_mask, gmode_bias, gmode_kbias,
-                          heads, block_q, block_k, interpret, fwd_name)
+                          heads, block_q, block_k, interpret, fwd_name, d)
     return out, (q3, k3, v3, lengths, kmask, kbias, fmask, bias, out, lse)
 
 
 def _flash_vjp_bwd(scale, causal, gmode_mask, gmode_bias, gmode_kbias, heads,
-                   block_q, block_k, interpret, fwd_name, res, do):
+                   block_q, block_k, interpret, fwd_name, d, res, do):
     q3, k3, v3, lengths, kmask, kbias, fmask, bias, out, lse = res
     dq, dk, dv, dbias, dkbias = _flash_bwd(
         q3, k3, v3, lengths, kmask, kbias, fmask, bias, out, lse, do, scale,
         causal, gmode_mask, gmode_bias, gmode_kbias, heads, block_q, block_k,
-        interpret)
-    b = q3.shape[0] // heads
+        interpret, d)
+    b = lse.shape[0] // heads
     if bias is not None:
         # reduce the dense (B·H, S, S) tile grads over the broadcast group
         dbias = _group_reduce(dbias, gmode_bias, b, heads, bias.shape,
@@ -801,8 +936,15 @@ def _broadcast_group(x, b, h, s_q, s_kv, name):
 
 def flash_attention(q, k, v, causal=False, scale=None, lengths=None,
                     key_mask=None, mask=None, bias=None,
-                    block_q=None, block_k=None, interpret=False):
-    """Blockwise flash attention for (B, H, S, D) inputs.
+                    block_q=None, block_k=None, interpret=False, heads=None):
+    """Blockwise flash attention for (B, H, S, D) inputs — or, with
+    ``heads=H``, for PACKED (B, S, H·D) inputs, read and written as a
+    projection leaves them (module docstring): the output and the three
+    gradients come back packed too, and no transposed copy of any of them
+    is made.  A packed call needs a head size that :func:`packed_width`
+    accepts with ``H·D`` a multiple of it, and takes ``causal``,
+    ``lengths`` and ``key_mask`` alone (a dense ``mask`` / ``bias`` is laid
+    out per head: head-major callers').
 
     ``lengths``: optional (B,) int32 valid-KEY counts per sequence — keys
     at positions >= lengths[b] are masked out (padding mask); fully masked
@@ -820,8 +962,9 @@ def flash_attention(q, k, v, causal=False, scale=None, lengths=None,
     straight-line code with zero masking overhead.
     ``block_q`` / ``block_k``: left ``None``, :func:`_pick_blocks` chooses
     them from this call's shapes (the whole key range in one block where
-    it fits VMEM); given, they are used as they are.  Which geometry a
-    trace compiled is counted in ``metrics.flash_call_counts()``.
+    it fits VMEM); given, they are used as they are.  Which geometry and
+    layout a trace compiled is counted in ``metrics.flash_call_counts()``
+    (``"512x512:one_pass"``, ``"256x512:one_pass:packed"``).
     Ragged (non-128-multiple) sequence lengths are BUCKETED: padded up to
     the next flash-legal bucket (128/256/384/…), the pad keys masked by the
     kernel's existing lengths/key-mask strip path, and the output sliced
@@ -832,8 +975,23 @@ def flash_attention(q, k, v, causal=False, scale=None, lengths=None,
     explicit ``flash_fallback_reason``.  ``interpret=True`` runs the
     Pallas interpreter so CPU CI exercises the same kernel code.
     """
-    b, h, s_q, d = q.shape
-    s_kv = k.shape[2]
+    packed = heads is not None
+    if packed:
+        b, s_q, lanes = q.shape
+        h, d, seq = heads, lanes // heads, 1
+        width = packed_width(d)
+        if width is None or lanes % width:
+            raise ValueError(
+                f"packed flash attention needs heads of a size that "
+                f"divides or is a multiple of {_LANES} lanes in whole "
+                f"column blocks, got {h} heads of {d}")
+        if mask is not None or bias is not None:
+            raise ValueError("packed flash attention takes causal, lengths "
+                             "and key_mask only")
+    else:
+        b, h, s_q, d = q.shape
+        seq, width = 2, d
+    s_kv = k.shape[seq]
     pad_q = flash_bucket(s_q) - s_q
     pad_k = flash_bucket(s_kv) - s_kv
     if causal and pad_q != pad_k:
@@ -845,9 +1003,9 @@ def flash_attention(q, k, v, causal=False, scale=None, lengths=None,
             f"the bottom-right-aligned diagonal")
     s_q_orig = s_q
     if pad_q or pad_k:
-        q = _pad_seq(q, 2, pad_q)
-        k = _pad_seq(k, 2, pad_k)
-        v = _pad_seq(v, 2, pad_k)
+        q = _pad_seq(q, seq, pad_q)
+        k = _pad_seq(k, seq, pad_k)
+        v = _pad_seq(v, seq, pad_k)
         if pad_k:
             # pad KEYS must be invisible: ``lengths`` already masks cols
             # >= lengths[b] <= s_kv; a given key_mask/mask extends with
@@ -880,15 +1038,20 @@ def flash_attention(q, k, v, causal=False, scale=None, lengths=None,
         s_q += pad_q
         s_kv += pad_k
     scale = float(scale) if scale is not None else 1.0 / (d ** 0.5)
-    q3 = q.reshape(b * h, s_q, d)
-    k3 = k.reshape(b * h, s_kv, d)
-    v3 = v.reshape(b * h, s_kv, d)
+    if packed:
+        q3, k3, v3 = q, k, v
+    else:
+        q3 = q.reshape(b * h, s_q, d)
+        k3 = k.reshape(b * h, s_kv, d)
+        v3 = v.reshape(b * h, s_kv, d)
     if lengths is None:
         len3 = None    # static: kernels compile the dense straight-line path
     else:
+        # one scalar a program: a head, or a packed column block of heads
+        groups = h * d // width
         len3 = jnp.broadcast_to(
-            jnp.asarray(lengths, jnp.int32).reshape(b, 1), (b, h)
-        ).reshape(b * h, 1, 1)
+            jnp.asarray(lengths, jnp.int32).reshape(b, 1), (b, groups)
+        ).reshape(b * groups, 1, 1)
     gmode_mask = gmode_bias = gmode_kbias = "one"
     kmask2 = kbias3 = fmask3 = bias3 = None
     if key_mask is not None:
@@ -915,7 +1078,7 @@ def flash_attention(q, k, v, causal=False, scale=None, lengths=None,
     # blocks by the rule, from this call's shapes; a dense bias counts
     # twice (its tile and the backward's dbias tile).  Explicit blocks win.
     rule_q, rule_k = _pick_blocks(
-        s_q, s_kv, d, q.dtype.itemsize, causal,
+        s_q, s_kv, width, q.dtype.itemsize, causal,
         (fmask3 is not None) + 2 * (bias3 is not None))
     block_q = block_q or rule_q
     block_k = block_k or rule_k
@@ -924,14 +1087,18 @@ def flash_attention(q, k, v, causal=False, scale=None, lengths=None,
             f"flash_attention needs seq divisible by block "
             f"({s_q}, {s_kv}) vs ({block_q}, {block_k})")
     from ...metrics import record_flash_call
-    record_flash_call(block_q, block_k, one_pass=block_k == s_kv)
+    record_flash_call(block_q, block_k, one_pass=block_k == s_kv,
+                      packed=packed)
     # the one-token decode call (one query row, padded to a bucket of
     # 128) under a name of its own in the device trace
     out = _flash(q3, k3, v3, len3, kmask2, kbias3, fmask3, bias3, scale,
                  causal, gmode_mask, gmode_bias, gmode_kbias, h, block_q,
                  block_k, interpret,
-                 "flash_fwd_q1" if s_q_orig == 1 else "flash_fwd")
-    out = out.reshape(b, h, s_q, d)
+                 "flash_fwd_q1" if s_q_orig == 1 else "flash_fwd",
+                 d if packed else None)
+    if not packed:
+        out = out.reshape(b, h, s_q, d)
     if s_q != s_q_orig:
-        out = out[:, :, :s_q_orig]    # unpad: bucketing is caller-invisible
+        # unpad: bucketing is caller-invisible
+        out = jax.lax.slice_in_dim(out, 0, s_q_orig, axis=seq)
     return out

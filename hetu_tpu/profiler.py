@@ -229,7 +229,7 @@ class HetuProfiler:
         """{family: {kind: count}} over EVERY counter family on the
         observability registry in one call (``hetu_tpu.metrics``
         ``all_counts``): flash_fallbacks, flash_calls,
-        decode_attn_calls, kv_append_calls, moe_calls,
+        flash_head_major, decode_attn_calls, kv_append_calls, moe_calls,
         sparse_attn_calls,
         emb_pallas_fallbacks, faults, elastic, autoparallel, cache, zero,
         step_cache, compile, setup_us, setup_bytes, run_plan, serve,
@@ -275,12 +275,25 @@ class HetuProfiler:
 
     @staticmethod
     def flash_calls():
-        """{"<block_q>x<block_k>:<one_pass|two_pass>": count} of traced
-        flash-attention calls by the block shapes the kernel module's
-        rule chose for them and the backward they get (one kernel while
-        the whole key range is one block, else dq + dkv).  Per trace."""
+        """{"<block_q>x<block_k>:<one_pass|two_pass>[:packed]": count} of
+        traced flash-attention calls by the block shapes the kernel
+        module's rule chose for them, the backward they get (one kernel
+        while the whole key range is one block, else dq + dkv) and the
+        operand layout: ``:packed`` = (B, S, H·D) as the projections
+        leave it, no tag = (B, H, S, D).  Per trace."""
         from .metrics import flash_call_counts
         return flash_call_counts()
+
+    @staticmethod
+    def flash_head_major():
+        """{reason: count} of attention calls that KEPT the (B, H, S, D)
+        layout and its transposes where the packed entry would have
+        spared them: ``bias``, ``mask_shape:…``, ``context_parallel:…``,
+        ``head_dim:…`` from ``MultiHeadAttention`` (per graph build),
+        ``tp_splits_column_block:…`` from the op under a mesh (per
+        trace)."""
+        from .metrics import flash_head_major_counts
+        return flash_head_major_counts()
 
     @staticmethod
     def decode_attn_calls():

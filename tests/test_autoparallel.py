@@ -422,48 +422,44 @@ def test_cp_plan_executes_t5_end_to_end():
     assert np.isfinite(float(out[0].asnumpy()))
 
 
-def test_flash_ab_resume_and_gate_rules(tmp_path, monkeypatch):
-    """Producer-side lifecycle rules of tools/flash_ab.py: complete or
-    geometry-mismatched or pre-kmask artifacts are never resumed, and the
-    gate requires a MEASURED kmask win (review findings)."""
-    import json
+def test_flash_ab_gate_rules_and_layouts(tmp_path, monkeypatch):
+    """tools/flash_ab.py: the gate requires a MEASURED kmask win (review
+    finding); the sweep holds the kept geometry only (whole key range);
+    and its two layouts are one computation — the head-major entry as a
+    layer called it equals the packed operands' own attention."""
     import sys
     import os
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir))
+    import jax.numpy as jnp
     import tools.flash_ab as ab
+    from hetu_tpu.ops.attention import dispatch_sdpa_packed, sdpa_reference
 
     monkeypatch.setattr(ab, "ROOT", str(tmp_path))
-    art_dir = tmp_path / "artifacts"
-    art_dir.mkdir()
-    path = art_dir / "flash_ab.json"
-    row = {"winner_dense": "flash", "winner_kmask": "flash",
-           "blocks_dense": [128, 128]}
-    base = {"backend": "cpu", "heads": ab.HEADS, "head_dim": ab.HEAD_DIM,
-            "token_budget": ab.TOKEN_BUDGET, "rows": {"128": row},
-            "partial": True, "flash_min_len": 128}
-
-    path.write_text(json.dumps(base))
-    assert ab._load_previous_rows("cpu") == {"128": row}   # resumable
-    assert ab._load_previous_rows("tpu") == {}             # other backend
-
-    complete = dict(base, partial=False)
-    path.write_text(json.dumps(complete))
-    assert ab._load_previous_rows("cpu") == {}     # complete: fresh rerun
-
-    wrong_geom = dict(base, token_budget=ab.TOKEN_BUDGET * 2)
-    path.write_text(json.dumps(wrong_geom))
-    assert ab._load_previous_rows("cpu") == {}     # geometry mismatch
-
-    old_tool = dict(base)
-    old_tool["rows"] = {"128": {"winner_dense": "flash"}}  # pre-kmask row
-    path.write_text(json.dumps(old_tool))
-    assert ab._load_previous_rows("cpu") == {}     # must re-measure
-
+    row = {"winner_dense": "flash", "winner_kmask": "flash"}
     # gate: an unmeasured kmask case is NOT a win
     out = ab._persist("cpu", {"128": {"winner_dense": "flash"}}, False)
     assert out["flash_min_len"] == ab.SEQS[-1] * 2        # sentinel
     out = ab._persist("cpu", {"128": row}, False)
     assert out["flash_min_len"] == 128
+    assert not hasattr(ab, "_load_previous_rows")   # nothing of
+    # ``artifacts/`` survives a chip call: there was nothing to resume
+
+    assert ab._sweep_blocks(512) == [(128, 512), (256, 512), (512, 512)]
+    assert ab._sweep_blocks(1024) == [(128, 1024), (256, 1024),
+                                      (512, 1024)]
+    rng = np.random.RandomState(0)
+    q, k, v = (jnp.asarray(rng.randn(2, ab.HEADS, 16, ab.HEAD_DIM),
+                           jnp.float32) for _ in range(3))
+    packed = tuple(ab._pack(x) for x in (q, k, v))
+    assert packed[0].shape == (2, 16, ab.HEADS * ab.HEAD_DIM)
+    layer = ab._as_layer(sdpa_reference)(*packed)
+    np.testing.assert_array_equal(
+        np.asarray(layer), np.asarray(ab._pack(sdpa_reference(q, k, v))))
+    np.testing.assert_array_equal(
+        np.asarray(layer),
+        np.asarray(dispatch_sdpa_packed(*packed, head_dim=ab.HEAD_DIM)))
+    assert ab._max_diff(ab._as_layer(sdpa_reference), sdpa_reference,
+                        packed, (q, k, v)) == 0.0
 
 
 def test_plan_responds_to_hardware_constants():

@@ -183,6 +183,8 @@ def test_metrics_dump_roundtrips_every_counter_family():
     metrics.reset_all()
     metrics.record_flash_fallback("test_reason")
     metrics.record_flash_call(512, 512, one_pass=True)
+    metrics.record_flash_call(256, 512, one_pass=True, packed=True)
+    metrics.record_flash_head_major("bias")
     metrics.record_decode_attn_call(10, 256)
     metrics.record_kv_append_call(16, 640, "kernel")
     metrics.record_moe_call(40, 320, 8)
@@ -218,6 +220,7 @@ def test_metrics_dump_roundtrips_every_counter_family():
     legacy = {
         "flash_fallbacks": metrics.flash_fallback_counts(),
         "flash_calls": metrics.flash_call_counts(),
+        "flash_head_major": metrics.flash_head_major_counts(),
         "decode_attn_calls": metrics.decode_attn_call_counts(),
         "kv_append_calls": metrics.kv_append_call_counts(),
         "moe_calls": metrics.moe_call_counts(),
@@ -245,7 +248,9 @@ def test_metrics_dump_roundtrips_every_counter_family():
     }
     for fam, want in legacy.items():
         assert dump["counters"][fam] == want, fam
-    assert legacy["flash_calls"] == {"512x512:one_pass": 1}
+    assert legacy["flash_calls"] == {"512x512:one_pass": 1,
+                                     "256x512:one_pass:packed": 1}
+    assert legacy["flash_head_major"] == {"bias": 1}
     assert legacy["decode_attn_calls"] == {"10x256": 1}
     assert legacy["kv_append_calls"] == {"16x640:kernel": 1}
     assert legacy["moe_calls"] == {"40of320:top8:ragged": 1}

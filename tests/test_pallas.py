@@ -586,6 +586,8 @@ def test_flash_gate_is_the_module_constant(monkeypatch):
     (2048, 2048, 128, 2, False, 0),   # llama-width head, long
     (4096, 4096, 64, 2, False, 0),
     (256, 512, 64, 4, False, 0),      # cross-attention, f32
+    (512, 512, 128, 2, False, 0),     # bert-base packed: two heads a block
+    (2048, 2048, 128, 2, False, 0),
     (128, 768, 64, 4, False, 1),      # chunked prefill: full mask
     (384, 384, 64, 4, True, 0),       # 384 has no 256 divisor
     (128, 128, 64, 4, True, 0),
@@ -598,7 +600,8 @@ def test_flash_block_rule(s_q, s_kv, d, itemsize, causal, dense):
     bq, bk = fa._pick_blocks(s_q, s_kv, d, itemsize, causal, dense)
     assert bq % 128 == 0 and bk % 128 == 0
     assert s_q % bq == 0 and s_kv % bk == 0
-    assert fa._block_bytes(bq, bk, d, itemsize, dense) <= fa._VMEM_BUDGET
+    assert fa._block_bytes(bq, bk, d, itemsize, dense, s_q // bq,
+                           s_kv // bk) <= fa._VMEM_BUDGET
     if causal and bk < s_kv:
         # several key blocks: pruning-friendly, at least two blocks a side
         assert bq <= max(128, s_q // 2) and bk <= max(128, s_kv // 2)
@@ -800,3 +803,287 @@ def test_flash_key_bias_strip_path(bias_shape, causal):
     for a, e in zip(g, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(e),
                                    rtol=3e-5, atol=3e-6)
+
+
+# ------------------------------------------- packed (B, S, H·D) flash entry
+from hetu_tpu.ops.attention import _merge_heads as _pack  # noqa: E402
+
+
+def _both_layouts(q, k, v, h, **kw):
+    """(out, dq, dk, dv) of the head-major entry and of the packed entry
+    over the same heads, the packed side's in the packed layout."""
+    def run(fn, args):
+        loss = lambda *a: jnp.sum(fn(*a) ** 2)           # noqa: E731
+        return (fn(*args),) + jax.grad(loss, argnums=(0, 1, 2))(*args)
+    head_major = run(lambda q, k, v: flash_attention(
+        q, k, v, interpret=True, **kw), (q, k, v))
+    packed = run(lambda q, k, v: flash_attention(
+        q, k, v, heads=h, interpret=True, **kw),
+        tuple(_pack(x) for x in (q, k, v)))
+    return [_pack(x) for x in head_major], packed
+
+
+def _layout_extras(extra, b, s_kv, seed):
+    rng = np.random.RandomState(seed)
+    return {"plain": {}, "causal": {"causal": True},
+            "lengths": {"lengths": jnp.asarray(
+                rng.randint(1, s_kv + 1, b), jnp.int32)},
+            "key_mask": {"key_mask": jnp.asarray(
+                rng.rand(b, s_kv) > 0.3)}}[extra]
+
+
+@pytest.mark.parametrize("blocks", [{}, {"block_q": 128, "block_k": 128}],
+                         ids=["one_pass", "two_pass"])
+@pytest.mark.parametrize("extra", ["plain", "causal", "lengths", "key_mask"])
+@pytest.mark.parametrize("h,d", [(4, 64), (2, 128), (4, 32)],
+                         ids=["d64x2", "d128x1", "d32x4"])
+def test_flash_packed_matches_head_major(h, d, extra, blocks):
+    """The packed entry — (B, S, H·D) in, (B, S, H·D) out and back — is the
+    head-major kernel's arithmetic: forward and all three gradients, two
+    heads a column block (d = 64), one (d = 128), four (d = 32), every
+    extra the packed entry takes, the one-pass kernels (the rule's whole
+    key range) and the online-softmax forward with the dq + dkv backward
+    (two key blocks).  To the last bit wherever the interpreter's matrix
+    products block alike in both layouts (a head per column block, or
+    128 x 128 blocks at d = 64: XLA's CPU product picks its contraction
+    blocking by operand shape, the MXU does not); elsewhere to a few
+    f32 ulps of a sum."""
+    b, s = 2, 256
+    q, k, v = _rand_qkv(b, h, s, d, seed=3 + d)
+    head_major, packed = _both_layouts(
+        q, k, v, h, **_layout_extras(extra, b, s, seed=d), **blocks)
+    exact = d == 128 or (d == 64 and blocks)
+    for name, want, got in zip(("out", "dq", "dk", "dv"), head_major,
+                               packed):
+        assert got.shape == (b, s, h * d)
+        if exact:
+            np.testing.assert_array_equal(np.asarray(got),
+                                          np.asarray(want), err_msg=name)
+        else:
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=0, atol=3e-6, err_msg=name)
+
+
+@pytest.mark.parametrize("s_q,s_kv,extra", [
+    (256, 384, "key_mask"),     # cross-attention, one pass
+    (128, 384, "lengths"),
+    (200, 300, "plain"),        # ragged: bucketed to 256 / 384, the pad
+    (200, 300, "key_mask"),     # keys masked by lengths / the strip
+    (300, 300, "causal"),
+])
+def test_flash_packed_cross_attention_and_ragged_lengths(s_q, s_kv, extra):
+    """S_q != S_kv and lengths off the 128 grid go through the packed
+    entry as through the head-major one (pad, mask, unpad along axis 1),
+    and agree with the reference."""
+    b, h, d = 2, 4, 64
+    rng = np.random.RandomState(s_q + s_kv)
+    q = jnp.asarray(rng.randn(b, h, s_q, d).astype(np.float32) * 0.3)
+    k = jnp.asarray(rng.randn(b, h, s_kv, d).astype(np.float32) * 0.3)
+    v = jnp.asarray(rng.randn(b, h, s_kv, d).astype(np.float32) * 0.3)
+    kw = _layout_extras(extra, b, s_kv, seed=s_q)
+    head_major, packed = _both_layouts(q, k, v, h, **kw)
+    assert packed[0].shape == (b, s_q, h * d)
+    for name, want, got in zip(("out", "dq", "dk", "dv"), head_major,
+                               packed):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=0, atol=3e-6, err_msg=name)
+    cols = jnp.arange(s_kv)[None, None, None, :]
+    mask = {"key_mask": lambda: kw["key_mask"][:, None, None, :],
+            "lengths": lambda: cols < kw["lengths"][:, None, None, None],
+            }.get(extra, lambda: None)()
+    ref = sdpa_reference(q, k, v, causal=extra == "causal", mask=mask)
+    np.testing.assert_allclose(np.asarray(packed[0]),
+                               np.asarray(_pack(ref)), rtol=2e-5, atol=2e-5)
+
+
+def test_flash_packed_entry_refuses_what_it_cannot_lay_out():
+    from hetu_tpu.ops.pallas.flash_attention import (_block_bytes,
+                                                     _pick_blocks,
+                                                     packed_width)
+    assert [packed_width(d) for d in (16, 32, 64, 128, 256, 80, 96)] \
+        == [128, 128, 128, 128, 256, None, None]
+    q = jnp.zeros((1, 256, 160), jnp.float32)
+    with pytest.raises(ValueError, match="2 heads of 80"):
+        flash_attention(q, q, q, heads=2, interpret=True)
+    q = jnp.zeros((1, 256, 192), jnp.float32)      # 3 heads of 64: 1.5 blocks
+    with pytest.raises(ValueError, match="3 heads of 64"):
+        flash_attention(q, q, q, heads=3, interpret=True)
+    q = jnp.zeros((1, 256, 128), jnp.float32)
+    with pytest.raises(ValueError, match="causal, lengths and key_mask"):
+        flash_attention(q, q, q, heads=2, interpret=True,
+                        bias=jnp.zeros((1, 2, 256, 256)))
+    # the rule counts a packed row block at its 128 lanes — twice the
+    # bytes of a 64-lane head — and BERT's 512 x 512 still fits, to the
+    # byte: one program holds both ranges whole, so no sum crosses a grid
+    # step and no f32 accumulator is kept (same blocks in both layouts)
+    assert _pick_blocks(512, 512, 64, 2) == (512, 512)
+    assert _pick_blocks(512, 512, packed_width(64), 2) == (512, 512)
+    assert _block_bytes(512, 512, 128, 2, 0, 1, 1) == 8 * 2 ** 20 \
+        < _block_bytes(512, 512, 128, 2, 0)
+    assert _block_bytes(512, 512, 64, 2, 0, 1, 1) \
+        < _block_bytes(512, 512, 128, 2, 0, 1, 1)
+    assert _pick_blocks(2048, 2048, packed_width(64), 2) == (256, 512)
+
+
+# ------------------------------------------- the layer's choice of layout
+def _mha_graph(case):
+    """One MultiHeadAttention call of ``case`` → (output node, feeds)."""
+    import hetu_tpu as ht
+    b, s = 2, 16
+    hid, heads, kw, layer_kw = {
+        "plain": (256, 4, {}, {}),
+        "causal_d128": (256, 2, {}, {"causal": True}),
+        "d32": (128, 4, {}, {}),
+        "key_mask": (256, 4, {"mask": (b, 1, 1, s)}, {}),
+        "shared_key_mask": (256, 4, {"mask": (1, 1, 1, s)}, {}),
+        "bias": (256, 4, {"bias": (1, 4, s, s)}, {}),
+        "full_mask": (256, 4, {"mask": (b, 1, s, s)}, {}),
+        "mask_of_unknown_shape": (256, 4, {"mask": None}, {}),
+        "ring": (256, 4, {}, {"context_parallel": "ring"}),
+        "ulysses": (256, 4, {}, {"context_parallel": "ulysses"}),
+        "d80": (160, 2, {}, {}),
+        "half_a_column_block": (64, 4, {}, {}),
+    }[case]
+    x = ht.placeholder_op("x", shape=(b * s, hid))
+    feeds = {x: np.random.RandomState(5).randn(b * s, hid).astype(
+        np.float32)}
+    extras = {}
+    for name, shape in kw.items():
+        node = ht.placeholder_op(
+            name, shape=shape,
+            dtype=np.int32 if name == "mask" else np.float32)
+        extras[name] = node
+        shape = shape or (b, 1, s, s)
+        feeds[node] = (np.random.RandomState(6).rand(*shape) > 0.3
+                       ).astype(np.int32) if name == "mask" else \
+            np.random.RandomState(6).randn(*shape).astype(np.float32)
+    mha = ht.layers.MultiHeadAttention(hid, heads, name="lay", **layer_kw)
+    return mha(x, b, s, **extras), feeds
+
+
+@pytest.mark.parametrize("case,reason", [
+    ("plain", None), ("causal_d128", None), ("d32", None),
+    ("key_mask", None), ("shared_key_mask", None),
+    ("bias", "bias"),
+    ("full_mask", "mask_shape:(2, 1, 16, 16)"),
+    ("mask_of_unknown_shape", "mask_shape:None"),
+    ("ring", "context_parallel:ring"),
+    ("ulysses", "context_parallel:ulysses"),
+    ("d80", "head_dim:80"),
+    ("half_a_column_block", "column_block:4x16%128"),
+])
+def test_mha_takes_the_packed_layout_by_what_it_can_observe(
+        case, reason, monkeypatch):
+    """Where the rule passes — head size, whole column blocks, no context
+    parallelism, no bias, at most a key-padding mask — the layer builds NO
+    transpose node: q, k, v go to ``sdpa_packed_op`` as the projections
+    leave them.  Where it does not, today's graph, and the counter names
+    the reason.  Both graphs give the same numbers."""
+    import hetu_tpu as ht
+    from hetu_tpu import metrics
+    from hetu_tpu.graph.node import topo_sort
+    from hetu_tpu.layers.attention import MultiHeadAttention
+    from hetu_tpu.profiler import HetuProfiler
+
+    metrics.reset_all()
+    out, feeds = _mha_graph(case)
+    kinds = [n.op_type for n in topo_sort([out])]
+    attention = [t for t in kinds if "Attention" in t]
+    if reason is None:
+        assert kinds.count("Transpose") == 0
+        assert attention == ["ScaledDotProductAttentionPacked"]
+        assert HetuProfiler.flash_head_major() == {}
+    else:
+        assert kinds.count("Transpose") == 4
+        assert len(attention) == 1 and "Packed" not in attention[0]
+        assert HetuProfiler.flash_head_major() == {reason: 1}
+        assert HetuProfiler.all_counters()["flash_head_major"] \
+            == {reason: 1}
+    if reason is None:
+        got = ht.Executor({"f": [out]}, seed=0).run(
+            "f", feed_dict=feeds)[0].asnumpy()
+        monkeypatch.setattr(MultiHeadAttention, "_head_major_reason",
+                            lambda self, mask, bias: "forced")
+        old, old_feeds = _mha_graph(case)
+        assert [n.op_type for n in topo_sort([old])].count("Transpose") == 4
+        want = ht.Executor({"f": [old]}, seed=0).run(
+            "f", feed_dict=old_feeds)[0].asnumpy()
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    metrics.reset_all()
+
+
+def _interpreted_flash(monkeypatch):
+    """The attention dispatch hears a TPU and reaches the flash kernels,
+    which run under the interpreter."""
+    import functools
+    import sys
+    from hetu_tpu.ops import attention as att
+    fa = sys.modules["hetu_tpu.ops.pallas.flash_attention"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(fa, "flash_attention", functools.partial(
+        fa.flash_attention, interpret=True))
+    return att
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["plain", "key_mask"])
+def test_sdpa_packed_op_reaches_the_packed_kernel(masked, monkeypatch):
+    from hetu_tpu import metrics
+    att = _interpreted_flash(monkeypatch)
+    metrics.reset_all()
+    q, k, v = _rand_qkv(2, 4, 256, 64, seed=17)
+    km = jnp.asarray(np.random.RandomState(17).rand(2, 1, 1, 256) > 0.3) \
+        if masked else None
+    out = att._sdpa_packed(None, _pack(q), _pack(k), _pack(v), km,
+                           head_dim=64, causal=not masked)
+    assert metrics.flash_call_counts() == {"256x256:one_pass:packed": 1}
+    assert metrics.flash_fallback_counts() == {}
+    ref = sdpa_reference(q, k, v, mask=km, causal=not masked)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(_pack(ref)),
+                               rtol=2e-5, atol=2e-5)
+    # below the gate and with a full mask: the counted head-major dispatch
+    # between two transposes, the same numbers
+    short = [_pack(x[:, :, :128]) for x in (q, k, v)]
+    att._sdpa_packed(None, *short, head_dim=64)
+    assert metrics.flash_fallback_counts() == {"below_gate:seq128<256": 1}
+    full = jnp.asarray(np.random.RandomState(18).rand(2, 1, 256, 256) > 0.3)
+    out = att._sdpa_packed(None, _pack(q), _pack(k), _pack(v), full,
+                           head_dim=64)
+    assert metrics.flash_head_major_counts() \
+        == {"mask_shape:(2, 1, 256, 256)": 1}
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_pack(sdpa_reference(q, k, v,
+                                                         mask=full))),
+        rtol=2e-5, atol=2e-5)
+    metrics.reset_all()
+
+
+@pytest.mark.parametrize("heads,reason", [
+    (4, None),              # a shard keeps one whole block of two heads
+    (2, "tp_splits_column_block:2x64/2"),   # a shard would keep half a one
+])
+def test_sdpa_packed_op_under_a_tp_mesh(heads, reason, monkeypatch):
+    """On a dp x tp mesh the packed op shards batch rows over ``dp`` and
+    the LAST axis over ``tp`` — only where each shard keeps whole column
+    blocks of heads; where one would be cut it shards head-major as
+    before and says so."""
+    import types
+    import hetu_tpu as ht
+    from hetu_tpu import metrics
+    att = _interpreted_flash(monkeypatch)
+    metrics.reset_all()
+    mesh = ht.make_mesh({"dp": 2, "tp": 2}, jax.devices()[:4])
+    q, k, v = _rand_qkv(2, heads, 256, 64, seed=23)
+    km = jnp.asarray(np.random.RandomState(23).rand(2, 1, 1, 256) > 0.3)
+    out = att._sdpa_packed(types.SimpleNamespace(mesh=mesh), _pack(q),
+                           _pack(k), _pack(v), km, head_dim=64)
+    assert out.shape == (2, 256, heads * 64)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_pack(sdpa_reference(q, k, v, mask=km))),
+        rtol=2e-5, atol=2e-5)
+    if reason is None:
+        assert metrics.flash_call_counts() == {"256x256:one_pass:packed": 1}
+        assert metrics.flash_head_major_counts() == {}
+    else:
+        assert metrics.flash_call_counts() == {"256x256:one_pass": 1}
+        assert metrics.flash_head_major_counts() == {reason: 1}
+    metrics.reset_all()

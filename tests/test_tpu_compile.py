@@ -136,6 +136,130 @@ def test_flash_statistics_cross_hbm_unpadded(chip):
     assert "f32[384,1,512]" in call and "f32[384,512,1]" not in call
 
 
+def _whole_tensor_moves(text, least=25_000_000):
+    """``copy`` and ``transpose`` instructions of an optimized HLO over a
+    tensor of ``least`` bytes or more (a prefetch is a ``copy-start``)."""
+    import math
+    hits = []
+    for m in re.finditer(
+            r"= (\w+?)(\d+)\[([\d,]+)\]\S* (?:copy|transpose)\(", text):
+        size = math.prod(int(d) for d in m.group(3).split(","))
+        if size * int(m.group(2)) // 8 >= least:
+            hits.append(m.group(0))
+    return hits
+
+
+@pytest.mark.parametrize("packed", [True, False],
+                         ids=["packed", "head_major"])
+def test_attention_layer_moves_no_whole_tensor_when_packed(chip, monkeypatch,
+                                                           packed):
+    """ISSUE 44: one attention layer at the bert cell's shape (b 32,
+    s 512, 12 heads of 64, bf16, a fed key mask), forward and gradient —
+    three projections, the attention op as ``MultiHeadAttention`` calls
+    it, the output projection — compiled for the described chip.
+    Head-major, the way the layer was built until PR 44 (a transpose each
+    side of ``sdpa_masked_op``), the program holds 15 ``copy``
+    instructions over whole ``bf16[32,12,512,64]`` tensors (25 MB each:
+    the transposes, their gradients and relayouts into the kernels'
+    64-lane operands) — 14.4 ms of the cell's 107.75 ms step.  Packed,
+    it holds none, and no kernel operand with a minor dimension under
+    128 lanes."""
+    from hetu_tpu import metrics
+    from hetu_tpu.ops import attention as att
+    b, s, h, d = 32, 512, 12, 64
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def layer(x, wq, wk, wv, wo, km):
+        q, k, v = (x @ w for w in (wq, wk, wv))
+        if packed:
+            o = att._sdpa_packed(
+                None, *(t.reshape(b, s, h * d) for t in (q, k, v)), km,
+                head_dim=d)
+        else:
+            q, k, v = (t.reshape(b, s, h, d).transpose(0, 2, 1, 3)
+                       for t in (q, k, v))
+            o = att._sdpa_masked(None, q, k, v, km).transpose(0, 2, 1, 3)
+        return o.reshape(b * s, h * d) @ wo
+
+    want = "512x512:one_pass" + ":packed" * packed
+    before = metrics.flash_call_counts().get(want, 0)
+    w = chip((h * d, h * d), jnp.bfloat16)
+    text = _compiles_with_kernel(
+        jax.grad(lambda *a: _sum32(layer(*a)), argnums=(0, 1, 2, 3, 4)),
+        chip((b * s, h * d), jnp.bfloat16), w, w, w, w,
+        chip((b, 1, 1, s), jnp.bool_))
+    assert metrics.flash_call_counts().get(want, 0) == before + 1
+    assert "flash_fwd" in text and "flash_bwd" in text
+    moves = _whole_tensor_moves(text)
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line]
+    narrow = [line for line in kernels if re.search(r"bf16\[[\d,]*,64\]",
+                                                    line)]
+    if packed:
+        assert moves == [] and narrow == []
+        assert all("bf16[32,512,768]" in line for line in kernels)
+    else:
+        assert len(moves) >= 12 and len(narrow) == len(kernels) == 2
+
+
+def test_flash_packed_two_pass_compiles(chip):
+    """A key range past one block in the packed layout (s 2048, bert's
+    heads): the online-softmax forward and the dq + dkv backward, two
+    heads a program, compile for the described chip with the rule's
+    blocks."""
+    from hetu_tpu import metrics
+    from hetu_tpu.ops.pallas.flash_attention import (_pick_blocks,
+                                                     flash_attention)
+    bq, bk = _pick_blocks(2048, 2048, 128, 2)
+    assert bk < 2048
+    want = f"{bq}x{bk}:two_pass:packed"
+    before = metrics.flash_call_counts().get(want, 0)
+    qkv = chip((4, 2048, 768), jnp.bfloat16)
+    text = _compiles_with_kernel(
+        jax.grad(lambda q, k, v, km: _sum32(flash_attention(
+            q, k, v, key_mask=km, heads=12)), argnums=(0, 1, 2)),
+        qkv, qkv, qkv, chip((4, 2048), jnp.bool_))
+    assert metrics.flash_call_counts().get(want, 0) == before + 1
+    assert "flash_bwd_dq" in text and "flash_bwd_dkv" in text
+    assert _whole_tensor_moves(text, least=4 * 2048 * 768 * 2) == []
+
+
+def test_packed_attention_partitions_itself_over_dp_and_tp(topo,
+                                                          monkeypatch):
+    """The packed op under a 2 x 2 mesh of the described chips: a
+    ``shard_map`` with batch rows over ``dp`` and the LAST axis over
+    ``tp`` (six of bert's twelve heads a shard: three whole column
+    blocks), forward and gradient — the kernels compile per shard at
+    (16, 512, 384) and no whole-tensor transpose stands around them."""
+    import types
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from hetu_tpu import metrics
+    from hetu_tpu.ops import attention as att
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("dp", "tp"))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def shape(dims, dtype, spec):
+        return jax.ShapeDtypeStruct(dims, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    def attend(q, k, v, km):
+        return _sum32(att._sdpa_packed(types.SimpleNamespace(mesh=mesh),
+                                       q, k, v, km, head_dim=64))
+
+    before = dict(metrics.flash_call_counts())
+    kept_head_major = dict(metrics.flash_head_major_counts())
+    qkv = shape((32, 512, 768), jnp.bfloat16, P("dp", None, "tp"))
+    text = _compiles_with_kernel(
+        jax.grad(attend, argnums=(0, 1, 2)), qkv, qkv, qkv,
+        shape((32, 1, 1, 512), jnp.bool_, P("dp")))
+    assert metrics.flash_call_counts().get("512x512:one_pass:packed", 0) \
+        == before.get("512x512:one_pass:packed", 0) + 1
+    assert metrics.flash_head_major_counts() == kept_head_major
+    assert "bf16[16,512,384]" in text
+    assert _whole_tensor_moves(text, least=16 * 512 * 384 * 2) == []
+
+
 def test_flash_decode_q1_compiles(chip):
     """The decode engine's q_len=1 entry against a 256-row cache bucket."""
     from hetu_tpu.ops.pallas.flash_attention import flash_attention

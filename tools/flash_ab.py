@@ -11,12 +11,20 @@ evidence for changing the constant).  A chip tool: run it on the machine
 with the chip.
 
 The flash side is timed as the dispatcher runs it: with the blocks the
-kernel module's rule picks from the call's shapes (``_pick_blocks``).
-Beside it each row keeps a SWEEP for the reader of PERF.md, not for the
-program (nothing reads block shapes from the artifact): forward-only and
-forward + backward milliseconds at yesterday's 128 × 128, at square tiles
-and at whole-key-range blocks, so the rule's choice can be seen against
-its neighbours.  ``--seqs 512 --tags kmask`` narrows a run.
+kernel module's rule picks from the call's shapes (``_pick_blocks``), in
+BOTH operand layouts — ``flash_ms`` the (B, H, S, D) entry alone,
+``packed_ms`` the (B, S, H·D) entry ``MultiHeadAttention`` takes since
+PR 44, and ``layer_ms`` the head-major entry as a layer had to call it
+until then: from packed projections, a transpose each side.
+``packed_max_diff`` is the largest difference, output and three gradients,
+between the two entries at EQUAL blocks (0.0: the same arithmetic).
+Beside them each row keeps a SWEEP of the kept geometry for the reader of
+PERF.md, not for the program (nothing reads block shapes from the
+artifact): forward-only and forward + backward milliseconds of each layout
+at every whole-key-range block, the rule's among them, so its choice can
+be seen against its neighbours (128 × 128 and square tiles lost in PR 28
+and are not timed any more; nor is there a killed sweep to resume: the
+chip tool brings back ``chiprun_out/`` alone).  ``--seqs 512 --tags kmask`` narrows a run.
 """
 import functools
 import json
@@ -63,13 +71,39 @@ def _timed_grad_step(fn, q, k, v, grad=True):
 
 
 def _sweep_blocks(seq):
-    """Block shapes worth seeing beside the rule's: 128 × 128 (every call's
-    blocks until PR 28), square tiles, and whole-key-range blocks (under
-    ``causal`` those prune nothing but run the one-pass backward)."""
-    sizes = [b for b in (128, 256, 512, 1024) if b <= seq and seq % b == 0]
-    cands = {(b, b) for b in sizes} | {(b, seq) for b in sizes}
+    """Block shapes worth seeing beside the rule's: the whole key range
+    under every query block (the one-pass backward; under ``causal`` those
+    prune nothing and still won, PR 28)."""
     # a 1024 × 1024 f32 score tile is 4 MiB a temporary: past VMEM
-    return sorted(c for c in cands if c[0] * c[1] <= 512 * 1024)
+    return [(b, seq) for b in (128, 256, 512, 1024)
+            if b <= seq and seq % b == 0 and b * seq <= 512 * 1024]
+
+
+def _pack(x):
+    """(B, H, S, D) → (B, S, H·D), the layout a projection leaves."""
+    from hetu_tpu.ops.attention import _merge_heads
+    return _merge_heads(x)
+
+
+def _as_layer(fn):
+    """The head-major entry ``fn`` as a layer called it before PR 44:
+    packed operands in, packed result out, a transpose each side."""
+    from hetu_tpu.ops.attention import _head_major
+    return lambda q, k, v: _head_major(fn, None, q, k, v, None, HEAD_DIM)
+
+
+def _max_diff(fn_a, fn_b, args_a, args_b):
+    """Largest |difference| over output and gradients of two entries,
+    ``fn_b``'s compared in ``fn_a``'s layout."""
+    import jax
+    import jax.numpy as jnp
+
+    def both(fn, args):
+        loss = lambda *a: jnp.sum(fn(*a).astype(jnp.float32) ** 2)  # noqa
+        return (fn(*args),) + jax.grad(loss, argnums=(0, 1, 2))(*args)
+    return max(float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                     - _pack(b).astype(jnp.float32))))
+               for a, b in zip(both(fn_a, args_a), both(fn_b, args_b)))
 
 
 def main(argv=None):
@@ -96,11 +130,8 @@ def main(argv=None):
         print(f"refusing flash A/B on the {backend} backend",
               file=sys.stderr)
         return 1
-    rows = {} if narrowed else _load_previous_rows(backend)
+    rows = {}
     for seq in args.seqs:
-        if str(seq) in rows:
-            print(f"seq {seq}: already measured (resumed)", flush=True)
-            continue
         b = max(1, TOKEN_BUDGET // seq)
         key = jax.random.PRNGKey(seq)
         kq, kk, kv = jax.random.split(key, 3)
@@ -108,6 +139,7 @@ def main(argv=None):
         q = jax.random.normal(kq, shape, jnp.bfloat16)
         k = jax.random.normal(kk, shape, jnp.bfloat16)
         v = jax.random.normal(kv, shape, jnp.bfloat16)
+        packed = tuple(_pack(x) for x in (q, k, v))
         row = {"batch": b}
         # padded-pretraining key mask (the FLAGSHIP bench path since round
         # 4): same length distribution as synthetic_mlm_batch
@@ -123,36 +155,46 @@ def main(argv=None):
             if tag not in args.tags:
                 continue
             causal = kw.get("causal", False)
-            # what the dispatcher runs: the rule's blocks
-            fl = _timed_grad_step(
-                functools.partial(flash_attention, **kw), q, k, v)
+
+            def flash(**at):
+                return functools.partial(flash_attention, **kw, **at)
+
+            # what the dispatcher runs: the rule's blocks — head-major,
+            # the same call packed, and head-major as a layer paid for it
+            fl = _timed_grad_step(flash(), q, k, v)
             row[f"flash_ms_{tag}"] = round(fl, 3)
-            row[f"flash_fwd_ms_{tag}"] = round(_timed_grad_step(
-                functools.partial(flash_attention, **kw), q, k, v,
-                grad=False), 3)
-            row[f"rule_{tag}"] = "%dx%d" % fa._pick_blocks(
-                seq, seq, HEAD_DIM, q.dtype.itemsize, causal)
-            sweep = {}
-            for bq, bk in _sweep_blocks(seq):
-                fn = functools.partial(flash_attention, block_q=bq,
-                                       block_k=bk, **kw)
-                sweep[f"{bq}x{bk}"] = [
-                    round(_timed_grad_step(fn, q, k, v, grad=False), 3),
-                    round(_timed_grad_step(fn, q, k, v), 3)]
-            row[f"sweep_{tag}"] = sweep         # [fwd ms, fwd+bwd ms]
+            rule = fa._pick_blocks(seq, seq, HEAD_DIM, q.dtype.itemsize,
+                                   causal)
+            row[f"rule_{tag}"] = "%dx%d" % rule
+            row[f"packed_ms_{tag}"] = round(
+                _timed_grad_step(flash(heads=HEADS), *packed), 3)
+            row[f"packed_rule_{tag}"] = "%dx%d" % fa._pick_blocks(
+                seq, seq, fa.packed_width(HEAD_DIM), q.dtype.itemsize,
+                causal)
+            row[f"layer_ms_{tag}"] = round(
+                _timed_grad_step(_as_layer(flash()), *packed), 3)
+            at = dict(block_q=rule[0], block_k=rule[1])
+            row[f"packed_max_diff_{tag}"] = _max_diff(
+                flash(heads=HEADS, **at), flash(**at), packed, (q, k, v))
+            # [fwd ms, fwd+bwd ms] head-major, then the same packed
+            row[f"sweep_{tag}"] = {
+                f"{bq}x{bk}": [
+                    round(_timed_grad_step(
+                        flash(block_q=bq, block_k=bk, **lay), *xs,
+                        grad=g), 3)
+                    for lay, xs in (({}, (q, k, v)),
+                                    ({"heads": HEADS}, packed))
+                    for g in (False, True)]
+                for bq, bk in _sweep_blocks(seq)}
             ref_kw = dict(causal=causal)
             if "key_mask" in kw:
                 ref_kw["mask"] = km[:, None, None, :]
             ref = functools.partial(sdpa_reference, **ref_kw)
             xl = _timed_grad_step(ref, q, k, v)
             row[f"xla_ms_{tag}"] = round(xl, 3)
-            row[f"xla_fwd_ms_{tag}"] = round(
-                _timed_grad_step(ref, q, k, v, grad=False), 3)
             row[f"winner_{tag}"] = "flash" if fl < xl else "xla"
         rows[str(seq)] = row
         print(f"seq {seq}: {json.dumps(row)}", flush=True)
-        if not narrowed:
-            _persist(backend, rows, partial=True)  # completion marked below
 
     if narrowed:
         # a narrowed run is a reading for PERF.md, not a gate: the gate's
@@ -167,39 +209,14 @@ def main(argv=None):
     return 0
 
 
-def _load_previous_rows(backend):
-    """Rows measured by an earlier KILLED sweep (partial=true) on the SAME
-    backend and measurement geometry — restarting from scratch would
-    re-lose them at the first persist.  Complete artifacts are never
-    resumed (a manual rerun means the caller wants fresh numbers), rows
-    from a different geometry or from a pre-kmask tool version (no
-    winner_kmask) are dropped so they get re-measured rather than
-    vacuously satisfying the both-must-win gate."""
-    path = os.path.join(ROOT, "artifacts", "flash_ab.json")
-    try:
-        with open(path) as f:
-            data = json.load(f)
-    except (OSError, json.JSONDecodeError):
-        return {}
-    if data.get("backend") != backend or not data.get("partial"):
-        return {}
-    if (data.get("heads"), data.get("head_dim"),
-            data.get("token_budget")) != (HEADS, HEAD_DIM, TOKEN_BUDGET):
-        return {}
-    return {seq: row for seq, row in data.get("rows", {}).items()
-            if "winner_kmask" in row}
-
-
 def _persist(backend, rows, partial):
-    """Write the artifact after EVERY measured seq (atomic): a run killed
-    mid-sweep must not lose the rows already measured."""
+    """Write the artifact (atomically)."""
     import jax
 
     measured = [s for s in SEQS if str(s) in rows]
     # gate rule: the smallest seq from which flash wins BOTH the dense AND
     # the key-mask case at every measured length >= it (kmask is the
-    # flagship padded-pretraining path; dense the generic one).  Partial
-    # artifacts carry a prefix-only gate: read it only once partial=false.
+    # flagship padded-pretraining path; dense the generic one).
     def _wins(s):
         row = rows[str(s)]
         # an absent kmask measurement is NOT a win — the flagship path
@@ -216,8 +233,8 @@ def _persist(backend, rows, partial):
     out = {
         "backend": backend,
         "device_kind": jax.devices()[0].device_kind,
-        # heads/head_dim/token_budget stay top-level (the resume check
-        # reads them); provenance embeds only sha + hash over them
+        # heads/head_dim/token_budget stay top-level (readers compare
+        # geometries by them); provenance embeds only sha + hash
         "heads": HEADS, "head_dim": HEAD_DIM,
         "token_budget": TOKEN_BUDGET,
         **provenance({"heads": HEADS, "head_dim": HEAD_DIM,
