@@ -227,6 +227,30 @@ def sparse_attn_call_counts():
     return _sparse_attn_calls.counts()
 
 
+# Which form of the Mamba-2 state update a decode graph traced
+# (``ops.ssd._ssd_chunk``): the one-token update (the state read once and
+# written once) or the chunk form in matrix products, and over what state.
+# Per TRACE, as the families above.
+_ssd_calls = REGISTRY.counter_family(
+    "ssd_calls",
+    "Mamba-2 state updates by form and state, "
+    "\"<ssd_step_calls|ssd_chunk_calls>:<heads>x<head dim>x<state dim>\" "
+    "(per jax trace)")
+
+
+def record_ssd_call(chunk, heads, head_dim, d_state):
+    """Count one traced Mamba-2 state update of a ``chunk``-column call."""
+    if counters_suppressed():
+        return
+    form = "ssd_step_calls" if chunk == 1 else "ssd_chunk_calls"
+    _ssd_calls.inc(f"{form}:{heads}x{head_dim}x{d_state}")
+
+
+def ssd_call_counts():
+    """{"<form>:<heads>x<head dim>x<state dim>": count} snapshot."""
+    return _ssd_calls.counts()
+
+
 # ---------------------------------------------- embedding Pallas fallbacks
 # The device-resident embedding-cache dispatchers
 # (``ops/pallas/emb_cache.py``) record WHY a gather / grad scatter-add
@@ -1287,6 +1311,7 @@ _FAMILIES = {
     "kv_append_calls": _kv_append_calls,
     "moe_calls": _moe_calls,
     "sparse_attn_calls": _sparse_attn_calls,
+    "ssd_calls": _ssd_calls,
     "emb_pallas_fallbacks": _emb_pallas,
     "faults": _faults,
     "elastic": _elastic,

@@ -36,3 +36,6 @@ from .glm4_moe_lite import (Glm4MoeLiteConfig, glm4_moe_lite_decode_graph,
 from .minicpm_sala import (MiniCPMSALAConfig, minicpm_sala_decode_graph,
                            minicpm_sala_decode_chunked_graph,
                            minicpm_sala_lm_graph)
+from .granite_hybrid import (GraniteHybridConfig, granite_hybrid_decode_graph,
+                             granite_hybrid_decode_chunked_graph,
+                             granite_hybrid_lm_graph)

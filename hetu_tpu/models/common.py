@@ -185,14 +185,17 @@ def moe_block(g, x, name):
 
 def build_decoder(cfg, layer, chunk, max_len, name, fed=True,
                   with_valid=True, embed_scale=1.0, logit_scale=1.0,
-                  chosen=None):
+                  chosen=None, tied_head=False):
     """The graph of ``cfg.num_hidden_layers`` blocks ``layer(g, x, i,
     name) -> x`` between the embedding and the head: ``(g, logits, greedy
     token ids, chosen expert ids)``.  ``fed=False``: zero states and
     position 0 (the full-sequence graph).  ``embed_scale`` / ``logit_scale``
     multiply the embedding and the logits (a muP-scaled model's);
     ``chosen(ids, *g.chosen) -> node`` stacks what the layers left in
-    ``g.chosen`` in place of ``ops.moe_choices_op``."""
+    ``g.chosen`` in place of ``ops.moe_choices_op`` (None where no layer
+    left anything).  ``tied_head``: the
+    head IS the embedding, ``logits = n(x) Eᵀ``, and no ``lm_head`` leaf
+    exists."""
     from ..graph.node import name_scope, placeholder_op
     b = cfg.batch_size
     ids = placeholder_op("input_ids", shape=(b, chunk), dtype=np.int32)
@@ -208,10 +211,9 @@ def build_decoder(cfg, layer, chunk, max_len, name, fed=True,
         g.feeds["positions"] = positions
     if valid is not None:
         g.feeds["valid"] = valid
+    embed = g.var(name + ".embed", (cfg.vocab_size, cfg.hidden_size))
     x = ops.array_reshape_op(                                # (B*C, d)
-        ops.embedding_lookup_op(
-            g.var(name + ".embed", (cfg.vocab_size, cfg.hidden_size)), ids,
-            dtype=np.float32),
+        ops.embedding_lookup_op(embed, ids, dtype=np.float32),
         output_shape=(-1, cfg.hidden_size))
     if embed_scale != 1.0:
         x = x * float(embed_scale)
@@ -219,14 +221,19 @@ def build_decoder(cfg, layer, chunk, max_len, name, fed=True,
         x = layer(g, x, i, f"{name}.l{i}")
     if chosen is not None:
         choices = chosen(ids, *g.chosen)
-    else:
+    elif g.chosen:
         with name_scope("moe.route"):
             choices = ops.moe_choices_op(ids, *g.chosen)
+    else:
+        choices = None                   # no layer chooses anything
     with name_scope("lm_head"):
         if valid is not None:
             x = ops.chunk_emit_gather_op(x, ids, valid)
-        logits = g.dense(g.norm(x, name + ".ln_f"), name + ".lm_head",
-                         cfg.hidden_size, cfg.vocab_size)
+        x = g.norm(x, name + ".ln_f")
+        logits = ops.matmul_op(x, embed, trans_B=True,
+                               out_dtype=np.float32) if tied_head \
+            else g.dense(x, name + ".lm_head", cfg.hidden_size,
+                         cfg.vocab_size)
         if logit_scale != 1.0:
             logits = logits * float(logit_scale)
         tokens = ops.greedy_token_op(logits)

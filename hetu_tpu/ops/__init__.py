@@ -54,6 +54,7 @@ from .ssm import (swiglu_op, silu_gate_op, greedy_token_op,
 from .kda import (rms_norm_op, sigmoid_gate_op, kda_chunk_op, kda_out_op,
                   head_norm_op, gqa_rows_op, gqa_attention_kv_op)
 from .lightning import lightning_chunk_op
+from .ssd import ssd_chunk_op, ssd_step_op
 from .sparse_attention import (pool_rows_op, sparse_attention_kv_op,
                                sparse_choices_op)
 from .mla import (rope_op, mla_latent_rows_op, mla_attention_kv_op,

@@ -171,12 +171,15 @@ def _gqa_rows(c, t, ids, head_dim=128):
 gqa_rows_op = def_op("GQARows", _gqa_rows)
 
 
-def _gqa_attention_kv(c, q, k_slab, v_slab, positions, ids, head_dim=128):
+def _gqa_attention_kv(c, q, k_slab, v_slab, positions, ids, head_dim=128,
+                      scale=None):
     """Causal softmax attention of a (B, C) chunk's queries over growable
     KV slabs that already hold the chunk's own rows
     (``kv_cache_append_op``), no positional term: query ``j`` of sequence
     ``b`` sees keys ``<= positions[b] + j``.  ``q``: (B*C, H * D); slabs
     (B, G, L/r, r * D); query head ``h`` reads key head ``h // (H // G)``.
+    ``scale`` multiplies the scores (``1/√D`` where none is given; a
+    muP-scaled model states its own).
 
     The one-token step on the chip (``C == 1``, no mesh, the decode gate of
     ``ops.attention``) hands the slabs AS STORED to the one-token kernel,
@@ -188,7 +191,8 @@ def _gqa_attention_kv(c, q, k_slab, v_slab, positions, ids, head_dim=128):
     b, chunk = ids.shape
     g, _, lanes = k_slab.shape[1:]
     pack = lanes // d
-    q = (_f32(q) * (d ** -0.5)).reshape(b, chunk, g, -1, d)
+    q = (_f32(q) * (d ** -0.5 if scale is None else float(scale))).reshape(
+        b, chunk, g, -1, d)
     r = q.shape[3]
     q = q.astype(k_slab.dtype)
     at = positions.astype(jnp.int32)

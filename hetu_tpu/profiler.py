@@ -230,7 +230,7 @@ class HetuProfiler:
         observability registry in one call (``hetu_tpu.metrics``
         ``all_counts``): flash_fallbacks, flash_calls,
         flash_head_major, decode_attn_calls, kv_append_calls, moe_calls,
-        sparse_attn_calls,
+        sparse_attn_calls, ssd_calls,
         emb_pallas_fallbacks, faults, elastic, autoparallel, cache, zero,
         step_cache, compile, setup_us, setup_bytes, run_plan, serve,
         decode, prefix_cache, decode_recovery, serve_rejection_reason,
@@ -339,6 +339,15 @@ class HetuProfiler:
         read of the whole slab (a chunk, the CPU).  Per trace."""
         from .metrics import sparse_attn_call_counts
         return sparse_attn_call_counts()
+
+    @staticmethod
+    def ssd_calls():
+        """{"<ssd_step_calls|ssd_chunk_calls>:<heads>x<head dim>x<state
+        dim>": count} of traced Mamba-2 state updates (``ops.ssd``): the
+        one-token update, which reads and writes the state once, or the
+        chunk form in matrix products.  Per trace."""
+        from .metrics import ssd_call_counts
+        return ssd_call_counts()
 
     @staticmethod
     def emb_pallas_fallbacks():

@@ -46,3 +46,17 @@ def pytest_configure(config):
         "end-to-end/interpret-mode parity tests whose core coverage a "
         "cheaper sibling already provides, plus multiprocess launcher "
         "tests that need more CPU than the 1.5-core CI box offers")
+
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _counters_start_at_zero():
+    """The program's counters are process-wide and its ``_hw`` gauges keep
+    the largest value seen: a file that reads a window's state BY KIND
+    (``decode_state_bytes_<kind>_hw``) would see the kinds of whatever file
+    its xdist worker ran before it — a ``ring`` in a cell that has none.
+    Which files share a worker moves with every file a PR adds."""
+    from hetu_tpu import metrics
+    metrics.reset_all()

@@ -189,6 +189,7 @@ def test_metrics_dump_roundtrips_every_counter_family():
     metrics.record_kv_append_call(16, 640, "kernel")
     metrics.record_moe_call(40, 320, 8)
     metrics.record_sparse_attn_call(96, 64, "kernel")
+    metrics.record_ssd_call(1, 64, 64, 128)
     metrics.record_fault("test_fault", 2)
     metrics.record_elastic("elastic_shrink")
     metrics.record_concurrency("concurrency_preemptions")
@@ -225,6 +226,7 @@ def test_metrics_dump_roundtrips_every_counter_family():
         "kv_append_calls": metrics.kv_append_call_counts(),
         "moe_calls": metrics.moe_call_counts(),
         "sparse_attn_calls": metrics.sparse_attn_call_counts(),
+        "ssd_calls": metrics.ssd_call_counts(),
         "emb_pallas_fallbacks": metrics.emb_pallas_fallback_counts(),
         "faults": metrics.fault_counts(),
         "elastic": metrics.elastic_counts(),
@@ -255,6 +257,7 @@ def test_metrics_dump_roundtrips_every_counter_family():
     assert legacy["kv_append_calls"] == {"16x640:kernel": 1}
     assert legacy["moe_calls"] == {"40of320:top8:ragged": 1}
     assert legacy["sparse_attn_calls"] == {"96x64:kernel": 1}
+    assert legacy["ssd_calls"] == {"ssd_step_calls:64x64x128": 1}
     assert legacy["faults"] == {"test_fault": 2}
     assert legacy["compile"] == {
         "serve:programs": 1, "serve:trace_us": 10, "serve:lower_us": 20,

@@ -518,6 +518,31 @@ def test_layers_lower_under_their_scopes(weights):
         assert f"/{scope}/" in text, scope
 
 
+def test_the_graph_is_the_same_program_with_the_softmax_scale_left_out(
+        weights, monkeypatch):
+    """``_gqa_attention_kv`` took a ``scale=`` for a muP-scaled model
+    (ISSUE 45); this graph names none and its nodes carry none: the step
+    lowers to the program it lowers to with ``1/√D`` spelled out."""
+    def lowered():
+        eng = _engine(weights, 0)
+        feeds = {eng._fk["input_ids"]: np.zeros((1, 1), np.int32),
+                 eng._fk["positions"]: np.zeros(1, np.int32)}
+        return jax.jit(eng._program(eng.iex, eng._fk)).lower(
+            eng.iex.params, (feeds, tuple(eng.caches.values())),
+            np.zeros(1, np.int32)).as_text()
+    from hetu_tpu.graph.node import topo_sort
+    cfg = SolarOpen2Config.tiny()
+    logits = solar_open2_decode_graph(cfg, MAX_LEN)[1]
+    reads = [n for n in topo_sort([logits])
+             if getattr(n, "op_type", "") == "GQAAttentionKV"]
+    assert reads and all("scale" not in n.attrs for n in reads)
+    plain = lowered()
+    real = kda.gqa_attention_kv_op
+    monkeypatch.setattr(kda, "gqa_attention_kv_op", lambda *a, **kw: real(
+        *a, scale=kw["head_dim"] ** -0.5, **kw))
+    assert lowered() == plain
+
+
 # ---------------------------------------------------------------- the ops
 
 def test_kda_step_is_the_delta_rule_written_out():

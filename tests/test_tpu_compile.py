@@ -590,13 +590,15 @@ def test_grouped_expert_product_compiles(chip, monkeypatch, rows, k, n):
 
 
 # ------------------------------------------- the Solar-Open2 cell's programs
-def _share_engine(config, traffic, system, graphs, **cfg_over):
+def _share_engine(config, traffic, system, graphs, aux="moe_choices",
+                  **cfg_over):
     """The engine of a served share as its system file builds it, from the
     cell's own configuration (``cfg_over`` laid over it) and mix, over
     ABSTRACT weights: billions of parameters are shapes here, never
     arrays.  ``system``: the module's name under ``benchmarks/systems``;
     ``graphs``: the names of the one-token and the chunked graph
-    constructor in ``hetu_tpu.models``."""
+    constructor in ``hetu_tpu.models``; ``aux``: the name of the auxiliary
+    fetch the graphs hand back last (None: they hand back none)."""
     import importlib
     import sys
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -619,13 +621,13 @@ def _share_engine(config, traffic, system, graphs, **cfg_over):
     mcfg = system.model_config(cfg, system.storage(cfg))
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(InferenceExecutor, "_load_weights", shapes_only)
-        f, lg, st, tok, ch = getattr(models, graphs[0])(mcfg, mix["max_len"])
-        cf, cl, cs, ctok, cch = getattr(models, graphs[1])(
+        f, lg, st, tok, *ch = getattr(models, graphs[0])(mcfg, mix["max_len"])
+        cf, cl, cs, ctok, *cch = getattr(models, graphs[1])(
             mcfg, mix["max_len"])
         eng = DecodeEngine(
-            f, lg, st, tokens=tok, aux={"moe_choices": ch},
+            f, lg, st, tokens=tok, aux=dict(zip([aux], ch)),
             max_slots=mix["max_slots"], max_len=mix["max_len"],
-            chunked=(cf, cl, cs, ctok, {"moe_choices": cch}),
+            chunked=(cf, cl, cs, ctok) + tuple({aux: c} for c in cch),
             max_chunk=mix["max_chunk"], validate="off")
     return eng, mix
 
@@ -912,6 +914,68 @@ def test_sala_cut_programs_fit_and_update_every_state_in_place(
     assert "flash_fwd_q1" not in text and "mla_fwd_q1" not in text
     assert not _slab_copies(text, slab)
     assert not re.findall(r"= f32\[64,32,128,128\]\S* copy\(", text)
+
+
+# ----------------------------------------- the Granite-4.0-H cell's programs
+@pytest.mark.parametrize("chunk", [1, 2, 32],
+                         ids=["one_token", "chunk2", "chunk32"])
+def test_granite_programs_fit_and_hold_no_loop_over_the_state(
+        chip, monkeypatch, chunk):
+    """ISSUE 45: the one-token and two chunked programs of the WHOLE model
+    (40 layers, 3.19 B bfloat16 parameters) at the cell's sizes (64 slots x
+    768 positions), compiled for the described chip as the engine jits them.
+    Weights (6.38 GB) and state (5.35 GB: 36 Mamba-2 states of 134 MB a layer
+    at 64 slots, their convolution windows, K and V slabs of four attention
+    layers) fit the chip; the one-token program reads each attention layer
+    through the one-token kernel at its new geometry (8 key heads a program,
+    4 score rows x 2 key rows each); every slab row is appended by the aliased
+    kernel; no program loops over a Mamba state (ROADMAP.md D23) or holds a
+    copy of one."""
+    import math
+    from hetu_tpu import metrics
+    eng, mix = _share_engine(
+        "granite4-h-micro", "chat-c64", "granite_hybrid_decode",
+        ("granite_hybrid_decode_graph",
+         "granite_hybrid_decode_chunked_graph"), aux=None)
+    iex, keys = (eng.iex, eng._fk) if chunk == 1 else (eng.ciex, eng._cfk)
+    b, length = mix["max_slots"], mix["max_len"]
+    dims = functools.partial(_state_dims, eng, b, length // 2)  # pack 2
+    kinds = [eng._kinds[n] for n in eng.cache_names]
+    assert [kinds.count(k) for k in ("kv", "recurrent")] == [8, 72] \
+        and len(kinds) == 80
+    slab, state = (64, 8, 384, 128), (64, 64, 64, 128)
+    assert dims("k_cache_5") == (slab, jnp.dtype(jnp.bfloat16))
+    assert dims("ssd_0") == (state, jnp.dtype(jnp.float32))
+    assert dims("conv_0") == ((64, 3, 4352), jnp.dtype(jnp.float32))
+    recurrent = sum(math.prod(dims(n)[0]) * 4 for n in eng._recurrent)
+    assert recurrent == 64 * 36 * (2 ** 21 + 3 * 4352 * 4)   # 77.4 MB a slot
+    assert sum(math.prod(d) * t.itemsize
+               for d, t in map(dims, eng.cache_names)) == 5354815488
+    feeds = {"input_ids": ((b, chunk), jnp.int32),
+             "positions": ((b,), jnp.int32)}
+    if chunk > 1:
+        feeds["valid"] = ((b,), jnp.int32)
+    params = {k: chip(v.shape, v.dtype) for k, v in iex.params.items()}
+    assert sum(v.size for v in params.values()) == 3191396096
+    fed = ({keys[name]: chip(d, t) for name, (d, t) in feeds.items()},
+           tuple(chip(*dims(n)) for n in eng.cache_names))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    form = "ssd_step_calls" if chunk == 1 else "ssd_chunk_calls"
+    before = (metrics.decode_attn_call_counts().get("8x384", 0),
+              metrics.ssd_call_counts().get(form + ":64x64x128", 0))
+    compiled = jax.jit(eng._program(iex, keys), donate_argnums=(1,)).lower(
+        params, fed, chip((b,), jnp.int32)).compile()
+    text, peak = compiled.as_text(), _peak(compiled)
+    assert 11.7e9 < peak < 12.8e9, peak                      # of 16 GB
+    assert _appends_in_place(text) == 8          # K and V of four layers
+    assert metrics.decode_attn_call_counts().get("8x384", 0) \
+        == before[0] + 4 * (chunk == 1)
+    assert metrics.ssd_call_counts()[form + ":64x64x128"] == before[1] + 36
+    assert ("flash_fwd_q1" in text) == (chunk == 1)
+    assert not _loops(text)
+    assert not _slab_copies(text, slab)
+    assert not re.findall(r"= f32\[64,64,64,128\]\S* copy\(", text)
+    assert "/mix.ssm/ssd.update/" in text
 
 
 # ------------------------------------------------------------ moe dispatch

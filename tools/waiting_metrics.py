@@ -4,7 +4,8 @@
         --seed 4200000301 --seconds 51
 
 ``benchmarks/metrics_waiting/`` holds per-layer readers and their entries
-(``entries.json``) that ``BENCHMARK.json`` cannot list yet (PERF.md §7: an
+(``entries.json``; a later cell's in a sub-directory of its own, ``--waiting
+granite4-h-micro``) that ``BENCHMARK.json`` cannot list yet (PERF.md §7: an
 entry goes at the end of ``per_layer``, and a test the benchmark owns pins
 another cell's entries there).  This builds, under ``.bench_out/``, the root a
 ``benchmark`` PR would make — the readers beside the others, the entries
@@ -26,11 +27,13 @@ os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
                       os.path.join(ROOT, ".jax_cache"))
 
 
-def build_root(dest):
+def build_root(dest, sub=""):
     """``dest``: ``BENCHMARK.json`` with the waiting entries appended, and
-    ``benchmarks/`` with the waiting readers in ``metrics/``."""
+    ``benchmarks/`` with the waiting readers in ``metrics/``.  ``sub``: the
+    sub-directory of ``metrics_waiting/`` whose readers wait (its own
+    ``entries.json``); the directory itself by default."""
     bench_dir = os.path.join(ROOT, "benchmarks")
-    waiting = os.path.join(bench_dir, "metrics_waiting")
+    waiting = os.path.join(bench_dir, "metrics_waiting", sub)
     shutil.rmtree(dest, ignore_errors=True)
     shutil.copytree(bench_dir, os.path.join(dest, "benchmarks"),
                     ignore=shutil.ignore_patterns("__pycache__"))
@@ -54,9 +57,12 @@ def main(argv):
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--waiting", default="",
+                    help="sub-directory of benchmarks/metrics_waiting/")
     args = ap.parse_args(argv)
     out = os.path.join(ROOT, ".bench_out")
-    files = harness.Files(build_root(os.path.join(out, "waiting_root")))
+    files = harness.Files(build_root(os.path.join(out, "waiting_root"),
+                                     args.waiting))
     try:
         result = harness.run_cell(args.workload, args.seed, args.seconds,
                                   True, files=files, t_start=T_START,
