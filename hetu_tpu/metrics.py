@@ -143,15 +143,17 @@ def flash_head_major_counts():
 # slabs whole through the jnp path.
 _decode_attn_calls = REGISTRY.counter_family(
     "decode_attn_calls",
-    "one-token KV-slab attention calls by geometry, "
-    "\"<heads per program>x<block rows>\" (per jax trace)")
+    "KV-slab attention calls by geometry, "
+    "\"<heads per program>x<block rows>[:c<chunk>]\" (per jax trace)")
 
 
-def record_decode_attn_call(heads, block_rows):
-    """Count one traced one-token attention call by its geometry."""
+def record_decode_attn_call(heads, block_rows, chunk=1):
+    """Count one traced one-token attention call by its geometry, a
+    chunk's (``chunk > 1``) by its positions behind it: ``"16x128:c32"``."""
     if counters_suppressed():
         return
-    _decode_attn_calls.inc(f"{heads}x{block_rows}")
+    _decode_attn_calls.inc(f"{heads}x{block_rows}"
+                           + (f":c{chunk}" if chunk > 1 else ""))
 
 
 def decode_attn_call_counts():

@@ -242,7 +242,7 @@ def _block_decode_chunked(cfg, x, ids, k_cache, v_cache, positions, valid,
     q = heads(lq(h))
     kc = ops.kv_cache_append_op(k_cache, heads(lk(h)), positions, valid)
     vc = ops.kv_cache_append_op(v_cache, heads(lv(h)), positions, valid)
-    att = ops.sdpa_prefill_op(q, kc, vc, positions)      # (B, H, C, dk)
+    att = ops.sdpa_prefill_op(q, kc, vc, positions, valid)  # (B, H, C, dk)
     att = ops.merge_heads_chunk_op(att)                  # (B*C, n_embd)
     x = x + lo(att)
     h = LayerNorm(cfg.n_embd, cfg.layer_norm_epsilon, name + ".ln2")(x)
@@ -313,6 +313,9 @@ def gpt2_decode_chunked_graph(cfg, max_len=None, chunk=4, name="gpt2"):
             f"k_cache_{i}", cfg.batch_size, cfg.n_head, max_len, dk)
         vc = ops.kv_slab_placeholder(
             f"v_cache_{i}", cfg.batch_size, cfg.n_head, max_len, dk)
+        # ``sdpa_prefill_op`` reads a slab as far as its sequence reaches
+        # (what ``DecodeEngine._kv_rows`` counts a chunked step by)
+        kc.attrs["chunk_read"] = vc.attrs["chunk_read"] = "live"
         feeds[f"k_cache_{i}"] = kc
         feeds[f"v_cache_{i}"] = vc
         x, kc2, vc2, layer = _block_decode_chunked(

@@ -546,10 +546,11 @@ def kv_slab_from_rows(rows, lanes):
 def kv_slab_to_rows(slab, head_dim):
     """(..., n, r * D) slab rows -> the (..., n * r, D) key rows they
     hold.  A relayout on the device where ``r > 1``: for snapshots, tests
-    and the one step that amortises it (a prefill chunk that tiles the
-    flash kernel), never for a whole slab in a one-token step — both
-    one-token callers (``dispatch_sdpa_decode`` and ``ops/ssm.py``'s
-    ``_diff_attention_kv``) hand their slabs to the kernel as stored."""
+    and the one step that amortises it (a prefill chunk of 128 rows, which
+    tiles the flash kernel), never for a whole slab in a one-token step or
+    a shorter chunk's — ``dispatch_sdpa_decode``, ``dispatch_sdpa_prefill``
+    and ``ops/ssm.py``'s ``_diff_attention_kv`` hand their slabs to the
+    kernel as stored."""
     n, lanes = slab.shape[-2:]
     return slab.reshape(*slab.shape[:-2], n * (lanes // head_dim), head_dim)
 
@@ -563,6 +564,21 @@ def kv_slab_queries(q, pack):
     eye = jnp.eye(pack, dtype=q.dtype)
     rows = eye[:, :, None] * q[..., None, None, :]       # (..., r, r, D)
     return rows.reshape(*q.shape[:-1], pack, pack * q.shape[-1])
+
+
+def kv_slab_chunk_rows(q, pack):
+    """(B, H, C, D) queries of a chunk -> the (B, H, C * r, r * D) score
+    rows of the slab kernel's chunk form, a query's ``r`` rows together:
+    :func:`kv_slab_queries` with its two row axes merged, made by ``r``
+    pads and a stack.  In front of a kernel XLA lays the eye-product out
+    in a ``(2, 128)``-tiled buffer and relays it out afterwards: at
+    docqa-c16's shape the call with its rows made so took 0.131 ms where
+    it takes 0.113 this way (PERF.md §6, PR 46)."""
+    b, h, chunk, d = q.shape
+    rows = jnp.stack(
+        [jnp.pad(q, ((0, 0),) * 3 + ((j * d, (pack - 1 - j) * d),))
+         for j in range(pack)], axis=3)                # (B, H, C, r, r * D)
+    return rows.reshape(b, h, chunk * pack, pack * d)
 
 
 def sdpa_slab_reference(q, k_slab, v_slab, lengths, scale=None):
@@ -619,24 +635,27 @@ def kv_rows_read(lengths, slab_shape, pack, itemsize):
     every row the slabs hold on the jnp path.  No counter reads it: what
     the kernel copies of those blocks is :func:`kv_rows_fetched` (kept
     for ``tests/bench_harness``, PERF.md §7)."""
-    return _kv_rows(lengths, slab_shape, pack, itemsize, whole=True)
+    return _kv_rows(lengths, slab_shape, pack, itemsize, 1, whole=True)
 
 
-def kv_rows_fetched(lengths, slab_shape, pack, itemsize):
-    """Key rows a ONE-TOKEN step's attention FETCHES of those slabs: of a
+def kv_rows_fetched(lengths, slab_shape, pack, itemsize, chunk=1):
+    """Key rows a step's attention FETCHES of those slabs through the
+    kernel — a one-token step's, or a step of ``chunk`` positions a
+    sequence through the chunk form (``dispatch_sdpa_prefill``),
+    ``lengths`` then where the rows each sequence wrote end: of a
     sequence's last live block only the rows below its length, rounded up
     to what one copy moves (``decode_attention._tail``).  Host arithmetic
     over shapes — what ``DecodeEngine`` counts a step by
     (``decode_kv_rows_read``)."""
-    return _kv_rows(lengths, slab_shape, pack, itemsize, whole=False)
+    return _kv_rows(lengths, slab_shape, pack, itemsize, chunk, whole=False)
 
 
-def _kv_rows(lengths, slab_shape, pack, itemsize, whole):
+def _kv_rows(lengths, slab_shape, pack, itemsize, chunk, whole):
     b, heads, slab_rows, lanes = slab_shape
-    if _decode_gate_reason(slab_rows * pack) is not None:
+    if _slab_gate_reason(slab_shape, pack, itemsize, chunk) is not None:
         return b * slab_rows * pack
     from .pallas.decode_attention import _tail, geometry
-    block = geometry(heads, slab_rows, lanes, itemsize)[1]
+    block = geometry(heads, slab_rows, lanes, itemsize, 2, chunk * pack)[1]
     unit = block if whole else _tail(block, itemsize)[0] or block
     rows = -(-np.clip(lengths, 1, slab_rows * pack) // pack)
     return int((-(-rows // unit) * unit).sum()) * pack
@@ -762,27 +781,23 @@ def _kv_append_loop(cache, new, positions, count):
 kv_cache_append_op = def_op("KVCacheAppend", _kv_cache_append)
 
 
-def _prefill_gate_reason(q, k_cache):
-    """Why a chunked-prefill step leaves the flash path (None =
-    flash-able).  Like the decode gate it keys on the KV-cache length
-    (the tiled axis); additionally the per-batch position offsets mean
-    kernel-causal (bottom-right-aligned diagonal) cannot express the
-    mask, so the kernel is entered through its full-mask path — legal
-    only when q_len also tiles."""
-    be = jax.default_backend()
-    if be != "tpu":
-        return f"backend:{be}"
-    s_kv = _slab_len(q, k_cache)
-    if s_kv < _FLASH_MIN_LEN:
-        return f"prefill_below_gate:kv{s_kv}<{_FLASH_MIN_LEN}"
-    if s_kv % 128:
-        return f"prefill_kv_ragged:kv{s_kv}"
-    if q.shape[-2] % 128 and q.shape[-2] != s_kv:
-        return f"prefill_chunk_ragged:q{q.shape[-2]}"
-    return None
+def _slab_gate_reason(slab_shape, pack, itemsize, chunk):
+    """Why the attention of ``chunk`` positions a sequence over K and V
+    slabs of ``slab_shape`` (``pack`` key rows a slab row) leaves the
+    slab kernel (None = kernel-able): the one-token read's gate, and the
+    chunk's score rows beside a key block have to fit the kernel's VMEM
+    (``decode_attention.fits``; every chunk of an engine's ladder does)."""
+    _, heads, slab_rows, lanes = slab_shape
+    reason = _decode_gate_reason(slab_rows * pack)
+    if reason is None and chunk > 1:
+        from .pallas.decode_attention import fits
+        if not fits(heads, slab_rows, lanes, itemsize, 2, chunk * pack):
+            reason = f"prefill_chunk_rows:q{chunk}"
+    return reason
 
 
-def dispatch_sdpa_prefill(q, k_cache, v_cache, positions, scale=None):
+def dispatch_sdpa_prefill(q, k_cache, v_cache, positions, valid=None,
+                          scale=None):
     """A chunked prefill step against a bucketed KV cache — the q_len=C
     generalization of ``dispatch_sdpa_decode`` (ISSUE 18).
 
@@ -792,35 +807,54 @@ def dispatch_sdpa_prefill(q, k_cache, v_cache, positions, scale=None):
     ``kv_cache_append_op``).  ``positions``: (B,) int — the cache row of
     each sequence's FIRST chunk token; chunk-local query j may see keys
     ``< positions+j+1`` (causal-within-chunk, everything before the
-    chunk visible).  Chunks below 128 rows — every chunk of the engine's
-    default ladder — take the counted jnp reference, which reads the
-    slabs as stored.  A chunk that tiles goes to the flash kernel's
-    full-mask path (kernel-causal can't shift its diagonal per batch
-    row), which wants (L, D) rows: a packed slab is unpacked for it, a
-    whole-slab relayout that a 128-row chunk amortises.  Rows past a
-    sequence's real prompt are masked by the CALLER's cache-write
-    ``valid`` and sliced away by the emit gather — their outputs are
-    don't-cares here."""
+    chunk visible).  ``valid``: (B,) int or None — the chunk rows each
+    sequence really took, as the append was told.
+
+    On the TPU, over a slab the one-token read's gate lets in
+    (:func:`_decode_gate_reason`), a chunk is the one-token kernel's chunk
+    form (:func:`~hetu_tpu.ops.pallas.decode_attention.decode_attention`,
+    ``chunk=C``): the slabs read as stored, only the key blocks below
+    where a sequence's ``valid`` rows end fetched, ``C * r`` score rows a
+    head with a causal limit each.  A chunk of whole 128-row tiles keeps
+    the flash kernel's full-mask path (kernel-causal can't shift its
+    diagonal per batch row), which wants (L, D) rows: a packed slab is
+    unpacked for it, a whole-slab relayout that a 128-row chunk amortises.
+    Everything else — every other backend, a short length bucket — is the
+    counted jnp reference, which reads the slabs whole as stored, and is
+    what the kernel is held to.  Rows past a sequence's real prompt are
+    masked by the CALLER's cache-write ``valid`` and sliced away by the
+    emit gather — their outputs are don't-cares here."""
     chunk, d = q.shape[-2:]
-    lengths = (positions.astype(jnp.int32)[:, None]
+    positions = positions.astype(jnp.int32)
+    lengths = (positions[:, None]
                + 1 + jnp.arange(chunk, dtype=jnp.int32)[None, :])  # (B, C)
-    reason = _prefill_gate_reason(q, k_cache)
-    if reason is None:
+    pack = k_cache.shape[-1] // d
+    reason = _slab_gate_reason(k_cache.shape, pack, k_cache.dtype.itemsize,
+                               chunk)
+    if reason is None and chunk % 128 == 0:
         from .pallas.flash_attention import flash_attention
         cols = jnp.arange(_slab_len(q, k_cache), dtype=jnp.int32)
         mask = cols[None, None, None, :] < lengths[:, None, :, None]
         return flash_attention(q, kv_slab_to_rows(k_cache, d),
                                kv_slab_to_rows(v_cache, d), causal=False,
                                scale=scale, mask=mask)
+    if reason is None:
+        from .pallas.decode_attention import decode_attention
+        scale = scale if scale is not None else 1.0 / (d ** 0.5)
+        rows = kv_slab_chunk_rows(q * scale, pack)
+        return decode_attention(rows.astype(k_cache.dtype), k_cache, v_cache,
+                                positions + 1, pack=pack, chunk=chunk,
+                                count=valid).astype(q.dtype)
     _note_flash_fallback(reason)
     return sdpa_slab_reference(q, k_cache, v_cache, lengths, scale=scale)
 
 
-def _sdpa_prefill(c, q, k_cache, v_cache, positions, scale=None):
+def _sdpa_prefill(c, q, k_cache, v_cache, positions, valid=None, scale=None):
+    extras = (positions,) if valid is None else (positions, valid)
     return _partitioned(
-        c, lambda q, k, v, pos: dispatch_sdpa_prefill(q, k, v, pos,
-                                                      scale=scale),
-        q, k_cache, v_cache, positions)
+        c, lambda q, k, v, pos, *valid: dispatch_sdpa_prefill(
+            q, k, v, pos, *valid, scale=scale),
+        q, k_cache, v_cache, *extras)
 
 
 sdpa_prefill_op = def_op("ScaledDotProductAttentionPrefill", _sdpa_prefill)

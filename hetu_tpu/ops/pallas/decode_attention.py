@@ -24,6 +24,11 @@ fourth, ``ops/mla.py``'s latent read, is the latent mode below):
   ``[k1; k2]``): the four heads that read a key pair are four rows, each
   laid in its own 64-lane half.
 
+A chunked step's ``C`` positions a sequence are the same call's CHUNK
+form (``chunk=C``; ``dispatch_sdpa_prefill``, GPT-2's): ``C * r`` score
+rows a head, the schedule and the last block's copy run to where the rows
+the step appended end, and each row masked at its own position's limit.
+
 Every score row keeps its own online softmax (running max, sum and
 ``P @ V`` accumulator, float32); the ``r`` rows of a query are merged at
 the end by their log-sum-exp, ``out = sum_j e^(m_j - m) acc_j[lanes j] /
@@ -84,6 +89,9 @@ BLOCK_BYTES = 2560 * 1024
 #: reverse (glm's call 0.41 ms at 2, 0.34 at 3, 0.325 at 4: 10 of the 16
 #: MiB a kernel may use)
 DEPTH = 4
+#: what a kernel's buffers may take of the 16 MiB of VMEM it is given, the
+#: compiler's own temporaries beside them
+VMEM_BYTES = 14 * 1024 * 1024
 #: slab rows to a sub-block: the products of a sequence's last block stop
 #: at the first sub-block boundary past its rows (at 128 twice the
 #: branches and 1 to 7 % slower)
@@ -92,23 +100,45 @@ SUB_ROWS = 256
 MIN_BLOCK_ROWS = 64
 
 
-def geometry(heads, slab_rows, lanes, itemsize, slabs=2):
-    """``(heads per program, slab rows per key block)`` of a call over
-    ``slabs`` slabs of ``(B, heads, slab_rows, lanes)``: every head of a
-    slot in one program while a block of ``MIN_BLOCK_ROWS`` rows of them
-    fits ``BLOCK_BYTES`` (else their largest divisor that does), and the
-    largest sublane-aligned divisor of the slab's rows that keeps the
-    block inside it."""
+def _fit(hb, rows, lanes, itemsize, slabs):
+    """Slab rows the key block of a program of ``hb`` heads and ``rows``
+    score rows a head may have: ``BLOCK_BYTES`` of them over the call's
+    slabs, and no more than ``VMEM_BYTES`` leave beside what the score
+    rows hold — the queries (two buffers), the running max, sum and
+    ``P @ V`` (float32), and a score and a weight a key row — for
+    ``DEPTH`` blocks."""
     row = lanes * itemsize * slabs
+    held = hb * rows * (2 * lanes * itemsize + 4 * (2 * 128 + lanes))
+    return min(BLOCK_BYTES // (hb * row),
+               (VMEM_BYTES - held) // (hb * (DEPTH * row + 8 * rows)))
+
+
+def geometry(heads, slab_rows, lanes, itemsize, slabs=2, rows=8):
+    """``(heads per program, slab rows per key block)`` of a call of
+    ``rows`` score rows a head over ``slabs`` slabs of ``(B, heads,
+    slab_rows, lanes)``: every head of a slot in one program while a
+    block of ``MIN_BLOCK_ROWS`` rows of them fits (:func:`_fit`; else
+    their largest divisor that does), and the largest sublane-aligned
+    divisor of the slab's rows that keeps the block inside it.  The few
+    score rows of a one-token call leave ``BLOCK_BYTES`` the bound; a
+    chunk's ``C * pack`` take their share of VMEM first."""
     floor = min(slab_rows, MIN_BLOCK_ROWS)
     hb = max((h for h in range(1, heads + 1)
-              if heads % h == 0 and h * floor * row <= BLOCK_BYTES),
+              if heads % h == 0
+              and _fit(h, rows, lanes, itemsize, slabs) >= floor),
              default=1)
     tile = 32 // itemsize
-    fit = BLOCK_BYTES // (hb * row)
-    rows = max((r for r in range(tile, min(fit, slab_rows) + 1, tile)
-                if slab_rows % r == 0), default=slab_rows)
-    return hb, rows
+    fit = _fit(hb, rows, lanes, itemsize, slabs)
+    block = max((r for r in range(tile, min(fit, slab_rows) + 1, tile)
+                 if slab_rows % r == 0), default=slab_rows)
+    return hb, block
+
+
+def fits(heads, slab_rows, lanes, itemsize, slabs=2, rows=8):
+    """Whether such a call's key block is one :func:`_fit` allows: score
+    rows too many for one head's program leave it none."""
+    hb, block = geometry(heads, slab_rows, lanes, itemsize, slabs, rows)
+    return block <= _fit(hb, rows, lanes, itemsize, slabs)
 
 
 def _tail(block_k, itemsize):
@@ -129,15 +159,22 @@ def _init(m_scr, l_scr, acc_scr):
     acc_scr[...] = jnp.zeros_like(acc_scr)
 
 
-def _block(q, k, v, first, length, m_scr, l_scr, acc_scr, pack):
+def _block(q, k, v, first, length, m_scr, l_scr, acc_scr, pack, chunk=None):
     """Some key rows into the running softmax of every score row.  ``q``:
     (heads, rows, lanes); ``k`` / ``v``: (heads, n, lanes), slab rows
-    ``first ...``."""
+    ``first ...``.  ``chunk``: None, every row sees the keys below
+    ``length``; else ``(start, per)``, the rows are a chunk's, ``per`` a
+    position: row ``i`` sees the keys below ``start + i // per``, and none
+    at or past ``length``, where the rows the step wrote end."""
     s = jax.lax.dot_general(
         q, k, (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32)          # (heads, rows, block_k)
-    row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+    shape = s.shape if chunk is None else (1,) + s.shape[1:]
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 2)
+    if chunk is not None:
+        start, per = chunk
+        length = jnp.minimum(start + jax.lax.div(row, per), length)
     # row i, column m scores key (first + m)*r + i % r
     _fold(s, (col + first) * pack + row % pack < length, v, m_scr, l_scr,
           acc_scr)
@@ -195,6 +232,20 @@ def _finish(o_ref, m_scr, l_scr, acc_scr, pack):
             num / jnp.where(den == 0.0, 1.0, den)).astype(o_ref.dtype)
 
 
+def _finish_chunk(o_ref, m_scr, l_scr, acc_scr, pack):
+    """:func:`_finish` of a chunk's many queries at once: row ``j`` of
+    every query is a strided read of the scratch."""
+    queries, d = o_ref.shape[-2:]
+    at = [pl.ds(j, queries, stride=pack) for j in range(pack)]
+    m = [m_scr[:, i, :][:, :, :1] for i in at]       # (heads, queries, 1)
+    top = functools.reduce(jnp.maximum, m)
+    w = [jnp.exp(mj - top) for mj in m]
+    den = sum(wj * l_scr[:, i, :][:, :, :1] for wj, i in zip(w, at))
+    num = sum(wj * acc_scr[:, i, :][:, :, j * d:(j + 1) * d]
+              for j, (wj, i) in enumerate(zip(w, at)))
+    o_ref[...] = (num / jnp.where(den == 0.0, 1.0, den)).astype(o_ref.dtype)
+
+
 def _live_rows(len_ref, slot, ki, block_k, pack, tile):
     """Slab rows a copy moves of key block ``ki`` of ``slot``: the block's
     rows below the sequence's length, rounded up to a sublane tile
@@ -229,7 +280,8 @@ def _zero_past(buf, place, rows, sub):
             row < rows - j, blk, jnp.zeros_like(blk))
 
 
-def _products(q, bufs, place, rows, first, length, scr, pack, v_lanes, sub):
+def _products(q, bufs, place, rows, first, length, scr, pack, v_lanes, sub,
+              chunk=None):
     """The ``rows`` copied rows at ``place`` of the ring (slab rows
     ``first ...``) into the running softmax: ONE product over as
     many sub-blocks of ``sub`` rows as hold a copied row — a product a
@@ -245,15 +297,22 @@ def _products(q, bufs, place, rows, first, length, scr, pack, v_lanes, sub):
             k = kbuf[place, :, :n * sub, :]
             v = (k[:, :, :v_lanes] if v_lanes
                  else vbuf[place, :, :n * sub, :])
-            _block(q, k, v, first, length, *scr, pack)
+            _block(q, k, v, first, length, *scr, pack, chunk)
 
 
-def _kernel(len_ref, slot_ref, blk_ref, q_ref, *rest, pack, v_lanes, tile,
-            sub):
-    """``rest``: the slabs where they lie (K, V — or, in the latent mode
-    (``v_lanes``), ONE: the value is the first ``v_lanes`` lanes of the key
-    block already in VMEM), the output, then the scratch: a ring of block
-    buffers a slab, their semaphores, and the running softmax."""
+def _kernel(len_ref, slot_ref, blk_ref, *rest, pack, v_lanes, tile, sub,
+            per):
+    """``rest``: in the chunk form (``per``: the score rows a chunk
+    position, None for one token's) a fourth scalar, the
+    keys the FIRST position of each slot's chunk sees (``len_ref`` is then
+    where the rows the step wrote end: the schedule's and the copies'
+    length); the score rows; the slabs where they lie (K, V — or, in the
+    latent mode (``v_lanes``), ONE: the value is the first ``v_lanes``
+    lanes of the key block already in VMEM), the output, then the scratch:
+    a ring of block buffers a slab, their semaphores, and the running
+    softmax."""
+    start_ref = rest[0] if per else None
+    q_ref, *rest = rest[bool(per):]
     n = 1 if v_lanes else 2
     slabs, o_ref, bufs = rest[:n], rest[n], rest[n + 1:2 * n + 1]
     sems, *scr = rest[2 * n + 1:]
@@ -293,10 +352,11 @@ def _kernel(len_ref, slot_ref, blk_ref, q_ref, *rest, pack, v_lanes, tile,
     for c in mine:
         c.wait()
     _products(q_ref[...], bufs, jax.lax.rem(at, depth), rows, ki * block_k,
-              length, scr, pack, v_lanes, sub)
+              length, scr, pack, v_lanes, sub,
+              (start_ref[slot_ref[t]], per) if per else None)
     # the slot's last live block
     pl.when(ki == (length - 1) // (block_k * pack))(
-        lambda: _finish(o_ref, *scr, pack))
+        lambda: (_finish_chunk if per else _finish)(o_ref, *scr, pack))
 
 
 def _schedule(lengths, keys_per_block, num_kv):
@@ -313,7 +373,7 @@ def _schedule(lengths, keys_per_block, num_kv):
 
 
 def decode_attention(rows, k_slab, v_slab, lengths, pack=1,
-                     interpret=False, v_lanes=None):
+                     interpret=False, v_lanes=None, chunk=1, count=None):
     """Attention of a few query rows per (sequence, KV head) over KV slabs.
 
     ``rows``: (B, H, n, lanes) score rows, scaled, in the slabs' dtype —
@@ -328,6 +388,19 @@ def decode_attention(rows, k_slab, v_slab, lengths, pack=1,
     reads NaN until written (the CPU tests exercise the same body; a
     ``pltpu.InterpretParams`` is passed through).
 
+    **The chunk form** (``chunk = C > 1``; ``dispatch_sdpa_prefill``): the
+    rows are those of ``C`` consecutive positions, ``n / C`` to a position
+    and a position's together; ``lengths`` is what the FIRST sees, and row
+    ``i`` sees the keys below ``lengths[b] + i // (n / C)``.  ``count``:
+    (B,) int, the positions of the chunk a sequence really took (its
+    append wrote), clamped to ``[1, C]``, ``C`` where None: the schedule
+    and the last block's copy end at ``lengths[b] + count[b] - 1`` and no
+    row sees a key at or past it, so a row past ``count`` — a don't-care
+    of the caller's — reads what the last real one does, a finite number.
+    The same kernel over the same schedule; the trace knows the call as
+    ``flash_fwd_qc``, ``decode_attn_calls`` by ``:c<C>`` behind its
+    geometry.
+
     **The latent mode** (``v_slab=None``, ``v_lanes``, ``pack == 1``;
     ``ops/mla.py``): a cache row is a key whose first ``v_lanes`` lanes are
     also its value, so the one slab is fetched once — a second operand of
@@ -338,6 +411,9 @@ def decode_attention(rows, k_slab, v_slab, lengths, pack=1,
     if latent and (pack != 1 or not v_lanes):
         raise ValueError("the latent mode reads plain rows (pack 1) and "
                          "needs v_lanes")
+    if chunk < 1 or n % (chunk * pack):
+        raise ValueError(f"{n} score rows are no {chunk} positions of "
+                         f"queries of {pack} rows")
     if lanes % 128:
         # plain rows narrower than a lane row (no caller stores them so:
         # ``kv_slab_shape`` packs them) lie padded in HBM, where a copy
@@ -347,17 +423,21 @@ def decode_attention(rows, k_slab, v_slab, lengths, pack=1,
         wide = (lambda x: x if x is None else jnp.pad(
             x, ((0, 0),) * 3 + ((0, -lanes % 128),)))
         out = decode_attention(wide(rows), wide(k_slab), wide(v_slab),
-                               lengths, 1, interpret, v_lanes)
+                               lengths, 1, interpret, v_lanes, chunk, count)
         return out if latent else out[..., :lanes]
     slabs = (k_slab,) if latent else (k_slab, v_slab)
     itemsize = k_slab.dtype.itemsize
-    hb, block_k = geometry(h, k_slab.shape[2], lanes, itemsize, len(slabs))
+    hb, block_k = geometry(h, k_slab.shape[2], lanes, itemsize, len(slabs),
+                           n)
     from ...metrics import record_decode_attn_call
-    record_decode_attn_call(hb, block_k)
-    return _call(jnp.asarray(lengths, jnp.int32), rows, *slabs, hb=hb,
+    record_decode_attn_call(hb, block_k, chunk)
+    count = (None if chunk == 1
+             else jnp.full((b,), chunk, jnp.int32) if count is None
+             else jnp.asarray(count, jnp.int32))
+    return _call(jnp.asarray(lengths, jnp.int32), count, rows, *slabs, hb=hb,
                  block_k=block_k, tail=_tail(block_k, itemsize), depth=DEPTH,
                  pack=pack, v_lanes=int(v_lanes) if latent else None,
-                 interpret=interpret)
+                 chunk=int(chunk), interpret=interpret)
 
 
 # jitted so that the calls of one program that share a shape — every
@@ -365,29 +445,39 @@ def decode_attention(rows, k_slab, v_slab, lengths, pack=1,
 # — are traced and lowered to ONE kernel: lowered one by one, its branches
 # added 4.5 s to every start of the glm cell (PERF.md section 6, PR 41)
 @functools.partial(jax.jit, static_argnames=(
-    "hb", "block_k", "tail", "depth", "pack", "v_lanes", "interpret"))
-def _call(lengths, rows, *slabs, hb, block_k, tail, depth, pack, v_lanes,
-          interpret):
+    "hb", "block_k", "tail", "depth", "pack", "v_lanes", "chunk",
+    "interpret"))
+def _call(lengths, count, rows, *slabs, hb, block_k, tail, depth, pack,
+          v_lanes, chunk, interpret):
     b, h, n, lanes = rows.shape
     slab_rows = slabs[0].shape[2]
     out_lanes = v_lanes or lanes
     lengths = jnp.clip(lengths, 1, slab_rows * pack)
+    scalars = ()
+    if chunk > 1:
+        # the schedule and the copies run to where the rows the step wrote
+        # end; what the chunk's first position sees rides behind them
+        scalars = (lengths,)
+        lengths = jnp.minimum(lengths + jnp.clip(count, 1, chunk) - 1,
+                              slab_rows * pack)
     slot, block, steps = _schedule(lengths, block_k * pack,
                                    slab_rows // block_k)
     tile = 32 // rows.dtype.itemsize             # a whole sublane tile
     padded = -(-n // tile) * tile
     rows = jnp.pad(rows, ((0, 0), (0, 0), (0, padded - n), (0, 0)))
 
-    def at_slot(hi, t, len_ref, slot_ref, blk_ref):
+    def at_slot(hi, t, len_ref, slot_ref, blk_ref, *_):
         return slot_ref[t], hi, 0, 0
 
     return pl.pallas_call(
         functools.partial(_kernel, pack=pack, tile=tail[0], sub=tail[1],
-                          v_lanes=v_lanes),
+                          v_lanes=v_lanes,
+                          per=n // chunk if chunk > 1 else None),
         # the one-token call keeps the name the device trace knows it by
-        name="mla_fwd_q1" if v_lanes else "flash_fwd_q1",
+        name=("mla_fwd_q" if v_lanes else "flash_fwd_q")
+        + ("c" if chunk > 1 else "1"),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=3 + len(scalars),
             # the second bound is the traced count of live blocks
             grid=(h // hb, steps),
             in_specs=[pl.BlockSpec((None, hb, padded, lanes), at_slot)]
@@ -407,7 +497,7 @@ def _call(lengths, rows, *slabs, hb, block_k, tail, depth, pack, v_lanes,
         # interpreter; it hands out VMEM that reads NaN until written
         interpret=(pltpu.InterpretParams(uninitialized_memory="nan")
                    if interpret is True else interpret),
-    )(lengths, slot, block, rows, *slabs)
+    )(lengths, slot, block, *scalars, rows, *slabs)
 
 
 # ------------------------------------------------------ selected blocks

@@ -613,6 +613,9 @@ class DecodeEngine:
         #: ``(block rows, blocks, dense_len)`` where a one-token step reads
         #: chosen blocks of the slabs only (``ops/sparse_attention.py``)
         self._selected = ck0.attrs.get("selected") if kv else None
+        #: whether a chunked step's attention reads a slab as far as its
+        #: sequence reaches (``sdpa_prefill_op``) and not whole
+        self._chunk_live = False
         self.ciex = None
         self.chunk_ladder = (1,)
         self.chunk_top = 1
@@ -648,6 +651,8 @@ class DecodeEngine:
             self.chunk_top = self.chunk_ladder[-1]
             self._cfk = {name: self.ciex._k(node)
                          for name, node in cfeeds.items()}
+            self._chunk_live = bool(kv) and cfeeds[kv[0]].attrs.get(
+                "chunk_read") == "live"
         # dispatch plans: one per (batch, len) pair for the one-token
         # entry plus one per (batch, chunk, len) triple for the chunked
         # entry — plan_cache_hit here is the steady-state proof
@@ -765,23 +770,28 @@ class DecodeEngine:
     def kv_bytes(self):
         return sum(self.state_bytes().values())
 
-    def _kv_rows(self, chunk):
+    def _kv_rows(self, chunk, consume=1):
         """``(read, held)``: the key rows this step's attention fetches
         of a KV slab, and the key rows the slab holds, summed over the
-        batch bucket's slots.  A one-token step on the kernel path reads
-        each slot's rows as far as the sequence reaches, rounded up to a
-        copy's tile (``ops.attention.kv_rows_fetched``: the compiled
-        geometry, from the slab's shape); a chunked step and the jnp
-        path read the slab whole.  One slab's worth: every KV
-        layer, and every reader of a shared slab, fetches the same."""
+        batch bucket's slots.  ``consume``: the positions each slot takes
+        this step (one where not said).  A step on the kernel path — a
+        one-token step, or a chunked step whose graph reads through the
+        kernel's chunk form (``chunk_read`` on its ``kv`` placeholder:
+        GPT-2's) — reads each slot's rows as far as the sequence reaches
+        once the step has appended, rounded up to a copy's tile
+        (``ops.attention.kv_rows_fetched``: the compiled geometry, from
+        the slab's shape); every other chunked step and the jnp path read
+        the slab whole.  One slab's worth: every KV layer, and every
+        reader of a shared slab, fetches the same."""
         if not self._kv:
             return 0, 0
         rows = self._slab_rows(self.lb)
         held = self.bb * rows * self._pack
-        if chunk > 1:
+        if chunk > 1 and not self._chunk_live:
             return held, held
         from ..ops.attention import _decode_gate_reason, kv_rows_fetched
-        if self._selected and _decode_gate_reason(rows * self._pack) is None:
+        if chunk == 1 and self._selected \
+                and _decode_gate_reason(rows * self._pack) is None:
             # the selected-block kernel: every live block below
             # ``dense_len`` keys, the chosen blocks whatever the length past
             block, blocks, dense_len = self._selected
@@ -789,8 +799,9 @@ class DecodeEngine:
             return int(np.where(n < dense_len, -(-n // block),
                                 blocks).sum()) * block, held
         return kv_rows_fetched(
-            self.positions + 1, (self.bb, self._heads, rows, self._lanes),
-            self._pack, self._tails[self._kv[0]][1].itemsize), held
+            self.positions + np.maximum(consume, 1),
+            (self.bb, self._heads, rows, self._lanes), self._pack,
+            self._tails[self._kv[0]][1].itemsize, chunk), held
 
     # -- capacity ----------------------------------------------------------
 
@@ -1291,7 +1302,6 @@ class DecodeEngine:
                       > 1 for i in rows)
         ph.meta(rows=len(rows), chunk=chunk, prefill=prefill)
         self._grow_len_if_needed(span=chunk)
-        read, held = self._kv_rows(chunk)
         if chunk > 1:
             fn, ex, fk = self._chunk_step_fn(chunk), self.ciex, self._cfk
         else:
@@ -1317,13 +1327,14 @@ class DecodeEngine:
                      fk["positions"]: self.positions.copy(),
                      fk["valid"]: consume}
         else:
-            consume = [1] * self.bb
+            consume = np.ones(self.bb, np.int32)
             feeds = {
                 fk["input_ids"]: self.tokens.reshape(self.bb, 1).copy(),
                 fk["positions"]: self.positions.copy()}
         # the key rows the stepping sequences hold once this step has
         # appended: what its attention has to read, exactly
-        after = self.positions[rows] + np.asarray(consume)[rows]
+        read, held = self._kv_rows(chunk, consume)
+        after = self.positions[rows] + consume[rows]
         # and of an ``index`` slab, a row per ``stride`` positions (one
         # slab's worth, as the KV rows)
         stride = next((s for n, s in self._strides.items()

@@ -400,6 +400,185 @@ def test_a_chunk_and_the_cpu_keep_the_jnp_read(monkeypatch):
     assert metrics.decode_attn_call_counts() == {}
 
 
+# the kernel's chunk form (ISSUE 46): C positions a sequence, C x pack
+# score rows a head, each with its own causal limit.  256 keys in key
+# blocks of 32 slab rows; where the chunk's FIRST position stands (0, in
+# the middle of a block, on a block's last row, as far as the slab lets a
+# chunk start, in an idle slot) and how many positions the step took of it
+_CHUNK_STARTS = ("first", "mid_block", "block_end", "last", "idle", "one")
+
+
+def _chunk_case(pack, chunk, dtype, seed=11):
+    """``(q, k_slab, v_slab, positions, count)`` of six slots over slabs
+    of 256 keys in ``dtype`` (the queries scaled before they are rounded
+    to it), the key rows at and past ``positions + max(count, 1)`` large
+    finite garbage."""
+    import jax.numpy as jnp
+    d = 128 // pack
+    rng, _, k_slab = _slab_case(d, length=256, batch=6, seed=seed)
+    _, _, v_slab = _slab_case(d, length=256, batch=6, seed=seed + 1)
+    block = 32 * pack                                   # keys a key block
+    positions = np.array([0, block + 5, 2 * block - 1, 256 - chunk, 17, 70],
+                         np.int32)
+    count = np.array([chunk, chunk, chunk, chunk, 0, 1], np.int32)
+    ends = positions + np.maximum(count, 1)
+    k_slab = _garbage_past(k_slab, ends, pack, 3.0e4)
+    v_slab = _garbage_past(v_slab, ends, pack, -3.0e4)
+    q = rng.standard_normal((6, 2, chunk, d)).astype(np.float32) * d ** -0.5
+    return (jnp.asarray(q, dtype), jnp.asarray(k_slab, dtype),
+            jnp.asarray(v_slab, dtype), positions, count)
+
+
+def _chunk_read(q, k_slab, v_slab, positions, count, pack):
+    """The chunk form as ``dispatch_sdpa_prefill`` calls it, of scaled
+    queries."""
+    from hetu_tpu.ops import attention as att
+    from hetu_tpu.ops.pallas import decode_attention as da
+    rows = att.kv_slab_chunk_rows(q, pack)
+    assert np.array_equal(rows, att.kv_slab_queries(q, pack).reshape(
+        rows.shape))
+    return np.asarray(da.decode_attention(
+        rows, k_slab, v_slab, positions + 1, pack=pack, interpret=True,
+        chunk=q.shape[2], count=count))
+
+
+@pytest.mark.parametrize("chunk", [2, 4, 32])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("pack", [1, 2])
+def test_chunk_form_matches_the_slab_reference(pack, dtype, chunk,
+                                               monkeypatch):
+    """Every row the step took reads what ``sdpa_slab_reference`` reads
+    (the same values in float32: a bfloat16 slab's weights meet ``V`` as
+    hi + lo rows), over eight key blocks, VMEM NaN wherever nothing was
+    copied; a row past ``count`` — a don't-care — is a finite number."""
+    import jax.numpy as jnp
+    from hetu_tpu.ops import attention as att
+    from hetu_tpu.ops.pallas import decode_attention as da
+    q, k_slab, v_slab, positions, count = _chunk_case(pack, chunk, dtype)
+    itemsize = k_slab.dtype.itemsize
+    _cut(monkeypatch, 32, 2, 128, itemsize)
+    assert da.geometry(2, 256 // pack, 128, itemsize, 2, chunk * pack) \
+        == (2, 32)
+    metrics.reset_all()
+    got = _chunk_read(q, k_slab, v_slab, positions, count, pack)
+    assert metrics.decode_attn_call_counts() == {f"2x32:c{chunk}": 1}
+    assert got.shape == q.shape and np.isfinite(got).all()
+    lengths = positions[:, None] + 1 + np.arange(chunk)[None, :]
+    want = np.asarray(att.sdpa_slab_reference(
+        *(jnp.asarray(x, jnp.float32) for x in (q, k_slab, v_slab)),
+        jnp.asarray(lengths), scale=1.0))
+    for b, n in enumerate(np.maximum(count, 1)):
+        np.testing.assert_allclose(
+            got[b, :, :n], want[b, :, :n], rtol=2e-5,
+            atol=2e-6 if dtype == "float32" else 2e-5,
+            err_msg=_CHUNK_STARTS[b])
+
+
+@pytest.mark.parametrize("pack", [1, 2])
+def test_one_position_through_the_chunk_form_is_the_one_token_call(
+        pack, monkeypatch):
+    """``chunk=1`` IS today's call (the same program: no fourth scalar,
+    the trace's ``flash_fwd_q1``), and the first position of a chunk of
+    which the step took one reads the one-token call's result to the
+    last bit: the same keys, blocks and sums."""
+    from hetu_tpu.ops.pallas import decode_attention as da
+    q, k_slab, v_slab, positions, _ = _chunk_case(pack, 2, "float32")
+    _cut(monkeypatch, 32, 2, 128, 4)
+    ones = np.ones(6, np.int32)
+    metrics.reset_all()
+    want = _chunk_read(q[:, :, :1], k_slab, v_slab, positions, None, pack)
+    assert np.array_equal(want, _chunk_read(
+        q[:, :, :1], k_slab, v_slab, positions, ones, pack))
+    assert metrics.decode_attn_call_counts() == {"2x32": 2}
+    got = _chunk_read(q, k_slab, v_slab, positions, ones, pack)
+    assert np.array_equal(got[:, :, :1], want)
+    assert np.isfinite(got).all()
+    with pytest.raises(ValueError, match="score rows"):
+        da.decode_attention(np.zeros((1, 1, 3 * pack, 128), np.float32),
+                            k_slab[:1, :1], v_slab[:1, :1], ones[:1],
+                            pack=pack, chunk=2)
+
+
+def test_chunk_schedule_ends_where_the_steps_rows_do(monkeypatch):
+    """The schedule, and the last block's copy, run to ``positions +
+    count``: with every slab row past the copy tile that holds it NaN,
+    a block or a row fetched past it would show (a weight of zero times
+    a NaN); and ``kv_rows_fetched(chunk=)`` counts exactly those rows."""
+    import jax
+    from hetu_tpu.ops import attention as att
+    q, k_slab, v_slab, positions, count = _chunk_case(2, 4, "float32")
+    _cut(monkeypatch, 32, 2, 128, 4)
+    ends = positions + np.maximum(count, 1)
+    tile = 8 * 2                           # keys a copy's tile holds
+    copied = -(-ends // tile) * tile
+    k_nan = _garbage_past(np.asarray(k_slab), copied, 2, np.nan)
+    v_nan = _garbage_past(np.asarray(v_slab), copied, 2, np.nan)
+    assert np.isnan(k_nan).any()
+    got = _chunk_read(q, k_nan, v_nan, positions, count, 2)
+    assert np.isfinite(got).all()
+    assert np.array_equal(got, _chunk_read(q, k_slab, v_slab, positions,
+                                           count, 2))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    slab = (6, 2, 256, 128)                # on the gate: 512 keys
+    assert att.kv_rows_fetched(ends, slab, 2, 4, chunk=4) == copied.sum()
+    assert att.kv_rows_fetched(ends, slab, 2, 4, chunk=4) \
+        == att.kv_rows_fetched(ends, slab, 2, 4)
+    # off the gate (under 256 keys), and a chunk too long for one head's
+    # program: the slab whole
+    assert att.kv_rows_fetched(ends, (6, 2, 64, 128), 2, 4, chunk=4) \
+        == 6 * 128
+    assert att.kv_rows_fetched(ends, slab, 2, 4, chunk=4096) == 6 * 512
+
+
+@pytest.mark.parametrize("chunk", [4, 32, 128])
+def test_prefill_dispatch_enters_the_chunk_form_on_the_chip_alone(
+        chunk, monkeypatch):
+    """``dispatch_sdpa_prefill`` over a slab on the one-token read's gate
+    (512 keys): off the chip the jnp read and no ``decode_attn_calls``;
+    behind a backend that says tpu the kernel's chunk form, counted with
+    the chunk behind its geometry, reading what the jnp read reads — a
+    chunk of whole 128-row tiles keeps the flash kernel's path."""
+    import functools
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+    from hetu_tpu.ops import attention as att
+    from hetu_tpu.ops.pallas import decode_attention as da
+    fa = importlib.import_module("hetu_tpu.ops.pallas.flash_attention")
+    rng, _, k_slab = _slab_case(64, length=512, batch=3, seed=21)
+    _, _, v_slab = _slab_case(64, length=512, batch=3, seed=22)
+    q = rng.standard_normal((3, 2, chunk, 64)).astype(np.float32)
+    positions = np.array([0, 200, 512 - chunk], np.int32)
+    valid = np.array([chunk, 1, chunk], np.int32)
+    metrics.reset_all()
+    want = np.asarray(att.dispatch_sdpa_prefill(q, k_slab, v_slab,
+                                                positions, valid))
+    assert metrics.decode_attn_call_counts() == {}
+    assert metrics.flash_fallback_counts() == {"backend:cpu": 1}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(da, "decode_attention", functools.partial(
+        da.decode_attention, interpret=True))
+    monkeypatch.setattr(fa, "flash_attention", functools.partial(
+        fa.flash_attention, interpret=True))
+    metrics.reset_all()
+    got = np.asarray(att.dispatch_sdpa_prefill(
+        jnp.asarray(q), jnp.asarray(k_slab), jnp.asarray(v_slab),
+        jnp.asarray(positions), jnp.asarray(valid)))
+    assert metrics.flash_fallback_counts() == {}
+    assert metrics.decode_attn_call_counts() == (
+        {} if chunk == 128 else {f"2x256:c{chunk}": 1})
+    assert bool(metrics.flash_call_counts()) == (chunk == 128)
+    for b, n in enumerate(valid):
+        np.testing.assert_allclose(got[b, :, :n], want[b, :, :n],
+                                   rtol=2e-5, atol=2e-6)
+    # a slab under the gate keeps the jnp read, counted with its reason
+    att.dispatch_sdpa_prefill(q[:, :, :4], k_slab[:, :, :64],
+                              v_slab[:, :, :64], positions % 100)
+    assert metrics.flash_fallback_counts() == {
+        "decode_below_gate:kv128<256": 1}
+
+
 def test_geometry_follows_the_calls_shape():
     """All the heads of a slot in one program, key blocks sized to
     ``BLOCK_BYTES`` over the call's slabs together: the four cells'
@@ -538,6 +717,88 @@ def test_engine_counts_the_kv_rows_the_kernel_fetches(jnp_rows_run,
     assert HetuProfiler.all_counters()["decode_attn_calls"] == calls
     assert HetuProfiler.all_counters()["kv_append_calls"] == {
         "8x128:kernel": 2 * _ROWS_CFG.n_layer}
+
+
+def _chunked_engine():
+    from hetu_tpu.models import gpt2_decode_chunked_graph
+    cfg = GPT2Config.tiny(n_positions=512, batch_size=1, seq_len=16)
+    feeds, logits, caches, _ = gpt2_decode_graph(cfg, max_len=512)
+    cf, cl, cc, _ = gpt2_decode_chunked_graph(cfg, max_len=512)
+    eng = DecodeEngine(feeds, logits, caches, seed=0, max_slots=2,
+                       max_len=512, chunked=(cf, cl, cc), max_chunk=32)
+    eng.reserve(2, 512)
+    assert eng._chunk_live
+    return eng
+
+
+def _serve_chunked_and_count(steps):
+    """One prompt of 300 tokens (3 new tokens) beside one of 2 through a
+    2 x 512 engine with GPT-2's chunked entry, chunks up to 32:
+    ``(tokens, counters, geometries)``; ``steps`` collects ``(chunk,
+    positions + consumed)`` of every launch."""
+    eng = _chunked_engine()
+    kv_rows = eng._kv_rows
+
+    def noted(chunk, consume=1):
+        steps.append((chunk, eng.positions + np.maximum(consume, 1)))
+        return kv_rows(chunk, consume)
+    eng._kv_rows = noted
+    metrics.reset_all()
+    with DecodeRouter(eng, start=False) as router:
+        streams = [router.submit(p, max_new_tokens=3)
+                   for p in (list(range(3, 303)), [5, 6])]
+        router.start()
+        tokens = [s.result(timeout=300) for s in streams]
+    return tokens, metrics.decode_counts(), HetuProfiler.decode_attn_calls()
+
+
+def test_engine_counts_a_chunked_step_by_what_it_fetches(monkeypatch):
+    """A chunked step of a graph whose ``kv`` placeholder says
+    ``chunk_read`` (GPT-2's) counts ``kv_rows_fetched(positions +
+    consumed)`` where the kernel's gate passes, and every row the slabs
+    hold off it (here: the CPU) — emitting the same tokens through the
+    kernel's chunk form in interpret mode behind a backend that says tpu;
+    a graph without the mark (phi4's, solar's, glm's, sala's, granite's)
+    keeps counting a chunked step whole."""
+    import functools
+
+    import jax
+    from hetu_tpu.ops import attention as att
+    from hetu_tpu.ops.pallas import decode_attention as da
+    from hetu_tpu.ops.pallas import kv_append as ka
+    steps = []
+    tokens, c, calls = _serve_chunked_and_count(steps)
+    assert calls == {} and c["decode_prefill_steps"] >= 300 // 32
+    assert c["decode_kv_rows_held"] == c["decode_steps"] * 2 * 512
+    assert c["decode_kv_rows_read"] == c["decode_kv_rows_held"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(da, "decode_attention", functools.partial(
+        da.decode_attention, interpret=True))
+    monkeypatch.setattr(ka, "kv_append", functools.partial(
+        ka.kv_append, interpret=True))
+    steps = []
+    got, k, calls = _serve_chunked_and_count(steps)
+    assert got == tokens
+    assert k["decode_steps"] == c["decode_steps"] == len(steps)
+    assert k["decode_kv_rows_held"] == c["decode_kv_rows_held"]
+    slab = (2, 2, 256, 128)
+    assert k["decode_kv_rows_read"] == sum(
+        att.kv_rows_fetched(ends, slab, 2, 4, chunk)
+        for chunk, ends in steps) < k["decode_kv_rows_held"] // 2
+    wide = [chunk for chunk, _ in steps if chunk > 1]
+    assert wide and max(wide) == 32
+    # once a layer a traced program: the one-token key as it was, a
+    # chunked program's with its chunk behind it
+    layers = _ROWS_CFG.n_layer
+    assert calls == {"2x256": layers, **{
+        f"2x256:c{chunk}": layers for chunk in set(wide)}}
+    # a chunked graph that does not say so reads whole, whatever the gate
+    eng = _chunked_engine()
+    consume = np.array([32, 1])
+    assert eng._kv_rows(32, consume) == (32 + 16, 1024)
+    eng._chunk_live = False
+    assert eng._kv_rows(32, consume) == (1024, 1024)
+    assert eng._kv_rows(1) == (2 * 16, 1024)
 
 
 def test_slab_format_follows_head_dim_alone(decode_graph):

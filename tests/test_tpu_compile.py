@@ -307,6 +307,33 @@ def test_decode_attention_over_slabs_compiles(chip, head_dim, heads, batch,
     assert "flash_fwd_q1" in text and "mla_fwd_q1" not in text
 
 
+@pytest.mark.parametrize("length,chunk,want", [
+    (768, 2, "16x128:c2"), (768, 32, "16x128:c32"),
+    (1024, 32, "16x128:c32"), (384, 16, "16x96:c16")],
+    ids=["chat_c2", "chat_c32", "docqa_c32", "bucket384_c16"])
+def test_chunk_form_compiles_at_the_cells_buckets(chip, length, chunk, want):
+    """ISSUE 46: the one-token kernel's chunk form — ``C x 2`` score rows
+    a head over GPT-2's packed float32 slabs, the limits of a chunk's
+    first position a fourth prefetched scalar, a strided read of the
+    softmax scratch at the end — at chat's bucket (768), docqa-c16's
+    (1024) and a length bucket on the way up: the one-token geometry
+    while VMEM has room for it, named apart in the trace."""
+    from hetu_tpu import metrics
+    from hetu_tpu.ops.attention import kv_slab_shape
+    from hetu_tpu.ops.pallas.decode_attention import decode_attention
+    slab = kv_slab_shape(16, 16, length, 64)
+    before = metrics.decode_attn_call_counts().get(want, 0)
+    text = _compiles_with_kernel(
+        lambda rows, k, v, n, c: decode_attention(
+            rows, k, v, n, pack=2, chunk=chunk, count=c),
+        chip((16, 16, chunk * 2, 128), jnp.float32),
+        chip(slab, jnp.float32), chip(slab, jnp.float32),
+        chip((16,), jnp.int32), chip((16,), jnp.int32))
+    assert metrics.decode_attn_call_counts().get(want, 0) == before + 1
+    assert not _slab_copies(text, slab)
+    assert "flash_fwd_qc" in text and "flash_fwd_q1" not in text
+
+
 def _slab_copies(text, slab):
     """``copy`` instructions of the optimized HLO whose result has a
     slab's element count (a prefetch is a ``copy-start``, not a copy)."""
@@ -383,11 +410,14 @@ def chat_engine():
                         seed=0, chunked=(cf, cl, cc), max_chunk=32)
 
 
-@pytest.mark.parametrize("chunk", [1, 2, 32])
+@pytest.mark.parametrize("length,chunk", [
+    (768, 1), (768, 2), (768, 32), (1024, 32)],
+    ids=["1", "2", "32", "docqa_32"])
 def test_decode_steps_hold_no_slab_copy(chip, chat_engine, monkeypatch,
-                                        chunk):
+                                        length, chunk):
     """ISSUE 26: the engine's one-token step and its chunked steps at
-    the chat cell's shape (batch 16, cache 768), compiled for the
+    the chat cell's shape (batch 16, cache 768) and the docqa-c16 cell's
+    widest (cache 1024, chunk 32), compiled for the
     described chip as the engine jits them, hold NO ``copy`` of a slab's
     size.  Two mechanisms put one there.  Stored as (16, 16, 768, 64)
     the slabs were kept length-minor and transposed, padded, in front
@@ -396,11 +426,12 @@ def test_decode_steps_hold_no_slab_copy(chip, chat_engine, monkeypatch,
     And fed inside the feed dict, sorted by key, each donated slab was
     paired with another layer's output and copied whole; handed over in
     the fetches' order, slab i is updated in place."""
+    from hetu_tpu import metrics
     from hetu_tpu.ops.attention import kv_slab_shape
     eng = chat_engine
     iex, keys = (eng.iex, eng._fk) if chunk == 1 else (eng.ciex, eng._cfk)
-    slab = kv_slab_shape(16, eng._heads, 768, eng._head_dim)
-    assert slab == (16, 16, 384, 128)
+    slab = kv_slab_shape(16, eng._heads, length, eng._head_dim)
+    assert slab == (16, 16, length // 2, 128)
     assert sorted(keys[n] for n in eng.cache_names) \
         != [keys[n] for n in eng.cache_names]
     feeds = {"input_ids": ((16, chunk), jnp.int32),
@@ -413,11 +444,19 @@ def test_decode_steps_hold_no_slab_copy(chip, chat_engine, monkeypatch,
            tuple(chip(slab, jnp.float32) for _ in eng.cache_names))
     # the attention dispatch asks jax.default_backend() and must hear tpu
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # ISSUE 46: a chunk reads the slabs through the one-token kernel's
+    # chunk form, counted with the chunk behind the one-token geometry
+    want = "16x128" + (f":c{chunk}" if chunk > 1 else "")
+    before = metrics.decode_attn_call_counts().get(want, 0)
     compiled = jax.jit(eng._program(iex, keys), donate_argnums=(1,)).lower(
         params, fed, chip((16,), jnp.int32)).compile()
+    assert metrics.decode_attn_call_counts().get(want, 0) == before + 4
     text = compiled.as_text()
     assert ("flash_fwd_q1" in text) == (chunk == 1)
+    assert ("flash_fwd_qc" in text) == (chunk > 1)
     assert not _slab_copies(text, slab)
+    # nor does a chunk's read materialise its scores: (16, 16, C, 2, L/2)
+    assert f"f32[16,16,{chunk},2,{length // 2}]" not in text
     # ISSUE 37: K and V of every layer appended by the aliased kernel,
     # straight onto the donated parameter; no loop walks the batch
     assert _appends_in_place(text) == 2 * 4 and _loops(text) == []
@@ -427,11 +466,12 @@ def test_decode_steps_hold_no_slab_copy(chip, chat_engine, monkeypatch,
         # it, as it did the loop's)
         assert len(re.findall(r"custom-call\([^\n]*%fed_1__\d+_[.\d]*\), "
                               r"[^\n]*/kv_append/", text)) == 2 * 4
-    assert _peak(compiled) <= _PEAK_BEFORE_37["chat", chunk] + _TOY_ROOM
+    if length == 768:
+        assert _peak(compiled) <= _PEAK_BEFORE_37["chat", chunk] + _TOY_ROOM
     # and the slabs are fed and returned row-major, unpadded
     layout = re.search(r"entry_computation_layout=\{(.*)\}", text).group(1)
-    assert "f32[16,16,384,128]{3,2,1,0:T(8,128)}" in layout
-    assert "f32[16,16,384,128]{2," not in layout
+    assert f"f32[16,16,{length // 2},128]{{3,2,1,0:T(8,128)}}" in layout
+    assert f"f32[16,16,{length // 2},128]{{2," not in layout
 
 
 def _state_dims(eng, batch, length, name):

@@ -19,6 +19,15 @@ buffers nothing was copied into).  For the reader of PERF.md: the program
 reads nothing from what this prints.  A chip tool: run it on the machine
 with the chip.
 
+``--chunk C`` (PR 46) times the kernel's CHUNK form — ``C`` positions a
+sequence, ``C x pack`` score rows a head, each with its own causal limit,
+what ``dispatch_sdpa_prefill`` calls for a chunked step — at the float32
+cells' shapes (``--cells chat,docqa``; ``docqa``: ``gpt2-medium.docqa-c16``,
+(16, 16, 512, 128)) against ``sdpa_slab_reference`` over the whole slab, a
+sequence's rows ending at a length drawn as above (at least ``C``).  There
+every one of the ``READERS`` calls has slabs of its OWN, as a step's 24
+layers have.
+
 Every timed program makes ``READERS`` calls over the SAME slabs, as the
 phi4 step's eight readers do; the compiler merges the ``jnp`` calls' products
 over a float32 slab into one read of it, so the chat cell's ``jnp_whole``
@@ -54,6 +63,9 @@ CELLS = {
     "solar": {"traffic": "assist-c128", "slab": (128, 1, 4096, 128),
               "dtype": "bfloat16", "rows": 8, "pack": 1,
               "sweep": [(1, 4096), (1, 2048), (1, 1024)]},
+    "docqa": {"traffic": "docqa-c16", "slab": (16, 16, 512, 128),
+              "dtype": "float32", "rows": 2, "pack": 2,
+              "sweep": [(16, 128), (16, 64), (16, 32), (8, 256)]},
 }
 
 
@@ -93,6 +105,14 @@ def _readers(call):
     return run
 
 
+def _readers_own(call):
+    """``READERS`` calls in one program, each over slabs of its own."""
+    def run(q, ks, vs, lengths):
+        return sum(call(q * (1.0 + 0.01 * i), k, v, lengths)
+                   for i, (k, v) in enumerate(zip(ks, vs)))
+    return run
+
+
 def _jnp_whole(pack, v_lanes):
     """The whole-slab read the ``jnp`` paths make: scores against every
     slab row, masked afterwards, one softmax per score row."""
@@ -119,13 +139,13 @@ def _stood_in(da, **names):
     other names is dropped before and after."""
     import jax
 
-    def timed(call, *args):
+    def timed(call, *args, readers=_readers):
         kept = {name: getattr(da, name) for name in names}
         try:
             for name, value in names.items():
                 setattr(da, name, value)
             jax.clear_caches()
-            return _time(_readers(call), *args)
+            return _time(readers(call), *args)
         except Exception as e:  # noqa: BLE001 - a refused geometry is data
             return f"refused: {str(e).splitlines()[0][:120]}"
         finally:
@@ -135,10 +155,87 @@ def _stood_in(da, **names):
     return timed
 
 
+def _block_unmasked(da):
+    """A stand-in for the kernel's ``_block`` that counts every key."""
+    import jax
+    import jax.numpy as jnp
+
+    def block(q, k, v, first, length, m_scr, l_scr, acc_scr, pack,
+              chunk=None):
+        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32)
+        da._fold(s, jnp.full(s.shape, True), v, m_scr, l_scr, acc_scr)
+    return block
+
+
+def chunk_ab(name, chunk, seed, dev):
+    """The chunk form at cell ``name``'s slab shape, ``chunk`` positions a
+    sequence: one line of times a lengths mix."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from hetu_tpu.ops import attention as att
+    from hetu_tpu.ops.pallas import decode_attention as da
+    cell = CELLS[name]
+    b, h, slab_rows, lanes = cell["slab"]
+    pack = cell["pack"]
+    d = lanes // pack
+    dtype = jnp.dtype(cell["dtype"])
+    keys = jax.random.split(jax.random.PRNGKey(seed % (2 ** 31)),
+                            1 + 2 * READERS)
+    q = jax.random.normal(keys[0], (b, h, chunk, d), jnp.float32).astype(dtype)
+    ks, vs = (tuple(jax.random.normal(k, cell["slab"], jnp.float32).astype(
+        dtype) for k in keys[1 + i::2]) for i in range(2))
+
+    def kernel(q, k, v, first):
+        return da.decode_attention(
+            att.kv_slab_chunk_rows(q * d ** -0.5, pack), k, v, first,
+            pack=pack, chunk=chunk)
+
+    def reference(q, k, v, first):
+        return att.sdpa_slab_reference(
+            q, k, v, first[:, None] + jnp.arange(chunk)[None, :])
+
+    picked = da.geometry(h, slab_rows, lanes, dtype.itemsize, 2, chunk * pack)
+    ends = {"cell": np.maximum(chunk, cell_lengths(cell["traffic"], b, seed)),
+            "full": np.full(b, slab_rows * pack, np.int32),
+            "one": np.full(b, chunk, np.int32)}
+    for mix, end in ends.items():
+        first = jnp.asarray(end - chunk + 1, jnp.int32)
+        out = {"cell": name, "chunk": chunk, "lengths": mix,
+               "device": dev.device_kind, "mean_len": float(end.mean()),
+               "picked": list(picked), "slab_keys": slab_rows * pack,
+               "ms_per_call": {}}
+        ms = out["ms_per_call"]
+        ms["slab_reference"] = _time(_readers_own(reference), q, ks, vs,
+                                     first)
+
+        def under(**names):
+            return _stood_in(da, **names)(kernel, q, ks, vs, first,
+                                          readers=_readers_own)
+        sweep = cell["sweep"] if mix == "cell" else cell["sweep"][:2]
+        for geo in [tuple(picked)] + [g for g in sweep if g != picked]:
+            ms[f"{geo[0]}x{geo[1]}"] = under(
+                geometry=lambda *shape, geo=geo: geo)
+        ms["copy_alone"] = under(_products=lambda *a, **kw: None)
+        ms["products_alone"] = under(_fetch=lambda *a, **kw: [])
+        # what the per-row limits cost: every key counted (a time only)
+        ms["unmasked"] = under(_block=_block_unmasked(da))
+        got, want = (jax.jit(f)(q, ks[0], vs[0], first)
+                     for f in (kernel, reference))
+        out["max_abs_diff"] = float(jnp.max(jnp.abs(got - want)))
+        out["rows_fetched_pct"] = 100.0 * att.kv_rows_fetched(
+            end, cell["slab"], pack, dtype.itemsize, chunk
+        ) / (b * slab_rows * pack)
+        print(json.dumps(out), flush=True)
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--cells", default="glm,phi4,chat,solar")
     ap.add_argument("--seed", type=int, default=2860486313)
+    ap.add_argument("--chunk", type=int, default=1,
+                    help="positions a sequence: above 1 the chunk form")
     args = ap.parse_args(argv)
     import jax
     import jax.numpy as jnp
@@ -150,6 +247,9 @@ def main(argv=None):
         print(json.dumps({"error": f"needs the chip, found {dev.platform}"}))
         return 2
     for name in args.cells.split(","):
+        if args.chunk > 1:
+            chunk_ab(name, args.chunk, args.seed, dev)
+            continue
         cell = CELLS[name]
         b, h, slab_rows, lanes = cell["slab"]
         dtype = jnp.dtype(cell["dtype"])
