@@ -132,9 +132,9 @@ def build_bert(batch, seq_len, *, size="base", compute_dtype="bfloat16",
     feeds, loss, _ = bert_pretrain_graph(cfg)
     opt = ht.optim.AdamOptimizer(1e-4)
     strategy = ht.dist.DataParallel(num_devices=dp) if dp else None
-    ex = ht.Executor({"train": [loss, opt.minimize(loss)]}, seed=seed,
-                     compute_dtype=compute_dtype, dist_strategy=strategy,
-                     zero=zero)
+    ex = ht.Executor({"train": [loss, opt.minimize(loss), loss.mlm_overflow]},
+                     seed=seed, compute_dtype=compute_dtype,
+                     dist_strategy=strategy, zero=zero)
     ids, tt, labels, attn = synthetic_mlm_batch(cfg, seed=seed)
     fd = {feeds["input_ids"]: ids, feeds["token_type_ids"]: tt,
           feeds["masked_lm_labels"]: labels, feeds["attention_mask"]: attn}
@@ -216,6 +216,14 @@ def train(batch=32, seq_len=512, size="base", warmup=3, steps=6,
     _check(abs(losses[0] - want) < loss_band,
            f"first loss {losses[0]:.3f} not within {loss_band} of "
            f"ln({cfg.vocab_size}) = {want:.3f}")
+    # the rows the MLM head runs on, and the fed batch's rows over that
+    # capacity (0: the head ran once a step)
+    over = int(ex.run("train", feed_dict=fd)[2].asnumpy())
+    k = cfg.max_predictions_per_seq
+    rows = f"{k}of{seq_len}:" + ("gathered" if k < seq_len else "all")
+    _check(HetuProfiler.mlm_head_calls().get(rows),
+           f"no MLM head over {rows} was traced: "
+           f"{HetuProfiler.mlm_head_calls()}")
     flash = _flash_in_hlo(ex, fd)
     fallbacks = HetuProfiler.flash_fallbacks()
     if _flash_expected(seq_len):
@@ -225,6 +233,8 @@ def train(batch=32, seq_len=512, size="base", warmup=3, steps=6,
            "compute_dtype": "bfloat16", "losses": [round(v, 4) for v in losses],
            "ln_vocab": round(want, 4), "flash_in_hlo": flash,
            "flash_fallbacks": fallbacks,
+           "mlm_head": {"rows": rows, "rows_over_capacity": over,
+                        "calls": HetuProfiler.mlm_head_calls()},
            "peak_bytes_in_use": _peak_bytes()[0],
            # everything the runtime reports, so the peak can be read
            # against the compiler's own temporaries

@@ -551,6 +551,8 @@ def graph_layer_specs(fetches, feeds=None, split=None, name="graph",
                 s_kv = float(kv[-2])
                 b[3] = True
                 b[1] += 2.0 * 2.0 * b_h * s_q * s_kv * d  # scores + values
+        elif hasattr(node, "fwd_flops"):
+            b[1] += node.fwd_flops(gs)   # an op that prices its own graph
     if not acc:
         return [LayerSpec(name, 0.0, 0.0, 0.0)]
     return [LayerSpec(k, *acc[k][:3], count=1, attn=acc[k][3])

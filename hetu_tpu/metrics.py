@@ -184,6 +184,29 @@ def kv_append_call_counts():
     return _kv_append_calls.counts()
 
 
+# Which rows a masked-LM head was compiled over (``models.common.
+# LabelledRowsLossOp``): ``"<K>of<S>:gathered"`` = each row's K labelled
+# positions of S a round, ``"<S>of<S>:all"`` = a capacity of the whole
+# row, nothing gathered.  Per TRACE, as the families above; whether a
+# step needed more than one round is the graph's ``overflow`` fetch.
+_mlm_head_calls = REGISTRY.counter_family(
+    "mlm_head_calls",
+    "masked-LM heads by rows a sequence, "
+    "\"<rows>of<seq_len>:<gathered|all>\" (per jax trace)")
+
+
+def record_mlm_head_call(rows, seq_len, how):
+    """Count one traced masked-LM head by the rows it runs on."""
+    if counters_suppressed():
+        return
+    _mlm_head_calls.inc(f"{rows}of{seq_len}:{how}")
+
+
+def mlm_head_call_counts():
+    """{"<rows>of<seq_len>:<gathered|all>": count} snapshot."""
+    return _mlm_head_calls.counts()
+
+
 # How a decode graph's expert layer multiplies (``ops.moe._moe_experts``):
 # the experts it holds of all the router chooses among, the experts a
 # token takes, and whether the grouped product is ``jax.lax.ragged_dot``
@@ -1311,6 +1334,7 @@ _FAMILIES = {
     "flash_head_major": _flash_head_major,
     "decode_attn_calls": _decode_attn_calls,
     "kv_append_calls": _kv_append_calls,
+    "mlm_head_calls": _mlm_head_calls,
     "moe_calls": _moe_calls,
     "sparse_attn_calls": _sparse_attn_calls,
     "ssd_calls": _ssd_calls,

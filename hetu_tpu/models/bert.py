@@ -16,12 +16,26 @@ from ..layers.core import Linear, LayerNorm, Embedding
 
 
 class BertConfig:
+    """``max_predictions_per_seq``: the most positions of a row that carry
+    an MLM label, the published pre-training's field of that name
+    (``google-research/bert`` ``run_pretraining.py``:
+    ``gather_indexes(input_tensor, masked_lm_positions)`` before the
+    transform; 20 at 128 positions, 80 at 512).  ``bert_pretrain_graph``
+    runs the MLM head on that many rows a sequence and not on all
+    ``seq_len``.  ``None`` (the default): 15 % of ``seq_len`` rounded up
+    to a multiple of 8 — 24 at 128, 80 at 512.  A batch in which some
+    row carries MORE labels than this still trains on every one of
+    them: that step runs the head again over each row's next
+    ``max_predictions_per_seq`` (a round more of the head's cost;
+    ``loss.mlm_overflow`` counts such rows), so raise the field where
+    the masking labels more."""
+
     def __init__(self, vocab_size=30522, hidden_size=768,
                  num_hidden_layers=12, num_attention_heads=12,
                  intermediate_size=3072, max_position_embeddings=512,
                  type_vocab_size=2, hidden_dropout_prob=0.1,
                  attention_probs_dropout_prob=0.1, layer_norm_eps=1e-12,
-                 batch_size=8, seq_len=128):
+                 batch_size=8, seq_len=128, max_predictions_per_seq=None):
         self.vocab_size = vocab_size
         self.hidden_size = hidden_size
         self.num_hidden_layers = num_hidden_layers
@@ -34,6 +48,9 @@ class BertConfig:
         self.layer_norm_eps = layer_norm_eps
         self.batch_size = batch_size
         self.seq_len = seq_len
+        if max_predictions_per_seq is None:
+            max_predictions_per_seq = -(-seq_len * 15 // 100 // 8) * 8
+        self.max_predictions_per_seq = min(max_predictions_per_seq, seq_len)
 
     @classmethod
     def base(cls, **kw):
@@ -120,6 +137,9 @@ def bert_pretrain_graph(cfg, name="bert", use_mask=True, use_nsp=False):
 
     Returns (placeholders dict, loss node, logits node).
     masked_lm_labels: (batch, seq) with -1 for unmasked positions.
+    ``loss.mlm_overflow``: a scalar node a caller may fetch, the rows of
+    the fed batch with more than ``cfg.max_predictions_per_seq`` labels
+    (0: the step ran the head once, on the labelled rows).
     ``use_mask=True`` (the flagship default) adds an ``attention_mask``
     (batch, seq) int32 input so padded pretraining attends only to real
     tokens (reference hetu_bert.py attention_mask input).
@@ -143,16 +163,29 @@ def bert_pretrain_graph(cfg, name="bert", use_mask=True, use_nsp=False):
     seq = bert_model(cfg, input_ids, token_type_ids,
                      attention_mask=attention_mask, name=name)
     # MLM head: transform + tied-ish decoder (fresh decoder weights, like the
-    # reference which also keeps an independent decoder matrix)
-    h = Linear(cfg.hidden_size, cfg.hidden_size, activation="gelu",
-               initializer=init.GenTruncatedNormal(0.0, 0.02),
-               name=name + ".mlm_transform")(seq)
-    h = LayerNorm(cfg.hidden_size, cfg.layer_norm_eps, name + ".mlm_ln")(h)
-    logits = Linear(cfg.hidden_size, cfg.vocab_size,
-                    initializer=init.GenTruncatedNormal(0.0, 0.02),
-                    name=name + ".mlm_decoder")(h)
-    from .common import masked_lm_loss
-    loss = masked_lm_loss(logits, labels, cfg.batch_size * cfg.seq_len)
+    # reference which also keeps an independent decoder matrix).  The loss
+    # runs it on the labelled rows (``BertConfig.max_predictions_per_seq``);
+    # ``logits`` is the same three layers over every position, computed
+    # only where a caller fetches it
+    from ..graph.node import name_scope
+    from .common import labelled_rows_lm_loss
+    with name_scope("mlm_head"):
+        transform = Linear(cfg.hidden_size, cfg.hidden_size,
+                           activation="gelu",
+                           initializer=init.GenTruncatedNormal(0.0, 0.02),
+                           name=name + ".mlm_transform")
+        ln = LayerNorm(cfg.hidden_size, cfg.layer_norm_eps, name + ".mlm_ln")
+        decoder = Linear(cfg.hidden_size, cfg.vocab_size,
+                         initializer=init.GenTruncatedNormal(0.0, 0.02),
+                         name=name + ".mlm_decoder")
+
+        def head(rows):
+            return decoder(ln(transform(rows)))
+
+        logits = head(seq)
+        loss, overflow = labelled_rows_lm_loss(
+            seq, labels, head, cfg.batch_size, cfg.seq_len,
+            cfg.max_predictions_per_seq)
     feeds = {"input_ids": input_ids, "token_type_ids": token_type_ids,
              "masked_lm_labels": labels}
     if use_nsp:
@@ -167,6 +200,7 @@ def bert_pretrain_graph(cfg, name="bert", use_mask=True, use_nsp=False):
         feeds["next_sentence_label"] = nsp_label
     if attention_mask is not None:
         feeds["attention_mask"] = attention_mask
+    loss.mlm_overflow = overflow
     return feeds, loss, logits
 
 
@@ -232,7 +266,9 @@ def synthetic_mlm_batch(cfg, seed=0, mask_frac=0.15, full_frac=0.35):
     follow a padded-pretraining distribution: ``full_frac`` of the batch is
     packed full-length, the rest is uniform over [seq/4, seq] (real MLM
     corpora mix packed segments with short documents).  Positions beyond a
-    row's length are PAD: id 0, label -1, attention_mask 0.
+    row's length are PAD: id 0, label -1, attention_mask 0.  A row carries
+    at most ``cfg.max_predictions_per_seq`` labels, the cap of the
+    published data pipeline (``create_pretraining_data.py``).
     """
     rng = np.random.RandomState(seed)
     b, s = cfg.batch_size, cfg.seq_len
@@ -245,6 +281,9 @@ def synthetic_mlm_batch(cfg, seed=0, mask_frac=0.15, full_frac=0.35):
     ids[~attn] = 0
     labels = np.full((b, s), -1, np.int64)
     mask = (rng.rand(b, s) < mask_frac) & attn
+    for row in np.flatnonzero(mask.sum(1) > cfg.max_predictions_per_seq):
+        at = rng.permutation(np.flatnonzero(mask[row]))
+        mask[row, at[cfg.max_predictions_per_seq:]] = False
     labels[mask] = ids[mask]
     return (ids.astype(np.int32), tt, labels.astype(np.int32),
             attn.astype(np.int32))

@@ -2,6 +2,7 @@
 import numpy as np
 
 from .. import ops
+from ..graph.node import Op
 
 
 def masked_lm_loss(logits, labels, n_tokens, ignored_index=-1):
@@ -15,6 +16,218 @@ def masked_lm_loss(logits, labels, n_tokens, ignored_index=-1):
     valid = ops.ne_op(flat, flat * 0.0 + float(ignored_index))
     return ops.reduce_sum_op(per_tok, [0]) \
         / (ops.reduce_sum_op(valid, [0]) + 1e-6)
+
+
+# ------------------------------------------ an MLM head's labelled rows
+# A masked-LM batch labels some 15 % of its positions and the loss reads no
+# other, so the head need not run on the rest: per row, the hidden states
+# of ``capacity`` labelled positions are gathered and the head — any graph
+# from hidden rows to logits — runs on those.  The work follows the labels
+# a step is fed: a row that carries more than ``capacity`` of them sends
+# the head round again over each row's next ``capacity``, so no label is
+# ever dropped and no tensor of (all positions, vocab) ever written.
+
+def _rows_over_capacity(c, labels, capacity, ignored_index=-1):
+    import jax.numpy as jnp
+    return jnp.sum(jnp.sum(labels != ignored_index, axis=1) > capacity,
+                   dtype=jnp.int32)
+
+
+rows_over_capacity_op = ops.def_op("RowsOverCapacity", _rows_over_capacity,
+                                   lambda a, **kw: ())
+
+
+class _HeadArm:
+    """``Σ cross-entropy(head(rows), labels) · scale`` over ``n_rows`` rows
+    as a graph of its own on three stand-in inputs: what one round of
+    :class:`LabelledRowsLossOp` lowers.  ``scale`` is one over the count
+    of ALL the batch's labels, inside the sum's gradient as the division
+    of :func:`masked_lm_loss` is."""
+
+    def __init__(self, head, n_rows, ignored_index):
+        from ..graph.node import PlaceholderOp, placeholder_op, topo_sort
+        self.rows = placeholder_op("head_rows")
+        self.labels = placeholder_op("head_row_labels", dtype=np.int32,
+                                     shape=(n_rows,))
+        self.scale = placeholder_op("head_loss_scale", shape=())
+        per_row = ops.softmaxcrossentropy_sparse_op(
+            head(self.rows), self.labels, ignored_index=ignored_index)
+        self.loss = ops.reduce_sum_op(per_row, [0]) * self.scale
+        self.topo = topo_sort([self.loss])
+        self.variables = [n for n in self.topo
+                          if isinstance(n, PlaceholderOp) and n.is_variable]
+
+    def lower(self, ctx, rows, labels, scale, values):
+        """The scaled sum for traced inputs; ``values``: the variables'
+        traced values in ``self.variables``' order.  The head draws no
+        random numbers and writes no state (its context has neither)."""
+        from ..graph.node import LowerCtx
+        env = {self.rows: rows, self.labels: labels, self.scale: scale,
+               **dict(zip(self.variables, values))}
+        sub = LowerCtx(ctx.training, mesh=ctx.mesh)
+        for node in self.topo:
+            if node not in env:
+                env[node] = node.lower(sub, *[env[i] for i in node.inputs])
+        return env[self.loss]
+
+    def fingerprint(self):
+        """The arm's structure by content, for the compiled-step cache:
+        the arm is no input of its op, so the op carries this."""
+        import hashlib
+        from ..graph import step_cache
+        from ..graph.node import PlaceholderOp
+        h = hashlib.sha256()
+        try:
+            step_cache._hash_nodes(
+                h, self.topo, [self.loss],
+                lambda n: n.name if isinstance(n, PlaceholderOp) else "")
+        except step_cache._Uncachable:
+            return self      # no content hash: the step is not cached
+        return h.hexdigest()
+
+
+class LabelledRowsLossOp(Op):
+    """``masked_lm_loss(head(seq), labels)`` — the mean cross-entropy over
+    the labelled positions of ``labels (batch, seq_len)`` — with the head
+    run on labelled rows alone.  Inputs: ``seq (batch·seq_len, h)``,
+    ``labels`` and the head's variables.
+
+    One ROUND gathers, per row of the batch, the hidden states of its
+    next ``capacity`` labelled positions (in order; a row with fewer pads
+    with unlabelled ones) and runs the head over those ``batch·capacity``
+    rows.  The first round always runs; a ``while_loop`` runs as many
+    more as the fullest row needs, so a batch within capacity pays one
+    and a row over it costs a round, not the head over every position.
+    Per row, so that a sharded batch axis stays sharded.  (A capacity of
+    the whole row gathers every position, in order: the plain head.)
+
+    Each round computes its gradients beside its sum, inside the loop
+    (the loss is a scalar: a backward pass only scales them), so nothing
+    but the sums and the gradients themselves outlives a round."""
+
+    op_type = "LabelledRowsLoss"
+
+    def __init__(self, seq, labels, head, batch, seq_len, capacity,
+                 ignored_index=-1, name=None):
+        capacity = min(int(capacity), seq_len)
+        self.arm = _HeadArm(head, batch * capacity, ignored_index)
+        super().__init__([seq, labels] + self.arm.variables, name=name,
+                         batch=batch, seq_len=seq_len, capacity=capacity,
+                         ignored_index=ignored_index,
+                         arm=self.arm.fingerprint())
+
+    def infer_shape(self, input_shapes):
+        return ()
+
+    def fwd_flops(self, gs):
+        """One round's forward matrix products, for the cost model (which
+        walks a graph's own nodes and sees into no arm): a capacity
+        that fits the masking runs no second."""
+        from ..autoparallel.cost_model import graph_layer_spec
+        rows = (self.arm.labels.shape[0], gs.shape(self.inputs[0])[-1])
+        return graph_layer_spec([self.arm.loss],
+                                feeds={self.arm.rows: rows}).fwd_flops
+
+    def _sum(self, ctx, seq, labels, values, with_grads):
+        """``(loss, d loss / d seq, d loss / d values)`` summed over the
+        rounds; the gradients None unless ``with_grads``."""
+        import jax
+        import jax.numpy as jnp
+        b, s, k, ignored = (self.attrs[a] for a in (
+            "batch", "seq_len", "capacity", "ignored_index"))
+        h = seq.shape[-1]
+        seq3 = seq.reshape(b, s, h)
+        held = labels != ignored
+        counts = jnp.sum(held, axis=1)
+        scale = 1.0 / (jnp.sum(counts).astype(jnp.float32) + 1e-6)
+        rounds = -(-jnp.max(counts) // k)
+        # every row's labelled positions in order, then ``s`` for "none"
+        at = jnp.sort(jnp.where(held, jnp.arange(s), s), axis=1)
+        at = jnp.pad(at, ((0, 0), (0, -s % k)), constant_values=s)
+
+        def one(r, d_seq3):
+            """Round ``r``: ``(its sum, d_seq3 with its rows' gradients
+            added, d sum / d values)``."""
+            pos = jax.lax.dynamic_slice_in_dim(at, r * k, k, axis=1)
+            live, pos = pos < s, jnp.minimum(pos, s - 1)
+            rows = jnp.take_along_axis(seq3, pos[:, :, None], axis=1)
+            row_labels = jnp.where(
+                live, jnp.take_along_axis(labels, pos, axis=1), ignored)
+
+            def f(rows, values):
+                return self.arm.lower(ctx, rows.reshape(b * k, h),
+                                      row_labels.reshape(b * k), scale,
+                                      values)
+            if not with_grads:
+                return f(rows, values), None, None
+            total, (d_rows, d_values) = jax.value_and_grad(
+                f, argnums=(0, 1))(rows, values)
+            d_seq3 = jax.vmap(
+                lambda d, p, u: d.at[p].add(u, indices_are_sorted=True))(
+                    d_seq3, pos, d_rows)
+            return total, d_seq3, d_values
+
+        def more(c):
+            r, total, d_seq3, d_values = c
+            t, d_seq3, d_v = one(r, d_seq3)
+            return (r + 1, total + t, d_seq3,
+                    jax.tree.map(jnp.add, d_values, d_v))
+
+        # the first round outside the loop: a batch within capacity, every
+        # batch of a well-set capacity, adds nothing to anything
+        first = one(0, jnp.zeros_like(seq3) if with_grads else None)
+        _, total, d_seq3, d_values = jax.lax.while_loop(
+            lambda c: c[0] < rounds, more, (1, *first))
+        if with_grads:
+            d_seq3 = d_seq3.reshape(b * s, h)
+        return total, d_seq3, d_values
+
+    def lower(self, ctx, seq, labels, *values):
+        from contextlib import nullcontext
+        import jax
+        import jax.numpy as jnp
+        from ..metrics import record_mlm_head_call
+        k, s = self.attrs["capacity"], self.attrs["seq_len"]
+        record_mlm_head_call(k, s, "gathered" if k < s else "all")
+
+        # the node's scope once more, inside: a gradient taken around this
+        # op renames the outer one ``jvp(<scope>)``, which no reader of
+        # scopes matches, and these operations are forward and backward
+        def scope():
+            return jax.named_scope(self.scope) if self.scope \
+                else nullcontext()
+
+        @jax.custom_vjp
+        def loss(seq, labels, values):
+            with scope():
+                return self._sum(ctx, seq, labels, values, False)[0]
+
+        def fwd(seq, labels, values):
+            with scope():
+                total, *grads = self._sum(ctx, seq, labels, values, True)
+            return total, grads
+
+        def bwd(grads, g):
+            d_seq, d_values = jax.tree.map(
+                lambda x: (g * x).astype(x.dtype), grads)
+            return d_seq, None, d_values
+
+        loss.defvjp(fwd, bwd)
+        return loss(seq, labels.astype(jnp.int32), tuple(values))
+
+
+def labelled_rows_lm_loss(seq, labels, head, batch, seq_len, capacity,
+                          ignored_index=-1):
+    """The masked-LM loss of ``head`` — ``head(rows) -> logits`` — over
+    ``seq (batch·seq_len, h)`` and ``labels (batch, seq_len)``: ``(loss,
+    overflow)``, a :class:`LabelledRowsLossOp` and a scalar node a caller
+    may fetch — the rows of the fed batch that carry more than
+    ``capacity`` labels (above 0 the step ran the head more than once:
+    ``capacity`` is too small for the masking)."""
+    loss = LabelledRowsLossOp(seq, labels, head, batch, seq_len, capacity,
+                              ignored_index)
+    return loss, rows_over_capacity_op(
+        labels, capacity=loss.attrs["capacity"], ignored_index=ignored_index)
 
 
 def patchify(images, batch, channels, image_size, patch_size, hidden,

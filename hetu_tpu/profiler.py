@@ -229,7 +229,8 @@ class HetuProfiler:
         """{family: {kind: count}} over EVERY counter family on the
         observability registry in one call (``hetu_tpu.metrics``
         ``all_counts``): flash_fallbacks, flash_calls,
-        flash_head_major, decode_attn_calls, kv_append_calls, moe_calls,
+        flash_head_major, decode_attn_calls, kv_append_calls,
+        mlm_head_calls, moe_calls,
         sparse_attn_calls, ssd_calls,
         emb_pallas_fallbacks, faults, elastic, autoparallel, cache, zero,
         step_cache, compile, setup_us, setup_bytes, run_plan, serve,
@@ -320,6 +321,17 @@ class HetuProfiler:
         13}``.  Per trace."""
         from .metrics import kv_append_call_counts
         return kv_append_call_counts()
+
+    @staticmethod
+    def mlm_head_calls():
+        """{"<rows>of<seq_len>:<gathered|all>": count} of traced
+        masked-LM heads (``models.common.LabelledRowsLossOp``) by the
+        rows they run on: ``80of512:gathered`` = each row's 80 labelled
+        positions of 512 a round (BERT-base at 512), ``32of32:all`` = a
+        capacity of the whole row.  Whether a step needed a second round
+        is the graph's ``loss.mlm_overflow`` fetch.  Per trace."""
+        from .metrics import mlm_head_call_counts
+        return mlm_head_call_counts()
 
     @staticmethod
     def moe_calls():

@@ -22,6 +22,9 @@ def test_train_phase_tiny_on_cpu():
     assert out["run_plan"]["plan_cache_hit"] >= 3
     assert out["flash_in_hlo"] is False        # CPU: the XLA path, counted
     assert set(out["flash_fallbacks"]) == {"backend:cpu"}
+    # 15 % of 64 positions in whole eights: the head ran on 16 rows each
+    assert out["mlm_head"]["rows"] == "16of64:gathered"
+    assert out["mlm_head"]["rows_over_capacity"] == 0
 
 
 def test_decode_phase_tiny_on_cpu():

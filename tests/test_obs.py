@@ -187,6 +187,7 @@ def test_metrics_dump_roundtrips_every_counter_family():
     metrics.record_flash_head_major("bias")
     metrics.record_decode_attn_call(10, 256)
     metrics.record_kv_append_call(16, 640, "kernel")
+    metrics.record_mlm_head_call(80, 512, "gathered")
     metrics.record_moe_call(40, 320, 8)
     metrics.record_sparse_attn_call(96, 64, "kernel")
     metrics.record_ssd_call(1, 64, 64, 128)
@@ -224,6 +225,7 @@ def test_metrics_dump_roundtrips_every_counter_family():
         "flash_head_major": metrics.flash_head_major_counts(),
         "decode_attn_calls": metrics.decode_attn_call_counts(),
         "kv_append_calls": metrics.kv_append_call_counts(),
+        "mlm_head_calls": metrics.mlm_head_call_counts(),
         "moe_calls": metrics.moe_call_counts(),
         "sparse_attn_calls": metrics.sparse_attn_call_counts(),
         "ssd_calls": metrics.ssd_call_counts(),
@@ -255,6 +257,7 @@ def test_metrics_dump_roundtrips_every_counter_family():
     assert legacy["flash_head_major"] == {"bias": 1}
     assert legacy["decode_attn_calls"] == {"10x256": 1}
     assert legacy["kv_append_calls"] == {"16x640:kernel": 1}
+    assert legacy["mlm_head_calls"] == {"80of512:gathered": 1}
     assert legacy["moe_calls"] == {"40of320:top8:ragged": 1}
     assert legacy["sparse_attn_calls"] == {"96x64:kernel": 1}
     assert legacy["ssd_calls"] == {"ssd_step_calls:64x64x128": 1}

@@ -1019,6 +1019,44 @@ def test_granite_programs_fit_and_hold_no_loop_over_the_state(
 
 
 # ------------------------------------------------------------ moe dispatch
+# ------------------------------------------------------------- MLM head
+def test_mlm_head_writes_no_all_position_logits(chip):
+    """ISSUE 47: BERT-base's MLM head at the cell's shape (b 32, s 512,
+    h 768, vocab 30,522, bf16, capacity 80), loss and every gradient,
+    compiled for the described chip: ``[2560,30522]`` tensors and not one
+    of ``[16384,30522]`` (1.0 GB in bf16: what the head wrote every step
+    until PR 47), the further rounds one ``while`` whose carry holds the
+    gradients and nothing with a vocabulary axis beside a row axis."""
+    from hetu_tpu import initializers as init
+    from hetu_tpu.graph.node import LowerCtx, placeholder_op
+    from hetu_tpu.layers.core import LayerNorm, Linear
+    from hetu_tpu.models.common import labelled_rows_lm_loss
+    b, s, h, v, k = 32, 512, 768, 30522, 80
+    normal = init.GenTruncatedNormal(0.0, 0.02)
+    transform = Linear(h, h, activation="gelu", initializer=normal,
+                       name="t.mlm_transform")
+    ln = LayerNorm(h, 1e-12, "t.mlm_ln")
+    decoder = Linear(h, v, initializer=normal, name="t.mlm_decoder")
+    loss, _ = labelled_rows_lm_loss(
+        placeholder_op("seq", shape=(b * s, h)),
+        placeholder_op("labels", shape=(b, s), dtype="int32"),
+        lambda rows: decoder(ln(transform(rows))), b, s, k)
+
+    def head(seq, labels, *values):
+        return loss.lower(LowerCtx(True), seq, labels, *values)
+
+    weights = [chip(n.shape, jnp.bfloat16) for n in loss.inputs[2:]]
+    assert len(weights) == 6
+    text = jax.jit(jax.value_and_grad(
+        head, argnums=(0,) + tuple(range(2, 8)))).lower(
+            chip((b * s, h), jnp.bfloat16), chip((b, s), jnp.int32),
+            *weights).compile().as_text()
+    assert f"[{b * k},{v}]" in text and f"[{b * s},{v}]" not in text
+    (loop,) = [ln for ln in text.splitlines() if " while(" in ln]
+    assert f"[{h},{v}]" in loop                 # the decoder's gradient
+    assert f"[{b * k},{v}]" not in loop
+
+
 # ------------------------------------------------------------ moe dispatch
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])

@@ -79,6 +79,10 @@ def report(t_window=None, out=sys.stderr):
             "decode_step_compile_us": metrics.decode_counts().get(
                 "decode_step_compile_us", 0),
             "programs": mine}
+    heads = HetuProfiler.mlm_head_calls()
+    if heads:       # a training graph with a masked-LM head: its rows
+        say(f"[setup] mlm_head_calls {heads}")
+        blob["mlm_head_calls"] = heads
     if t_window is not None:
         late = [r for r in mine if r["t_end"] > t_window]
         blob["window_decode_step_compile_us"] = sum(
